@@ -171,6 +171,9 @@ def main() -> int:
     from paddlebox_tpu.train import Trainer, TrainerConfig
     from paddlebox_tpu.utils import profiler
 
+    from paddlebox_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     short = "--short" in sys.argv
     telemetry_dir = os.environ.get("PBTPU_TELEMETRY_DIR")
     if telemetry_dir:

@@ -27,11 +27,8 @@ if _os.environ.get("PBTPU_NO_JAX", "").lower() in ("1", "true", "yes"):
     # Pure-host tooling opt-out (the pblint CLI gate sets this): skip the
     # accelerator stack entirely so `python -m paddlebox_tpu.analysis.lint`
     # costs milliseconds, not a jax import. The opt-out must fail LOUDLY
-    # if training code runs under it: jax being installed would otherwise
-    # import fine with the compat shims silently skipped (wrong numerics
-    # on 0.4.x images, NoneType errors deep in the first backward pass) —
-    # so jax imports are blocked outright, and touching jax_compat itself
-    # names the flag.
+    # if training code runs under it, so jax imports are blocked outright
+    # and the error names the flag.
     import sys as _sys
 
     class _JaxBlockedUnderNoJax:
@@ -44,25 +41,6 @@ if _os.environ.get("PBTPU_NO_JAX", "").lower() in ("1", "true", "yes"):
                     "accelerator stack", name=name)
             return None
 
-    class _NoJaxCompat:
-        def __getattr__(self, name):
-            raise RuntimeError(
-                "paddlebox_tpu was imported with PBTPU_NO_JAX=1, so the "
-                "jax_compat shims were skipped (pure-host tooling mode); "
-                f"jax_compat.{name} is unavailable — unset PBTPU_NO_JAX "
-                "for training/inference")
-
     _sys.meta_path.insert(0, _JaxBlockedUnderNoJax())
-    jax_compat = _NoJaxCompat()  # type: ignore[assignment]
-else:
-    try:
-        from paddlebox_tpu import jax_compat as jax_compat  # noqa: F401  (shims first)
-    except ModuleNotFoundError as _e:  # pragma: no cover - jax-less host
-        # A box without jax can still run the pure-host subset (analysis/,
-        # config): only a missing jax/jaxlib is forgiven — any other
-        # import failure inside the shims is a real bug and re-raises.
-        if (_e.name or "").partition(".")[0] not in ("jax", "jaxlib"):
-            raise
-        jax_compat = None  # type: ignore[assignment]
 from paddlebox_tpu import config as config  # noqa: F401
 from paddlebox_tpu.config import flags as flags  # noqa: F401

@@ -207,13 +207,12 @@ _native_cache: list = []
 
 
 def _maybe_native():
-    """Lazy-load the C++ parser; None if the shared lib isn't built."""
+    """The C++ parser binding, or None when its library cannot be built
+    or loaded here (native/loader.py names the reason once)."""
     if not _native_cache:
-        try:
-            from paddlebox_tpu.native import slot_parser_binding
-            _native_cache.append(slot_parser_binding)
-        except Exception:
-            _native_cache.append(None)
+        from paddlebox_tpu.native import slot_parser_binding
+        _native_cache.append(slot_parser_binding
+                             if slot_parser_binding.available() else None)
     return _native_cache[0]
 
 

@@ -23,13 +23,13 @@ scale-out literature grounds (ROADMAP "Sharded embedding scale-out"):
   (``sharded.routed_lookup``). f32 keeps the wire exact (the parity
   baseline and the ``sharded2_wire_f32`` bench point).
 
-The fused Pallas ``gather_pool`` pull (PR 1) runs **per shard after
-routing**: ``routed_pull_pooled`` routes the unique rows, lands them in a
-local (lanes, pull_width) table, and pools per (example, slot) from THAT
-table — the kernel's gather source is the received lanes (the retuned
-``lanes_table`` tile geometry), so the (B*T, pull_width) token matrix
-never materializes on the sharded path either (CPU meshes and
-unsupported geometries run the identical jnp math).
+The fused gather-pool pull runs **per shard after routing**:
+``routed_pull_pooled`` routes the unique rows, lands them in a local
+(lanes, pull_width) table, and pools per (example, slot) from THAT
+table with plain jnp (the Pallas ``gather_pool`` kernel needs a source
+of whole 128-lane tiles; received lanes are pull_width wide). On a TPU
+the trainer selects this route only where that kernel engages, which a
+multi-shard mesh never does — it is the CPU-mesh form of the engine.
 
 The push side mirrors it: when ``resolve_push_engine`` selects the
 fused ``scatter_accumulate`` engine, ``routed_push``'s apply tail
@@ -326,9 +326,7 @@ def routed_pull_pooled(table_shard, idx: jnp.ndarray, cfg: EmbeddingConfig,
     on the sharded mesh. The unique rows route once (plan-keyed when a
     plan rides the batch, device dedup otherwise), land in a local
     (lanes, pull_width) table, and the per-(example, slot) pool gathers
-    FROM THAT local table — on a supported real-TPU geometry through the
-    Pallas ``gather_pool`` kernel, per shard, after routing; elsewhere
-    the identical jnp math. Masked tokens point at the null row's lane,
+    FROM THAT local table. Masked tokens point at the null row's lane,
     whose routed value is the zero row, so padding contributes zeros
     exactly like the single-shard fused path."""
     B = idx.shape[0]
@@ -346,29 +344,12 @@ def routed_pull_pooled(table_shard, idx: jnp.ndarray, cfg: EmbeddingConfig,
     rows, dropped = sharded.routed_lookup(table_shard, uniq, cfg,
                                           axis_name, capacity_factor,
                                           return_dropped=True)
-    pooled = _pool_lanes(rows, inverse.reshape(B, num_slots * slot_len),
-                         cfg, num_slots, slot_len)
+    # pool per (example, slot) from the received-lane table — plain jnp:
+    # the lanes are pull_width columns wide, never the whole 128-lane
+    # tiles the Pallas gather_pool kernel's row DMAs need
+    pooled = jnp.take(rows, inverse, axis=0).reshape(
+        B, num_slots, slot_len, rows.shape[1]).sum(axis=2)
     return (pooled, dropped) if return_dropped else pooled
-
-
-def _pool_lanes(rows: jnp.ndarray, lane_idx: jnp.ndarray,
-                cfg: EmbeddingConfig, num_slots: int,
-                slot_len: int) -> jnp.ndarray:
-    """Per-(example, slot) sum pool gathering from the received-lane
-    table (the per-shard-after-routing half of fused_pull_pool)."""
-    from paddlebox_tpu.ops import pallas_kernels
-    B = lane_idx.shape[0]
-    # lanes_table: the gather source is the received-lane array
-    # (cap*D x pull_width), not the HBM row_width table — the retuned
-    # tile geometry (bigger batch tiles, scratch sized off the actual
-    # lane width; see gather_pool_geometry)
-    if pallas_kernels.gather_pool_supported(cfg, B, num_slots, slot_len,
-                                            rows.shape[1],
-                                            lanes_table=True):
-        return pallas_kernels.gather_pool(rows, lane_idx, cfg, num_slots,
-                                          slot_len, lanes_table=True)
-    take = jnp.take(rows, lane_idx.reshape(-1), axis=0)
-    return take.reshape(B, num_slots, slot_len, rows.shape[1]).sum(axis=2)
 
 
 # ---------------------------------------------------------------------------

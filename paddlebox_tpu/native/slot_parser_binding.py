@@ -1,8 +1,8 @@
 """ctypes binding for the native MultiSlot parser (slot_parser.cc).
 
-Loads ``libslotparser.so`` from this directory, building it with ``make``
-on first use if a toolchain is available (set ``PBTPU_NO_NATIVE_BUILD=1``
-to disable the auto-build). ``parse_lines`` mirrors
+Loads ``libslotparser.so`` from this directory after ``make`` has brought
+it up to date with its source (native/loader.py; ``PBTPU_NO_NATIVE_BUILD=1``
+opts out of the native path altogether). ``parse_lines`` mirrors
 ``parser._parse_python`` exactly — same columnar output, same error
 behavior — so the two paths are interchangeable and tested against each
 other (tests/test_native_parser.py).

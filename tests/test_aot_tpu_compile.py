@@ -1,0 +1,205 @@
+"""The chip's compiler, asked without the chip.
+
+The TPU compiler is installed here and compiles for a v5e that is
+described, not attached (on-chip-measurement guide, section 2). Interpret
+mode — how every other kernel test in this suite runs — accepts what
+Mosaic refuses: row DMAs that break the HBM tiling, SMEM blocks off the
+1024-word tiling, scratch past the VMEM limit. These tests hand every
+Pallas kernel a resolver can select on a TPU to the real compiler at the
+widths the bench matrix names, and hold the geometry functions to the
+compiler's verdict: nothing they accept may be refused.
+
+All compiles run in this process (the worker that describes the topology
+holds libtpu's lock until it exits), in this one file, with the persistent
+compilation cache off — a described-device executable can be written to
+the cache but not read back.
+"""
+
+import functools
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import SingleDeviceSharding
+
+from paddlebox_tpu.config import flags
+from paddlebox_tpu.embedding.config import EmbeddingConfig
+from paddlebox_tpu.ops import pallas_kernels as pk
+
+ROWS = 1 << 19          # bench.py's device-step table
+BATCH, SLOTS = 8192, 26
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 — any failure means no libtpu
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+@pytest.fixture()
+def on_tpu(monkeypatch):
+    """Code that asks jax.default_backend() takes its TPU branch."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _cfg(dim, **kw):
+    return EmbeddingConfig(dim=dim, optimizer="adagrad", learning_rate=0.05,
+                           **kw)
+
+
+def _lane_tiles(cfg):
+    return -(-cfg.row_width // 128) * 128
+
+
+def _compiled_text(fn, one_chip, *shapes, donate=()):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    return jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
+
+
+# ---------------------------------------------------------------------------
+# every kernel a resolver can select, at the bench matrix's widths
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dim,hot,storage", [
+    (8, 1, "f32"), (16, 1, "f32"), (32, 4, "f32"), (8, 1, "int8")])
+def test_binned_merge_acc_compiles(one_chip, dim, hot, storage):
+    """The storage-agnostic merge half of binned_push (quantized planes
+    dequant -> update -> requant around the same accumulator), fed the
+    host plan's token grouping as the pack pipeline stages it."""
+    cfg = _cfg(dim, storage=storage)
+    geom = pk.binned_push_geometry(cfg, ROWS)
+    assert geom is not None and pk.lane_groups(cfg, ROWS) >= 2
+    n_tok, n_blocks = BATCH * SLOTS * hot, geom[1]
+    i32, f32 = jnp.int32, jnp.float32
+
+    def merge(idx, grads, shows, clks, order, rstart, end):
+        return pk.binned_merge_acc(idx, grads, shows, clks, cfg, ROWS,
+                                   n_split=flags.binned_push_splits,
+                                   plan=(order, rstart, end))
+
+    text = _compiled_text(
+        merge, one_chip, ((n_tok,), i32), ((n_tok, cfg.grad_width), f32),
+        ((n_tok,), f32), ((n_tok,), f32), ((n_tok,), i32),
+        ((n_blocks,), i32), ((n_blocks,), i32))
+    assert "tpu_custom_call" in text and "pbtpu_binned_merge_acc" in text
+
+
+@pytest.mark.parametrize("dim,hot", [(32, 4), (64, 1), (128, 1)])
+def test_gather_pool_compiles(one_chip, dim, hot):
+    """The fused pull on a table of whole lane tiles: one (dim 32, 64)
+    and two (dim 128) tiles a row."""
+    cfg = _cfg(dim)
+    W = _lane_tiles(cfg)
+    assert pk.gather_pool_geometry(BATCH, SLOTS, hot, W) is not None
+    text = _compiled_text(
+        lambda t, i: pk.gather_pool(t, i, cfg, SLOTS, hot, interpret=False),
+        one_chip, ((ROWS, W), jnp.float32),
+        ((BATCH, SLOTS * hot), jnp.int32))
+    assert "tpu_custom_call" in text and "pbtpu_gather_pool" in text
+
+
+@pytest.mark.parametrize("dim,hot", [(32, 4), (64, 1), (128, 1)])
+def test_scatter_accumulate_compiles(one_chip, dim, hot):
+    """The fused push over premerged lanes, aliased in place."""
+    cfg = _cfg(dim)
+    W = _lane_tiles(cfg)
+    assert pk.scatter_accumulate_geometry(ROWS, W) is not None
+    n = BATCH * SLOTS * hot
+    f32 = jnp.float32
+    text = _compiled_text(
+        lambda t, i, g, s, c: pk.scatter_accumulate(t, i, g, s, c, cfg,
+                                                    interpret=False),
+        one_chip, ((ROWS, W), f32), ((n,), jnp.int32),
+        ((n, cfg.grad_width), f32), ((n,), f32), ((n,), f32), donate=(0,))
+    assert "tpu_custom_call" in text and "pbtpu_scatter_accumulate" in text
+
+
+@pytest.mark.parametrize("dim", [8, 64])
+def test_merge_update_compiles(one_chip, dim):
+    """The table-update scan: PBTPU_PALLAS=1 at narrow widths, and what
+    sharded.push picks on a TPU for accumulators of 64 lanes and more."""
+    cfg = _cfg(dim)
+    text = _compiled_text(
+        lambda t, a: pk.merge_update(t, a, cfg, interpret=False),
+        one_chip, ((ROWS, cfg.row_width), jnp.float32),
+        ((ROWS, cfg.grad_width + 3), jnp.float32))
+    assert "tpu_custom_call" in text and "pbtpu_merge_update" in text
+
+
+# ---------------------------------------------------------------------------
+# what the compiler refuses, the geometry refuses
+# ---------------------------------------------------------------------------
+
+def _one_row_dma(table, idx):
+    """The fused kernels' access pattern on a plain 2-D table: one
+    (1, W) row per DMA — what they did before the (n, 1, W) row view."""
+    n_rows, W = table.shape
+
+    def kernel(idx_ref, table_ref, out_ref, sem):
+        cp = pltpu.make_async_copy(table_ref.at[pl.ds(idx_ref[0], 1), :],
+                                   out_ref, sem)
+        cp.start()
+        cp.wait()
+
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((1, W), table.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, W), lambda i, *_: (0, 0)),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
+    )(idx, table)
+
+
+@pytest.mark.parametrize("dim", [8, 32, 128])
+def test_logical_row_widths_are_refused(one_chip, dim):
+    """At the logical row widths (13, 37, 133 columns) the compiler
+    refuses the per-row DMA, so the geometry functions return None and
+    the resolvers name other engines openly."""
+    W = _cfg(dim).row_width
+    assert W % 128
+    assert pk.gather_pool_geometry(BATCH, SLOTS, 1, W) is None
+    assert pk.scatter_accumulate_geometry(ROWS, W) is None
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compiled_text(_one_row_dma, one_chip, ((ROWS, W), jnp.float32),
+                       ((1,), jnp.int32))
+
+
+@pytest.mark.parametrize("table_width,push,pull_kernel", [
+    (37, "binned_kernel", False),       # flags.table_pad_width = 0
+    (128, "scatter_accumulate", True),  # flags.table_pad_width = 128
+])
+def test_resolvers_follow_the_geometry(on_tpu, table_width, push,
+                                       pull_kernel):
+    """On a TPU the engine record names what compiles: the 4-hot dim-32
+    layout keeps the binned push and the unfused pull at its logical
+    width, and takes both fused kernels on a lane-tile table."""
+    cfg = _cfg(32)
+    assert pk.resolve_push_engine(cfg, ROWS, premerged=True,
+                                  table_width=table_width) == push
+    assert pk.gather_pool_supported(cfg, BATCH, SLOTS, 4,
+                                    table_width) is pull_kernel

@@ -42,10 +42,11 @@ def test_gate_trips_on_unwaived_regression(bench):
 def test_gate_honors_waiver_note(bench):
     best = {"device_kind": None,
             "metrics": {"headline_eps": 1000.0},
-            "waivers": {"headline_eps": "known tunnel variance"}}
+            "waivers": {"headline_eps": "known host-load variance"}}
     g = bench.apply_regression_gate({"headline_eps": 500.0}, best, "cpu")
     assert g["ok"]
-    assert "waived: known tunnel variance" in g["lines"]["headline_eps"]
+    assert "waived: known host-load variance" in \
+        g["lines"]["headline_eps"]
 
 
 def test_gate_within_threshold_passes(bench):
@@ -202,15 +203,57 @@ def test_gate_latency_floor_ignores_timer_noise(bench):
     assert not g2["ok"] and g2["regressed"] == ["serving.swap_pause_ms"]
 
 
-def test_committed_bench_best_is_wellformed():
-    with open(os.path.join(REPO, "BENCH_BEST.json")) as f:
-        best = json.load(f)
-    assert best["device_kind"] == "TPU v5 lite"
-    assert 0 < best["threshold"] <= 0.5
-    assert best["metrics"]["headline_eps"] > 1e6, \
-        "the recorded best headline predates the round-5 regression"
-    for name, note in best.get("waivers", {}).items():
-        assert name in best["metrics"] and len(note) > 10
+def _fake_step_bench(fail_dim):
+    """device_step_bench stand-in: one canned point per call, and a
+    forced failure at `fail_dim`."""
+    def fake(small, return_ctx=False, emb_dim=8, **kw):
+        if emb_dim == fail_dim:
+            raise RuntimeError(f"forced failure at dim {emb_dim}")
+        detail = {"device_kind": "cpu", "devices": 1,
+                  "audit": {"ok": True, "step_seconds": 0.01},
+                  "push_engine": "xla_scatter",
+                  "pull_engine": "gather_seqpool", "pack_engine": None,
+                  "push_overlap": "on", "table_layout": "single",
+                  "exchange_wire": "-", "table_shards": 1}
+        ctx = {"mode": "allreduce", "n_dev": 1}
+        return (100.0, detail, ctx) if return_ctx else (100.0, detail)
+    return fake
+
+
+@pytest.mark.parametrize("fail_dim,want_rc", [(64, 3), (None, 0)])
+def test_failed_matrix_point_fails_exit_code(bench, monkeypatch, capsys,
+                                             fail_dim, want_rc):
+    """A matrix point that raises is recorded as {"error": ...} and the
+    artifact still prints — but the run exits non-zero: a section that
+    did not run must not hide inside a green exit code."""
+    from paddlebox_tpu.utils import compile_cache
+    monkeypatch.setattr(compile_cache, "enable_compile_cache",
+                        lambda: {"dir": None, "from": "test",
+                                 "warm": False})
+    monkeypatch.setattr(bench, "device_step_bench",
+                        _fake_step_bench(fail_dim))
+    monkeypatch.setattr(sys, "argv", ["bench.py"])
+    monkeypatch.setenv("PBTPU_BENCH_SMALL", "1")
+    monkeypatch.setenv("PBTPU_BENCH_BEST", os.devnull)
+    monkeypatch.setenv("PBTPU_BENCH_MATRIX_ATTR", "")
+    for section in ("ATTR", "SHARDED", "SPILL", "ELASTIC", "SERVING",
+                    "HOST", "E2E"):
+        monkeypatch.setenv(f"PBTPU_BENCH_{section}", "0")
+    rc = 0
+    try:
+        bench.main()
+    except SystemExit as e:
+        rc = e.code
+    assert rc == want_rc
+    out = capsys.readouterr()
+    artifact = json.loads(out.out.strip().splitlines()[0])
+    matrix = artifact["detail"]["matrix"]
+    assert "examples_per_sec_per_chip" in matrix["kstep_f32"]
+    if fail_dim is None:
+        assert "examples_per_sec_per_chip" in matrix["allreduce_f32_dim64"]
+    else:
+        assert "forced failure" in matrix["allreduce_f32_dim64"]["error"]
+        assert "matrix.allreduce_f32_dim64" in out.err
 
 
 def test_bench_dryrun_smoke():

@@ -22,6 +22,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from paddlebox_tpu import monitor
@@ -96,8 +97,11 @@ def test_pull_bit_identical_2shard(mesh2):
     plan = _device_plans(idx, ws.padded_rows, 2)
 
     def body(tshard, i, *p):
-        return exchange.routed_pull(tshard, i, c, ("dp",), 2.0, plan=p,
-                                    return_dropped=True)
+        # the per-shard drop count is psummed to the global one, as the
+        # trainer's step does — that is what makes it replicated (P())
+        out, dropped = exchange.routed_pull(tshard, i, c, ("dp",), 2.0,
+                                            plan=p, return_dropped=True)
+        return out, lax.psum(dropped, "dp")
 
     out, dropped = jax.jit(jax.shard_map(
         body, mesh=mesh2, in_specs=(P("dp"),) * 7,
@@ -132,9 +136,9 @@ def test_pull_pooled_bit_identical_2shard(mesh2):
     plan = _device_plans(idx.reshape(-1), ws.padded_rows, 2)
 
     def body(tshard, i, *p):
-        return exchange.routed_pull_pooled(tshard, i, c, ("dp",), S, L,
-                                           2.0, plan=p,
-                                           return_dropped=True)
+        pooled, dropped = exchange.routed_pull_pooled(
+            tshard, i, c, ("dp",), S, L, 2.0, plan=p, return_dropped=True)
+        return pooled, lax.psum(dropped, "dp")
 
     pooled, dropped = jax.jit(jax.shard_map(
         body, mesh=mesh2, in_specs=(P("dp"),) * 7,

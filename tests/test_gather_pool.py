@@ -26,10 +26,14 @@ from paddlebox_tpu.ops.seqpool_cvm import (PooledSlots,
 
 def _mk(B=4, S=3, L=2, dim=4, n=64, seed=0, mask_p=0.7):
     """Table with counter-like show/clk (CVM logs need nonneg pools) and
-    the NULL-row contract (row 0 all zeros, like a pass working set)."""
+    the NULL-row contract (row 0 all zeros, like a pass working set),
+    padded to whole 128-lane tiles — the device-table form the kernel's
+    row DMAs take (flags.table_pad_width); the pad columns carry junk
+    the pool must never read."""
     cfg = EmbeddingConfig(dim=dim, optimizer="adagrad", learning_rate=0.05)
     rng = np.random.default_rng(seed)
-    table = rng.normal(size=(n, cfg.row_width)).astype(np.float32)
+    width = -(-cfg.row_width // 128) * 128
+    table = rng.normal(size=(n, width)).astype(np.float32)
     table[:, 0] = rng.integers(0, 20, size=n)        # show
     table[:, 1] = rng.integers(0, 5, size=n)         # clk
     table[0] = 0.0
@@ -52,7 +56,7 @@ def _ref_pulled(table, idx, mask, cfg):
 @pytest.mark.parametrize("B,S,L,dim", [
     (4, 3, 2, 4),      # multi-hot
     (8, 5, 1, 4),      # one-hot (L=1), >8 in-flight DMAs per tile
-    (4, 2, 3, 128),    # wide rows: >128-lane gathered scratch
+    (4, 2, 3, 128),    # wide rows: two lane tiles per row (W=256)
 ])
 def test_kernel_interpret_matches_reference_pool(B, S, L, dim):
     cfg, table, idx, mask, seg = _mk(B=B, S=S, L=L, dim=dim)
@@ -212,44 +216,36 @@ def test_fused_pull_pool_reference_path_matches_lookup():
 def test_gather_pool_geometry_bounds():
     # the tile divides the batch (odd batches degrade to BB=1, still
     # valid); absurd widths fall back
-    assert pallas_kernels.gather_pool_geometry(8, 3, 2, 13) is not None
-    assert pallas_kernels.gather_pool_geometry(7, 3, 2, 13) == 1
+    assert pallas_kernels.gather_pool_geometry(8, 3, 2, 128) is not None
+    assert pallas_kernels.gather_pool_geometry(7, 3, 2, 128) == 1
     assert pallas_kernels.gather_pool_geometry(8, 3, 2, 1024) is None
     # wide rows shrink the tile instead of overflowing VMEM
     bb = pallas_kernels.gather_pool_geometry(4096, 26, 4, 128)
     assert bb is not None and 4096 % bb == 0
+    wide = pallas_kernels.gather_pool_geometry(4096, 26, 4, 512)
+    assert wide is not None and wide < bb
 
 
-def test_gather_pool_geometry_lanes_table_retune():
-    """The routed path's received-lane geometry (ISSUE 13 satellite):
-    the gather source is the cap*D x pull_width lane array, not the
-    n_rows x row_width HBM table the 64-row cap was tuned on — narrow
-    lane sources take bigger batch tiles (fewer grid prologues), the
-    same VMEM budget rule still bounds wide ones."""
-    # narrow received lanes: the tile cap doubles past the HBM tuning
-    bb_hbm = pallas_kernels.gather_pool_geometry(256, 3, 2, 13)
-    bb_lan = pallas_kernels.gather_pool_geometry(256, 3, 2, 13,
-                                                 lanes_table=True)
-    assert bb_hbm == 64 and bb_lan == 128
-    # the budget rule is unchanged: wide lane sources shrink the tile
-    wide = pallas_kernels.gather_pool_geometry(4096, 26, 4, 128,
-                                               lanes_table=True)
-    assert wide is not None and wide <= 64
-    assert pallas_kernels.gather_pool_geometry(8, 3, 2, 1024,
-                                               lanes_table=True) is None
+@pytest.mark.parametrize("width", [13, 37, 69, 133, 200])
+def test_gather_pool_geometry_refuses_partial_lane_tiles(width):
+    """The v5e compiler refuses row DMAs narrower or wider than whole
+    128-lane tiles (tests/test_aot_tpu_compile.py holds the compiler's
+    side), so the geometry must too — the logical row widths of dim
+    8/32/64/128 tables among them."""
+    assert pallas_kernels.gather_pool_geometry(4096, 26, 4, width) is None
 
 
-def test_gather_pool_kernel_parity_at_lanes_table_tile():
-    """Kernel parity at a lanes-table tile the HBM cap would never pick
-    (BB=128): the retuned geometry must change only the tiling, never
-    the pooled sums."""
+def test_gather_pool_kernel_parity_across_batch_tiles():
+    """Two batch tiles (B=128 at BB=64): each tile's ids ride their own
+    SMEM window, padded from BB*T words to a whole 1024-word block — the
+    padding must change only the tiling, never the pooled sums."""
     cfg, table, idx, mask, seg = _mk(B=128, S=1, L=1, dim=4, n=64,
                                      seed=9)
     idx0 = np.where(mask, idx, 0).astype(np.int32)
     assert pallas_kernels.gather_pool_geometry(
-        128, 1, 1, int(table.shape[1]), lanes_table=True) == 128
+        128, 1, 1, int(table.shape[1])) == 64
     out = pallas_kernels.gather_pool(table, jnp.asarray(idx0), cfg, 1, 1,
-                                     lanes_table=True, interpret=True)
+                                     interpret=True)
     P = cfg.pull_width
     ref = np.asarray(table)[idx0.reshape(-1), :P].reshape(
         128, 1, 1, P).sum(axis=2)

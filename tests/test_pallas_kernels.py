@@ -50,12 +50,7 @@ def test_vma_plumbing_api_canary():
     in CI instead of erroring first on a TPU pod."""
     import jax
     from jax.sharding import PartitionSpec as P
-    from paddlebox_tpu import jax_compat
     from paddlebox_tpu.parallel import make_mesh
-
-    if jax_compat.LEGACY_SHARD_MAP:
-        pytest.skip("pre-vma jax (0.4.x shim): no vma to plumb — every "
-                    "vma consumer getattr-defaults to frozenset()")
 
     mesh = make_mesh(8)
     axes = tuple(mesh.axis_names)

@@ -50,9 +50,14 @@ def _cfg(**kw):
     return EmbeddingConfig(**kw)
 
 
-def _table(cfg, n_rows, seed=0, pad_cols=0):
+def _table(cfg, n_rows, seed=0, width=None):
+    """Device table at `width` columns — by default whole 128-lane
+    tiles, the form the kernel's row DMAs take (flags.table_pad_width).
+    The pad columns carry junk: they must ride the update untouched."""
     rng = np.random.default_rng(seed)
-    t = (rng.integers(-512, 512, size=(n_rows, cfg.row_width + pad_cols))
+    if width is None:
+        width = -(-cfg.row_width // 128) * 128
+    t = (rng.integers(-512, 512, size=(n_rows, width))
          / 1024.0).astype(np.float32)
     t[:, 0] = rng.integers(0, 20, size=n_rows)       # show
     t[:, 1] = rng.integers(0, 5, size=n_rows)        # clk
@@ -187,25 +192,38 @@ def test_all_pad_batch_leaves_table_bit_identical():
 
 
 def test_padded_table_width_columns_pass_through():
-    """Physical tables padded past row_width (table_pad_width): pad
-    columns ride apply_updates untouched, same as the scatter engine."""
+    """Pad columns past row_width ride apply_updates untouched, same as
+    the scatter engine — in the kernel at whole lane tiles, and in the
+    jnp reference at any width (table_pad_width=row_width+5)."""
     c = _cfg()
-    table = _table(c, 64, seed=6, pad_cols=5)
     idx, grads, shows, clks = _tokens(c, 64, 120, seed=8)
-    ref = np.asarray(sharded.push(table, jnp.asarray(idx),
-                                  jnp.asarray(grads), jnp.asarray(shows),
-                                  jnp.asarray(clks), c))
     uniq, mg, ms, mc, _ = _premerged(c, idx, grads, shows, clks, 64)
-    out = pk.scatter_accumulate(table, uniq, mg, ms, mc, c,
-                                interpret=True)
-    np.testing.assert_array_equal(np.asarray(out), ref)
+    for width, interpret in ((None, True), (c.row_width + 5, None)):
+        table = _table(c, 64, seed=6, width=width)
+        ref = np.asarray(sharded.push(table, jnp.asarray(idx),
+                                      jnp.asarray(grads),
+                                      jnp.asarray(shows),
+                                      jnp.asarray(clks), c))
+        out = pk.scatter_accumulate(table, uniq, mg, ms, mc, c,
+                                    interpret=interpret)
+        np.testing.assert_array_equal(np.asarray(out), ref)
+        np.testing.assert_array_equal(
+            np.asarray(out)[:, c.row_width:],
+            np.asarray(table)[:, c.row_width:])
 
 
 def test_geometry_bounds():
-    assert pk.scatter_accumulate_geometry(64, 13) is not None
+    assert pk.scatter_accumulate_geometry(64, 128) is not None
     assert pk.scatter_accumulate_geometry(64, 512) is not None
-    assert pk.scatter_accumulate_geometry(64, 513) is None   # width cap
-    assert pk.scatter_accumulate_geometry(0, 13) is None
+    assert pk.scatter_accumulate_geometry(64, 640) is None   # width cap
+    assert pk.scatter_accumulate_geometry(0, 128) is None
+    # partial lane tiles: what the v5e compiler refuses
+    # (tests/test_aot_tpu_compile.py) the geometry refuses too
+    for width in (13, 37, 69, 133):
+        assert pk.scatter_accumulate_geometry(64, width) is None
+    # off-TPU the forced engine runs the jnp reference at any width
+    assert pk.scatter_accumulate_supported(64, 13)
+    assert not pk.scatter_accumulate_supported(64, 1024)
 
 
 # ---------------------------------------------------------------------------
