@@ -384,10 +384,10 @@ class Flags:
     trace_run_id: str = ""                  # (new)
     # Per-pass-window DEVICE capture: start a jax.profiler trace at
     # every sampled begin_pass and stop it at end_pass, dumping under
-    # trace_device_dir/pass-NNNNN — linked to the host spans by the
-    # pass/step markers both carry. No-op off TPU (and any profiler
-    # failure is counted, never raised: tracing must not take down
-    # training).
+    # trace_device_dir/pass-NNNNN, with the program's spans inside it
+    # as pbtpu/<name> annotations (`python -m paddlebox_tpu.monitor.trace
+    # --device` reads it). Any backend; a profiler failure is counted
+    # and warned once, never raised: tracing must not take down training.
     trace_device: bool = False              # (new)
     trace_device_dir: str = ""              # (new) "" = <tmp>/pbtpu_device_trace
     # --- serving observability (new — serving/obs.py, ISSUE 19) ---

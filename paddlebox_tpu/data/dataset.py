@@ -27,6 +27,7 @@ from paddlebox_tpu.data.schema import DataFeedSchema
 from paddlebox_tpu.data.slot_record import PackedBatch, SlotRecordBatch, batch_iterator
 from paddlebox_tpu.data.shuffle import LocalShuffler, RoutingMode, TcpShuffleService, route_records
 from paddlebox_tpu.monitor import counter_add as stat_add
+from paddlebox_tpu.monitor import span as mon_span
 
 
 class SlotDataset:
@@ -80,6 +81,7 @@ class SlotDataset:
 
     # ---- ingest (LoadIntoMemory, data_set.cc:1780) ----
 
+    @mon_span("ingest")
     def load_into_memory(self, global_shuffle: bool = True,
                          routing: RoutingMode = "random") -> None:
         n_threads = min(flags.dataset_load_thread_num, max(1, len(self.filelist)))

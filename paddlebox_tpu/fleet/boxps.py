@@ -95,6 +95,7 @@ class BoxPS:
     def set_date(self, date: int) -> None:
         self.date = int(date)
 
+    @monitor.span("box_begin_pass")
     def begin_pass(self) -> None:
         if self.in_pass:
             raise RuntimeError("begin_pass while a pass is open")
@@ -138,6 +139,30 @@ class BoxPS:
         if not self.in_pass:
             raise RuntimeError("end_pass without begin_pass")
         self.in_pass = False
+        out = self._close_pass(need_save_delta, delta_path, checkpointer,
+                               trainer, dataset, publisher)
+        # flight-record commit LAST: checkpoint/delta durations and bytes
+        # above land in this pass's stats_delta and event stream
+        out["flight_record"] = monitor.hub().end_pass(metrics=self.metrics)
+        # live doctor (flags.doctor_live): end_pass above ran the rule
+        # set over the committed records and emitted doctor.finding
+        # events; surface the findings to the driver too — the operator
+        # loop reads the end_pass dict, not the event stream
+        findings = monitor.hub().last_doctor_findings
+        if findings:
+            out["doctor"] = findings
+        if self._heartbeat is not None:
+            self._heartbeat.publish()
+        if self._col is not None:
+            self._col.barrier("end_pass")
+        return out
+
+    @monitor.span("box_end_pass")
+    def _close_pass(self, need_save_delta, delta_path, checkpointer,
+                    trainer, dataset, publisher) -> dict[str, Any]:
+        """What end_pass does for the pass before its flight record
+        commits — one span, closed while the pass scope is still open so
+        that its record carries the pass."""
         out: dict[str, Any] = {"pass_id": self.pass_id,
                                "seconds": time.time() - self._pass_t0}
         if checkpointer is not None:
@@ -203,20 +228,6 @@ class BoxPS:
             healed = trainer.remediation_boundary()
             if healed is not None:
                 out["remediation"] = healed
-        # flight-record commit LAST: checkpoint/delta durations and bytes
-        # above land in this pass's stats_delta and event stream
-        out["flight_record"] = monitor.hub().end_pass(metrics=self.metrics)
-        # live doctor (flags.doctor_live): end_pass above ran the rule
-        # set over the committed records and emitted doctor.finding
-        # events; surface the findings to the driver too — the operator
-        # loop reads the end_pass dict, not the event stream
-        findings = monitor.hub().last_doctor_findings
-        if findings:
-            out["doctor"] = findings
-        if self._heartbeat is not None:
-            self._heartbeat.publish()
-        if self._col is not None:
-            self._col.barrier("end_pass")
         return out
 
     def flip_phase(self) -> None:

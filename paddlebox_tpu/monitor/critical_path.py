@@ -2,9 +2,10 @@
 getting worse.
 
 The flight record carries the raw account: the pass wall (``seconds``),
-the trainer's main-thread stage split (``stage_seconds``: read wait,
-train dispatch, auc, post-loop drain; ``translate`` runs on the pack
-thread and OVERLAPS), and since ISSUE 12 the pass-boundary cost
+the trainer's main-thread stage split (``stage_seconds``: unique_keys,
+preplan, read wait, batch H2D, train dispatch, auc, post-loop drain;
+``translate`` runs on the pack thread and OVERLAPS; ``head`` and
+``close`` ENCLOSE others), and since ISSUE 12 the pass-boundary cost
 (``extra.boundary_seconds`` — working-set build + H2D — with its
 ``boundary_split``: build vs H2D vs spill fault-in). This module turns
 that into the statement an operator acts on: the **limiter** (the
@@ -26,6 +27,13 @@ from __future__ import annotations
 # count the interval the train stage already covers)
 OVERLAPPED_STAGES = ("translate",)
 
+# stage_seconds keys that ENCLOSE other components on the main thread
+# ("head": train_pass entry to the first step's dispatch, holding
+# unique_keys, the boundary, preplan, the first read and h2d; "close":
+# the pending apply, end_pass, drain and the AUC read) — reported beside
+# the composition, never summed into it
+NESTED_STAGES = ("head", "close")
+
 # components eligible to be the limiter, largest-first tie broken by
 # this order (boundary first: it is the one with a named fix)
 LIMITER_ORDER = ("boundary", "train", "read", "drain", "auc")
@@ -38,8 +46,10 @@ def attribute_pass(fr: dict) -> dict:
     stages = dict(fr.get("stage_seconds") or {})
     comp: dict[str, float] = {}
     overlapped: dict[str, float] = {}
+    nested: dict[str, float] = {}
     for name, v in stages.items():
-        (overlapped if name in OVERLAPPED_STAGES else comp)[name] = \
+        (overlapped if name in OVERLAPPED_STAGES
+         else nested if name in NESTED_STAGES else comp)[name] = \
             round(float(v), 6)
     boundary = float(extra.get("boundary_seconds") or 0.0)
     comp["boundary"] = round(boundary, 6)
@@ -54,6 +64,7 @@ def attribute_pass(fr: dict) -> dict:
         "wall_seconds": round(wall, 6),
         "stages": comp,
         "overlapped": overlapped,
+        "nested": nested,
         "unattributed_seconds": round(max(0.0, wall - attributed), 6),
         "coverage": round(attributed / wall, 4) if wall > 0 else 0.0,
         "limiter": limiter,

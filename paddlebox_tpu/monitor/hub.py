@@ -14,7 +14,9 @@ Cost model: the hub is DISABLED by default and the disabled path is one
 attribute check (asserted by a micro-test) — instrumentation stays in the
 code permanently, like ``STAT_ADD`` in the reference. Counters/gauges are
 always live (they are the pre-existing ``STATS`` registry); the *event
-stream* is what enabling turns on.
+stream* is what enabling turns on. A span scope is besides always a
+profiler annotation (:func:`annotate`): inert, at about a microsecond,
+until somebody opens a ``jax.profiler`` capture.
 
 Pass lifecycle: ``begin_pass`` snapshots the cumulative counters;
 ``end_pass`` commits a **flight record** — stage-time split, examples/sec,
@@ -29,16 +31,19 @@ flight records.
 from __future__ import annotations
 
 import collections
+import functools
 import re
 import threading
 import time
 
 from paddlebox_tpu.monitor import context
+from paddlebox_tpu.monitor.names import ANNOTATION_PREFIX
 from paddlebox_tpu.monitor.registry import STATS
 from paddlebox_tpu.monitor.sinks import Sink  # noqa: F401  (re-export)
 
 _prof = None
 _trace = None
+_annotation_cls = None
 
 
 def _profiler():
@@ -63,15 +68,39 @@ def _tracer():
     return _trace
 
 
+def annotate(name: str):
+    """Enter and return the profiler annotation ``pbtpu/<name>`` carrying
+    the current pass and step, so that any ``jax.profiler`` capture (a
+    benchmark's own, an operator's ``flags.trace_device``) holds the
+    program's timeline on the device trace's clock. Always on: outside a
+    capture the scope costs about a microsecond (micro-test), so there is
+    no flag for it. The parent of a span is whatever span encloses it on
+    its thread."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    c = context.current()
+    if c.pass_id is None:
+        ann = _annotation_cls(ANNOTATION_PREFIX + name)
+    else:
+        ann = _annotation_cls(ANNOTATION_PREFIX + name,
+                              pass_id=c.pass_id, step=c.step)
+    ann.__enter__()
+    return ann
+
+
 class _Span:
-    """Timed scope: chrome-trace span (when the profiler is on) + hub span
-    event (when the hub is on). Disabled cost: two module-global checks
-    (a third — ``trace._ACTIVE`` — only on the already-enabled path).
+    """Timed scope: profiler annotation (always; see :func:`annotate`) +
+    chrome-trace span (when the profiler ring is on) + hub span event
+    (when the hub is on). Disabled cost: the annotation and two
+    module-global checks (a third — ``trace._ACTIVE`` — only on the
+    already-enabled path).
     Inside a traced pass the scope additionally pushes a span id onto
     the trace stack, so the committed record carries its own
     ``span_id`` + ``parent_span_id`` (the world-trace parent links)."""
 
-    __slots__ = ("_hub", "_name", "_fields", "_t0", "_trace")
+    __slots__ = ("_hub", "_name", "_fields", "_t0", "_trace", "_ann")
 
     def __init__(self, hub, name, fields):
         self._hub = hub
@@ -79,6 +108,7 @@ class _Span:
         self._fields = fields
 
     def __enter__(self):
+        self._ann = annotate(self._name)
         if self._hub._enabled or _profiler()._enabled:
             self._t0 = time.perf_counter()
             tr = _tracer()
@@ -90,6 +120,7 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
+        self._ann.__exit__(None, None, None)
         t0 = self._t0
         if t0 is None:
             return False
@@ -109,10 +140,10 @@ class _Span:
         return False
 
     def __call__(self, fn):
+        @functools.wraps(fn)
         def wrapped(*a, **kw):
             with _Span(self._hub, self._name, self._fields):
                 return fn(*a, **kw)
-        wrapped.__name__ = getattr(fn, "__name__", self._name)
         return wrapped
 
 

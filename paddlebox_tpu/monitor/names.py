@@ -17,6 +17,10 @@ where a reviewer sees the telemetry surface grow.
 
 from __future__ import annotations
 
+# a span name's profiler annotation is this prefix plus the name
+# (hub.annotate writes it, ``monitor.trace --device`` reads it back)
+ANNOTATION_PREFIX = "pbtpu/"
+
 # event names (monitor.event / hub.event emissions across the tree)
 EVENT_NAMES: tuple[str, ...] = (
     # pass lifecycle (hub / boxps)
@@ -116,17 +120,43 @@ EVENT_NAMES: tuple[str, ...] = (
 )
 
 # span names (monitor.span scopes + the StageTimers "stage/<name>"
-# emissions — the trainer's emit_stages set)
+# emissions of the stages that share no span). Each is also the profiler
+# annotation ``pbtpu/<name>`` (hub.annotate), which is what
+# ``monitor.trace --device`` and PERF.md's layer table key off.
 SPAN_NAMES: tuple[str, ...] = (
-    "pack_batch",
-    "train_step",
-    "auc_update",
-    "push_apply",
-    "h2d_stage",
-    "publish",
+    # one pass on the training thread, in order: the root, the head's
+    # stages, the step loop, the close (PERF.md section 3 names the
+    # metric each is for)
+    "train_pass",
+    "unique_keys",
+    "boundary",
+    "boundary/diff",
+    "boundary/wait_feed",
+    "boundary/fetch",
+    "boundary/h2d",
+    "boundary/build",
+    "boundary/writeback",
+    "boundary/combine",
+    "boundary/land",
+    "preplan",
     "stage/read",
-    "stage/translate",
+    "h2d_stage",
+    "train_step",
+    "push_apply",
+    "auc_update",
+    "pass_close",
+    "pass_close/rebind",
+    "pass_close/end_pass",
     "stage/drain",
+    "pass_close/read",
+    # the pack thread
+    "stage/translate",
+    # around the pass: ingest (the caller's or the preload thread) and
+    # the BoxPS lifecycle calls
+    "ingest",
+    "box_begin_pass",
+    "box_end_pass",
+    "publish",
     # serving request spans (serving/frontend.py + server.py, sampled by
     # flags.serving_trace_sample): batch-coalesce wait vs. score time
     "serve/wait",

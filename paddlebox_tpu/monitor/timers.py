@@ -8,7 +8,12 @@ feed the per-pass flight record's stage split (the trainer diffs them at
 pass boundaries), and when the hub's event stream is on each stage scope
 additionally emits a tagged span event — so the "read" wait, the pack
 thread's "translate", and the post-loop "drain" all land in the JSONL
-with their pass/step identity. Disabled cost: one global check per scope
+with their pass/step identity; the scope is besides a profiler
+annotation ``pbtpu/<prefix>/<stage>`` (``hub.annotate``), like every
+``monitor.span``. A stage whose interval is also a registered span
+(``timers("train", span="train_step")``) is one scope for both: the span
+carries the annotation and the events, the stage only the total.
+Disabled cost: the annotation and one global check per scope
 (``utils.timer`` re-exports this class for back-compat).
 """
 
@@ -17,39 +22,41 @@ from __future__ import annotations
 import contextlib
 import time
 
-from paddlebox_tpu.monitor.hub import _HUB
+from paddlebox_tpu.monitor.hub import _HUB, _Span, annotate
 
 
 class StageTimers:
-    def __init__(self, stages: list[str], emit_prefix: str = "stage",
-                 emit_stages: set | None = None):
-        """``emit_stages``: stages whose scopes emit hub span events (None
-        = all). Totals accumulate for EVERY stage regardless — callers
-        exclude stages another span already covers (e.g. the trainer's
-        "train" scope wraps the same interval as its ``train_step`` span)
-        so the hot loop never double-emits one measurement."""
+    def __init__(self, stages: list[str], emit_prefix: str = "stage"):
         self.total: dict[str, float] = {s: 0.0 for s in stages}
         self.count: dict[str, int] = {s: 0 for s in stages}
         self._emit_prefix = emit_prefix
-        self._emit_stages = emit_stages
 
     @contextlib.contextmanager
-    def __call__(self, stage: str):
+    def __call__(self, stage: str, span: str | None = None):
+        """Time one scope of `stage`. With `span`, the scope is that
+        ``monitor.span`` too and the stage emits nothing of its own, so
+        one interval is never measured or emitted twice."""
+        if span is None:
+            scope = annotate(f"{self._emit_prefix}/{stage}")
+        else:
+            scope = _Span(_HUB, span, {}).__enter__()
         t0 = time.perf_counter()
         try:
             yield
         finally:
-            t1 = time.perf_counter()
-            dt = t1 - t0
-            self.total[stage] = self.total.get(stage, 0.0) + dt
-            self.count[stage] = self.count.get(stage, 0) + 1
-            h = _HUB
-            if h._enabled and (self._emit_stages is None
-                               or stage in self._emit_stages):
-                rec = h._record("span", f"{self._emit_prefix}/{stage}",
-                                None)
-                rec["dur_s"] = dt
-                h._dispatch(rec)
+            self.add(stage, time.perf_counter() - t0, emit=span is None)
+            scope.__exit__(None, None, None)
+
+    def add(self, stage: str, seconds: float, emit: bool = False) -> None:
+        """Account `seconds` to `stage`: for a stage that is not one
+        scope (the trainer's ``head`` ends inside the step loop)."""
+        self.total[stage] = self.total.get(stage, 0.0) + seconds
+        self.count[stage] = self.count.get(stage, 0) + 1
+        h = _HUB
+        if emit and h._enabled:
+            rec = h._record("span", f"{self._emit_prefix}/{stage}", None)
+            rec["dur_s"] = seconds
+            h._dispatch(rec)
 
     def mean(self, stage: str) -> float:
         c = self.count.get(stage, 0)

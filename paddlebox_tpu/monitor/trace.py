@@ -36,9 +36,15 @@ wall clock and eyesight. This module makes one pass ONE causal timeline:
   spans as slices, flow arrows for the exchange and publish→swap edges.
   Open it in Perfetto (ui.perfetto.dev) or chrome://tracing.
 - **Device capture** — ``flags.trace_device`` starts a ``jax.profiler``
-  trace at every sampled ``begin_pass`` and stops it at ``end_pass``
-  (dump under ``trace_device_dir/pass-NNNNN``), linked to the host
-  spans by the pass markers both carry. No-op off TPU.
+  trace at every sampled ``begin_pass`` and stops it at ``end_pass`` or
+  ``abort_pass`` (dump under ``trace_device_dir/pass-NNNNN``), on any
+  backend. Every ``monitor.span`` and stage scope is in it as a
+  ``pbtpu/<name>`` annotation with its ``pass_id`` and ``step``
+  (``hub.annotate``), on the device events' clock. ``--device`` reads
+  such a capture back (:func:`reduce_capture`): seconds and self
+  seconds per span, the device's busy and idle time inside
+  ``train_pass``, and each idle gap under the innermost span of the
+  training thread that covers it.
 
 Cost discipline: tracing disabled costs ONE module-flag check per scope
 (``_ACTIVE``) — the same contract as the hub's disabled event path,
@@ -49,19 +55,24 @@ CLI::
 
     python -m paddlebox_tpu.monitor.trace RANK_DIR... \
         [-o world_trace.json] [--rank-names 4,5,7] [--json]
+    python -m paddlebox_tpu.monitor.trace --device \
+        <file.xplane.pb | trace_device_dir/pass-NNNNN> [--json]
 """
 
 from __future__ import annotations
 
 import contextvars
+import glob
 import json
 import os
 import sys
 import uuid
+import warnings
 import zlib
 
 from paddlebox_tpu.config import flags as config_flags
 from paddlebox_tpu.monitor import aggregate as agg_lib
+from paddlebox_tpu.monitor.names import ANNOTATION_PREFIX as SPAN_PREFIX
 from paddlebox_tpu.monitor.registry import STATS
 
 # ---------------------------------------------------------------------------
@@ -85,6 +96,7 @@ _stack: contextvars.ContextVar[tuple] = contextvars.ContextVar(
 
 # device-capture state (one window per sampled pass)
 _device_dir: str | None = None
+_device_warned = False
 
 # has this process EVER opened a pass scope? A training process owns
 # the trace window via begin/end_pass sampling; a co-located serving
@@ -233,27 +245,37 @@ def flow_propagated(kind: str, key: str, role: str,
 # device capture (flags.trace_device — per-pass jax.profiler window)
 # ---------------------------------------------------------------------------
 
+def _capture_failed(what: str, err: Exception) -> None:
+    """Tracing must never take down the training it observes, and must
+    not fail in silence either: every failure is counted, the first one
+    of the process says why."""
+    global _device_warned
+    STATS.add("trace.device_capture_errors", 1)
+    if not _device_warned:
+        _device_warned = True
+        warnings.warn(f"flags.trace_device: {what} failed ({err!r}); "
+                      f"training goes on without the device capture",
+                      RuntimeWarning, stacklevel=3)
+
+
 def _maybe_start_device_capture(pass_id: int) -> None:
     global _device_dir
     if not config_flags.trace_device or _device_dir is not None:
         return
     try:
         import jax
-        if jax.default_backend() != "tpu":
-            return                      # no-op off-TPU by contract
         import tempfile
         root = config_flags.trace_device_dir or os.path.join(
             tempfile.gettempdir(), "pbtpu_device_trace")
         logdir = os.path.join(root, f"pass-{pass_id:05d}")
         jax.profiler.start_trace(logdir)
-        _device_dir = logdir
-        from paddlebox_tpu.monitor.hub import event as hub_event
-        hub_event("trace.device_capture", type="flow", logdir=logdir,
-                  state="started")
-    except Exception:
-        # tracing must never take down the training it observes
-        STATS.add("trace.device_capture_errors", 1)
-        _device_dir = None
+    except Exception as e:
+        _capture_failed("start_trace", e)
+        return
+    _device_dir = logdir
+    from paddlebox_tpu.monitor.hub import event as hub_event
+    hub_event("trace.device_capture", type="flow", logdir=logdir,
+              state="started")
 
 
 def _stop_device_capture() -> None:
@@ -264,11 +286,12 @@ def _stop_device_capture() -> None:
     try:
         import jax
         jax.profiler.stop_trace()
-        from paddlebox_tpu.monitor.hub import event as hub_event
-        hub_event("trace.device_capture", type="flow", logdir=logdir,
-                  state="stopped")
-    except Exception:
-        STATS.add("trace.device_capture_errors", 1)
+    except Exception as e:
+        _capture_failed("stop_trace", e)
+        return
+    from paddlebox_tpu.monitor.hub import event as hub_event
+    hub_event("trace.device_capture", type="flow", logdir=logdir,
+              state="stopped")
 
 
 # ---------------------------------------------------------------------------
@@ -610,6 +633,251 @@ def records_to_stream(records: "list[dict]") -> dict:
 
 
 # ---------------------------------------------------------------------------
+# device-capture reader (what flags.trace_device, or any jax.profiler
+# capture of the program, wrote)
+# ---------------------------------------------------------------------------
+
+ROOT_SPAN = "train_pass"
+PATH_SEP = " > "
+DEVICE_OPS_LINE = "XLA Ops"
+NO_DEVICE_PLANE = "host hlo_op events (no device plane in the capture)"
+
+
+def find_xplane(path: str) -> str:
+    """`path` if it is a file; else the newest ``.xplane.pb`` under it (a
+    ``trace_device_dir/pass-NNNNN``, or any ``jax.profiler`` log dir)."""
+    if os.path.isfile(path):
+        return path
+    found = sorted(glob.glob(os.path.join(
+        path, "plugins", "profile", "*", "*.xplane.pb")))
+    if not found:
+        raise FileNotFoundError(f"no .xplane.pb under {path}")
+    return found[-1]
+
+
+def read_capture(xplane_path: str) -> dict:
+    """The two things the reduction needs, in seconds on the capture's
+    one clock: ``threads`` — per host thread that holds any, its
+    ``pbtpu/`` spans as ``(start, end, name)`` — and ``device_ops`` —
+    the intervals in which an operation ran on the first device (a TPU
+    plane's ``XLA Ops`` line; on the CPU backend, where the device is
+    the host, the host events that carry an ``hlo_op``).
+    ``device_source`` says which of the two was read: every report
+    prints it, so host events never pass for the chip's."""
+    import jax
+    data = jax.profiler.ProfileData.from_file(xplane_path)
+    threads, devices, host_lines = [], [], []   # lines: read twice at most
+    for plane in data.planes:
+        if plane.name.startswith("/device:TPU:"):
+            for ln in plane.lines:
+                if ln.name == DEVICE_OPS_LINE:
+                    devices.append((plane.name, [
+                        (e.start_ns / 1e9, (e.start_ns + e.duration_ns) / 1e9)
+                        for e in ln.events]))
+        elif plane.name.startswith("/host:"):
+            for ln in plane.lines:
+                host_lines.append(ln)
+                spans = [(e.start_ns / 1e9,
+                          (e.start_ns + e.duration_ns) / 1e9,
+                          e.name[len(SPAN_PREFIX):])
+                         for e in ln.events
+                         if e.name.startswith(SPAN_PREFIX)]
+                if spans:
+                    threads.append(spans)
+    if devices:
+        plane_name, device_ops = min(devices)
+        source = f"{plane_name} {DEVICE_OPS_LINE}"
+    else:
+        source = NO_DEVICE_PLANE
+        with warnings.catch_warnings():
+            # jaxlib's stats iterator warns about its own missing
+            # __module__ (DeprecationWarning) on first use
+            warnings.simplefilter("ignore", DeprecationWarning)
+            device_ops = [
+                (e.start_ns / 1e9, (e.start_ns + e.duration_ns) / 1e9)
+                for ln in host_lines for e in ln.events
+                if e.duration_ns > 0
+                and any(k == "hlo_op" for k, _ in e.stats)]
+    return {"threads": threads, "device_ops": device_ops,
+            "devices": len(devices), "device_source": source}
+
+
+def nest_spans(spans: "list[tuple]") -> "tuple[list[dict], list[tuple]]":
+    """Nest one thread's spans by time (the spans of a thread nest or
+    follow, never cross; a child that ends a rounding error after its
+    parent is cut to it). Returns the spans as records — ``name``,
+    ``path`` (the names from the outermost span down), ``start``,
+    ``end``, ``self_s`` (duration minus what the direct children cover) —
+    and the thread's covered time as segments ``(start, end, path)``,
+    each under the innermost span that covers it."""
+    recs: list[dict] = []
+    segs: list[tuple] = []
+    stack: list[list] = []          # [record, start of its open segment]
+
+    def close(upto: float) -> None:
+        while stack and stack[-1][0]["end"] <= upto:
+            rec, cursor = stack.pop()
+            if rec["end"] > cursor:
+                segs.append((cursor, rec["end"], rec["path"]))
+            if stack:
+                stack[-1][1] = rec["end"]
+
+    for a, b, name in sorted(spans, key=lambda t: (t[0], -t[1])):
+        close(a)
+        path: tuple = (name,)
+        if stack:
+            parent, cursor = stack[-1]
+            b = min(b, parent["end"])
+            if a > cursor:
+                segs.append((cursor, a, parent["path"]))
+            parent["self_s"] -= b - a
+            path = parent["path"] + path
+        rec = {"name": name, "path": path, "start": a, "end": b,
+               "self_s": b - a}
+        recs.append(rec)
+        stack.append([rec, a])
+    close(float("inf"))
+    return recs, sorted(segs)
+
+
+def _union(intervals) -> "list[tuple]":
+    out: list[list[float]] = []
+    for a, b in sorted(intervals):
+        if out and a <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], b)
+        else:
+            out.append([a, b])
+    return [(a, b) for a, b in out]
+
+
+def reduce_capture(threads: "list[list[tuple]]",
+                   device_ops: "list[tuple]", top: int = 10) -> dict:
+    """From the spans and the device's operation intervals
+    (:func:`read_capture` shapes) to the report: per span name count,
+    seconds and self seconds over all threads; for the training thread
+    (the one that holds ``train_pass``) the root's own account, the
+    device's busy and idle seconds inside it, the idle seconds by the
+    innermost span that covers them, and the longest idle gaps, each
+    with the spans it ran under."""
+    by_name: dict[str, dict] = {}
+    main = None
+    for spans in threads:
+        recs, segs = nest_spans(spans)
+        for r in recs:
+            acc = by_name.setdefault(
+                r["name"], {"count": 0, "seconds": 0.0, "self_s": 0.0})
+            acc["count"] += 1
+            acc["seconds"] += r["end"] - r["start"]
+            acc["self_s"] += r["self_s"]
+        if main is None and any(r["name"] == ROOT_SPAN for r in recs):
+            main = (recs, segs)
+    out: dict = {"spans": by_name}
+    if main is None:
+        return out
+    recs, segs = main
+    roots = [r for r in recs if r["name"] == ROOT_SPAN]
+    root_paths = {r["path"] for r in roots}
+    busy = _union(device_ops)
+    idle: list[tuple] = []          # (start, end, root start)
+    busy_s = 0.0
+    for r in roots:
+        edge = r["start"]
+        for a, b in busy:
+            a, b = max(a, r["start"]), min(b, r["end"])
+            if b <= a:
+                continue
+            if a > edge:
+                idle.append((edge, a, r["start"]))
+            busy_s += b - a
+            edge = max(edge, b)
+        if r["end"] > edge:
+            idle.append((edge, r["end"], r["start"]))
+    by_span: dict[str, float] = {}
+    gaps = []
+    for a, b, root_start in idle:
+        under: dict[str, float] = {}
+        for sa, sb, path in segs:
+            if sb <= a:
+                continue
+            if sa >= b:
+                break
+            key = PATH_SEP.join(path)
+            under[key] = under.get(key, 0.0) + min(b, sb) - max(a, sa)
+        for key, sec in under.items():
+            by_span[key] = by_span.get(key, 0.0) + sec
+        gaps.append({"at_s": a - root_start, "seconds": b - a,
+                     "spans": sorted(([k, v] for k, v in under.items()),
+                                     key=lambda kv: -kv[1])})
+    seconds = sum(r["end"] - r["start"] for r in roots)
+    out["train_pass"] = {
+        "passes": len(roots), "seconds": seconds,
+        "self_s": sum(r["self_s"] for r in roots),
+        "longest_hole_s": max((sb - sa for sa, sb, path in segs
+                               if path in root_paths), default=0.0),
+        "device_busy_s": busy_s, "device_idle_s": seconds - busy_s}
+    out["idle_by_span"] = dict(sorted(by_span.items(),
+                                      key=lambda kv: -kv[1]))
+    out["longest_gaps"] = sorted(gaps, key=lambda g: -g["seconds"])[:top]
+    return out
+
+
+def render_capture_text(report: dict) -> str:
+    lines = [f"device intervals read from: {report['device_source']}",
+             f"{'span':<24}{'count':>7}{'seconds':>12}{'self':>12}"]
+    for name, acc in sorted(report["spans"].items(),
+                            key=lambda kv: -kv[1]["seconds"]):
+        lines.append(f"{name:<24}{acc['count']:>7}{acc['seconds']:>12.6f}"
+                     f"{acc['self_s']:>12.6f}")
+    tp = report.get("train_pass")
+    if tp is None:
+        lines.append(f"no {SPAN_PREFIX}{ROOT_SPAN} span in the capture: "
+                     "nothing to attribute the device's idle time to")
+        return "\n".join(lines)
+    lines.append(
+        f"{ROOT_SPAN}: {tp['passes']} pass(es), {tp['seconds']:.6f} s; "
+        f"self {tp['self_s']:.6f} s, longest hole outside a child "
+        f"{tp['longest_hole_s']:.6f} s; device busy "
+        f"{tp['device_busy_s']:.6f} s, idle {tp['device_idle_s']:.6f} s")
+    lines.append("device idle by innermost span of the training thread:")
+    for path, sec in report["idle_by_span"].items():
+        lines.append(f"  {sec:>10.6f}  {path}")
+    lines.append("longest idle gaps (seconds, at, spans under it):")
+    for g in report["longest_gaps"]:
+        under = "; ".join(f"{p} {v:.6f}" for p, v in g["spans"][:4])
+        if len(g["spans"]) > 4:
+            under += f"; and {len(g['spans']) - 4} more (--json has all)"
+        lines.append(f"  {g['seconds']:>10.6f}  +{g['at_s']:.6f}  {under}")
+    return "\n".join(lines)
+
+
+def device_main(argv: "list[str]") -> int:
+    as_json = "--json" in argv
+    paths = [a for a in argv if not a.startswith("-")]
+    if len(paths) != 1:
+        print("usage: python -m paddlebox_tpu.monitor.trace --device "
+              "<file.xplane.pb | trace_device_dir/pass-NNNNN> [--json]",
+              file=sys.stderr)
+        return 2
+    try:
+        xplane = find_xplane(paths[0])
+        capture = read_capture(xplane)
+    except (OSError, ValueError) as e:
+        print(f"trace: cannot read the capture: {e}", file=sys.stderr)
+        return 2
+    if not capture["threads"]:
+        print(f"trace: no {SPAN_PREFIX} span in {xplane} (was the "
+              "program running inside the capture?)", file=sys.stderr)
+        return 2
+    report = reduce_capture(capture["threads"], capture["device_ops"])
+    report["xplane"] = xplane
+    report["devices"] = capture["devices"]
+    report["device_source"] = capture["device_source"]
+    print(json.dumps(report) if as_json
+          else render_capture_text(report), flush=True)
+    return 0
+
+
+# ---------------------------------------------------------------------------
 # CLI
 # ---------------------------------------------------------------------------
 
@@ -634,6 +902,8 @@ def render_text(summary: dict, out_path: str | None) -> str:
 
 def main(argv: "list[str] | None" = None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
+    if "--device" in argv:
+        return device_main([a for a in argv if a != "--device"])
     as_json = "--json" in argv
     argv = [a for a in argv if a != "--json"]
     out_path = None

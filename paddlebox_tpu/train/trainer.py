@@ -206,12 +206,15 @@ class Trainer:
                 init_params, self.opt_state)
         self._n_dense_args = (self._dense_packer[2]
                               if self._dense_packer else 2)
-        # "train"/"auc" scopes are covered by the train_step/auc_update
-        # spans — only the stages without one emit hub events themselves
+        # "read", "translate" (pack thread) and "drain" emit as
+        # stage/<name>; the others share their scope with a span
+        # (timers("train", span="train_step")). "head" (entry of
+        # train_pass to the first step's dispatch) and "close" enclose
+        # other stages (critical_path.NESTED_STAGES); the rest are
+        # disjoint on their thread.
         self.timers = StageTimers(["read", "translate", "train", "auc",
-                                   "drain"],
-                                  emit_stages={"read", "translate",
-                                               "drain"})
+                                   "drain", "unique_keys", "preplan",
+                                   "h2d", "head", "close"])
         # incremental + overlapped pass boundaries (BoxHelper FeedPass):
         # resident device rows are reused across passes, write-back is lazy.
         # Pass a shared manager when several trainers drive one table
@@ -972,12 +975,12 @@ class Trainer:
         return (idx, pb.mask, dense.astype(np.float32),
                 labels.astype(np.float32), *plan, *extras)
 
-    def _stage_device(self, host_tuple: tuple):
+    def _stage_device(self, host_tuple: tuple, sharding=None):
         # ONE device_put for all arrays: each put is its own
         # host->device dispatch
-        with monitor.span("h2d_stage"):
-            return jax.device_put(host_tuple,
-                                  mesh_lib.batch_sharding(self.mesh))
+        with self.timers("h2d", span="h2d_stage"):
+            return jax.device_put(
+                host_tuple, sharding or mesh_lib.batch_sharding(self.mesh))
 
     def _put_batch(self, ws: PassWorkingSet, pb: PackedBatch,
                    with_plan: bool = True):
@@ -1092,7 +1095,7 @@ class Trainer:
                                 f"a mesh-divisible axis 0; got stacked "
                                 f"shape {a.shape} on a {n_sh}-way mesh")
                     yield ([pb for pb, _ in buf],
-                           jax.device_put(stacked, stk_sh), True)
+                           self._stage_device(stacked, stk_sh), True)
                     buf = []
             for pb, host_tuple in buf:      # tail: single-step program
                 yield [pb], self._stage_device(host_tuple), False
@@ -1318,6 +1321,33 @@ class Trainer:
             premerged=self.push_premerged(ws), storage_f32=f32,
             table_width=width)
 
+    def engines(self) -> dict:
+        """What the resolvers chose, in one place: the table's layout and
+        wire, the pull and push engines, whether the push is deferred and
+        planned on the host, and the live table's shape (the engines and
+        the shape are those of the last pass's working set; None before
+        the first pass)."""
+        ws = self._last_ws
+        return {
+            "table_layout": self.table_layout,
+            "pull_engine": self.pull_engine,
+            "push_engine": (self.resolved_push_engine(ws)
+                            if ws is not None else None),
+            "exchange_wire": self.exchange_wire,
+            "push_overlap": bool(self.push_overlap),
+            "host_plan": bool(self._use_plan),
+            "table_shape": (list(jax.tree.leaves(ws.table)[0].shape)
+                            if ws is not None and ws.table is not None
+                            else None)}
+
+    def block_until_ready(self) -> None:
+        """Wait for everything the loop has dispatched: the table of the
+        live working set (the last deferred apply lands after the last
+        loss is read), the dense params and the optimizer state."""
+        ws = self._last_ws
+        jax.block_until_ready((ws.table if ws is not None else None,
+                               self.params, self.opt_state))
+
     def train_pass(self, dataset, metrics: Any = None,
                    preload_keys: np.ndarray | None = None,
                    skip_steps: int = 0) -> dict[str, float]:
@@ -1354,8 +1384,13 @@ class Trainer:
             # a phased lifecycle — the controller observes whole passes)
             self._wire_stats0 = monitor.STATS.snapshot()
         try:
-            out = self._train_pass_impl(dataset, metrics, preload_keys,
-                                        skip_steps=skip_steps)
+            # the root of the pass's timeline: every span of the training
+            # thread nests in it (entered after open_pass_auto, inside
+            # which a flags.trace_device capture starts)
+            with monitor.span("train_pass"):
+                out = self._train_pass_impl(dataset, metrics, preload_keys,
+                                            skip_steps=skip_steps,
+                                            pass_t0=pass_t0)
         except BaseException as e:
             if owned_pass:
                 hub.abort_pass(reason=repr(e))
@@ -1374,8 +1409,7 @@ class Trainer:
             # which push merge engine this pass's steps compiled with
             # (THE resolver's verdict — the doctor's push-floor rule
             # names it when suggesting a forced A/B)
-            push_engine=(self.resolved_push_engine(self._last_ws)
-                         if self._last_ws is not None else None),
+            push_engine=self.engines()["push_engine"],
             # pass-boundary cost (this pass's working-set build) + its
             # split — the run doctor's boundary-wall rule reads both
             boundary_seconds=round(fm.last_boundary_seconds, 6),
@@ -1506,14 +1540,19 @@ class Trainer:
 
     def _train_pass_impl(self, dataset, metrics: Any = None,
                          preload_keys: np.ndarray | None = None,
-                         skip_steps: int = 0) -> dict[str, float]:
+                         skip_steps: int = 0, *,
+                         pass_t0: float) -> dict[str, float]:
         cfg = self.cfg
-        ws = self.feed_mgr.begin_pass(dataset.unique_keys())
+        with self.timers("unique_keys", span="unique_keys"):
+            keys = dataset.unique_keys()
+        ws = self.feed_mgr.begin_pass(keys)
+        del keys        # megabytes at a real pass's size: not held through it
         self.feed_mgr.pass_opened()
         self._overlap_ws = ws if self.push_overlap else None
         if preload_keys is not None:
             self.preload_pass(preload_keys)
-        self._preplan_capacity(dataset, ws)
+        with self.timers("preplan", span="preplan"):
+            self._preplan_capacity(dataset, ws)
         table = ws.table
         params, opt_state = self.params, self.opt_state
         # flat dense-state transport (see pack_dense); identity when off
@@ -1558,6 +1597,8 @@ class Trainer:
                 f"a cadence on the dispatch boundary: every_steps="
                 f"{self._midpass[1]} is not a multiple of {k_sd}")
         skip_remaining = int(skip_steps)
+        head_open = True
+        completed = False
         pack_it = self._pack_iter(dataset, ws, cfg.global_batch_size,
                                   group=k_sd)
         try:
@@ -1583,8 +1624,7 @@ class Trainer:
                     # the finally below drains in-flight work
                     self.peer_check()
                 faultpoint.hit("trainer.step.pre")
-                with monitor.span("pack_batch"):
-                    idx, mask, dense, labels, *plan = staged
+                idx, mask, dense, labels, *plan = staged
                 if mon_trace._ACTIVE and self.table_layout == "sharded":
                     # world-trace flow point for this step's all_to_all:
                     # every rank stamps the SAME deterministic key (all
@@ -1598,7 +1638,13 @@ class Trainer:
                         **exchange.flow_fields(self.store.cfg,
                                                self.exchange_wire,
                                                int(idx.size)))
-                with self.timers("train"), monitor.span("train_step"):
+                if head_open:
+                    # the head of the pass ends where its first step is
+                    # dispatched: unique_keys, the boundary, preplan, the
+                    # first batch's read wait and H2D are all inside
+                    self.timers.add("head", time.perf_counter() - pass_t0)
+                    head_open = False
+                with self.timers("train", span="train_step"):
                     if stacked:
                         out = self._superstep_fn(table, *dstate, *staged)
                         (table, dstate, loss, preds,
@@ -1654,7 +1700,7 @@ class Trainer:
                 # its input table, and a concurrent flush (store read/save
                 # from another thread) must never gather from a dead buffer
                 ws.table = table
-                with self.timers("auc"), monitor.span("auc_update"):
+                with self.timers("auc", span="auc_update"):
                     # the AUC histogram is order-invariant: a stacked
                     # (k, B) group updates in one flattened call
                     auc_acc.update(self._auc_fn, preds.reshape(-1),
@@ -1723,68 +1769,87 @@ class Trainer:
                         and pass_step % mp[1] == 0):
                     table = self._midpass_save(table, ws, dstate, params,
                                                opt_state, pass_step)
+            completed = True
         finally:
-            import sys as _sys
-            # elastic drain crumbs: how far this pass got and whether it
-            # aborted (a peer failure unwinding through here) — the
-            # drain snapshot reads these after the exception lands
-            self.last_pass_steps = pass_step
-            self._last_ws = ws
-            self._pass_aborted = _sys.exc_info()[0] is not None
-            # close the pack generator explicitly so its finally (cancel
-            # event + producer join) runs NOW, not whenever GC finalizes
-            # the suspended frame — on a non-refcounting interpreter the
-            # daemon producer would otherwise keep translating and
-            # touching ws for the rest of the dataset
-            pack_it.close()
-            # The step donates table/params/opt_state, so the objects bound
-            # before the loop are dead buffers; rebind to the last good step
-            # even when a batch raised (the pass/day crash-recovery flow
-            # catches and resumes from checkpoint — the Trainer must stay
-            # usable).
-            if self.push_overlap:
-                # pass-boundary flush: the last step's table apply is
-                # still pending (bounded staleness of one) — land it
-                # before anything reads or persists the table
-                table = self._dispatch_pending_apply(table)
-            ws.table = table
-            self.feed_mgr.pass_closed()
-            if mode == "async":
-                self.dense_table.flush()
-                self.params = jax.device_put(
-                    self._unravel(self.dense_table.pull()), repl)
-                self.opt_state = self.dense_table.state_dict()
-                self._last_dense = None      # state dict IS the state
-            else:
-                # elastic drain crumb: the LIVE loop planes exactly as
-                # _midpass_save would store them — for kstep, BEFORE the
-                # finalize pmean below (k·x/k can round for
-                # non-power-of-2 shard counts, and the drain snapshot
-                # must stay bit-identical to the stacked loop state the
-                # uninterrupted run continues from)
-                self._last_dense = (self.unpack_dense(dstate)
-                                    if dstate is not None
-                                    else (params, opt_state))
-                if mode == "kstep":  # end-of-pass sync (trainer Finalize)
-                    params, opt_state = self._sync_fn(params, opt_state)
-                if dstate is not None:
-                    params, opt_state = self.unpack_dense(dstate)
-                self.params, self.opt_state = params, opt_state
-            if dump_stream is not None:
-                # flush the tail batch even when the pass raised — a nan
-                # trip must keep the debug stream it exists for. A dump IO
-                # failure is reported but never masks the training exception.
-                try:
-                    if dump_pending is not None:
-                        s, p, y, ex = dump_pending
-                        dump_stream.write_fields(s, p, y, ex)
-                    if cfg.dump_param:
-                        self._dump_params(dump_stream)
-                    dump_stream.close()
-                except Exception as e:
-                    import warnings
-                    warnings.warn(f"dump stream failed: {e}")
-        self.feed_mgr.end_pass(ws, table)
+            # pass_close: from the loop's end to the pass's numbers — the
+            # pending apply, the dense state's rebind, end_pass, the
+            # drain and the AUC read, in one scope; a pass that raised
+            # leaves it after the rebind.
+            with self.timers("close", span="pass_close"):
+                # elastic drain crumbs: how far this pass got and whether it
+                # aborted (a peer failure unwinding through here) — the
+                # drain snapshot reads these after the exception lands
+                self.last_pass_steps = pass_step
+                self._last_ws = ws
+                self._pass_aborted = not completed
+                # close the pack generator explicitly so its finally (cancel
+                # event + producer join) runs NOW, not whenever GC finalizes
+                # the suspended frame — on a non-refcounting interpreter the
+                # daemon producer would otherwise keep translating and
+                # touching ws for the rest of the dataset
+                pack_it.close()
+                # The step donates table/params/opt_state, so the objects
+                # bound before the loop are dead buffers; rebind to the last
+                # good step even when a batch raised (the pass/day
+                # crash-recovery flow catches and resumes from checkpoint —
+                # the Trainer must stay usable).
+                if self.push_overlap:
+                    # pass-boundary flush: the last step's table apply is
+                    # still pending (bounded staleness of one) — land it
+                    # before anything reads or persists the table
+                    table = self._dispatch_pending_apply(table)
+                ws.table = table
+                self.feed_mgr.pass_closed()
+                with monitor.span("pass_close/rebind"):
+                    if mode == "async":
+                        self.dense_table.flush()
+                        self.params = jax.device_put(
+                            self._unravel(self.dense_table.pull()), repl)
+                        self.opt_state = self.dense_table.state_dict()
+                        self._last_dense = None   # state dict IS the state
+                    else:
+                        # elastic drain crumb: the LIVE loop planes exactly
+                        # as _midpass_save would store them — for kstep,
+                        # BEFORE the finalize pmean below (k·x/k can round
+                        # for non-power-of-2 shard counts, and the drain
+                        # snapshot must stay bit-identical to the stacked
+                        # loop state the uninterrupted run continues from)
+                        self._last_dense = (self.unpack_dense(dstate)
+                                            if dstate is not None
+                                            else (params, opt_state))
+                        if mode == "kstep":
+                            # end-of-pass sync (trainer Finalize)
+                            params, opt_state = self._sync_fn(params,
+                                                              opt_state)
+                        if dstate is not None:
+                            params, opt_state = self.unpack_dense(dstate)
+                        self.params, self.opt_state = params, opt_state
+                if dump_stream is not None:
+                    # flush the tail batch even when the pass raised — a
+                    # nan trip must keep the debug stream it exists for. A
+                    # dump IO failure is reported but never masks the
+                    # training exception.
+                    try:
+                        if dump_pending is not None:
+                            s, p, y, ex = dump_pending
+                            dump_stream.write_fields(s, p, y, ex)
+                        if cfg.dump_param:
+                            self._dump_params(dump_stream)
+                        dump_stream.close()
+                    except Exception as e:
+                        import warnings
+                        warnings.warn(f"dump stream failed: {e}")
+                if completed:
+                    out = self._read_pass(ws, table, dev_losses,
+                                          dev_dropped, auc_acc)
+        return out
+
+    def _read_pass(self, ws: PassWorkingSet, table, dev_losses: list,
+                   dev_dropped: list, auc_acc) -> dict[str, float]:
+        """The end of a pass that ran through: end_pass, then the drain
+        of the loop's losses and the AUC read (inside ``pass_close``)."""
+        with monitor.span("pass_close/end_pass"):
+            self.feed_mgr.end_pass(ws, table)
         with self.timers("drain"):
             # one sync, post-loop: every queued step completes here, so
             # this is where async-dispatch wall time actually lands.
@@ -1795,13 +1860,15 @@ class Trainer:
         # retired-slot buffer refs (the pipeline's leak invariant:
         # live() == 0 between passes)
         self._push_stager.clear()
-        out = auc_acc.compute()
-        out["loss_first"] = losses[0] if losses else float("nan")
-        out["loss_last"] = losses[-1] if losses else float("nan")
-        out["loss_mean"] = float(np.mean(losses)) if losses else float("nan")
-        out["losses"] = losses
-        out["steps"] = len(losses)
-        out["routed_dropped"] = self._check_dropped(dev_dropped)
+        with monitor.span("pass_close/read"):
+            out = auc_acc.compute()
+            out["loss_first"] = losses[0] if losses else float("nan")
+            out["loss_last"] = losses[-1] if losses else float("nan")
+            out["loss_mean"] = (float(np.mean(losses)) if losses
+                                else float("nan"))
+            out["losses"] = losses
+            out["steps"] = len(losses)
+            out["routed_dropped"] = self._check_dropped(dev_dropped)
         return out
 
     def _preplan_capacity(self, dataset, ws: PassWorkingSet,

@@ -6,8 +6,9 @@ The reference's observability stack (SURVEY.md §5):
   (RecordEvent, profiler.cc:303) and device_tracer.cc:815 (CUPTI → chrome
   trace). Here: :class:`RecordEvent` spans collected by a process-global
   profiler, exported with :func:`export_chrome_trace`; device-side traces
-  delegate to ``jax.profiler`` (:func:`start_device_trace`), whose TensorBoard
-  dumps play the CUPTI role on TPU. Spans are tagged with the current
+  are ``jax.profiler`` captures (``flags.trace_device``, read back by
+  ``python -m paddlebox_tpu.monitor.trace --device``), which play the
+  CUPTI role on TPU. Spans are tagged with the current
   pass/step (``monitor.context``) and the buffer is a bounded ring
   (``flags.profiler_max_events``) with a dropped-span counter — a day-scale
   run can leave the profiler on without growing without limit.
@@ -195,18 +196,6 @@ def export_chrome_trace(path: str) -> int:
         with open(tmp, "w") as f:
             json.dump({"traceEvents": evs, "displayTimeUnit": "ms"}, f)
     return len(evs)
-
-
-def start_device_trace(logdir: str) -> None:
-    """Begin a device-level trace via jax.profiler (CUPTI's role on TPU —
-    the dump is read with TensorBoard or xprof)."""
-    import jax
-    jax.profiler.start_trace(logdir)
-
-
-def stop_device_trace() -> None:
-    import jax
-    jax.profiler.stop_trace()
 
 
 # ---------------------------------------------------------------------------

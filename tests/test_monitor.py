@@ -268,15 +268,18 @@ def test_disabled_path_call_cost():
     for _ in range(n):
         monitor.event("noop", x=1)
     event_cost = (time.perf_counter() - t0) / n
+    with monitor.span("noop"):        # the scope's lazy imports, once
+        pass
     t0 = time.perf_counter()
     for _ in range(n):
         with monitor.span("noop"):
             pass
     span_cost = (time.perf_counter() - t0) / n
     # generous bounds (CI noise): the disabled event is one flag check,
-    # the disabled span two — micro-seconds, not tens of them
+    # the disabled span two and an inert profiler annotation (ISSUE 24:
+    # about 1.2 us together) — micro-seconds, not tens of them
     assert event_cost < 5e-6, f"disabled event() costs {event_cost:.2e}s"
-    assert span_cost < 10e-6, f"disabled span() costs {span_cost:.2e}s"
+    assert span_cost < 5e-6, f"disabled span() costs {span_cost:.2e}s"
 
 
 # ---------------------------------------------------------------------------

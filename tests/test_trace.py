@@ -61,7 +61,7 @@ def _emit_rank_stream(dirpath, pass_id=1, steps=2):
     assert trace_lib.active()
     for s in range(steps):
         monitor.context.set_step(s)
-        with monitor.span("pack_batch"):
+        with monitor.span("h2d_stage"):
             pass
         trace_lib.flow("exchange", f"p{pass_id}.s{s}",
                        wire="f32", tokens=64, bytes_bound=4096)
@@ -168,12 +168,12 @@ def test_trace_ids_and_parent_links(tmp_path):
     h = monitor.hub()
     h.enable(ms)
     h.begin_pass(3)
-    with monitor.span("pack_batch"):
+    with monitor.span("train_pass"):
         with monitor.span("train_step"):
             monitor.event("nan_guard", n_bad=0)
     h.end_pass()
     by_name = {r["name"]: r for r in ms.records}
-    outer, inner = by_name["pack_batch"], by_name["train_step"]
+    outer, inner = by_name["train_pass"], by_name["train_step"]
     ev, fr = by_name["nan_guard"], by_name["pass"]
     tid = outer["trace_id"]
     assert tid and tid.endswith(":3")
@@ -194,15 +194,15 @@ def test_sampling_gates_whole_passes(tmp_path):
     h.enable(ms)
     h.begin_pass(1)                  # 1 % 2 != 0 -> unsampled
     assert not trace_lib.active()
-    with monitor.span("pack_batch"):
+    with monitor.span("h2d_stage"):
         pass
     h.end_pass()
     h.begin_pass(2)                  # sampled
     assert trace_lib.active()
-    with monitor.span("pack_batch"):
+    with monitor.span("h2d_stage"):
         pass
     h.end_pass()
-    spans = [r for r in ms.records if r["name"] == "pack_batch"]
+    spans = [r for r in ms.records if r["name"] == "h2d_stage"]
     assert len(spans) == 2
     assert "trace_id" not in spans[0]      # unsampled: no trace plane
     assert spans[1]["trace_id"].endswith(":2")
@@ -555,7 +555,7 @@ def test_event_name_registry_is_closed_and_consistent():
     for n in ("trace.flow", "trace.clock_probe", "trace.device_capture",
               "serving_swap", "pass_begin"):
         assert names.is_registered(n)
-    for n in ("pack_batch", "train_step", "publish"):
+    for n in ("h2d_stage", "train_step", "publish"):
         assert n in names.SPAN_NAMES
     assert not names.is_registered("totally_made_up")
 
