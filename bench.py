@@ -313,7 +313,7 @@ def device_step_bench(small: bool, mode: str = "allreduce",
     from paddlebox_tpu.config import flags as config_flags
     from paddlebox_tpu.data import DataFeedSchema
     from paddlebox_tpu.embedding import (EmbeddingConfig, HostEmbeddingStore,
-                                         PassWorkingSet)
+                                         PassWorkingSet, quant)
     from paddlebox_tpu.models import DeepFMModel
     from paddlebox_tpu.parallel import make_mesh, mesh as mesh_lib
     from paddlebox_tpu.train import Trainer, TrainerConfig
@@ -534,8 +534,7 @@ def device_step_bench(small: bool, mode: str = "allreduce",
         # counter bump can only tighten toward truth, never past it)
         unique_lanes=(min(measured_lanes, batch * T // n_dev)
                       if premerged and measured_lanes else None),
-        table_width=(int(ws.table.shape[1]) if storage == "f32"
-                     else None))
+        table_width=quant.row_engine_width(ws.table))
     detail = {
         "device_kind": kind,
         "storage": storage,

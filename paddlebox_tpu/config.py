@@ -55,7 +55,10 @@ class Flags:
     # Pass-boundary transfer compression: embedx crosses host<->device as
     # bf16 (counters/opt state stay f32). TPU-native analogue of the
     # reference's Quant/ShowClk quantized feature types; rounds embedx to
-    # 8 mantissa bits once per pass boundary. Opt-in.
+    # 8 mantissa bits once per pass boundary. Opt-in. It splits and
+    # rejoins the columns of ONE device array, so setting it switches the
+    # lane-tile plane layout off (working_set.plane_layout): a dim-128
+    # table is then the one array again, with the accumulator push.
     transfer_compress_embedx: bool = False  # (new)
     # Routed all_to_all capacity overflow policy (new — the reference sizes
     # buffers dynamically, box_wrapper_impl.h:44-81; fixed lanes are the
@@ -106,6 +109,10 @@ class Flags:
     # (no lane padding in HBM: a 64-wide table really stores 64 cols).
     # Opt-in for lookup-dominated workloads: "auto" = 64 (or 128 for
     # wide rows); 0 = logical width; N = explicit width >= row_width.
+    # A padded table is one array by definition: where this flag pads
+    # (device_width > row_width) the lane-tile plane layout is off
+    # (working_set.plane_layout), and a dim-128 table padded to 256
+    # takes the row-DMA kernels as before.
     table_pad_width: Any = 0                # (new)
     # Host-plan dedup pre-merge (the reference's DedupKeysAndFillIdx +
     # PushMergeCopy pairing, box_wrapper_impl.h:103): the pack thread's

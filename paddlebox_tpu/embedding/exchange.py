@@ -381,10 +381,10 @@ def _decompress_push(planes: tuple, wire: str) -> jnp.ndarray:
 
 def _scatter_engine(table_shard, cfg: EmbeddingConfig, rps: int) -> bool:
     from paddlebox_tpu.ops import pallas_kernels
-    s_f32 = not quant.is_quant(table_shard)
     return pallas_kernels.resolve_push_engine(
-        cfg, rps, premerged=True, storage_f32=s_f32,
-        table_width=table_shard.shape[1] if s_f32 else None) \
+        cfg, rps, premerged=True,
+        storage_f32=not quant.is_quant(table_shard),
+        table_width=quant.row_engine_width(table_shard)) \
         == "scatter_accumulate"
 
 

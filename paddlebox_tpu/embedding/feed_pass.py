@@ -71,7 +71,8 @@ from paddlebox_tpu.config import flags
 from paddlebox_tpu.embedding import quant, tiering
 from paddlebox_tpu.embedding.store import HostEmbeddingStore
 from paddlebox_tpu.embedding.working_set import (PassWorkingSet, bucket_size,
-                                                 fetch_rows, transfer_bytes,
+                                                 fetch_rows, plane_layout,
+                                                 transfer_bytes,
                                                  _put_compressed)
 from paddlebox_tpu.monitor import context as mon_ctx
 from paddlebox_tpu.monitor import counter_add as stat_add
@@ -101,7 +102,7 @@ def _combine_jit(out_sharding, donate: bool):
             from_prev = p[jnp.where(is_fresh, 0, src)]
             from_fresh = f[jnp.where(is_fresh, src, 0)]
             return jnp.where(is_fresh[:, None], from_fresh, from_prev)
-        # tree.map: the table may be a QuantTable pytree (quant.py planes)
+        # tree.map: the table may be a PlaneTable pytree (quant.py planes)
         return jax.tree.map(one, prev, fresh)
 
     kw: dict = {"donate_argnums": (0,)} if donate else {}
@@ -393,9 +394,11 @@ class FeedPassManager:
             staged = np.zeros((n_fresh_pad, cfg.row_width), np.float32)
             # parity: compressed/quantized transfers must convert the served
             # rows through the same rounding as store-fetched ones, so those
-            # paths fill the hit rows HOST-side before conversion; plain-f32
-            # fills them device-side from the replica plane below
-            host_fill = bool(cfg.storage != "f32"
+            # paths fill the hit rows HOST-side before conversion (and so do
+            # plane tables, whose staging is split by columns on the host);
+            # the plain-f32 array fills them device-side from the replica
+            # plane below
+            host_fill = bool(plane_layout(cfg)
                              or (flags.transfer_compress_embedx
                                  and cfg.total_dim))
             if served is None:
@@ -407,8 +410,8 @@ class FeedPassManager:
         t1 = time.perf_counter()
         with mon_span("boundary/h2d"):
             repl = self._repl_sharding()
-            if cfg.storage != "f32":
-                fresh_dev = quant.device_table(staged, cfg, repl)
+            if plane_layout(cfg):
+                fresh_dev = quant.device_planes(staged, cfg, repl)
             elif flags.transfer_compress_embedx and cfg.total_dim:
                 fresh_dev = _put_compressed(staged, cfg, repl)
             elif repl is not None:
@@ -578,8 +581,8 @@ class FeedPassManager:
         idx_p = np.full(k_pad, idx[k - 1], np.int32)
         idx_p[:k] = idx                # ...so duplicate writes are benign
         repl = self._repl_sharding()
-        if cfg.storage != "f32":
-            rows_dev = quant.device_table(rows_p, cfg, repl)
+        if plane_layout(cfg):
+            rows_dev = quant.device_planes(rows_p, cfg, repl)
         elif repl is not None:
             rows_dev = jax.device_put(rows_p, repl)
         else:

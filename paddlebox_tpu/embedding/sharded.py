@@ -7,8 +7,10 @@ CUDA kernel families (box_wrapper.cu:35-830), and the sharded
 ``PullSparseGPU``/``PushSparseGPU`` lookups inside libbox_ps.
 
 Design (SURVEY.md §2.3 "TPU-native equivalents"): the pass working set is a
-dense ``(N, row_width)`` float32 table sharded contiguously over the mesh's
-device axis; batches carry dense int32 indices (index 0 = null/padding row).
+dense ``(N, row_width)`` float32 table (one array, or the two planes of
+``quant.PlaneTable`` where ``working_set.plane_layout`` says so) sharded
+contiguously over the mesh's device axis; batches carry dense int32 indices
+(index 0 = null/padding row).
 Three strategies:
 
 - ``lookup``/``push`` — single-shard (or fully-replicated) gather / dedup'd
@@ -20,11 +22,12 @@ Three strategies:
   NCCL+SyncDense collapses into mesh collectives).
 
 Duplicate keys are merged on-device before the optimizer applies (the role of
-``PushMergeCopy``): ``push`` scatter-adds all token payloads into a per-row
-accumulator in one fused scatter, then applies the optimizer vectorized over
-the table masked to touched rows — the math matches the reference's
-merge-then-update semantics, with exactly one scatter op per step (see the
-``push`` docstring for the TPU cost rationale).
+``PushMergeCopy``): ``push`` merges token payloads onto one lane per touched
+row, then applies the optimizer to those rows — in the table, row by row,
+where rows can be addressed (premerged lanes on a lane-tile table), or
+vectorized over a per-row accumulator masked to the touched rows elsewhere.
+The math matches the reference's merge-then-update semantics either way (see
+the ``push`` docstring for the TPU cost rationale).
 """
 
 from __future__ import annotations
@@ -73,14 +76,18 @@ def lookup(table: jnp.ndarray, idx: jnp.ndarray,
     catastrophically slow path on TPU (~26x: 568ms vs 22ms for 213k tokens
     from a 512k x 11 f32 table on one v5e, measured with forced D2H sync).
 
-    Quantized tables (cfg.storage != f32) gather both planes and
+    Plane tables (quant.PlaneTable) gather both planes: the embedx plane's
+    rows directly — an f32 plane of whole lane tiles is row-major on the
+    chip, so no copy of the table precedes the gather — and show, clk and
+    the w-block out of the narrow plane's rows. Quantized planes
     dequantize at the gather — f32 compute, int storage (quant.py).
     """
     flat = idx.reshape(-1)
-    if quant.is_quant(table):
+    if quant.is_planes(table):
         fp = _take_rows(table.fp, flat)
-        qx = _take_rows(table.qx, flat)
-        x = qx.astype(jnp.float32) * fp[:, -1:]
+        x = _take_rows(table.qx, flat)
+        if quant.is_quant(table):
+            x = x.astype(jnp.float32) * fp[:, -1:]
         pulled = jnp.concatenate([fp[:, :cfg.fixed_cols], x], axis=1)
         return gate_pull(pulled, cfg).reshape((*idx.shape, cfg.pull_width))
     rows = _take_rows(table, flat)
@@ -123,7 +130,7 @@ def fused_pull_pool(table, idx: jnp.ndarray, cfg: EmbeddingConfig,
     ops.seqpool_cvm.fused_gather_seqpool_cvm."""
     from paddlebox_tpu.ops import pallas_kernels
     B = idx.shape[0]
-    if (not quant.is_quant(table)
+    if (not quant.is_planes(table)
             and pallas_kernels.gather_pool_supported(
                 cfg, B, num_slots, slot_len, table.shape[1])):
         return pallas_kernels.gather_pool(table, idx, cfg, num_slots,
@@ -303,19 +310,22 @@ def push(table: jnp.ndarray, idx: jnp.ndarray, grads: jnp.ndarray,
     Implementation note (TPU): the merge engine is selected by
     pallas_kernels.resolve_push_engine — ONE resolver shared with the
     bench record (flags.push_engine forces for A/Bs). Premerged f32
-    lanes take the fused scatter_accumulate (each touched row gathered,
-    updated in VMEM, written back once — no full-table pass); narrow
-    raw token streams take the binned one-hot MXU merge; otherwise
-    duplicates are merged with ONE fused scatter-add into a per-row
-    accumulator and the optimizer applies vectorized over the whole
-    table, masked to touched rows. All three preserve the reference's
-    merge-then-update semantics (PushMergeCopy, box_wrapper.cu:630-830)
-    — sort-based dedup costs several gather/scatter/sort ops per step,
-    and on TPU each of those carries a large fixed cost. The scatter
-    engines' O(table) pass per step is the deliberate trade where they
-    run; for very large working sets pick a sharded mesh (each shard
-    scans only its rows) — whose routed apply now rides the fused
-    engine too (exchange.routed_push).
+    lanes on a table whose rows can be addressed (f32 planes, or one
+    array of whole lane tiles) take scatter_accumulate: each touched
+    row gathered, updated, written back once — no accumulator, no pass
+    over untouched rows, the table updated in place. Narrow raw token
+    streams take the binned one-hot MXU merge; otherwise duplicates are
+    merged with ONE fused scatter-add into a per-row accumulator and
+    the optimizer applies vectorized over the whole table, masked to
+    touched rows. All three preserve the reference's merge-then-update
+    semantics (PushMergeCopy, box_wrapper.cu:630-830) — sort-based
+    dedup costs several gather/scatter/sort ops per step, and on TPU
+    each of those carries a large fixed cost. Where the accumulator
+    engines run (a one-array table the chip stores column-major:
+    narrow and half-tile widths, where no row can be addressed; raw
+    token streams) their pass is O(table) per step; a wide-row table
+    leaves that by its layout (working_set.plane_layout), a very large
+    narrow one by a sharded mesh (each shard scans only its rows).
     """
     if premerged:
         kplan, dplan = plan, None
@@ -330,18 +340,20 @@ def push(table: jnp.ndarray, idx: jnp.ndarray, grads: jnp.ndarray,
         premerged = True
     n = idx.shape[0]
     n_rows = quant.table_rows(table)
-    is_q = quant.is_quant(table)
+    is_p = quant.is_planes(table)
     engine = pallas_kernels.resolve_push_engine(
-        cfg, n_rows, premerged=premerged, storage_f32=not is_q,
-        table_width=None if is_q else table.shape[1])
+        cfg, n_rows, premerged=premerged,
+        storage_f32=not quant.is_quant(table),
+        table_width=quant.row_engine_width(table))
     if engine == "scatter_accumulate":
-        # fused row-wise merge-apply over the premerged unique lanes:
-        # each touched row gathers once, updates in VMEM, writes back
-        # once — no full-table accumulator, no O(table) update pass
-        # (the Pallas kernel on real TPU; identical jnp math elsewhere)
+        # row-wise merge-apply over the premerged unique lanes: each
+        # touched row gathers once, updates, writes back once — no
+        # full-table accumulator, no O(table) update pass (XLA's gather
+        # and scatter on planes; on one array the Pallas kernel on real
+        # TPU, identical jnp math elsewhere)
         return pallas_kernels.scatter_accumulate(table, idx, grads,
                                                  shows, clks, cfg)
-    if (engine == "binned_kernel" and not is_q
+    if (engine == "binned_kernel" and not is_p
             and pallas_kernels.binned_push_supported(table, cfg)):
         # scatter-free merge+update for narrow rows: the binned kernel
         # streams the merge through the MXU and measures ~2x the XLA
@@ -360,7 +372,7 @@ def push(table: jnp.ndarray, idx: jnp.ndarray, grads: jnp.ndarray,
         acc = pallas_kernels.binned_merge_acc(
             idx, grads, shows, clks, cfg, n_rows,
             n_split=config_flags.binned_push_splits, plan=kplan,
-            vma=getattr(jax.typeof(table.fp if is_q else table), "vma",
+            vma=getattr(jax.typeof(table.fp if is_p else table), "vma",
                         frozenset()))
     else:
         payload = jnp.concatenate(
@@ -379,8 +391,7 @@ def push(table: jnp.ndarray, idx: jnp.ndarray, grads: jnp.ndarray,
     # requantize — round twice — unless it really changed). The null row
     # only ever receives zero grads/increments (callers mask padding), and
     # a fresh zero row is a fixed point of every optimizer — it stays zero.
-    if (not quant.is_quant(table) and acc.shape[1] >= 64
-            and jax.default_backend() == "tpu"):
+    if not is_p and acc.shape[1] >= 64 and jax.default_backend() == "tpu":
         # wide accumulators: XLA's fused update+where degrades ~3x when
         # the slice fusion consumes a computed acc (in-composition A/B
         # on one v5e, dim 64, 213k tokens: 15.7ms vs 5.9ms with the
@@ -388,14 +399,15 @@ def push(table: jnp.ndarray, idx: jnp.ndarray, grads: jnp.ndarray,
         # opposite — dim 8: 2.8ms vs 4.7ms — and keep the XLA fusion)
         return pallas_kernels.merge_update(table, acc, cfg)
     touched = acc[:, gw + 2] > 0
-    if quant.is_quant(table):
-        # dequant -> exact f32 update -> requant, one fused elementwise
-        # pass over the planes (no f32 table materializes in HBM)
+    if is_p:
+        # (dequant ->) exact f32 update (-> requant), one fused
+        # elementwise pass over the planes (no f32 table materializes
+        # in HBM)
         rows = quant.assemble_rows(table.fp, table.qx, cfg)
         new_rows = apply_updates(rows, acc[:, :gw], acc[:, gw],
                                  acc[:, gw + 1], cfg)
         new_fp, new_qx = quant.split_rows(new_rows, cfg)
-        return quant.QuantTable(
+        return quant.PlaneTable(
             fp=jnp.where(touched[:, None], new_fp, table.fp),
             qx=jnp.where(touched[:, None], new_qx, table.qx))
     if pallas_kernels.use_pallas():
@@ -500,19 +512,23 @@ def routed_lookup(table_shard: jnp.ndarray, idx: jnp.ndarray,
     recv_idx = lax.all_to_all(send_idx, axis_name, 0, 0, tiled=True)
     local_row = jnp.where(recv_idx >= 0, recv_idx % rps, 0)
     lane_ok = (recv_idx >= 0)[:, :, None]
-    if quant.is_quant(table_shard):
-        # quantized a2a payload: the embedx plane crosses ICI as int8/16
-        # plus a small f32 plane (show, clk, w-block, scale) — the
-        # reference's quant pull variants applied to the collective
+    if quant.is_planes(table_shard):
+        # plane a2a payload: the embedx plane crosses ICI as it is
+        # stored (int8/16 where quantized — the reference's quant pull
+        # variants applied to the collective) plus a small f32 plane
+        # (show, clk, w-block, and the scale of a quantized row)
+        is_q = quant.is_quant(table_shard)
         fc = cfg.fixed_cols
         fp = _take_rows(table_shard.fp, local_row.reshape(-1))
         qx = _take_rows(table_shard.qx, local_row.reshape(-1))
-        fph = jnp.concatenate([fp[:, :fc], fp[:, -1:]], axis=1)
-        fph = jnp.where(lane_ok, fph.reshape(D, cap, fc + 1), 0.0)
+        fph = (jnp.concatenate([fp[:, :fc], fp[:, -1:]], axis=1) if is_q
+               else fp[:, :fc])
+        fph = jnp.where(lane_ok, fph.reshape(D, cap, -1), 0.0)
         qx = jnp.where(lane_ok, qx.reshape(D, cap, -1), 0)
         back_fp = lax.all_to_all(fph, axis_name, 0, 0, tiled=True)
         back_qx = lax.all_to_all(qx, axis_name, 0, 0, tiled=True)
-        x = back_qx.astype(jnp.float32) * back_fp[:, :, -1:]
+        x = (back_qx.astype(jnp.float32) * back_fp[:, :, -1:] if is_q
+             else back_qx)
         back = jnp.concatenate([back_fp[:, :, :fc], x], axis=2)
     else:
         # full-row take + barrier + slice: see lookup() for the rationale

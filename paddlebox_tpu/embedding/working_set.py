@@ -91,6 +91,29 @@ def device_width(cfg: EmbeddingConfig) -> int:
     return max(rw, int(pad))
 
 
+def plane_layout(cfg: EmbeddingConfig) -> bool:
+    """Whether the device table is two planes (quant.PlaneTable) instead
+    of one (rows, device_width) array — THE layout rule, from the
+    embedding's shape alone.
+
+    Quantized storage always is (the embedx plane is the quantized part).
+    f32 storage is where embedx(+expand) is a whole number of 128-lane
+    tiles (128, 256, 384, 512 — the widths the fused kernels' geometry
+    functions name): the chip stores such an f32 plane row-major with no
+    padding, so its rows can be gathered and written in place, and the
+    push touches only the rows a step changed (sharded.push). Every
+    other width keeps the one array. The two flags that assume one
+    array switch the planes off when set: a table padded by
+    flags.table_pad_width is one array by definition, and
+    flags.transfer_compress_embedx splits and rejoins that array's
+    columns at the boundary."""
+    if cfg.storage != "f32":
+        return True
+    return (0 < cfg.total_dim <= 512 and cfg.total_dim % 128 == 0
+            and device_width(cfg) == cfg.row_width
+            and not flags.transfer_compress_embedx)
+
+
 @functools.lru_cache(maxsize=8)
 def _pad_width_jit(extra: int, sharding):
     def pad(t):
@@ -187,7 +210,7 @@ def _gather_rows_jit(compress: bool, lo: int, hi: int, rw: int):
 
 
 @functools.lru_cache(maxsize=2)
-def _gather_rows_quant_jit():
+def _gather_rows_planes_jit():
     # one dispatch for both planes; paired with a single device_get so a
     # pass-boundary flush pays one D2H round trip, not two serialized ones
     return jax.jit(lambda fp, qx, idx: (fp[idx], qx[idx]))
@@ -207,8 +230,8 @@ def fetch_rows(table: jax.Array, row_idx: np.ndarray,
     k_pad = bucket_size(k)
     idxp = np.zeros(k_pad, np.int32)
     idxp[:k] = row_idx
-    if quant.is_quant(table):
-        fp_d, qx_d = _gather_rows_quant_jit()(table.fp, table.qx, idxp)
+    if quant.is_planes(table):
+        fp_d, qx_d = _gather_rows_planes_jit()(table.fp, table.qx, idxp)
         fp, qx = (np.asarray(a) for a in jax.device_get((fp_d, qx_d)))
         rows = quant.decode_rows_np(fp, qx, cfg)
         return rows[:k], transfer_bytes(cfg, k_pad)
@@ -293,7 +316,8 @@ class PassWorkingSet:
                  table: jax.Array, rows_per_shard: int, n_shards: int):
         self.cfg = cfg
         self.sorted_keys = sorted_keys      # uint64 (K,), ascending
-        self.table = table                  # (N_pad, row_width) sharded
+        self.table = table                  # (N_pad, row_width) sharded:
+        #                                     one array or quant.PlaneTable
         self.rows_per_shard = rows_per_shard
         self.n_shards = n_shards
         # hash index over the pass keys: per-batch translate becomes one
@@ -379,13 +403,13 @@ class PassWorkingSet:
         t1 = _time.perf_counter()
         sharding = (mesh_lib.table_sharding(mesh) if mesh is not None
                     else None)
-        if cfg.storage != "f32":
-            if flags.transfer_compress_embedx:
-                raise ValueError(
-                    "transfer_compress_embedx is redundant with quantized "
-                    "storage — the embedx plane already crosses as "
-                    f"{cfg.storage}")
-            table = quant.device_table(host_table, cfg, sharding)
+        if cfg.storage != "f32" and flags.transfer_compress_embedx:
+            raise ValueError(
+                "transfer_compress_embedx is redundant with quantized "
+                "storage — the embedx plane already crosses as "
+                f"{cfg.storage}")
+        if plane_layout(cfg):
+            table = quant.device_planes(host_table, cfg, sharding)
         elif flags.transfer_compress_embedx and cfg.total_dim:
             table = _put_compressed(host_table, cfg, sharding)
         elif sharding is not None:
@@ -395,7 +419,7 @@ class PassWorkingSet:
         # pad f32 tables to the fast gather width ON DEVICE — the H2D
         # above carried logical bytes only (see device_width)
         W = device_width(cfg)
-        if cfg.storage == "f32" and W > cfg.row_width:
+        if W > cfg.row_width:
             table = _pad_width_jit(W - cfg.row_width, sharding)(table)
         if timing_out is not None:
             # device_put returns before bytes move; without this barrier
@@ -456,7 +480,7 @@ class PassWorkingSet:
             rows, nbytes = fetch_rows(t, dirty, self.cfg)
             store.write_back(self.sorted_keys[dirty - 1], rows)
             return nbytes
-        if quant.is_quant(t):
+        if quant.is_planes(t):
             host = quant.decode_rows_np(
                 np.asarray(jax.device_get(t.fp)),
                 np.asarray(jax.device_get(t.qx)), self.cfg)

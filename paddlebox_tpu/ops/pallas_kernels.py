@@ -52,6 +52,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from paddlebox_tpu.embedding import quant
 from paddlebox_tpu.embedding.config import EmbeddingConfig
 from paddlebox_tpu.embedding.optim import apply_updates
 
@@ -622,8 +623,14 @@ def resolve_push_engine(cfg: EmbeddingConfig, n_rows: int, *,
     storage_f32 : quantized tables keep the binned/scatter engines (the
         fused engine updates f32 rows in place; quant planes dequant →
         update → requant around the storage-agnostic merge acc instead).
-    table_width : physical device-table columns (>= cfg.row_width when
-        padded); bounds the fused engine's per-row DMA geometry.
+    table_width : columns of the f32 array whose rows the fused engine
+        moves (quant.row_engine_width): the one array's physical width
+        (>= cfg.row_width when padded), or the embedx plane's of an f32
+        plane table — whole lane tiles by working_set.plane_layout, so
+        the plane class always has the geometry. One rule on every
+        mesh: a plane table's premerged lanes take this engine on one
+        shard (sharded.push) and in the sharded exchange's apply tail
+        (exchange._apply_received) alike.
 
     Auto heuristic per (row width class, lane contract, storage, shard):
     premerged f32 lanes on a supported geometry — a device table of
@@ -1240,6 +1247,47 @@ def _scatter_accumulate_kernel(idx_ref, tch_ref, pay_ref, table_ref,
     lax.fori_loop(0, TILE, sbody, 0)
 
 
+def _scatter_accumulate_planes(table, idx, grads, shows, clks,
+                               cfg: EmbeddingConfig, touched):
+    """scatter_accumulate on f32 planes (quant.PlaneTable): the jnp
+    reference below, plane by plane — gather the touched rows of both
+    planes, assemble full rows, the same apply_updates, scatter them
+    back with the pads dropped. This is what runs on a TPU too: at 213 k
+    lanes of a 2.6 M-row dim-128 table XLA's gather -> update -> scatter
+    measured 8.5 ms against the row-DMA kernel's 20.0 ms (one v5e,
+    PERF.md PR 25), and the row-major embedx plane needs no view.
+
+    With no `touched` the lanes are plan_premerge's (ascending, unique,
+    pads ascending past the last row), so the scatter may promise both;
+    the routed apply's lanes (`touched` given) are unique only among the
+    touched ones, and their pads leave the scatter out of range."""
+    n_rows = table.shape[0]
+    in_range = (idx >= 0) & (idx < n_rows)
+    safe = jnp.where(in_range, idx, 0)
+    # barrier: the column slices of assemble_rows must not fuse into the
+    # narrow plane's gather (sharded.lookup on what that costs)
+    fp, qx = (lax.optimization_barrier(jnp.take(p, safe, axis=0))
+              for p in table)
+    new = quant.split_rows(
+        apply_updates(quant.assemble_rows(fp, qx, cfg), grads, shows, clks,
+                      cfg), cfg)
+    if touched is None:
+        wr = jnp.where(idx >= 0, idx, n_rows)
+        hints = {"indices_are_sorted": True, "unique_indices": True}
+    else:
+        wr = jnp.where((touched > 0) & in_range, idx, n_rows)
+        hints = {}
+    # the narrow plane column by column: the chip holds it column-major,
+    # where a scatter of rows costs one pass a column either way, and as
+    # 1-D scatters each pass is cheaper (five columns at 213 k lanes: 5.3
+    # against 8.6 ms inside the composed apply, one v5e, PERF.md PR 25)
+    fp = jnp.stack(
+        [table.fp[:, c].at[wr].set(new.fp[:, c], mode="drop", **hints)
+         for c in range(table.fp.shape[1])], axis=1)
+    return quant.PlaneTable(
+        fp=fp, qx=table.qx.at[wr].set(new.qx, mode="drop", **hints))
+
+
 def scatter_accumulate(table: jnp.ndarray, idx: jnp.ndarray,
                        grads: jnp.ndarray, shows: jnp.ndarray,
                        clks: jnp.ndarray, cfg: EmbeddingConfig,
@@ -1251,6 +1299,8 @@ def scatter_accumulate(table: jnp.ndarray, idx: jnp.ndarray,
             columns pass through apply_updates untouched). The kernel
             needs W a multiple of 128 columns
             (scatter_accumulate_geometry); the jnp reference takes any.
+            Or f32 planes (quant.PlaneTable): XLA's gather and scatter
+            on every backend (_scatter_accumulate_planes).
     idx   : (n,) int32 — ONE lane per touched row (plan_premerge's
             contract: ascending unique with out-of-range pads, or any
             unique-among-touched order — the routed apply's lanes).
@@ -1272,6 +1322,9 @@ def scatter_accumulate(table: jnp.ndarray, idx: jnp.ndarray,
     n_rows, W = table.shape
     gw = cfg.grad_width
     idx = idx.astype(jnp.int32)
+    if quant.is_planes(table):
+        return _scatter_accumulate_planes(table, idx, grads, shows, clks,
+                                          cfg, touched)
     if touched is None:
         tch = ((idx >= 0) & (idx < n_rows)).astype(jnp.int32)
     else:

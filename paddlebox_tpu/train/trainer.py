@@ -1313,21 +1313,24 @@ class Trainer:
         geometry (the engine dispatches on rows_per_shard after
         routing). Trace-time static; recorded per bench matrix point
         and in the flight record, like pull_engine."""
+        from paddlebox_tpu.embedding import quant
         from paddlebox_tpu.ops import pallas_kernels
-        f32 = self.store.cfg.storage == "f32"
-        width = int(ws.table.shape[1]) if f32 else None
         return pallas_kernels.resolve_push_engine(
             self.store.cfg, ws.rows_per_shard,
-            premerged=self.push_premerged(ws), storage_f32=f32,
-            table_width=width)
+            premerged=self.push_premerged(ws),
+            storage_f32=self.store.cfg.storage == "f32",
+            table_width=quant.row_engine_width(ws.table))
 
     def engines(self) -> dict:
         """What the resolvers chose, in one place: the table's layout and
         wire, the pull and push engines, whether the push is deferred and
-        planned on the host, and the live table's shape (the engines and
-        the shape are those of the last pass's working set; None before
-        the first pass)."""
+        planned on the host, and the live table's shape — the logical
+        (rows, row_width), and beside it the shape of each array the
+        device holds: one, or the two planes of a plane table (the
+        engines and the shapes are those of the last pass's working set;
+        None before the first pass)."""
         ws = self._last_ws
+        live = ws is not None and ws.table is not None
         return {
             "table_layout": self.table_layout,
             "pull_engine": self.pull_engine,
@@ -1336,9 +1339,10 @@ class Trainer:
             "exchange_wire": self.exchange_wire,
             "push_overlap": bool(self.push_overlap),
             "host_plan": bool(self._use_plan),
-            "table_shape": (list(jax.tree.leaves(ws.table)[0].shape)
-                            if ws is not None and ws.table is not None
-                            else None)}
+            "table_shape": list(ws.table.shape) if live else None,
+            "plane_shapes": ([list(p.shape)
+                              for p in jax.tree.leaves(ws.table)]
+                             if live else None)}
 
     def block_until_ready(self) -> None:
         """Wait for everything the loop has dispatched: the table of the

@@ -203,3 +203,108 @@ def test_resolvers_follow_the_geometry(on_tpu, table_width, push,
                                   table_width=table_width) == push
     assert pk.gather_pool_supported(cfg, BATCH, SLOTS, 4,
                                     table_width) is pull_kernel
+
+
+# ---------------------------------------------------------------------------
+# a wide-row trainer's two programs: no table-sized temporary
+# ---------------------------------------------------------------------------
+
+PLANE_ROWS = 2_621_440   # the benchmark's DLRM cell: 1.43 GB of table
+
+
+def _dlrm_programs(topo, rows):
+    """The deferred step and the apply of a DLRM-shaped trainer (dim 128,
+    adagrad in the table, MLPerf's tower, 8192 x 26 one-hot tokens) over a
+    table of `rows` rows, compiled for one described v5e chip."""
+    import types
+
+    import numpy as np
+
+    from paddlebox_tpu.data import DataFeedSchema
+    from paddlebox_tpu.embedding import (HostEmbeddingStore, quant,
+                                         working_set)
+    from paddlebox_tpu.models import DLRMModel
+    from paddlebox_tpu.parallel import make_mesh, mesh as mesh_lib
+    from paddlebox_tpu.train import Trainer, TrainerConfig
+    from paddlebox_tpu.train.trainer import PLAN_ARITY
+
+    cfg = _cfg(128)
+    assert working_set.plane_layout(cfg)
+    tr = Trainer(DLRMModel(SLOTS, 128, 13, (512, 256),
+                           (1024, 1024, 512, 256), use_cvm=False),
+                 HostEmbeddingStore(cfg),
+                 DataFeedSchema.ctr(SLOTS, 13, batch_size=BATCH),
+                 make_mesh(1), TrainerConfig(global_batch_size=BATCH))
+    tr.mesh = make_mesh(devices=topo.devices[:1])
+    tr._rebuild_steps()
+    bat, tbl, rep = (f(tr.mesh) for f in (
+        mesh_lib.batch_sharding, mesh_lib.table_sharding,
+        mesh_lib.replicated_sharding))
+
+    def like(x, sh):
+        return jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=sh)
+
+    table = quant.PlaneTable(
+        fp=jax.ShapeDtypeStruct((rows, quant.fp_width(cfg)), jnp.float32,
+                                sharding=tbl),
+        qx=jax.ShapeDtypeStruct((rows, 128), jnp.float32, sharding=tbl))
+    ws = types.SimpleNamespace(table=table, rows_per_shard=rows,
+                               padded_rows=rows)
+    idx = np.random.default_rng(0).integers(
+        1, rows, (BATCH, SLOTS)).astype(np.int32)
+    host = (idx, np.ones(idx.shape, bool), np.zeros((BATCH, 13), np.float32),
+            np.zeros(BATCH, np.float32), *tr._host_plan(ws, idx))
+    args = [like(x, bat) for x in host]
+    dstate = [like(x, rep) for x in tr.pack_dense()]
+    assert tr.push_overlap and tr._use_plan and tr.push_premerged(ws)
+    step = tr._defer_step_fn.lower(table, *dstate, *args).compile()
+    ops = tr.split_defer_out(jax.eval_shape(
+        tr._defer_step_fn, table, *dstate, *args))[1]
+    apply = tr._apply_fn.lower(
+        table, args[0], args[1], args[3], *args[4:4 + PLAN_ARITY],
+        *[like(o, bat) for o in ops]).compile()
+    return tr.resolved_push_engine(ws), step, apply
+
+
+def _table_sized_results(text, rows):
+    """Instructions of the optimized HLO whose result holds rows x 128
+    elements or more, by opcode."""
+    import re
+    found = []
+    for m in re.finditer(
+            r"= \(?(?:f32|bf16|s32|u32)\[(\d+),(\d+)\]\S* ([a-z\-]+)\(",
+            text):
+        if int(m.group(1)) * int(m.group(2)) >= rows * 128:
+            found.append(m.group(3))
+    return found
+
+
+def test_wide_row_programs_hold_no_table_sized_temporary(topo, on_tpu):
+    """Lane-tile planes (working_set.plane_layout): neither program of a
+    dim-128 trainer copies the table or builds a table-sized accumulator
+    — the embedx plane is gathered and scattered in place, the apply is
+    the touched-rows engine the resolver names, and what the apply holds
+    beside its operands barely grows with the table."""
+    engine, step, apply = _dlrm_programs(topo, PLANE_ROWS)
+    assert engine == "scatter_accumulate"
+    plane_bytes = PLANE_ROWS * 128 * 4
+    for prog in (step, apply):
+        text = prog.as_text()
+        assert prog.memory_analysis().temp_size_in_bytes < plane_bytes
+        assert "tpu_custom_call" not in text and "pbtpu_" not in text
+        # the plane itself, the in-place scatter and what carries them
+        assert set(_table_sized_results(text, PLANE_ROWS)) <= {
+            "parameter", "fusion", "scatter", "tuple", "bitcast",
+            "get-tuple-element"}
+    # the step only reads the table; the apply scatters the embedx
+    # plane's rows once and the narrow plane column by column
+    assert "scatter" not in _table_sized_results(step.as_text(), PLANE_ROWS)
+    assert apply.as_text().count(" scatter(") == 1 + 5
+    m = apply.memory_analysis()
+    assert m.alias_size_in_bytes >= plane_bytes         # updated in place
+    # ... and follows the lanes, not the table: over twice the rows it
+    # grows by the narrow plane's columns, a thirtieth of the table's
+    _, _, apply_half = _dlrm_programs(topo, PLANE_ROWS // 2)
+    half = apply_half.memory_analysis().temp_size_in_bytes
+    table_growth = PLANE_ROWS // 2 * 133 * 4
+    assert 0 <= m.temp_size_in_bytes - half <= table_growth // 10

@@ -321,7 +321,7 @@ def test_quant_push_binned_wiring(monkeypatch):
     shows = jnp.ones(tok, jnp.float32)
     clks = jnp.zeros(tok, jnp.float32)
     host = (rng.normal(size=(N, cfg.row_width)) * 0.01).astype(np.float32)
-    want_tbl = sharded.push(quant.device_table(host.copy(), cfg, None),
+    want_tbl = sharded.push(quant.device_planes(host.copy(), cfg, None),
                             idx, grads, shows, clks, cfg)
 
     monkeypatch.setattr(pk, "binned_acc_supported", lambda c, n: True)
@@ -332,7 +332,7 @@ def test_quant_push_binned_wiring(monkeypatch):
     old = flags.binned_push
     flags.binned_push = True
     try:
-        got_tbl = sharded.push(quant.device_table(host.copy(), cfg, None),
+        got_tbl = sharded.push(quant.device_planes(host.copy(), cfg, None),
                                idx, grads, shows, clks, cfg)
     finally:
         flags.binned_push = old

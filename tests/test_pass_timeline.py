@@ -234,15 +234,17 @@ def test_public_handles_on_the_live_state(captured, tmp_path):
     eng = tr.engines()
     assert set(eng) == {"table_layout", "pull_engine", "push_engine",
                         "exchange_wire", "push_overlap", "host_plan",
-                        "table_shape"}
+                        "table_shape", "plane_shapes"}
     assert eng["push_engine"] == tr.resolved_push_engine(tr._last_ws)
     assert eng["table_shape"] == list(tr._last_ws.table.shape)
+    assert eng["plane_shapes"] == [eng["table_shape"]]    # one array
     assert eng["push_overlap"] is bool(tr.push_overlap)
     assert captured["flights"][-1]["extra"]["push_engine"] == \
         eng["push_engine"]
     fresh, _ = _tiny_trainer(tmp_path)
     fresh.block_until_ready()                  # before any pass: no table
     assert fresh.engines()["table_shape"] is None
+    assert fresh.engines()["plane_shapes"] is None
     assert fresh.engines()["push_engine"] is None
 
 

@@ -48,7 +48,7 @@ def test_encode_decode_roundtrip(storage):
 def test_lookup_dequantizes(storage="int16"):
     cfg = EmbeddingConfig(dim=8, storage=storage)
     rows = _rows(cfg, 128)
-    table = quant.device_table(rows, cfg, None)
+    table = quant.device_planes(rows, cfg, None)
     idx = jnp.asarray(np.arange(128, dtype=np.int32))
     pulled = np.asarray(sharded.lookup(table, idx, cfg))
     np.testing.assert_allclose(pulled[:, :3], rows[:, :3], rtol=1e-6)
@@ -64,7 +64,7 @@ def test_push_parity_with_f32():
     q16 = EmbeddingConfig(dim=8, learning_rate=0.1, storage="int16")
     rows = _rows(f32, 256, seed=3)
     t_f = jnp.asarray(rows)
-    t_q = quant.device_table(rows, q16, None)
+    t_q = quant.device_planes(rows, q16, None)
     rng = np.random.default_rng(0)
     push_f = jax.jit(lambda t, i, g, s, c: sharded.push(t, i, g, s, c, f32))
     push_q = jax.jit(lambda t, i, g, s, c: sharded.push(t, i, g, s, c, q16))
@@ -91,7 +91,7 @@ def test_untouched_rows_keep_exact_bits():
     """Rows no batch referenced must not be re-rounded by the pass."""
     cfg = EmbeddingConfig(dim=4, storage="int8", learning_rate=0.1)
     rows = _rows(cfg, 64, seed=9)
-    t = quant.device_table(rows, cfg, None)
+    t = quant.device_planes(rows, cfg, None)
     qx0 = np.asarray(t.qx).copy()
     fp0 = np.asarray(t.fp).copy()
     idx = jnp.asarray(np.array([5, 9], np.int32))
