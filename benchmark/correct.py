@@ -53,10 +53,13 @@ import numpy as np
 from benchmark.reference.steps import B1
 
 
-def _leaves(tree) -> dict[str, np.ndarray]:
+def _leaves(tree) -> list[tuple[str, np.ndarray]]:
+    """(name, leaf) in the tree's order, each leaf as the tree holds it:
+    whoever reads one turns that one into float64, so the comparison's
+    memory follows the largest leaf and not the model."""
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)
-    return {"dense" + jax.tree_util.keystr(path): np.asarray(leaf, np.float64)
-            for path, leaf in flat}
+    return [("dense" + jax.tree_util.keystr(path), leaf)
+            for path, leaf in flat]
 
 
 def _norm(a) -> float:
@@ -65,8 +68,7 @@ def _norm(a) -> float:
 
 def grad_norms(after1: dict, dim: int) -> dict[str, float]:
     """Norm of the first gradient per leaf, from the state after one step."""
-    out = {name: _norm(m) / (1 - B1)
-           for name, m in _leaves(after1["m"]).items()}
+    out = {name: _norm(m) / (1 - B1) for name, m in _leaves(after1["m"])}
     rows = after1["rows"]
     out["table.w"] = float(np.sqrt(np.sum(rows[:, 3 + dim], dtype=np.float64)))
     out["table.embedding"] = float(np.sqrt(
@@ -76,9 +78,11 @@ def grad_norms(after1: dict, dim: int) -> dict[str, float]:
 
 def change_norms(after: dict, params0, rows0: np.ndarray,
                  dim: int) -> dict[str, float]:
-    p0 = _leaves(params0)
-    out = {name: _norm(p - p0[name])
-           for name, p in _leaves(after["params"]).items()}
+    now, before = _leaves(after["params"]), _leaves(params0)
+    if [name for name, _ in now] != [name for name, _ in before]:
+        raise ValueError("the trees compared do not hold the same leaves")
+    out = {name: _norm(np.asarray(p, np.float64) - np.asarray(p0, np.float64))
+           for (name, p), (_, p0) in zip(now, before)}
     d = np.asarray(after["rows"], np.float64) - rows0
     out["table.w"] = _norm(d[:, 2])
     out["table.embedding"] = _norm(d[:, 3:3 + dim])

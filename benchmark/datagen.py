@@ -43,6 +43,14 @@ SLOT_SHIFT = 27           # key = (slot + 1) << 27 | (index + 1)
 ID_DIGITS = 10            # so up to 73 slots: 74 << 27 < 10**10
 
 
+def local_index(ids: np.ndarray) -> np.ndarray:
+    """The key format inverted: each token's 0-based index within its
+    field (what the generator drew, less one); 0 where the id is absent
+    (the mask says which)."""
+    ids = np.asarray(ids, np.int64)
+    return np.where(ids != 0, (ids & ((1 << SLOT_SHIFT) - 1)) - 1, 0)
+
+
 @dataclasses.dataclass
 class Pass:
     """One pass of examples, as the generator wrote them."""

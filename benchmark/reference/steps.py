@@ -1,20 +1,26 @@
 """The plain reference of one training step, followed for a few steps.
 
-Shared by the model references (``deepfm.py``, ``dlrm.py``: parameters and
-logits of one model each). Written from the configuration's stated
-equations in straightforward ``jax.numpy``; imports nothing of
-``paddlebox_tpu`` and takes nothing the program made: the embedding rows
-come from the configuration's stated init rule, the dense weights from
-the model reference's ``init_params``, the batches from the generator.
+Shared by the model references (one file a model: its parameters and
+either its logits or its own loss; ``benchmark/README.md`` has the
+contract). Written from the configuration's stated equations in
+straightforward ``jax.numpy``; imports nothing of ``paddlebox_tpu`` and
+takes nothing the program made: the embedding rows come from the
+configuration's stated init rule, the dense weights from the model
+reference's ``init_params``, the batches from the generator.
 
 One step, as the configuration states it:
 
   pull     every token reads [show, clk, w, embedding] of its key's row
-  pool     tokens of one field are summed (absent tokens add nothing);
-           with ``use_cvm`` the summed show/clk become log(show+1) and
-           log(clk+1)-log(show+1), else they are dropped
-  model    logits(params, pooled features, dense)   (the model reference)
-  loss     mean over the batch of the sigmoid cross entropy
+  losses   one loss an example, the model's: its reference file's
+           ``example_losses`` over the pulled tokens in the order the
+           generator wrote them, or, where the file has none, the default:
+    pool     tokens of one field are summed (absent tokens add nothing);
+             with ``use_cvm`` the summed show/clk become log(show+1) and
+             log(clk+1)-log(show+1), else they are dropped
+    model    logits(params, pooled features, dense)  (the model reference)
+    loss     the sigmoid cross entropy of that one logit and the label
+  mean     over the batch, whole, or accumulated with its gradients over
+           blocks of ``reference_block_examples`` examples
   dense    Adam(lr, b1 0.9, b2 0.999, eps 1e-8) on the loss's gradient
   push     per row: g = sum of its tokens' gradients w.r.t. (w, embedding)
            — show and clk are counters and get no gradient — show += its
@@ -41,12 +47,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchmark import datagen
+
 B1, B2, EPS = 0.9, 0.999, 1e-8
 
 
 def model_reference(cfg: dict):
-    """The model's reference module, found by the configuration's model."""
-    return importlib.import_module(f"benchmark.reference.{cfg['model']}")
+    """The model's reference module: the one the configuration names under
+    ``reference``, else ``benchmark.reference.<model>``."""
+    return importlib.import_module(
+        cfg.get("reference", f"benchmark.reference.{cfg['model']}"))
 
 
 def initial_params(cfg: dict, seed: int):
@@ -83,25 +93,16 @@ def _bce(logit, label):
         + jnp.log1p(jnp.exp(-jnp.abs(logit)))
 
 
-def make_step(cfg: dict, slot_of_token: np.ndarray, dtype=jnp.float32,
-              fault: str | None = None):
-    """A jitted step: (params, m, v, count, rows, idx, mask, dense, labels)
-    -> (params, m, v, count, rows, loss). `rows` is (K + 1, W) with row 0
-    all zero for absent tokens; `idx` (B, T) indexes it."""
-    model = model_reference(cfg)
-    emb, tr = cfg["embedding"], cfg["trainer"]
-    dim = int(emb["dim"])
+def pooled_losses(cfg: dict, slot_of_token: np.ndarray, logits):
+    """The default ``example_losses``, for a model whose reference file
+    gives only ``logits`` over one pooled vector per field."""
     use_cvm = bool(cfg["model_args"].get("use_cvm", True))
     n_slots = int(cfg["model_args"]["num_slots"])
     pool = np.zeros((len(slot_of_token), n_slots), np.float32)
     pool[np.arange(len(slot_of_token)), slot_of_token] = 1.0
-    lr_d, lr_s = float(tr["dense_lr"]), float(emb["learning_rate"])
-    g2_0 = float(emb.get("initial_g2sum", 3.0))
-    precision = "highest" if dtype == jnp.float32 else "default"
 
-    def loss_fn(params, trained, counters, mask, dense, labels):
-        # trained (B, T, 1 + dim): w and embedding; counters (B, T, 2)
-        pulled = jnp.concatenate([counters, trained], axis=-1)
+    def example_losses(params, pulled, mask, dense, labels, local_ids, cfg):
+        dtype = pulled.dtype
         pulled = pulled * mask[..., None].astype(dtype)
         pooled = jnp.einsum("btp,ts->bsp", pulled, jnp.asarray(pool, dtype))
         if use_cvm:
@@ -111,20 +112,77 @@ def make_step(cfg: dict, slot_of_token: np.ndarray, dtype=jnp.float32,
                                     axis=-1)
         else:
             feats = pooled[..., 2:]
-        per_example = _bce(model.logits(params, feats, dense, cfg), labels)
+        return _bce(logits(params, feats, dense, cfg), labels)
+
+    return example_losses
+
+
+def make_step(cfg: dict, slot_of_token: np.ndarray, dtype=jnp.float32,
+              fault: str | None = None):
+    """A jitted step: (params, m, v, count, rows, idx, mask, dense, labels,
+    local_ids) -> (params, m, v, count, rows, loss). `rows` is (K + 1, W)
+    with row 0 all zero for absent tokens; `idx` (B, T) indexes it;
+    `local_ids` (B, T) are the tokens' indices within their fields."""
+    model = model_reference(cfg)
+    emb, tr = cfg["embedding"], cfg["trainer"]
+    dim = int(emb["dim"])
+    example_losses = getattr(model, "example_losses", None) \
+        or pooled_losses(cfg, slot_of_token, model.logits)
+    block = cfg.get("reference_block_examples")
+    lr_d, lr_s = float(tr["dense_lr"]), float(emb["learning_rate"])
+    g2_0 = float(emb.get("initial_g2sum", 3.0))
+    precision = "highest" if dtype == jnp.float32 else "default"
+
+    def losses(params, trained, counters, mask, dense, labels, local_ids):
+        # trained (B, T, 1 + dim): w and embedding; counters (B, T, 2)
+        pulled = jnp.concatenate([counters, trained], axis=-1)
+        return example_losses(params, pulled, mask, dense, labels, local_ids,
+                              cfg)
+
+    def whole_batch_loss(*args):
+        per_example = losses(*args)
         if fault == "half_batch":
             half = per_example.shape[0] // 2
             return jnp.mean(per_example[:half])
         return jnp.mean(per_example)
 
-    def step(params, m, v, count, rows, idx, mask, dense, labels):
+    def loss_and_grads(p, pulled, *batch):
+        """The batch's mean loss and its gradients w.r.t. the dense
+        parameters and every token's (w, embedding)."""
+        trained, counters = pulled[..., 2:3 + dim], pulled[..., 0:2]
+        if not block:
+            return jax.value_and_grad(whole_batch_loss, argnums=(0, 1))(
+                p, trained, counters, *batch)
+        n = trained.shape[0]
+        if n % block:
+            raise ValueError(f"reference_block_examples {block} does not "
+                             f"divide the batch of {n}")
+        counted = n // 2 if fault == "half_batch" else n
+
+        def block_loss(p, trained, counters, first, *batch):
+            per_example = losses(p, trained, counters, *batch)
+            keep = first + jnp.arange(block) < counted
+            return jnp.sum(jnp.where(keep, per_example, 0)) / counted
+
+        def one_block(carry, xs):
+            loss, (gp, gt) = jax.value_and_grad(block_loss, argnums=(0, 1))(
+                p, *xs)
+            return (carry[0] + loss, jax.tree.map(jnp.add, carry[1], gp)), gt
+
+        blocks = lambda a: a.reshape(n // block, block, *a.shape[1:])
+        (loss, gp), gt = jax.lax.scan(
+            one_block, (jnp.zeros((), dtype), jax.tree.map(jnp.zeros_like, p)),
+            (blocks(trained), blocks(counters), jnp.arange(0, n, block),
+             *map(blocks, batch)))
+        return loss, (gp, gt.reshape(trained.shape))
+
+    def step(params, m, v, count, rows, idx, mask, dense, labels, local_ids):
         with jax.default_matmul_precision(precision):
             cast = lambda t: jax.tree.map(lambda a: a.astype(dtype), t)
             p, r = cast(params), rows.astype(dtype)
-            pulled = r[idx]
-            loss, (gp, gt) = jax.value_and_grad(loss_fn, argnums=(0, 1))(
-                p, pulled[..., 2:3 + dim], pulled[..., 0:2], mask,
-                dense.astype(dtype), labels.astype(dtype))
+            loss, (gp, gt) = loss_and_grads(
+                p, r[idx], mask, dense.astype(dtype), labels.astype(dtype),
+                local_ids)
             if fault == "state_unchanged":
                 return params, m, v, count, rows, loss.astype(jnp.float32)
             # dense: Adam
@@ -161,7 +219,9 @@ def make_step(cfg: dict, slot_of_token: np.ndarray, dtype=jnp.float32,
             return (f32(p), f32(m), f32(v), count, new.astype(jnp.float32),
                     loss.astype(jnp.float32))
 
-    return jax.jit(step)
+    # a step in blocks is a tower too large to hold twice: its state's
+    # buffers are the step's to reuse
+    return jax.jit(step, donate_argnums=(0, 1, 2, 4) if block else ())
 
 
 def follow(cfg: dict, params0, batches: list[dict], hotness: np.ndarray,
@@ -179,22 +239,24 @@ def follow(cfg: dict, params0, batches: list[dict], hotness: np.ndarray,
     rows[1:1 + len(keys)] = init_rows(keys, cfg["embedding"], seed)
     slot_of_token = np.repeat(np.arange(len(hotness)), hotness)
     step = make_step(cfg, slot_of_token, dtype, fault)
-    params = jax.tree.map(jnp.asarray, params0)
+    # copies of its own: a step in blocks reuses its state's buffers
+    params = jax.tree.map(jnp.array, params0)
     m = jax.tree.map(jnp.zeros_like, params)
     v = jax.tree.map(jnp.zeros_like, params)
     count = jnp.zeros((), jnp.float32)
-    rows = jnp.asarray(rows)
+    rows = jnp.array(rows)
+    host = lambda t: jax.tree.map(np.array, jax.device_get(t))
     out = {"keys": keys, "rows0": np.asarray(rows[1:1 + len(keys)]),
-           "params0": jax.device_get(params), "losses": [], "after": {}}
+           "params0": host(params), "losses": [], "after": {}}
     for k, b in enumerate(batches, start=1):
         idx = np.where(b["mask"], np.searchsorted(keys, b["ids"]) + 1, 0)
         params, m, v, count, rows, loss = step(
             params, m, v, count, rows, jnp.asarray(idx, jnp.int32),
             jnp.asarray(b["mask"]), jnp.asarray(b["dense"]),
-            jnp.asarray(b["labels"], jnp.float32))
+            jnp.asarray(b["labels"], jnp.float32),
+            jnp.asarray(datagen.local_index(b["ids"]), jnp.int32))
         out["losses"].append(float(loss))
         if k in (1, len(batches)):
-            out["after"][k] = {"params": jax.device_get(params),
-                               "m": jax.device_get(m),
+            out["after"][k] = {"params": host(params), "m": host(m),
                                "rows": np.asarray(rows[1:1 + len(keys)])}
     return out

@@ -12,7 +12,8 @@ What a cell is comes from data, found by the name in ``BENCHMARK.json``:
 its configuration (``file`` of the entry in ``configs``), its traffic mix
 (``benchmark/workloads/<name>.json``), its per-layer metrics (one reader
 each, ``benchmark/metrics/<metric>.py``) and its model's plain reference
-(``benchmark/reference/<model>.py``). No cell's name is in this code.
+(``benchmark/reference/<model>.py``). No cell's name is in this code;
+``benchmark/README.md`` says what files a new cell brings.
 
 The window drives the user's day loop per pass — ``SlotDataset`` load of
 slot-text files, ``BoxPS.begin_pass``, ``Trainer.train_pass``,
@@ -33,8 +34,8 @@ come from that cycle. End-to-end metrics never come from a traced run.
 
 The run fails (non-zero, no last line) when JAX finds no TPU, or fewer
 chips than the cell asks for. ``--rehearse`` walks the same control flow
-at tiny sizes on whatever backend JAX has (the sandbox's CPU) and never
-prints a line of metrics.
+at tiny sizes (``rehearsal_sizes``) on whatever backend JAX has (the
+sandbox's CPU) and never prints a line of metrics.
 """
 
 from __future__ import annotations
@@ -56,6 +57,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+# a rehearsal's sizes, where the cell's own files give no ``rehearsal``
 REHEARSAL = {"steps_per_pass": 6, "max_ind_range": 2048, "files_per_pass": 2}
 REHEARSAL_BATCH = 256
 WARMUP_PASSES = 2      # one full cycle A, B: both row buckets compile
@@ -89,9 +91,21 @@ def load_cell(name: str, waiting: str | None = None
     cfg_entry = {c["name"]: c for c in configs}[cell["config"]]
     with open(os.path.join(ROOT, cfg_entry["file"])) as f:
         cfg = json.load(f)
-    mix = datagen.load_mix(os.path.join(ROOT, "benchmark", "workloads",
-                                        f"{name}.json"))
+    mix = datagen.load_mix(os.path.join(
+        ROOT, cell.get("file", f"benchmark/workloads/{name}.json")))
     return bench, cell, cfg, mix
+
+
+def rehearsal_sizes(cfg: dict, mix: dict) -> tuple[dict, dict]:
+    """The cell cut to a rehearsal: the defaults above, then the cell's own
+    ``rehearsal`` — in the configuration's file overrides of its groups
+    (``model_args``, ``trainer``, ``embedding``: merged key by key) and of
+    single keys, in the mix's file overrides of the mix."""
+    cfg = {**cfg, "trainer": {**cfg["trainer"],
+                              "global_batch_size": REHEARSAL_BATCH}}
+    for key, val in cfg.get("rehearsal", {}).items():
+        cfg[key] = {**cfg[key], **val} if isinstance(val, dict) else val
+    return cfg, {**mix, **REHEARSAL, **mix.get("rehearsal", {})}
 
 
 def metrics_of(bench: dict, cell: dict, kind: str) -> list[dict]:
@@ -165,9 +179,7 @@ def run(args) -> tuple[int, dict | None]:
     from benchmark.reference import steps as ref_steps
 
     if args.rehearse:
-        mix = {**mix, **REHEARSAL}
-        cfg = {**cfg, "trainer": {**cfg["trainer"],
-                                  "global_batch_size": REHEARSAL_BATCH}}
+        cfg, mix = rehearsal_sizes(cfg, mix)
     batch = int(cfg["trainer"]["global_batch_size"])
     n_sparse, dense_dim = datagen.slot_counts(cfg)
     hot = datagen.slot_hotness(mix, n_sparse)

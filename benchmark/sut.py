@@ -4,9 +4,10 @@ user's day loop.
 Everything the benchmark touches of ``paddlebox_tpu`` is in this file:
 the public entry points of the loop (``SlotDataset``, ``BoxPS``,
 ``Trainer.train_pass``), the counters the per-layer metrics read
-(``Trainer.timers``, ``feed_mgr.last_*``), the engine names of the flight
-record, and — for the comparison that decides ``correct`` — the trainer's
-own mid-pass snapshot hook, in whose place ``StepProbe`` stands.
+(``Trainer.timers``, ``feed_mgr.last_*``), ``Trainer.engines()`` and
+``Trainer.block_until_ready()``, and — for the comparison that decides
+``correct`` — the trainer's own mid-pass snapshot hook, in whose place
+``StepProbe`` stands.
 """
 
 from __future__ import annotations
@@ -63,17 +64,21 @@ def adam_first_moment(opt_state):
 
 
 def build_schema(cfg: dict, hotness: np.ndarray):
+    """The configuration's slot list as the program's schema. What a
+    slot's entry holds under ``args`` reaches ``Slot`` as keywords, so an
+    attribute the program's slots gain needs no edit here."""
     from paddlebox_tpu.data import DataFeedSchema
     from paddlebox_tpu.data.schema import Slot, SlotType
     slots, s = [], 0
     for spec in cfg["slots"]:
+        more = spec.get("args", {})
         if spec["kind"] == "sparse":
             slots.append(Slot(spec["name"], SlotType.UINT64,
-                              max_len=int(hotness[s])))
+                              max_len=int(hotness[s]), **more))
             s += 1
         else:
             slots.append(Slot(spec["name"], SlotType.FLOAT,
-                              max_len=int(spec.get("max_len", 1))))
+                              max_len=int(spec.get("max_len", 1)), **more))
     return DataFeedSchema(slots,
                           batch_size=cfg["trainer"]["global_batch_size"])
 
@@ -176,10 +181,7 @@ class System:
         """Wait for everything the loop has dispatched: the table (the
         last deferred apply lands after the last loss is read) and the
         dense state."""
-        import jax
-        ws = self.trainer._last_ws
-        jax.block_until_ready((ws.table if ws is not None else None,
-                               self.trainer.params, self.trainer.opt_state))
+        self.trainer.block_until_ready()
 
     def read_rows(self, keys: np.ndarray) -> np.ndarray:
         """The rows of `keys` as a user reads them between passes: the
@@ -188,17 +190,9 @@ class System:
         return self.store.get_rows(keys)
 
     def engines(self) -> dict:
-        """What the resolvers picked for the pass that just ran."""
-        tr = self.trainer
-        ws = tr._last_ws
-        return {"table_layout": tr.table_layout,
-                "pull_engine": tr.pull_engine,
-                "push_engine": tr.resolved_push_engine(ws),
-                "exchange_wire": tr.exchange_wire,
-                "push_overlap": bool(tr.push_overlap),
-                "host_plan": bool(tr._use_plan),
-                "table_shape": list(ws.table.shape),
-                "store_keys": len(self.store)}
+        """What the resolvers picked for the pass that just ran, and the
+        table's shape: the logical one and each array the device holds."""
+        return {**self.trainer.engines(), "store_keys": len(self.store)}
 
     def free(self) -> None:
         """Let go of the program's state, the device table with it (before

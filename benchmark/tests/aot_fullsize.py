@@ -60,13 +60,15 @@ def main(workload: str) -> None:
     def like(x, sh):
         return jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=sh)
 
-    table = like(ws.table, tbl)
+    # the table as the device holds it: one array, or a plane table's two
+    table = jax.tree.map(lambda plane: like(plane, tbl), ws.table)
     dstate = [like(x, rep) for x in tr.pack_dense()]
     args = [like(x, bat) for x in host]
     out = {"workload": workload, "table_shape": list(ws.table.shape),
+           "plane_shapes": [list(p.shape) for p in jax.tree.leaves(ws.table)],
            "push_engine": tr.resolved_push_engine(ws),
            "pull_engine": tr.pull_engine, "push_overlap": tr.push_overlap,
-           "host_plan": bool(tr._use_plan)}
+           "host_plan": tr.engines()["host_plan"]}
     t = time.time()
     if tr.push_overlap:
         step = tr._defer_step_fn.lower(table, *dstate, *args).compile()

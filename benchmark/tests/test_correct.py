@@ -36,11 +36,11 @@ def test_program_agrees_with_reference(cell):
     assert result["failed"] == 0 and result["attempted"] > 0
 
 
-def _followed(cell, seed, batch=256):
+def _followed(cell, seed):
     from benchmark import datagen, run
     from benchmark.reference import steps
-    _, _, cfg, mix = run.load_cell(cell, WAITING)
-    mix = {**mix, **run.REHEARSAL}
+    cfg, mix = run.rehearsal_sizes(*run.load_cell(cell, WAITING)[2:])
+    batch = cfg["trainer"]["global_batch_size"]
     n_sparse, dense_dim = datagen.slot_counts(cfg)
     hot = datagen.slot_hotness(mix, n_sparse)
     batches = datagen.make_passes(mix, n_sparse, dense_dim, batch,

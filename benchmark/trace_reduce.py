@@ -4,8 +4,9 @@ metrics read. Needs nothing but JAX (``jax.profiler.ProfileData``).
 A TPU's plane is ``/device:TPU:<n>``. Its line ``XLA Ops`` holds one event
 per executed HLO operation (a Pallas kernel shows under its custom call's
 name), ``XLA Modules`` one event per executed program. The host's plane
-``/host:CPU`` holds the harness's own spans as ``bench/<name>``
-annotations, on the same clock. From those:
+``/host:CPU`` holds, on the same clock, the harness's own spans as
+``bench/<name>`` annotations and the program's (every ``monitor.span`` and
+stage scope) as ``pbtpu/<name>``. From those:
 
   window_s     first start to last end of the ``bench/`` spans (of the
                device's events where there is no span)
@@ -15,9 +16,15 @@ annotations, on the same clock. From those:
                encloses others, a loop or a call, counts only what its
                children leave), averaged over the devices
   by_program   seconds per program name, from ``XLA Modules``
-  gaps         the device's idle intervals inside the window, cut at the
-               harness spans' edges, each piece with the innermost span
-               that covers it (first device)
+  gaps         the device's idle intervals inside the window (first
+               device), cut at the edges of the spans of the harness's
+               thread — the thread that drives the day loop, so the
+               program's training thread — each piece under the innermost
+               span, of either prefix, that covers it
+
+The nesting rule is the one of the program's own reader
+(``paddlebox_tpu.monitor.trace.nest_spans``), kept here as the benchmark's
+copy: what a trace is reduced to is the yardstick's to say.
 """
 
 from __future__ import annotations
@@ -26,7 +33,7 @@ import glob
 import os
 import re
 
-SPAN_PREFIX = "bench/"
+HARNESS_PREFIX, PROGRAM_PREFIX = "bench/", "pbtpu/"
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 
 
@@ -99,10 +106,59 @@ def program_name(event_name: str) -> str:
     return re.sub(r"\(\d+\)$", "", event_name)
 
 
+def innermost_segments(spans) -> list[tuple[float, float, str]]:
+    """One thread's spans ``(start, end, name)`` — they nest or follow,
+    never cross; a child that ends a rounding error after its parent is
+    cut to it — as the thread's covered time in segments ``(start, end,
+    name)``, each named by the innermost span that covers it."""
+    segs: list[tuple[float, float, str]] = []
+    stack: list[list] = []          # [end, name, start of its open segment]
+
+    def close(upto: float) -> None:
+        while stack and stack[-1][0] <= upto:
+            end, name, cursor = stack.pop()
+            if end > cursor:
+                segs.append((cursor, end, name))
+            if stack:
+                stack[-1][2] = end
+
+    for a, b, name in sorted(spans, key=lambda t: (t[0], -t[1])):
+        close(a)
+        if stack:
+            b = min(b, stack[-1][0])
+            if a > stack[-1][2]:
+                segs.append((stack[-1][2], a, stack[-1][1]))
+        stack.append([b, name, a])
+    close(float("inf"))
+    return sorted(segs)
+
+
+def label_gaps(busy, lo: float, hi: float, segments
+               ) -> list[tuple[str, float]]:
+    """The idle intervals that the merged `busy` intervals leave in
+    [lo, hi], cut at the edges of `segments` (sorted, disjoint): (name,
+    seconds) a piece, ``outside`` where no span covers it."""
+    gaps, edge, k = [], lo, 0
+    for a, b in list(busy) + [(hi, hi)]:
+        while edge < a:
+            while k < len(segments) and segments[k][1] <= edge:
+                k += 1
+            if k == len(segments) or segments[k][0] >= a:
+                name, upto = "outside", a
+            elif segments[k][0] > edge:
+                name, upto = "outside", segments[k][0]
+            else:
+                name, upto = segments[k][2], min(segments[k][1], a)
+            gaps.append((name, (upto - edge) / 1e9))
+            edge = upto
+        edge = max(edge, b)
+    return gaps
+
+
 def reduce(xplane_path: str) -> dict:
     import jax
     data = jax.profiler.ProfileData.from_file(xplane_path)
-    devices, spans = [], []
+    devices, threads = [], []
     for plane in data.planes:
         if plane.name.startswith("/device:TPU:"):
             lines = {ln.name: ln for ln in plane.lines}
@@ -113,16 +169,24 @@ def reduce(xplane_path: str) -> dict:
                                 if MODULES_LINE in lines else []))
         elif plane.name.startswith("/host:"):
             for ln in plane.lines:
-                spans += [(e.start_ns, e.start_ns + e.duration_ns,
-                           e.name[len(SPAN_PREFIX):])
-                          for e in ln.events
-                          if e.name.startswith(SPAN_PREFIX)]
-    out = {"devices": len(devices), "spans": sorted(spans)}
+                spans = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+                         for e in ln.events
+                         if e.name.startswith((HARNESS_PREFIX,
+                                               PROGRAM_PREFIX))]
+                if spans:
+                    threads.append(spans)
+    # the harness's thread, with the program's spans that ran on it; the
+    # window is the harness's own spans'
+    harness = next((t for t in threads if any(
+        name.startswith(HARNESS_PREFIX) for _, _, name in t)), [])
+    window = [s for s in harness if s[2].startswith(HARNESS_PREFIX)]
+    spans = sorted((a, b, name.split("/", 1)[1]) for a, b, name in harness)
+    out = {"devices": len(devices), "spans": spans}
     if not devices:
         return out
     every = [iv for _, ops, _ in devices for iv in ops]
-    lo = min(a for a, _, _ in (spans or every))
-    hi = max(b for _, b, _ in (spans or every))
+    lo = min(a for a, _, _ in (window or every))
+    hi = max(b for _, b, _ in (window or every))
     n = len(devices)
     busy, by_op, by_program = 0.0, {}, {}
     for _, ops, modules in devices:
@@ -132,24 +196,11 @@ def reduce(xplane_path: str) -> dict:
         for a, b, name in modules:
             name = program_name(name)
             by_program[name] = by_program.get(name, 0.0) + (b - a) / 1e9 / n
-    merged = _clip(union(devices[0][1]), lo, hi)
-    gaps, edge = [], lo
-    for a, b in merged + [(hi, hi)]:
-        if a > edge:
-            # a gap that runs across harness spans is cut at their edges,
-            # each piece under the innermost span that covers it
-            cuts = sorted({edge, a} | {t for s in spans for t in s[:2]
-                                       if edge < t < a})
-            for ga, gb in zip(cuts[:-1], cuts[1:]):
-                mid = (ga + gb) / 2
-                cover = [s for s in spans if s[0] <= mid <= s[1]]
-                label = min(cover, key=lambda s: s[1] - s[0])[2] \
-                    if cover else "outside"
-                gaps.append((label, (gb - ga) / 1e9))
-        edge = max(edge, b)
     out.update(
         window_s=(hi - lo) / 1e9, busy_s=busy, by_op=by_op,
-        by_program=by_program, gaps=gaps)
+        by_program=by_program,
+        gaps=label_gaps(_clip(union(devices[0][1]), lo, hi), lo, hi,
+                        innermost_segments(spans)))
     return out
 
 
