@@ -26,6 +26,11 @@ class Slot:
 
     ``max_len`` bounds the ids per example for sparse slots (longer lists are
     truncated, shorter padded); for float slots it is the fixed feature width.
+
+    ``sequence`` declares a sparse slot whose ids are ordered tokens (a
+    text of ``max_len`` positions over one vocabulary), not a bag: the
+    trainer hands their pulled rows to the model unpooled and in file
+    order on every pull engine, and never pools them inside the pull.
     """
 
     name: str
@@ -33,10 +38,14 @@ class Slot:
     is_dense: bool = False
     is_used: bool = True
     max_len: int = 1
+    sequence: bool = False
 
     def __post_init__(self) -> None:
         if self.max_len < 1:
             raise ValueError(f"slot {self.name}: max_len must be >= 1")
+        if self.sequence and self.type != SlotType.UINT64:
+            raise ValueError(f"slot {self.name}: only a sparse slot can be "
+                             f"a sequence")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,6 +65,11 @@ class DataFeedSchema:
     @property
     def sparse_slots(self) -> tuple[Slot, ...]:
         return tuple(s for s in self.slots if s.type == SlotType.UINT64 and s.is_used)
+
+    @property
+    def has_sequence(self) -> bool:
+        """Whether any sparse slot holds ordered tokens (Slot.sequence)."""
+        return any(s.sequence for s in self.sparse_slots)
 
     @property
     def float_slots(self) -> tuple[Slot, ...]:

@@ -150,12 +150,29 @@ def _patch_jit(out_sharding):
     return jax.jit(patch, **kw)
 
 
+def boundary_pad(n_fresh: int, n_leaving: int) -> int:
+    """Rows the fresh-row staging and the retiring rows' gather are padded
+    to at one incremental boundary: ONE bucket for both where the two
+    counts are of a size (within a factor of two). Passes that alternate
+    between two key sets swap the two counts boundary by boundary (A\\B
+    fresh and B\\A retiring, then the reverse), and counts that the draw
+    puts either side of a bucket's edge (5,116 | 5,343 around 5,120; 0 | 3
+    rows of a vocabulary) would otherwise be new shapes — a compile
+    inside a pass — on the second boundary. Lopsided churn (a key set
+    that shrinks or grows manyfold) keeps the fresh rows' own bucket: it
+    pays for no padding it has no use for."""
+    n_fresh, n_leaving = max(int(n_fresh), 1), max(int(n_leaving), 1)
+    if n_leaving <= 2 * n_fresh and n_fresh <= 2 * n_leaving:
+        return bucket_size(max(n_fresh, n_leaving))
+    return bucket_size(n_fresh)
+
+
 class _Staging:
     """Result of one feed pass: fresh rows staged on device + the diff."""
 
     __slots__ = ("keys", "pos_prev", "fresh_dev", "n_fresh", "h2d_bytes",
                  "prev", "store_gen", "full_ws", "timings", "marker",
-                 "patch_keys", "n_stale")
+                 "patch_keys", "n_stale", "pad_rows")
 
     def __init__(self, **kw):
         for k in self.__slots__:
@@ -390,7 +407,8 @@ class FeedPassManager:
             miss_rows = (self.store.peek_rows(miss_keys) if test_mode
                          else self.store.lookup_or_init(miss_keys))
             n_fresh = len(fresh_keys)
-            n_fresh_pad = bucket_size(max(1, n_fresh))
+            n_fresh_pad = boundary_pad(n_fresh,
+                                       prev.num_keys - (len(keys) - n_fresh))
             staged = np.zeros((n_fresh_pad, cfg.row_width), np.float32)
             # parity: compressed/quantized transfers must convert the served
             # rows through the same rounding as store-fetched ones, so those
@@ -448,6 +466,7 @@ class FeedPassManager:
                   h2d_bytes=int(transfer_bytes(cfg, n_fresh_pad)))
         return _Staging(keys=keys, pos_prev=pos, fresh_dev=fresh_dev,
                         n_fresh=n_fresh, n_stale=n_stale,
+                        pad_rows=n_fresh_pad,
                         h2d_bytes=transfer_bytes(cfg, n_fresh_pad),
                         prev=prev, store_gen=gen, marker=marker,
                         full_ws=None, timings=timing)
@@ -538,7 +557,8 @@ class FeedPassManager:
         d2h = 0
         if not test_mode:
             with mon_span("boundary/writeback"):
-                d2h = self._writeback_retiring(prev, keys)
+                d2h = self._writeback_retiring(prev, keys,
+                                               staged.pad_rows or 0)
         with mon_span("boundary/combine"):
             ws, carried = self._combine(staged, test_mode)
             n_patch, patch_bytes = self._apply_patch(ws, staged.patch_keys,
@@ -595,7 +615,7 @@ class FeedPassManager:
         return k, transfer_bytes(cfg, k_pad)
 
     def _writeback_retiring(self, prev: PassWorkingSet,
-                            new_keys: np.ndarray) -> int:
+                            new_keys: np.ndarray, pad_rows: int = 0) -> int:
         """Ship rows that are unsynced AND leaving the working set D2H —
         their device copy is about to be dropped, and it is the only fresh
         copy. Rows staying resident stay lazy. Returns bytes moved."""
@@ -613,9 +633,14 @@ class FeedPassManager:
         else:
             present = np.zeros(len(pkeys), bool)
         retiring = row_ids[~present]
+        # the gather runs at the boundary's common pad (boundary_pad) even
+        # for no row, so a boundary that is the first to retire a row — or
+        # to retire 5,343 where the last one retired 5,116 — compiles
+        # nothing a pass has to wait for
+        rows, nbytes = fetch_rows(prev.table, retiring, self.store.cfg,
+                                  pad_to=pad_rows)
         if len(retiring) == 0:
             return 0
-        rows, nbytes = fetch_rows(prev.table, retiring, self.store.cfg)
         rkeys = prev.sorted_keys[retiring - 1]
         self.store.write_back(rkeys, rows)
         if self._replica is not None:

@@ -98,8 +98,8 @@ def plane_layout(cfg: EmbeddingConfig) -> bool:
 
     Quantized storage always is (the embedx plane is the quantized part).
     f32 storage is where embedx(+expand) is a whole number of 128-lane
-    tiles (128, 256, 384, 512 — the widths the fused kernels' geometry
-    functions name): the chip stores such an f32 plane row-major with no
+    tiles (128, 256, ... — a CTR embedding's 128 or a token embedding's
+    2560 alike): the chip stores such an f32 plane row-major with no
     padding, so its rows can be gathered and written in place, and the
     push touches only the rows a step changed (sharded.push). Every
     other width keeps the one array. The two flags that assume one
@@ -109,7 +109,7 @@ def plane_layout(cfg: EmbeddingConfig) -> bool:
     columns at the boundary."""
     if cfg.storage != "f32":
         return True
-    return (0 < cfg.total_dim <= 512 and cfg.total_dim % 128 == 0
+    return (cfg.total_dim > 0 and cfg.total_dim % 128 == 0
             and device_width(cfg) == cfg.row_width
             and not flags.transfer_compress_embedx)
 
@@ -135,9 +135,16 @@ def bucket_size(x: int) -> int:
     the train step (and every pass-boundary kernel) per pass. Bucketing
     bounds the number of distinct compiled shapes to O(log N) while wasting
     at most ~25% rows (zero rows are never indexed — translate only maps to
-    1..K — and the per-step table scan cost is bandwidth-linear)."""
+    1..K — and the per-step table scan cost is bandwidth-linear).
+
+    Counts of 1 to 16 share the bucket 16: a table that every pass nearly
+    fills (a vocabulary) retires and admits a handful of rows a pass, and
+    each count in 1..16 must not be a compiled shape of its own (callers
+    pad by repeating the last row, or by rows nothing indexes)."""
+    if x <= 0:
+        return 0
     if x <= 16:
-        return int(x)
+        return 16
     p = 1 << (int(x).bit_length() - 1)
     step = p >> 2
     return -(-int(x) // step) * step
@@ -217,17 +224,23 @@ def _gather_rows_planes_jit():
 
 
 def fetch_rows(table: jax.Array, row_idx: np.ndarray,
-               cfg: EmbeddingConfig) -> tuple[np.ndarray, int]:
+               cfg: EmbeddingConfig,
+               pad_to: int = 0) -> tuple[np.ndarray, int]:
     """Device-side gather of `row_idx` rows, then D2H of just those rows.
 
     Returns (rows float32 (k, row_width), d2h_bytes). The index vector is
     padded to a size bucket so repeated pass boundaries reuse a handful of
     compiled gathers instead of recompiling per dirty-row count.
+    `pad_to`: pad the index vector to at least this many rows, and run
+    the gather even for no row (null rows) — for a caller whose count
+    differs from one pass boundary to the next by the draw alone (0 rows,
+    then a handful; 5,116, then 5,343), so that no boundary meets a
+    program the one before it did not compile.
     """
     k = len(row_idx)
-    if k == 0:
+    if k == 0 and not pad_to:
         return np.zeros((0, cfg.row_width), np.float32), 0
-    k_pad = bucket_size(k)
+    k_pad = max(bucket_size(k), int(pad_to))
     idxp = np.zeros(k_pad, np.int32)
     idxp[:k] = row_idx
     if quant.is_planes(table):

@@ -25,7 +25,7 @@ from __future__ import annotations
 # stage_seconds keys that run on a worker thread and overlap the main
 # loop (attributed separately — charging them to the wall would double-
 # count the interval the train stage already covers)
-OVERLAPPED_STAGES = ("translate",)
+OVERLAPPED_STAGES = ("translate", "extras")
 
 # stage_seconds keys that ENCLOSE other components on the main thread
 # ("head": train_pass entry to the first step's dispatch, holding

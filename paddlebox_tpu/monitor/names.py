@@ -149,8 +149,10 @@ SPAN_NAMES: tuple[str, ...] = (
     "pass_close/end_pass",
     "stage/drain",
     "pass_close/read",
-    # the pack thread
+    # the pack thread ("stage/extras": a model's own host stage, e.g. a
+    # sequence slot's ids within its vocabulary — Trainer._pack_host)
     "stage/translate",
+    "stage/extras",
     # around the pass: ingest (the caller's or the preload thread) and
     # the BoxPS lifecycle calls
     "ingest",
@@ -161,6 +163,19 @@ SPAN_NAMES: tuple[str, ...] = (
     # flags.serving_trace_sample): batch-coalesce wait vs. score time
     "serve/wait",
     "serve/score",
+)
+
+# statistics a model's loss may declare (models/base.py ``stat_names``):
+# one value a step, out of the step program, into the flight record's
+# counters at the pass's close. ``*_max``: the largest step of the pass
+# (a gauge); the others are sums (counters).
+MODEL_STAT_NAMES: tuple[str, ...] = (
+    # parallel/expert.py share layer: (token, choice) assignments routed,
+    # those that fell on an expert this chip holds, and a step's busiest
+    # held expert (imbalance)
+    "moe.assignments",
+    "moe.held_assignments",
+    "moe.expert_load_max",
 )
 
 ALL_NAMES: frozenset = frozenset(EVENT_NAMES) | frozenset(SPAN_NAMES)

@@ -649,8 +649,13 @@ def resolve_push_engine(cfg: EmbeddingConfig, n_rows: int, *,
     """
     eng = _push_engine_flag()
     width = int(table_width) if table_width is not None else cfg.row_width
-    sa_ok = (premerged and storage_f32
-             and scatter_accumulate_supported(n_rows, width))
+    # an f32 plane table's embedx plane is total_dim wide — narrower than
+    # any one-array table of this cfg — and takes XLA's gather and scatter
+    # (_scatter_accumulate_planes), which the row-DMA kernel's width cap
+    # does not bind: a 2560-wide token embedding is touched by rows too
+    planes = width == cfg.total_dim and width % _LANES == 0
+    sa_ok = (premerged and storage_f32 and n_rows > 0
+             and (planes or scatter_accumulate_supported(n_rows, width)))
     if eng == "xla_scatter":
         return "xla_scatter"
     if eng == "scatter_accumulate":

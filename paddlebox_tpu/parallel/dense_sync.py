@@ -45,6 +45,14 @@ def flatten_dense(params) -> tuple[np.ndarray, Callable]:
     return np.asarray(flat, dtype=np.float32), unravel
 
 
+# The flat transport pays for itself on towers of many small leaves (a CTR
+# tower: 10^5..10^7 floats in ~30 leaves). Past this many floats the
+# trainer keeps the trees: pack and unpack are copies of the whole state
+# inside every step, and the public trees would live beside the flat
+# vectors through a pass — at 5.6e8 parameters 6.7 GB twice.
+FLAT_STATE_MAX_FLOATS = 1 << 26
+
+
 def make_dense_packer(params_template, opt_template):
     """(pack, unpack, n_args): flatten the dense params and the f32
     leaves of the optimizer state into TWO flat vectors plus the non-f32

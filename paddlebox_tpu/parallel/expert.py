@@ -14,10 +14,21 @@ fixed-capacity ``all_to_all`` pattern the embedding engine uses for keys
 Tokens beyond a lane's capacity are dropped (standard MoE capacity-factor
 semantics; monitor with `dropped_tokens`). Numerics match `moe_reference`
 for all surviving tokens.
+
+The share layer (``route_top_k`` + ``held_expert_ffn``) is the other shape
+of expert parallelism: a chip is told which experts of a layer it holds
+(``held = (first, count)`` of ``n_experts``), every token is routed over
+ALL experts, and the chip computes the part of the layer's output that its
+held experts contribute — grouped matrix products over the (token, choice)
+assignments sorted by held expert, no capacity and no drop at any
+imbalance. What the absent experts would add is another chip's part and is
+not computed, approximated or stood in for; on a mesh of one chip the layer
+runs without an exchange.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Sequence
 
 import jax
@@ -177,3 +188,129 @@ def make_moe(mesh: Mesh, num_experts: int, top_k: int = 2,
     return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec_p, P(EP_AXIS)),
         out_specs=P(EP_AXIS)))
+
+
+# ---------------------------------------------------------------------------
+# the share layer: one chip's held experts of a layer routed over all
+# ---------------------------------------------------------------------------
+
+def route_top_k(router_logits: jnp.ndarray, top_k: int
+                ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(probs, experts), both (N, top_k): the top_k largest of each
+    token's logits over ALL experts and the softmax over those top_k
+    logits — equal to a softmax over all experts renormalised over the
+    chosen ones. Normalised over every choice, held here or not."""
+    vals, experts = lax.top_k(router_logits, top_k)
+    return jax.nn.softmax(vals, axis=-1), experts
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _tokens_by_expert(x, order, inv, k: int):
+    """x[order // k]: every (token, choice) assignment's token row, in the
+    sorted order. `inv` is the inverse permutation of `order`, so the
+    cotangent is a gather too (back to (token, choice) order, summed over
+    a token's k choices) and never a scatter-add over repeated rows."""
+    return jnp.take(x, order // k, axis=0)
+
+
+def _tokens_by_expert_fwd(x, order, inv, k):
+    return jnp.take(x, order // k, axis=0), inv
+
+
+def _tokens_by_expert_bwd(k, inv, g):
+    by_token = jnp.take(g, inv, axis=0).reshape(g.shape[0] // k, k, -1)
+    return (jnp.sum(by_token.astype(jnp.float32), axis=1).astype(g.dtype),
+            None, None)
+
+
+_tokens_by_expert.defvjp(_tokens_by_expert_fwd, _tokens_by_expert_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inv):
+    """x[perm] for a permutation `perm` with inverse `inv`: the cotangent
+    is the gather g[inv], not a scatter."""
+    return jnp.take(x, perm, axis=0)
+
+
+_permute_rows.defvjp(
+    lambda x, perm, inv: (jnp.take(x, perm, axis=0), inv),
+    lambda inv, g: (jnp.take(g, inv, axis=0), None, None))
+
+
+def _held_chunk(x, probs, experts, w_gate, w_up, w_down, first: int,
+                count: int):
+    """One chunk of tokens through the held experts (ReGLU). Returns the
+    chunk's output (n, D) and its assignments per held expert (count,)."""
+    n, k = experts.shape
+    local = experts - first
+    held = (local >= 0) & (local < count)
+    # not-held assignments sort past the last group: never computed
+    key = jnp.where(held, local, count).reshape(-1).astype(jnp.int32)
+    order = jnp.argsort(key)                      # stable: by expert
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(n * k, dtype=order.dtype))
+    sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
+    # rows past the last group belong to no held expert: the grouped
+    # products neither compute them nor their cotangents (on the chip what
+    # they leave there is whatever the buffer held), so nothing may flow
+    # through them in either direction
+    in_group = (jnp.arange(n * k) < jnp.sum(sizes))[:, None]
+    xs = jnp.where(in_group, _tokens_by_expert(
+        x.astype(w_gate.dtype), order, inv, k), 0)
+    dot = functools.partial(lax.ragged_dot, group_sizes=sizes,
+                            preferred_element_type=jnp.float32)
+    hidden = jax.nn.relu(dot(xs, w_gate)) * dot(xs, w_up)
+    ys = dot(hidden.astype(w_down.dtype), w_down).astype(x.dtype)
+    # back to (token, choice) order
+    y = _permute_rows(jnp.where(in_group, ys, 0), inv, order)
+    out = jnp.einsum("nkd,nk->nd", y.reshape(n, k, -1),
+                     probs.astype(y.dtype))
+    return out, sizes
+
+
+def held_expert_ffn(x: jnp.ndarray, probs: jnp.ndarray,
+                    experts: jnp.ndarray, w_gate: jnp.ndarray,
+                    w_up: jnp.ndarray, w_down: jnp.ndarray,
+                    held: tuple[int, int], chunk_tokens: int = 4096
+                    ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """sum over (token, choice) with the chosen expert held here of
+    p * (relu(x W_gate_e) * (x W_up_e)) W_down_e.
+
+    x (N, D); probs, experts (N, k) from ``route_top_k``; the weights of
+    the ``count`` held experts stacked on axis 0, (count, D, H) twice and
+    (count, H, D); ``held = (first, count)``: this chip holds the global
+    experts first .. first + count - 1. Returns the held part of the
+    layer's output (N, D) and the assignments each held expert received
+    (count,) int32 — nothing is dropped, whatever the imbalance.
+
+    The assignments are sorted by held expert and taken through
+    ``lax.ragged_dot`` (on a TPU XLA's grouped matrix product, whose row
+    tiles past the last group are not computed). Tokens go through in
+    chunks of ``chunk_tokens``, so the sorted copy is bounded by the
+    chunk's worst case (every choice held) and not the batch's; a chunk
+    is recomputed in the backward pass instead of stored."""
+    first, count = int(held[0]), int(held[1])
+    if w_gate.shape[0] != count:
+        raise ValueError(f"{w_gate.shape[0]} expert weights for "
+                         f"held={held}")
+    n = x.shape[0]
+    chunk = min(int(chunk_tokens), n)
+    if n % chunk:
+        raise ValueError(f"{n} tokens do not divide into chunks of {chunk}")
+    if jax.default_backend() == "tpu" and x.dtype == jnp.float32:
+        # the device's default precision for a float32 product, stated:
+        # bfloat16 operands, float32 sums (the grouped product would
+        # otherwise take float32 operands in several passes); the weights
+        # are cast once a call, not once a chunk
+        w_gate, w_up, w_down = (w.astype(jnp.bfloat16)
+                                for w in (w_gate, w_up, w_down))
+    one = jax.checkpoint(
+        lambda xc, pc, ec: _held_chunk(xc, pc, ec, w_gate, w_up, w_down,
+                                       first, count))
+    if chunk == n:
+        return one(x, probs, experts)
+    parts = lambda a: a.reshape(n // chunk, chunk, *a.shape[1:])
+    out, sizes = lax.map(lambda c: one(*c),
+                         (parts(x), parts(probs), parts(experts)))
+    return out.reshape(n, -1), jnp.sum(sizes, axis=0)

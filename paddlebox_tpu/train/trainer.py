@@ -37,6 +37,7 @@ from paddlebox_tpu.embedding import (EmbeddingConfig, HostEmbeddingStore,
 from paddlebox_tpu.embedding.feed_pass import FeedPassManager
 from paddlebox_tpu.embedding.working_set import PushOperandStager
 from paddlebox_tpu.metrics import auc as auc_lib
+from paddlebox_tpu.models import base as model_base
 from paddlebox_tpu.ops.seqpool_cvm import PooledSlots
 from paddlebox_tpu.parallel import dense_sync
 from paddlebox_tpu.train import optimizers
@@ -198,23 +199,31 @@ class Trainer:
         # self.params/self.opt_state stay pytrees — pack/unpack at pass
         # boundaries via pack_dense/unpack_dense.
         self._dense_packer = None
+        n_dense_floats = sum(int(np.prod(l.shape))
+                             for l in jax.tree.leaves(init_params))
+        # The transport is for a tower of many small leaves: packing
+        # copies the state inside the step and keeps the public trees
+        # alive beside the flat vectors through a pass, so a tower past
+        # FLAT_STATE_MAX_FLOATS keeps its tree (a few large leaves,
+        # donated and updated in place).
         if (self.cfg.dense_sync_mode == "allreduce"
-                and config_flags.flat_dense_state):
+                and config_flags.flat_dense_state
+                and n_dense_floats <= dense_sync.FLAT_STATE_MAX_FLOATS):
             # self.opt_state (built above in the allreduce branch) serves
             # as the shape/dtype template — no second tx.init
             self._dense_packer = dense_sync.make_dense_packer(
                 init_params, self.opt_state)
         self._n_dense_args = (self._dense_packer[2]
                               if self._dense_packer else 2)
-        # "read", "translate" (pack thread) and "drain" emit as
-        # stage/<name>; the others share their scope with a span
+        # "read", "translate" and "extras" (pack thread) and "drain" emit
+        # as stage/<name>; the others share their scope with a span
         # (timers("train", span="train_step")). "head" (entry of
         # train_pass to the first step's dispatch) and "close" enclose
         # other stages (critical_path.NESTED_STAGES); the rest are
         # disjoint on their thread.
-        self.timers = StageTimers(["read", "translate", "train", "auc",
-                                   "drain", "unique_keys", "preplan",
-                                   "h2d", "head", "close"])
+        self.timers = StageTimers(["read", "translate", "extras", "train",
+                                   "auc", "drain", "unique_keys",
+                                   "preplan", "h2d", "head", "close"])
         # incremental + overlapped pass boundaries (BoxHelper FeedPass):
         # resident device rows are reused across passes, write-back is lazy.
         # Pass a shared manager when several trainers drive one table
@@ -232,6 +241,17 @@ class Trainer:
             raise NotImplementedError(
                 "models with batch_extras support the allreduce "
                 "dense-sync mode only")
+        # What the model declares beside its loss (models/base.py):
+        # whether it makes a prediction (the AUC accumulator and the
+        # metric registry get it, or nothing), and the statistics its
+        # loss returns each step (counters of the flight record).
+        self._feeds_auc = model_base.predicts(model)
+        self._n_stats = len(model_base.stat_names(model))
+        if self._n_stats and (self.cfg.dense_sync_mode != "allreduce"
+                              or self.cfg.steps_per_dispatch > 1):
+            raise NotImplementedError(
+                "models that declare stat_names support the allreduce "
+                "dense-sync mode with steps_per_dispatch=1 only")
         # Table-layout engine (flags.table_layout): which embedding
         # exchange the step programs compile with. "sharded" routes the
         # dedup plan's unique rows through embedding/exchange.py (wire-
@@ -430,6 +450,13 @@ class Trainer:
         model = self.model
         capf = cfg.capacity_factor
         num_slots = self.layout.num_slots
+        # the loss and the prediction are the model's to declare
+        # (models/base.py): (loss, (preds, stats)) of one local batch
+        model_loss = model_base.declared_loss(model, seg, num_slots)
+        # a model's declared statistics ride out of the step as one small
+        # vector (model_base.stat_names); none for a model that has none
+        no_stats = ((jnp.zeros((self._n_stats,), jnp.float32),)
+                    if self._n_stats else ())
 
         # FLAGS_enable_pullpush_dedup_keys (flags.cc:603): merge duplicate
         # tokens before the all_to_all so routed traffic carries each key
@@ -500,14 +527,10 @@ class Trainer:
                     dropped = jnp.zeros((), jnp.int32)
 
                 def loss_fn(p, pooled_in):
-                    logits = model.apply(p, PooledSlots(pooled_in), mask_l,
-                                         dense_l, seg, num_slots,
-                                         *extras_l)
-                    loss = jnp.mean(
-                        optax.sigmoid_binary_cross_entropy(logits,
-                                                           labels_l))
-                    return loss, jax.nn.sigmoid(logits)
+                    return model_loss(p, PooledSlots(pooled_in), mask_l,
+                                      dense_l, labels_l, *extras_l)
 
+                stats = no_stats
                 if "fwdbwd" in ablate:
                     loss = jnp.sum(pooled) * 1e-8
                     preds = jnp.zeros((B_l,), jnp.float32)
@@ -518,14 +541,16 @@ class Trainer:
                 else:
                     grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1),
                                                  has_aux=True)
-                    (loss, preds), (gp, gpooled) = grad_fn(params, pooled)
+                    (loss, (preds, stats)), (gp, gpooled) = grad_fn(
+                        params, pooled)
                     sgrad = sharded.pooled_grad_tokens(gpooled, mask_l,
                                                        seg, num_slots)
                     if cfg.scale_sparse_grad_by_global_mean:
                         sgrad = sgrad / D
                 new_shard = push_tail(tshard, flat_idx, sgrad, mask_l,
                                       labels_l, plan)
-                return new_shard, gp, loss, preds, lax.psum(dropped, axes)
+                return (new_shard, gp, loss, preds, lax.psum(dropped, axes),
+                        *stats)
             if "lookup" in ablate:
                 pulled = lax.optimization_barrier(
                     jnp.zeros((B_l * T, emb_cfg.pull_width), jnp.float32)
@@ -542,12 +567,10 @@ class Trainer:
             pulled = pulled.reshape(B_l, T, emb_cfg.pull_width)
 
             def loss_fn(p, pulled_in):
-                logits = model.apply(p, pulled_in, mask_l, dense_l, seg,
-                                     num_slots, *extras_l)
-                loss = jnp.mean(
-                    optax.sigmoid_binary_cross_entropy(logits, labels_l))
-                return loss, jax.nn.sigmoid(logits)
+                return model_loss(p, pulled_in, mask_l, dense_l, labels_l,
+                                  *extras_l)
 
+            stats = no_stats
             if "fwdbwd" in ablate:
                 loss = jnp.sum(pulled) * 1e-8
                 preds = jnp.zeros((B_l,), jnp.float32)
@@ -558,7 +581,8 @@ class Trainer:
             else:
                 grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1),
                                              has_aux=True)
-                (loss, preds), (gp, gpull) = grad_fn(params, pulled)
+                (loss, (preds, stats)), (gp, gpull) = grad_fn(params,
+                                                              pulled)
                 # sparse grads: only (w, embedx) columns train; show/clk
                 # are counters (CVM grads dropped, like cvm_op's grad)
                 sgrad = gpull[..., 2:].reshape(B_l * T, emb_cfg.grad_width)
@@ -570,7 +594,7 @@ class Trainer:
             # all_to_all lanes could not carry this step (push routes the
             # same tokens at the same capacity, so one count covers both)
             dropped_g = lax.psum(dropped, axes)
-            return new_shard, gp, loss, preds, dropped_g
+            return new_shard, gp, loss, preds, dropped_g, *stats
 
         return core
 
@@ -663,15 +687,20 @@ class Trainer:
         # uniform-arity deferred push operand triple (flags.push_overlap)
         n_head = 3 if defer else 1
 
+        n_st = 1 if self._n_stats else 0
+        stat_names = model_base.stat_names(self.model)
+
         def body(tshard, idx_l, mask_l, dense_l, labels_l, params,
                  order, rstart, endb, uniq, segb, *extras_l):
-            head, gp, loss, preds, drop_g = core(
+            head, gp, loss, preds, drop_g, *stats = core(
                 tshard, idx_l, mask_l, dense_l, labels_l, params,
                 order, rstart, endb, uniq, segb, *extras_l)
             gp = _mean_replicated_grad(gp, axes)
             loss_g = lax.pmean(loss, axes)
             head = head if defer else (head,)
-            return (*head, gp, loss_g, preds, drop_g)
+            stats = [model_base.reduce_stats(stat_names, st, axes)
+                     for st in stats]
+            return (*head, gp, *stats, loss_g, preds, drop_g)
 
         def run_body(table, params, opt_state, idx, mask, dense, labels,
                      order, rstart, endb, uniq, segb, *extras):
@@ -682,13 +711,14 @@ class Trainer:
                           batch_spec, batch_spec, batch_spec)
                 + (batch_spec,) * n_extras,
                 out_specs=(batch_spec,) * n_head
-                + (P(), P(), batch_spec, P()),
+                + (P(),) * (1 + n_st) + (P(), batch_spec, P()),
             )(table, idx, mask, dense, labels, params,
               order, rstart, endb, uniq, segb, *extras)
-            head, (gp, loss, preds, drop_g) = out[:n_head], out[n_head:]
+            head, gp, tail = out[:n_head], out[n_head], out[n_head + 1:]
             updates, new_opt = tx.update(gp, opt_state, params)
             new_params = optax.apply_updates(params, updates)
-            return head, new_params, new_opt, loss, preds, drop_g
+            # tail: (*stats, loss, preds, drop_g)
+            return head, new_params, new_opt, tail
 
         if self._dense_packer is not None:
             pack_fn, unpack_fn, n_dense = self._dense_packer
@@ -698,24 +728,22 @@ class Trainer:
                 (idx, mask, dense, labels, order, rstart,
                  endb, uniq, segb, *extras) = args[n_dense:]
                 params, opt_state = unpack_fn(dstate)
-                head, new_params, new_opt, loss, preds, drop_g = \
+                head, new_params, new_opt, tail = \
                     run_body(table, params, opt_state, idx, mask, dense,
                              labels, order, rstart, endb, uniq, segb,
                              *extras)
                 if defer:
-                    # (*dstate, g0, g1, g2, loss, preds, dropped): the
-                    # table is read, never written — the apply program
-                    # owns the update (split with split_defer_out)
-                    return (*pack_fn(new_params, new_opt), *head, loss,
-                            preds, drop_g)
-                return (head[0], *pack_fn(new_params, new_opt), loss,
-                        preds, drop_g)
+                    # (*dstate, g0, g1, g2, [stats,] loss, preds,
+                    # dropped): the table is read, never written — the
+                    # apply program owns the update (split_defer_out)
+                    return (*pack_fn(new_params, new_opt), *head, *tail)
+                return (head[0], *pack_fn(new_params, new_opt), *tail)
 
             if defer:
                 return jax.jit(
                     step_flat, donate_argnums=tuple(range(1, 1 + n_dense)),
                     out_shardings=(repl,) * n_dense + (bat_sh,) * 3
-                    + (repl, bat_sh, repl))
+                    + (repl,) * n_st + (repl, bat_sh, repl))
 
             if scan_steps > 1:
                 # k-microbatch superstep: ONE dispatch runs k sequential
@@ -744,28 +772,28 @@ class Trainer:
 
             return jax.jit(step_flat, donate_argnums=(0, 1, 2),
                            out_shardings=(tbl_sh,) + (repl,) * n_dense
-                           + (repl, bat_sh, repl))
+                           + (repl,) * n_st + (repl, bat_sh, repl))
 
         def step(table, params, opt_state, idx, mask, dense, labels,
                  order=_NO_PLAN, rstart=_NO_PLAN, endb=_NO_PLAN,
                  uniq=_NO_PLAN, segb=_NO_PLAN, *extras):
-            head, new_params, new_opt, loss, preds, drop_g = run_body(
+            head, new_params, new_opt, tail = run_body(
                 table, params, opt_state, idx, mask, dense, labels,
                 order, rstart, endb, uniq, segb, *extras)
             if defer:
-                return (new_params, new_opt, *head, loss, preds, drop_g)
-            return (head[0], new_params, new_opt, loss, preds, drop_g)
+                return (new_params, new_opt, *head, *tail)
+            return (head[0], new_params, new_opt, *tail)
 
         if defer:
             return jax.jit(step, donate_argnums=(1, 2),
                            out_shardings=(repl, repl) + (bat_sh,) * 3
-                           + (repl, bat_sh, repl))
+                           + (repl,) * n_st + (repl, bat_sh, repl))
         # Donation aliases the (large) table and the dense state in place;
         # pinned out_shardings make output signatures identical to the inputs
         # so the train_pass feedback loop never retraces.
         return jax.jit(step, donate_argnums=(0, 1, 2),
-                       out_shardings=(tbl_sh, repl, repl, repl, bat_sh,
-                                      repl))
+                       out_shardings=(tbl_sh, repl, repl)
+                       + (repl,) * n_st + (repl, bat_sh, repl))
 
     def _build_apply_fn(self) -> Callable:
         """The deferred table-apply program (flags.push_overlap): consumes
@@ -959,8 +987,6 @@ class Trainer:
             labels, dense = self.split_floats(pb.floats)
             plan = (self._host_plan(ws, idx) if with_plan
                     else (np.zeros(0, np.int32),) * PLAN_ARITY)
-            extras = (self._extras_fn(pb, self.n_shards)
-                      if self._extras_fn is not None else ())
             # embedding-plane traffic counters (flight-record deltas):
             # pull = tokens * pull_width rows out, push = grad + show/clk
             # lanes back (approximate routed volume; exact per-engine
@@ -972,6 +998,12 @@ class Trainer:
             if with_plan:
                 monitor.counter_add("trainer.push_bytes",
                                     idx.size * 4 * (ecfg.grad_width + 2))
+        extras = ()
+        if self._extras_fn is not None:
+            # what the model's own host stage adds to a batch (a sequence
+            # slot's ids within its vocabulary, a PV batch's rank_offset)
+            with self.timers("extras"):
+                extras = self._extras_fn(pb, self.n_shards)
         return (idx, pb.mask, dense.astype(np.float32),
                 labels.astype(np.float32), *plan, *extras)
 
@@ -1231,6 +1263,15 @@ class Trainer:
             return "gather_seqpool"
         lay = self.layout
         cfg = self.store.cfg
+        if self.schema.has_sequence:
+            # ordered tokens reach the model unpooled, in file order, on
+            # every engine: the slot's declaration decides, not a flag
+            if fg == "on":
+                raise ValueError(
+                    "flags.fused_gather_pool='on' pools a slot's tokens "
+                    "inside the pull; this schema declares a sequence "
+                    "slot (Slot.sequence), whose tokens stay in order")
+            return "gather_seqpool"
         uniform = (lay.num_slots > 0
                    and len(lay.slot_lens)
                    and np.all(lay.slot_lens == lay.slot_lens[0]))
@@ -1408,6 +1449,8 @@ class Trainer:
             seconds=time.perf_counter() - pass_t0,
             loss_mean=out.get("loss_mean"), auc=out.get("auc"),
             routed_dropped=out.get("routed_dropped"),
+            # the model's declared statistics (None for a model with none)
+            model_stats=out.get("model_stats"),
             push_applies=(self.push_applies - applies0) or None,
             pull_engine=self.pull_engine,
             # which push merge engine this pass's steps compiled with
@@ -1573,6 +1616,7 @@ class Trainer:
         pass_step = 0
         dev_losses: list[Any] = []
         dev_dropped: list[Any] = []
+        dev_stats: list[Any] = []     # the model's declared statistics
         # DumpField stream: the PREVIOUS batch's (step, preds, labels) is
         # written each iteration — by then those arrays are ready, so the
         # D2H copy doesn't stall the freshly-dispatched step — and the
@@ -1691,25 +1735,32 @@ class Trainer:
                          dropped) = self.split_step_out(out)
                         pass_step += 1
                     else:
-                        (table, params, opt_state, loss, preds,
-                         dropped) = self._step_fn(
+                        out = self._step_fn(
                             table, params, opt_state, idx, mask, dense,
                             labels, *plan)
+                        (table, (params, opt_state), loss, preds,
+                         dropped) = self.split_step_out(out)
                         pass_step += 1
                         if (mode == "kstep"
                                 and pass_step % cfg.param_sync_step == 0):
                             params, opt_state = self._sync_fn(params,
                                                               opt_state)
+                if self._n_stats:
+                    # the model's statistics sit just before the loss in
+                    # every allreduce single-step program's output
+                    dev_stats.append(out[-4])
                 # keep the ws pointing at the live buffer: the step donates
                 # its input table, and a concurrent flush (store read/save
                 # from another thread) must never gather from a dead buffer
                 ws.table = table
                 with self.timers("auc", span="auc_update"):
                     # the AUC histogram is order-invariant: a stacked
-                    # (k, B) group updates in one flattened call
-                    auc_acc.update(self._auc_fn, preds.reshape(-1),
-                                   labels.reshape(-1))
-                    if metrics is not None:
+                    # (k, B) group updates in one flattened call. A model
+                    # that declares no prediction feeds nothing.
+                    if self._feeds_auc:
+                        auc_acc.update(self._auc_fn, preds.reshape(-1),
+                                       labels.reshape(-1))
+                    if metrics is not None and self._feeds_auc:
                         if stacked:
                             for i, gpb in enumerate(pbs):
                                 metrics.add_batch(preds[i], labels[i],
@@ -1845,11 +1896,12 @@ class Trainer:
                         warnings.warn(f"dump stream failed: {e}")
                 if completed:
                     out = self._read_pass(ws, table, dev_losses,
-                                          dev_dropped, auc_acc)
+                                          dev_dropped, auc_acc, dev_stats)
         return out
 
     def _read_pass(self, ws: PassWorkingSet, table, dev_losses: list,
-                   dev_dropped: list, auc_acc) -> dict[str, float]:
+                   dev_dropped: list, auc_acc,
+                   dev_stats: list = ()) -> dict[str, float]:
         """The end of a pass that ran through: end_pass, then the drain
         of the loop's losses and the AUC read (inside ``pass_close``)."""
         with monitor.span("pass_close/end_pass"):
@@ -1873,6 +1925,13 @@ class Trainer:
             out["losses"] = losses
             out["steps"] = len(losses)
             out["routed_dropped"] = self._check_dropped(dev_dropped)
+            if dev_stats:
+                # the model's declared statistics, one vector a step:
+                # into the flight record's counters (``*_max`` names are
+                # the pass's largest step, the others sums)
+                out["model_stats"] = model_base.publish_stats(
+                    self.model, np.stack([np.asarray(v)
+                                          for v in dev_stats]))
         return out
 
     def _preplan_capacity(self, dataset, ws: PassWorkingSet,
@@ -2476,6 +2535,10 @@ class Trainer:
         impossible. The trainer-level half of the exchange's
         never-silent overflow policy (the train side is preplanned
         lossless up front and doubles for its next pass)."""
+        if not self._feeds_auc:
+            raise NotImplementedError(
+                f"model {self.model.name!r} declares no prediction "
+                f"(models/base.py): an eval pass has nothing to score")
         # flush-before-eval ordering (push_overlap): predictions must see
         # every trained row value; a pending deferred apply lands first
         self.flush_push()

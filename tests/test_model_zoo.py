@@ -19,7 +19,8 @@ def mesh8():
 
 def test_registry_complete():
     assert set(MODEL_REGISTRY) == {"dnn_ctr", "deepfm", "wide_deep",
-                                   "dcn_v2", "dlrm", "mmoe", "pv_rank"}
+                                   "dcn_v2", "dlrm", "mmoe", "pv_rank",
+                                   "smallthinker"}
 
 
 @pytest.mark.parametrize("model_cls,kw", [

@@ -102,7 +102,8 @@ def _as_rows(table, cfg):
 @pytest.mark.parametrize("storage,dim,expand,planes", [
     ("f32", 8, 0, False), ("f32", 10, 0, False), ("f32", 64, 0, False),
     ("f32", 120, 0, False), ("f32", 128, 0, True), ("f32", 64, 64, True),
-    ("f32", 256, 0, True), ("f32", 512, 0, True), ("f32", 640, 0, False),
+    ("f32", 256, 0, True), ("f32", 512, 0, True), ("f32", 640, 0, True),
+    ("f32", 2560, 0, True), ("f32", 600, 0, False),
     ("f32", 0, 0, False), ("int8", 8, 0, True), ("int16", 128, 0, True)])
 def test_layout_rule(storage, dim, expand, planes):
     cfg = EmbeddingConfig(dim=dim, expand_dim=expand, storage=storage)
@@ -425,3 +426,67 @@ def test_routed_apply_on_planes_two_shards(restore_flags):
         out, jnp.asarray(idx))
     np.testing.assert_array_equal(np.asarray(pulled),
                                   got[idx][:, :cfg.pull_width])
+
+
+# ---------------------------------------------------------------------------
+# (g) a boundary that moves a handful of rows meets one compiled shape
+# ---------------------------------------------------------------------------
+
+def test_bucket_size_small_counts_share_one_bucket():
+    assert working_set.bucket_size(0) == 0
+    assert {working_set.bucket_size(x) for x in range(1, 17)} == {16}
+    assert working_set.bucket_size(17) == 20
+    # the counts of a large pass keep their buckets
+    assert working_set.bucket_size(5285) == 6144
+    assert working_set.bucket_size(5000) == 5120
+
+
+def test_boundary_pad_is_one_bucket_for_counts_of_a_size():
+    pad = feed_pass.boundary_pad
+    # two key sets in turn swap the counts: either order, one bucket —
+    # also where the draw puts them either side of a bucket's edge
+    assert pad(5116, 5343) == pad(5343, 5116) == 6144
+    assert pad(0, 3) == pad(3, 0) == pad(0, 0) == pad(16, 1) == 16
+    assert pad(5285, 5290) == 6144
+    # lopsided churn pays for no padding: the fresh rows' own bucket
+    assert pad(10, 100_000) == 16 and pad(100_000, 10) == 114688
+
+
+@pytest.mark.parametrize("dim", [128, 8])
+def test_boundary_of_a_few_rows_meets_one_compiled_shape(dim, restore_flags):
+    """A table that every pass nearly fills (a vocabulary): passes that
+    admit and retire 0, 1, 3 or 16 rows run the fresh-row staging, the
+    write-back of the retiring rows and jit_combine without compiling
+    anything the first such boundary did not."""
+    from paddlebox_tpu.utils.compile_cache import CompileMeter
+    cfg = _cfg("adagrad", dim=dim)
+    store = HostEmbeddingStore(cfg)
+    mgr = FeedPassManager(store, make_mesh(1))
+    rng = np.random.default_rng(11)
+    pool = rng.choice(1 << 40, 400, replace=False).astype(np.uint64)
+    # 196..212 keys through the test: one bucket (224) of table rows
+    keys, spare = pool[:208], list(pool[208:])
+    meter = CompileMeter()
+
+    def one_pass(keys):
+        ws = mgr.begin_pass(keys)
+        mgr.pass_opened()
+        ws.translate(keys.reshape(1, -1))        # every row touched
+        mgr.pass_closed()
+        mgr.end_pass(ws)
+        return mgr.last_fresh_rows
+
+    def churn(keys, fresh, retire):
+        keep = keys[retire:] if retire else keys
+        new = np.asarray([spare.pop() for _ in range(fresh)], np.uint64)
+        return np.concatenate([keep, new])
+
+    one_pass(keys)
+    keys = churn(keys, 2, 0)     # the first such boundary retires no row:
+    one_pass(keys)               # its write-back program is met all the same
+    warm = meter.snapshot()
+    for fresh, retire in ((1, 1), (3, 3), (16, 16), (0, 0), (3, 1),
+                          (0, 16), (16, 0), (1, 3)):
+        keys = churn(keys, fresh, retire)
+        assert one_pass(keys) == fresh
+        assert meter.since(warm)["compilations"] == 0, (fresh, retire)
