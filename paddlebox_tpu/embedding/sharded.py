@@ -189,6 +189,13 @@ def plan_premerge(idx: jnp.ndarray, grads: jnp.ndarray,
     out-of-range row ids, so downstream engines drop them and the
     scatter engine may legally promise sorted+unique indices.
 
+    `order` has one entry a token; `uniq` and `segend` have L lanes, any
+    L from the batch's distinct rows up to its tokens (the host ships a
+    bucket over the distinct rows where the plan carries no kernel
+    windows, Trainer._host_plan). The cumsum runs over tokens, only the
+    boundary gathers and their difference over lanes, so the merged
+    values of the valid lanes do not depend on L to the bit.
+
     Returns (uniq_idx, merged_grads, merged_shows, merged_clks,
     kernel_plan) — kernel_plan is (None, rstart, end) unique-lane DMA
     windows (order=None: already sorted), or None when the plan carries

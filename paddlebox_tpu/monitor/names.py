@@ -178,6 +178,19 @@ MODEL_STAT_NAMES: tuple[str, ...] = (
     "moe.expert_load_max",
 )
 
+# the host plan's counters (Trainer._host_plan, a batch at a time on the
+# pack thread; a pass's sums reach the flight record as ``stats_delta``):
+# tokens planned, the distinct rows among them (the dedup rate the doctor
+# and the world view read), the lanes shipped for those rows (pad share =
+# 1 - unique / lanes), and the times the lane count grew (each is a newly
+# compiled step and apply, Trainer._plan_lane_count)
+PLAN_COUNTER_NAMES: tuple[str, ...] = (
+    "trainer.plan_tokens",
+    "trainer.plan_unique_tokens",
+    "trainer.plan_lanes",
+    "trainer.plan_lane_grows",
+)
+
 ALL_NAMES: frozenset = frozenset(EVENT_NAMES) | frozenset(SPAN_NAMES)
 
 

@@ -249,7 +249,12 @@ void pbtpu_block_plan(const int32_t* idx, int64_t n, int32_t super_block,
 //   rstart   : (n_blocks,) out — 8-aligned unique-LANE window starts
 //              per table super-block (binned kernel DMA windows)
 //   end      : (n_blocks,) out — exclusive unique-lane window ends
-// Returns the number of unique valid rows.
+// Returns u, the number of unique valid rows. uniq and segend are filled
+// to n lanes (one a token, the most a batch can need), and every prefix
+// of L >= u lanes is a whole plan under the same contract: the caller
+// that carries no kernel windows ships only such a prefix
+// (Trainer._host_plan), so the device's lane-shaped work follows the
+// batch's distinct rows and not its tokens.
 int64_t pbtpu_dedup_plan(const int32_t* idx, int64_t n, int64_t n_rows,
                          int32_t super_block, int64_t n_blocks,
                          int32_t* order, int32_t* uniq, int32_t* segend,

@@ -74,16 +74,20 @@ def block_plan(idx: np.ndarray, super_block: int, n_blocks: int
             ends.astype(np.int32))
 
 
-def dedup_plan(idx: np.ndarray, n_rows: int, super_block: int,
-               n_blocks: int) -> tuple[np.ndarray, ...]:
+def dedup_plan_counted(idx: np.ndarray, n_rows: int, super_block: int,
+                       n_blocks: int) -> tuple[tuple[np.ndarray, ...], int]:
     """Full-row counting sort + unique-row segment bounds (the host half
     of the reference's DedupKeysAndFillIdx/PushMergeCopy pairing; see
-    key_index.cc pbtpu_dedup_plan for the array contracts).
+    key_index.cc pbtpu_dedup_plan for the array contracts), and the
+    number of distinct valid rows the batch has.
 
-    Returns (order (n,), uniq (n,), segend (n,), rstart (n_blocks,),
-    end (n_blocks,)) int32. `uniq` pads with ascending out-of-range ids
-    and `segend` pads with zero-width segments, so the device pre-merge
-    needs no dynamic shapes. Native when available; numpy otherwise.
+    Returns ((order (n,), uniq (n,), segend (n,), rstart (n_blocks,),
+    end (n_blocks,)) int32, n_unique). `uniq` holds the n_unique rows
+    ascending, then pads with ascending out-of-range ids; `segend` pads
+    with zero-width segments — so the device pre-merge needs no dynamic
+    shapes, and any prefix `uniq[:L]`, `segend[:L]` with L >= n_unique
+    is a whole plan of L lanes (Trainer._host_plan trims to one).
+    Native when available; numpy otherwise.
     """
     idx = np.ascontiguousarray(idx, dtype=np.int32)
     n = len(idx)
@@ -95,9 +99,9 @@ def dedup_plan(idx: np.ndarray, n_rows: int, super_block: int,
         segend = np.empty(n, np.int32)
         rstart = np.empty(n_blocks, np.int32)
         end = np.empty(n_blocks, np.int32)
-        lib.pbtpu_dedup_plan(idx, n, n_rows, super_block, n_blocks,
-                             order, uniq, segend, rstart, end)
-        return order, uniq, segend, rstart, end
+        u = lib.pbtpu_dedup_plan(idx, n, n_rows, super_block, n_blocks,
+                                 order, uniq, segend, rstart, end)
+        return (order, uniq, segend, rstart, end), int(u)
     r = np.where((idx < 0) | (idx >= n_rows), n_rows, idx)
     order = np.argsort(r, kind="stable").astype(np.int32)
     sr = r[order]
@@ -115,9 +119,17 @@ def dedup_plan(idx: np.ndarray, n_rows: int, super_block: int,
     b = np.minimum(uniq_rows // super_block, n_blocks - 1)
     counts = np.bincount(b, minlength=n_blocks)
     ends = np.cumsum(counts)
-    return (order, uniq, segend,
-            (((ends - counts) // 8) * 8).astype(np.int32),
-            ends.astype(np.int32))
+    return ((order, uniq, segend,
+             (((ends - counts) // 8) * 8).astype(np.int32),
+             ends.astype(np.int32)), u)
+
+
+def dedup_plan(idx: np.ndarray, n_rows: int, super_block: int,
+               n_blocks: int) -> tuple[np.ndarray, ...]:
+    """dedup_plan_counted's five arrays, every one at its full length
+    (`uniq` and `segend` n lanes: one a token, the most a batch can
+    need)."""
+    return dedup_plan_counted(idx, n_rows, super_block, n_blocks)[0]
 
 
 def native_available() -> bool:

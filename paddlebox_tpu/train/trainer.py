@@ -35,7 +35,8 @@ from paddlebox_tpu.embedding import (EmbeddingConfig, HostEmbeddingStore,
                                      PassWorkingSet, exchange, sharded,
                                      tiering)
 from paddlebox_tpu.embedding.feed_pass import FeedPassManager
-from paddlebox_tpu.embedding.working_set import PushOperandStager
+from paddlebox_tpu.embedding.working_set import (PushOperandStager,
+                                                 bucket_size)
 from paddlebox_tpu.metrics import auc as auc_lib
 from paddlebox_tpu.models import base as model_base
 from paddlebox_tpu.ops.seqpool_cvm import PooledSlots
@@ -55,7 +56,10 @@ from paddlebox_tpu.utils.profiler import DumpStream, dump_tree, find_nonfinite
 # plan = (order, rstart, end, uniq, segend): the first three are the
 # kernel's token/block grouping, the last two the dedup pre-merge's
 # unique-row segment bounds (sharded.plan_premerge). Zero-length
-# arrays = that half is absent (the jit static branch).
+# arrays = that half is absent (the jit static branch). `order` has one
+# entry a token; `uniq` and `segend` have one a LANE: a bucket over the
+# most distinct rows a batch has had where the plan carries no kernel
+# windows (_host_plan, _plan_lane_count), one a token elsewhere.
 PLAN_ARITY = 5
 
 
@@ -116,6 +120,28 @@ def _mean_replicated_grad(gp, axes):
 
 
 _NO_PLAN = np.zeros(0, np.int32)   # zero-length = "no host binned plan"
+
+
+def _level_plan_lanes(host_tuples: list, n_rows: int) -> list:
+    """Host batch tuples of one stacked dispatch with their plans' `uniq`
+    and `segend` brought to the group's largest lane count (the lanes
+    grow inside a group when a batch crosses a rung, _plan_lane_count):
+    further pads under the plan's contract — rows ascending past the
+    last one and out of range, zero-width segments at the stream's end."""
+    iu, isg = 4 + PLAN_ARITY - 2, 4 + PLAN_ARITY - 1
+    lanes = max(len(ht[iu]) for ht in host_tuples)
+    out = []
+    for ht in host_tuples:
+        k = lanes - len(ht[iu])
+        if k:
+            u, sg = ht[iu], ht[isg]
+            first = max(int(u[-1]) + 1, n_rows)
+            ht = (*ht[:iu],
+                  np.concatenate([u, first + np.arange(k, dtype=np.int32)]),
+                  np.concatenate([sg, np.full(k, sg[-1], np.int32)]),
+                  *ht[isg + 1:])
+        out.append(ht)
+    return out
 
 
 def _dense_tx(cfg: TrainerConfig) -> optax.GradientTransformation:
@@ -336,6 +362,10 @@ class Trainer:
         # eval capacity can grow past the train factor (skewed eval-only
         # datasets) without ever touching the train step's compilation
         self._eval_capacity = self.cfg.capacity_factor
+        # lanes of the windowless dedup plan (_plan_lane_count): grow-only
+        # for the trainer's life, so a day compiles one step and one
+        # apply per rung of working_set.bucket_size reached
+        self._plan_lanes = 0
         self._superstep_fn: Callable | None = None
         # Deferred sparse-push pipeline (flags.push_overlap): the step
         # returns packed push operands off the loss-producing path; the
@@ -1111,8 +1141,8 @@ class Trainer:
                 buf.append(item)
                 if len(buf) == group:
                     stacked = tuple(
-                        np.stack(cols)
-                        for cols in zip(*(ht for _, ht in buf)))
+                        np.stack(cols) for cols in zip(*_level_plan_lanes(
+                            [ht for _, ht in buf], ws.padded_rows)))
                     # the extras protocol requires batch-leading arrays
                     # (the step's shard_map in_specs shard dim 0); a 0-d
                     # or per-batch-scalar extra would stack to (k,) and
@@ -1142,7 +1172,10 @@ class Trainer:
         on the host pack pipeline (pallas_kernels.binned_push's `plan` /
         sharded.plan_premerge). Zero-length arrays mean "that half is
         absent" — the step's static-shape branch then keeps the
-        on-device grouping (or the XLA scatter path off-TPU)."""
+        on-device grouping (or the XLA scatter path off-TPU). The dedup
+        bounds have one lane a token, except on one shard with no kernel
+        windows (what a plane table takes): there a bucket over the most
+        distinct rows a batch has had (_plan_lane_count)."""
         Z = np.zeros(0, np.int32)
         empty = (Z,) * PLAN_ARITY
         if not self._use_plan:
@@ -1182,6 +1215,7 @@ class Trainer:
                                          self.exchange_wire))
             monitor.counter_add("trainer.plan_tokens", idx.size)
             monitor.counter_add("trainer.plan_unique_tokens", u_count)
+            monitor.counter_add("trainer.plan_lanes", len(u))
             return (o, Z, Z, u, s)
         from paddlebox_tpu.ops import pallas_kernels
         geom = pallas_kernels.binned_push_geometry(
@@ -1192,17 +1226,42 @@ class Trainer:
             from paddlebox_tpu.native.key_index import block_plan
             o, r, e = block_plan(idx.reshape(-1), geom[0], geom[1])
             return (o, r, e, Z, Z)
-        from paddlebox_tpu.native.key_index import dedup_plan
+        from paddlebox_tpu.native.key_index import dedup_plan_counted
         # scatter-engine widths carry no kernel windows; the counting
         # sort still needs a block granularity — one whole-table block
         SB, NB = geom if geom is not None else (ws.padded_rows, 1)
-        o, u, s, r, e = dedup_plan(idx.reshape(-1), ws.padded_rows,
-                                   SB, NB)
-        # per-pass dedup rate: unique lanes vs routed tokens (the
-        # Parallax-style per-slot skew signal rolls up from these)
+        (o, u, s, r, e), n_uniq = dedup_plan_counted(
+            idx.reshape(-1), ws.padded_rows, SB, NB)
+        if geom is None:
+            # every lane-shaped op of the premerge and of the touched-
+            # rows push costs a pad what it costs a row: ship a bucket
+            # over the batch's distinct rows, not a lane a token
+            lanes = self._plan_lane_count(n_uniq, idx.size)
+            u, s = u[:lanes], s[:lanes]
+        # per-pass dedup rate: distinct rows vs routed tokens (the
+        # Parallax-style per-slot skew signal rolls up from these), and
+        # the lanes shipped for them (pad share = 1 - unique / lanes)
         monitor.counter_add("trainer.plan_tokens", idx.size)
-        monitor.counter_add("trainer.plan_unique_tokens", len(u))
+        monitor.counter_add("trainer.plan_unique_tokens", n_uniq)
+        monitor.counter_add("trainer.plan_lanes", len(u))
         return (o, r, e, u, s) if geom is not None else (o, Z, Z, u, s)
+
+    def _plan_lane_count(self, n_uniq: int, n_tokens: int) -> int:
+        """Lanes of a windowless dedup plan for a batch of `n_uniq`
+        distinct rows: working_set.bucket_size over the most distinct
+        rows any batch of this trainer has had, never more than a lane a
+        token. Grow-only — a batch past the rung moves every later batch
+        to the next, none moves back — so the step and the apply compile
+        once a rung reached (`trainer.plan_lane_grows` counts them) and
+        pass sets that alternate repeat their shapes. Any count >= n_uniq
+        gives the same merged values and table to the bit: pads are
+        zero-width segments on rows the scatter drops."""
+        # at least one rung: a zero-length `uniq` reads as "no dedup half"
+        need = min(n_tokens, bucket_size(max(n_uniq, 1)))
+        if need > self._plan_lanes:
+            self._plan_lanes = need
+            monitor.counter_add("trainer.plan_lane_grows")
+        return min(n_tokens, self._plan_lanes)
 
     def _select_table_layout(self) -> str:
         """Which embedding exchange the step programs compile with
