@@ -2,9 +2,9 @@
 
 Drives slot files -> ``SlotDataset`` -> ``BoxPS.begin_pass`` ->
 ``Trainer.train_pass`` -> ``end_pass`` -> ``eval_pass`` once, in one
-process, at the full width of the DeepFM that ``bench.py``'s e2e section
-trains (26 sparse slots + 13 dense, emb dim 16, hidden (400, 400, 400),
-batch 8192, adagrad, allreduce dense sync), with default flags — the point
+process, at the full width of a Criteo-shaped DeepFM (26 sparse slots +
+13 dense, emb dim 16, hidden (400, 400, 400), batch 8192, adagrad,
+allreduce dense sync; ``FULL`` below), with default flags — the point
 is to see what ``auto`` picks on a chip — and checks what comes out.
 
     python chip_smoke.py              one chip: phases train, multihot, kernels
@@ -39,8 +39,8 @@ import time
 import numpy as np
 
 NUM_SLOTS, DENSE_DIM = 26, 13
-# the full sizes are bench.py's e2e geometry (batch, slot_space, tower) and
-# its allreduce_f32_multihot4_dim32 point (2^19-key pool, 4-hot, dim 32)
+# the full sizes: the DeepFM's batch, ids a slot and tower, and the
+# multi-hot phase's layout (2^19-key pool, 4-hot, dim 32)
 FULL = dict(batch=8192, steps=8, slot_space=650_000, hidden=(400, 400, 400),
             mh_steps=3, mh_keys=1 << 19, min_keys=1_000_000, files=4)
 TINY = dict(batch=512, steps=6, slot_space=4000, hidden=(32, 32),
@@ -132,8 +132,8 @@ def write_onehot_pass(root: str, tag: str, n_ex: int, slot_space: int,
 
 def write_multihot_pass(root: str, tag: str, n_ex: int, n_keys: int,
                         max_len: int, seed: int, n_files: int):
-    """1..max_len ids per slot from one n_keys pool (the bench's
-    multihot4 geometry: variable lengths, so the pad mask is real)."""
+    """1..max_len ids per slot from one n_keys pool (variable lengths,
+    so the pad mask is real)."""
     rng = np.random.default_rng(seed)
     pool = rng.choice(1 << 50, n_keys, replace=False).astype(np.int64)
     dense, labels = _dense_and_labels(rng, n_ex, seed)
@@ -352,9 +352,8 @@ def emit_run(run: dict, meter, **more) -> None:
 # --------------------------------------------------------------------------
 
 def sync_check(device: dict) -> None:
-    """Does ``block_until_ready`` wait for the device? bench.py ends its
-    windows by reading a scalar on the host; this says whether it has to.
-    A chain of matmuls is dispatched, waited on, and then a scalar is
+    """Does ``block_until_ready`` wait for the device? A measuring
+    program that ends its windows with it needs to know. A chain of matmuls is dispatched, waited on, and then a scalar is
     read back: if the wait had returned early the read would take the
     chain's time."""
     import jax

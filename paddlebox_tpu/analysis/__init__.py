@@ -7,8 +7,8 @@ atomic tmp->fsync->replace discipline was required, donefile lines
 written outside the one sanctioned appender, bare ``threading.Thread``
 spawns that strip telemetry context, faultpoints outside the closed
 kill-matrix registry, flags drifting from the registry. This package
-encodes those invariants as machine-checked rules, the same move
-BENCH_BEST.json made for performance: a recorded gate instead of
+encodes those invariants as machine-checked rules, the same move the
+driver's ledger makes for performance: a recorded gate instead of
 reviewer memory.
 
 Pieces:

@@ -101,8 +101,7 @@ class Project:
     tests_dir: str = "tests"
     # extra trees indexed for *references* (flag reads, faultpoint names)
     # but never linted themselves
-    aux_reference_paths: tuple[str, ...] = (
-        "bench.py", "bench_spill.py", "examples")
+    aux_reference_paths: tuple[str, ...] = ("examples",)
 
     @classmethod
     def discover(cls, start: str, package: str = "paddlebox_tpu"
@@ -647,7 +646,7 @@ class Linter:
                     f"{resolved}) — refusing to report a clean run over "
                     "nothing")
 
-        # 2. parse reference-only trees (tests, bench, examples) and any
+        # 2. parse reference-only trees (tests, examples) and any
         # load-bearing module not among the targets
         index = ProjectIndex()
         ref_contexts: dict[str, FileContext] = {}

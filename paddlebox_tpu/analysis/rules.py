@@ -357,7 +357,7 @@ class FlagAuditRule(Rule):
                 out.append(Finding(
                     project.flags_module, line, self.id,
                     f"flag {field!r} is never read anywhere (package, "
-                    "tests, bench, examples) — a dead flag documents "
+                    "tests, examples) — a dead flag documents "
                     "behavior the code does not have; remove it, wire "
                     "it, or waive naming the future consumer"))
         return out

@@ -95,8 +95,9 @@ class Flags:
     # here already carry bf16-level rounding from the backward matmuls
     # (TPU MXU), so plane 3's bits 17-24 are below the gradient noise
     # floor; dropping it measured 7.60 -> 6.95ms on the v5e headline
-    # step (+8.5%). Both endpoints stay measured as bench matrix points
-    # (allreduce_f32_push_exact / _push_bf16).
+    # step (+8.5%) in the first rounds. No cell of BENCHMARK.json
+    # resolves to the binned kernel, so neither endpoint is measured on
+    # this code (ROADMAP D2).
     binned_push_splits: int = 2             # (new)
     # Physical column count of the f32 device table. TPU random-row
     # gathers run ~2x faster from 64/128-column sources than from narrow
@@ -134,8 +135,8 @@ class Flags:
     # Trainer construction (trace time), like binned_push.
     fused_gather_pool: str = "auto"         # (new)
     # Push merge-engine override for A/B runs (resolve_push_engine —
-    # ONE resolver shared by the compiled dispatch and the per-point
-    # bench record). "auto" picks per (width class, lane contract,
+    # ONE resolver shared by the compiled dispatch and
+    # Trainer.engines()). "auto" picks per (width class, lane contract,
     # storage): premerged f32 unique lanes take the fused
     # "scatter_accumulate" (row-wise gather→update→write-back, no
     # O(table) pass — the dim64/dim128/multihot4 floor closer), narrow
@@ -143,8 +144,7 @@ class Flags:
     # headline winner), everything else "xla_scatter". Forcing
     # "scatter_accumulate" also forces the dedup premerge on (the fused
     # engine consumes unique lanes) and runs the identical-math jnp
-    # fallback off-TPU — the CPU-parity/A/B knob. Legacy spellings
-    # "kernel"/"scatter"/"fused" normalize.
+    # fallback off-TPU — the CPU-parity/A/B knob.
     push_engine: str = "auto"               # (new)
     # Deferred sparse-push apply (the reference hides push latency behind
     # the next pass's work — boxps_worker per-card push timers overlap
@@ -156,8 +156,8 @@ class Flags:
     # a second pending apply); flushed at pass boundaries and before
     # eval/save. Bit-identical to the inline push: the apply is always
     # data-sequenced before the next step consumes the table. "auto" =
-    # on where dense sync permits (allreduce, steps_per_dispatch == 1 —
-    # mirroring AsyncDenseTable's dispatch-decoupling semantics);
+    # on where dense sync permits (allreduce — mirroring
+    # AsyncDenseTable's dispatch-decoupling semantics);
     # "on"/"off" force. Read at Trainer construction (trace time).
     push_overlap: str = "auto"              # (new)
     # Sharded table exchange (embedding/exchange.py): which engine the
@@ -257,8 +257,8 @@ class Flags:
     # table; a background staging invalidated by such a mutation is
     # PATCHED with a compact delta plane rather than thrown away. Off =
     # the pre-incremental behavior (any mutation forces a full rebuild)
-    # — the A/B knob the boundary_incremental bench point measures and
-    # the doctor's boundary-wall rule names when reuse is off.
+    # — the A/B knob the doctor's boundary-wall rule names when reuse
+    # is off.
     incremental_feed: bool = True           # (new)
 
     # _bp_pack width-class engine override for A/B runs: "auto" selects
@@ -268,8 +268,8 @@ class Flags:
     # >= 64 packs at the full DMA width first). "narrow"/"gather_zone"/
     # "wide" force one path everywhere its layout allows — the
     # in-composed-step A/B knob whose absence let the round-5 _bp_pack
-    # rewrite regress the headline 1.87x unnoticed. Recorded per bench
-    # matrix point as pack_engine.
+    # rewrite regress the headline 1.87x unnoticed.
+    # pallas_kernels.pack_engine() names the path a geometry compiles.
     pack_engine: str = "auto"               # (new)
 
     # --- trainer (trainer_desc.proto:100-108, flags.cc:591-597) ---

@@ -21,7 +21,7 @@ scale-out literature grounds (ROADMAP "Sharded embedding scale-out"):
   and the scale ride a small f32 side plane — the same split the
   quantized-table pull already uses for its a2a payload
   (``sharded.routed_lookup``). f32 keeps the wire exact (the parity
-  baseline and the ``sharded2_wire_f32`` bench point).
+  baseline).
 
 The fused gather-pool pull runs **per shard after routing**:
 ``routed_pull_pooled`` routes the unique rows, lands them in a local
@@ -69,8 +69,8 @@ TOPOLOGIES = ("flat", "hier")
 
 
 def select_wire(cfg: EmbeddingConfig) -> str:
-    """Resolve flags.exchange_wire for this table (trace-time static,
-    recorded per bench matrix point as ``exchange_wire``). "auto" =
+    """Resolve flags.exchange_wire for this table (trace-time static;
+    ``Trainer.engines()["exchange_wire"]`` reports it). "auto" =
     bf16 — the sparse grads reaching the wire already carry bf16-level
     rounding from the backward matmuls (the same argument as
     binned_push_splits=2), so the wire halves for free; int8 tables get

@@ -315,8 +315,8 @@ def push(table: jnp.ndarray, idx: jnp.ndarray, grads: jnp.ndarray,
     Returns the updated table.
 
     Implementation note (TPU): the merge engine is selected by
-    pallas_kernels.resolve_push_engine — ONE resolver shared with the
-    bench record (flags.push_engine forces for A/Bs). Premerged f32
+    pallas_kernels.resolve_push_engine — ONE resolver shared with
+    Trainer.engines() (flags.push_engine forces for A/Bs). Premerged f32
     lanes on a table whose rows can be addressed (f32 planes, or one
     array of whole lane tiles) take scatter_accumulate: each touched
     row gathered, updated, written back once — no accumulator, no pass

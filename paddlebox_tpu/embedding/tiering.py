@@ -96,8 +96,8 @@ class TierManager:
     recency wins ties, a strictly hotter resident is never displaced by
     a cold fault-in (the anti-thrash property the direct-mapped "last
     wins" install lacked). ``policy="direct"`` keeps the legacy
-    always-install behavior as the measured baseline (bench_spill.py /
-    the ``spill_10x`` bench point A/B against it).
+    always-install behavior to A/B against (tests/test_tiering.py
+    holds both policies; neither has a reading on the chip, ROADMAP M2).
     """
 
     def __init__(self, n_rows: int, policy: str = "freq",
@@ -357,7 +357,7 @@ def fault_in_seconds(store) -> float:
 
 def spill_stats(store) -> dict | None:
     """Aggregate hot-tier statistics across a store's spill-backed
-    (sub-)stores — the operator view the bench/runbook read. None when
+    (sub-)stores — the operator view the runbook reads. None when
     the store has no spill tier."""
     subs = _spill_subs(store)
     if not subs:

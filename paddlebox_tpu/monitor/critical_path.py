@@ -16,8 +16,8 @@ records boundary_seconds of 23–68s against 39–115s of train per pass:
 up to half the wall is boundary, and pass-2 reuse already proves the
 overlap win).
 
-Pure functions over committed records: no hub, no jax — the doctor and
-the bench artifact both call in, offline or live.
+Pure functions over committed records: no hub, no jax — the doctor
+calls in, offline or live.
 """
 
 from __future__ import annotations

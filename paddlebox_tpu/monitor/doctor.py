@@ -5,7 +5,7 @@ The reference's operators kept day-scale CTR runs healthy by reading
 per-pass stats and AUC logs (SURVEY.md; log_for_profile) and pattern-
 matching against incidents they had seen before. This module is that
 pattern-matching, written down: every rule is grounded in a PRIOR
-INCIDENT recorded in this repo (ROADMAP/VERDICT/BENCH rounds), reads
+INCIDENT recorded in this repo (ROADMAP, CHANGES.md), reads
 only committed telemetry (flight records, counter deltas, retained
 evidence events, sink health), and returns a **named finding** carrying
 the evidence that fired it and the flag/runbook step that addresses it.
@@ -25,9 +25,9 @@ Three entry points:
   every ``end_pass``; findings are emitted as ``doctor.finding`` events
   into the event stream (tagged with the pass that produced them) and
   returned through ``BoxPS.end_pass``.
-- **Embedded** — bench.py embeds :func:`diagnose`'s report in every
-  artifact (``detail["doctor"]``) and ``--dryrun`` asserts it, like
-  ``telemetry_embedded``.
+- **In process** — :func:`diagnose_hub` returns the report of the hub's
+  in-memory records as a dict: the self-healing runtime
+  (runtime/remediation.py) reads its findings at every pass boundary.
 """
 
 from __future__ import annotations
@@ -59,7 +59,7 @@ class DoctorContext:
     (live: STATS snapshot; offline: summed per-pass deltas);
     ``evidence`` retained event samples by name; ``world`` the
     aggregate's per-pass world view when multiple ranks were read;
-    ``detail`` artifact extras (the bench's push_floor analysis);
+    ``detail`` extras the caller merged (the CLI's ``world_trace``);
     ``sink_health`` the hub's per-sink account."""
 
     def __init__(self, flights=None, counters=None, evidence=None,
@@ -398,58 +398,6 @@ class DedupDriftRule(Rule):
             "re-costs the exchange wire from exactly this drifting "
             "tokens/unique ratio instead of pinning one wire to a "
             "stale profile")
-
-
-class PushFloorRule(Rule):
-    id = "push-floor"
-    doc = "sparse push measured off its analytic floor"
-    incident = ("ROADMAP 'Close the recorded push floors': an 11ms push "
-                "can pass an MFU audit while sitting 10x above its own "
-                "physics — step_probe.push_floor_analysis closes each "
-                "bench point against the floor, and a non-closed floor "
-                "is the alarm line")
-
-    def evaluate(self, ctx):
-        floor = ctx.detail.get("push_floor")
-        if not isinstance(floor, dict) or "closed" not in floor:
-            return "no-data", None
-        closed = floor["closed"]
-        if closed is True:
-            return "quiet", None
-        if isinstance(closed, str) and not closed.startswith("measured"):
-            return "no-data", None      # abstained (no peaks/measurement)
-        # name the concrete engine to force: the per-candidate-engine
-        # closure statements (push_floor_analysis `engines`) carry each
-        # engine's bound at this geometry, and the per-point record
-        # (detail push_engine — the resolver's verdict) names what ran
-        engine = ctx.detail.get("push_engine") or floor.get("engine")
-        engines = floor.get("engines") if isinstance(
-            floor.get("engines"), dict) else {}
-        best = floor.get("best_engine")
-        if best and best != engine:
-            note = (engines.get(best) or {}).get("note")
-            suggestion = (
-                f"force flags.push_engine={best!r} (candidate floor "
-                f"{(engines.get(best) or {}).get('floor_seconds')}s vs "
-                f"the recorded {engine} run"
-                + (f"; {note}" if note else "") + ") and re-record the "
-                "point; flags.pack_engine is the companion A/B knob")
-        else:
-            suggestion = (
-                f"the resolver already picked the lowest-floor engine "
-                f"({engine}) — A/B flags.pack_engine and the plan "
-                "staging at this geometry before trusting the step; the "
-                "floor statement names which sub-stage (kernel DMA / "
-                "one-hot dots / fused update) carries the gap")
-        return "fired", Finding(
-            self.id, "warn",
-            f"push engine {engine} is off its recorded floor: {closed}",
-            {"engine": engine,
-             "floor_seconds": floor.get("floor_seconds"),
-             "measured_push_seconds": floor.get("measured_push_seconds"),
-             "engine_floors": {n: e.get("floor_seconds")
-                               for n, e in engines.items()}},
-            suggestion)
 
 
 class NanGuardRule(Rule):
@@ -954,7 +902,6 @@ ALL_RULES: "tuple[type[Rule], ...]" = (
     ExchangeOverflowRule,
     SpillThrashRule,
     DedupDriftRule,
-    PushFloorRule,
     NanGuardRule,
     ServingStalenessRule,
     HeartbeatGapRule,
@@ -1041,7 +988,8 @@ def diagnose(flights=None, counters=None, evidence=None, world=None,
 
 def validate_report(report: dict) -> "list[str]":
     """Schema errors for a doctor report (empty = valid) — the report is
-    a machine contract like the flight record (bench asserts it)."""
+    a machine contract like the flight record (the CLI refuses to
+    print a report that fails it)."""
     errs: list[str] = []
     if not isinstance(report, dict):
         return ["report is not an object"]
@@ -1089,8 +1037,7 @@ def validate_report(report: dict) -> "list[str]":
 def diagnose_hub(hub, detail=None, quarantined_rules=None) -> dict:
     """Diagnose a live hub's in-memory state (flight-record ring, the
     cumulative counter registry, this session's sink health) — the ONE
-    assembly run_live, the bench artifact embed, and the example all
-    share."""
+    assembly run_live, the self-healing runtime and the example share."""
     return diagnose(flights=hub.flight_records(),
                     counters=STATS.snapshot(),
                     sink_health=hub.sink_health(),

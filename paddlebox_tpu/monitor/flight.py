@@ -1,7 +1,7 @@
 """Flight-record and event schema — the machine-readable contract.
 
 Everything the hub emits is one JSON object per line; dashboards, the
-bench regression gate, and the tier-1 smoke all key off these shapes, so
+doctor, and the tier-1 smoke all key off these shapes, so
 the schema is code (validators returning error strings), not prose. The
 flight record is the per-pass unit the ROADMAP's regression discipline
 consumes: stage-time split, throughput, STATS deltas since pass start,

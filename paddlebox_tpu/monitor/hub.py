@@ -22,7 +22,7 @@ Pass lifecycle: ``begin_pass`` snapshots the cumulative counters;
 ``end_pass`` commits a **flight record** — stage-time split, examples/sec,
 STATS deltas since pass start, metric-registry snapshot — to every sink
 (the ParityLogSink renders it as the log_for_profile line) and keeps the
-last records in memory for artifact embeds (bench.py). ``BoxPS`` drives
+last records in memory (``flight_records()``). ``BoxPS`` drives
 the lifecycle in the full workflow; a bare ``Trainer.train_pass`` opens
 its own pass scope when none is active, so standalone runs still produce
 flight records.
@@ -351,7 +351,7 @@ class TelemetryHub:
 
     def end_pass(self, metrics=None, **extra) -> dict | None:
         """Commit the pass flight record and close the scope. Returns the
-        record (always built — the bench embeds it even when no sink is
+        record (always built — a driver reads it even when no sink is
         attached); emitted to sinks only when enabled."""
         p = self._pass
         if p is None:
@@ -537,15 +537,15 @@ class TelemetryHub:
         """Per-sink health for this telemetry session: live sinks, sinks
         the 3-strike rule detached, and sinks disable() closed — with
         queue-drop counts, latched write errors, and rotation state. The
-        bench artifact embeds this, so a silently-detached or erroring
-        JSONL sink reads as exactly that instead of as a mysteriously
-        short event stream."""
+        doctor's sink-health rule reads this, so a silently-detached or
+        erroring JSONL sink reads as exactly that instead of as a
+        mysteriously short event stream."""
         return ([self._sink_info(s, "attached") for s in self._sinks]
                 + [self._sink_info(s, "detached") for s in self._detached]
                 + [self._sink_info(s, "closed") for s in self._closed])
 
     def summary(self) -> dict:
-        """Compact snapshot for artifact embeds (bench.py detail)."""
+        """Compact snapshot of the hub: counters, gauges, sink health."""
         sinks = self.sink_health()
         dropped = sum(i["dropped"] for i in sinks)
         return {"enabled": self._enabled,

@@ -3,8 +3,8 @@
 Mirrors the reference's three observability outputs: the dump channel's
 background writer threads (→ :class:`JsonlSink`), the per-card
 ``log_for_profile`` stdout lines (→ :class:`ParityLogSink`), and the
-in-memory ``StatRegistry`` readers (→ :class:`MemorySink`, used by tests
-and the bench's artifact embed). Prometheus-style text exposition lives on
+in-memory ``StatRegistry`` readers (→ :class:`MemorySink`, used by
+tests). Prometheus-style text exposition lives on
 the hub itself (:meth:`TelemetryHub.prometheus_text`) since it reads the
 counter registry, not the event stream.
 

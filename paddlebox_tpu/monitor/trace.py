@@ -613,18 +613,18 @@ def write_trace(trace: dict, path: str) -> str:
 
 
 def summarize(trace: dict) -> dict:
-    """The embeddable machine summary of a merged trace (bench artifacts
-    carry this; the doctor's cross-rank-flow rule reads it)."""
+    """The embeddable machine summary of a merged trace (the doctor's
+    cross-rank-flow rule reads it)."""
     return dict(trace.get("pbtpu") or {})
 
 
 # ---------------------------------------------------------------------------
-# in-memory capture (bench/tests: one process, no files)
+# in-memory capture (one process, no files)
 # ---------------------------------------------------------------------------
 
 def records_to_stream(records: "list[dict]") -> dict:
     """A :func:`read_trace_records`-shaped stream from in-memory hub
-    records (a MemorySink ring) — the bench's artifact embed path."""
+    records (a MemorySink ring)."""
     kept = [r for r in records if r.get("type") in KEEP_TYPES]
     probes = [r.get("fields") or {} for r in records
               if r.get("name") == "trace.clock_probe"]

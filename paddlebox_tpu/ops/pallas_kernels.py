@@ -17,8 +17,8 @@ Four kernel families live here:
   pass of the scatter/binned engines disappears (see its section
   comment). Engine selection across the three push engines is owned by
   ``resolve_push_engine`` — ONE resolver shared by the compiled
-  dispatch and the per-point bench record. Tables of whole 128-lane
-  tiles only.
+  dispatch and ``Trainer.engines()``. Tables of whole 128-lane tiles
+  only.
 
 - ``binned_push`` (the production path, flags.binned_push): replaces the
   XLA token scatter-add with block-binned one-hot MXU matmuls that build
@@ -166,9 +166,9 @@ def merge_update(table: jnp.ndarray, acc: jnp.ndarray, cfg: EmbeddingConfig,
 #
 # Measured (one v5e, 528k x 13 f32 table, 213k tokens, adagrad, forced-D2H
 # repeat-in-one-jit windows): XLA scatter+update ~16.6 ms/call; round-2
-# kernel (in-VMEM optimizer) 5.2 ms; round-3 pre-split acc-only 3.6 ms;
-# this in-kernel-split layout is measured by bench.py's stage attribution
-# (sparse_push) and the dim-64/128 matrix points.
+# kernel (in-VMEM optimizer) 5.2 ms; round-3 pre-split acc-only 3.6 ms.
+# This in-kernel-split layout has no reading of its own: no cell of
+# BENCHMARK.json resolves to this engine (ROADMAP D2).
 # ---------------------------------------------------------------------------
 
 _BP_TILE = 1024          # tokens per DMA/matmul tile
@@ -193,9 +193,8 @@ def _bp_lanes(cfg: EmbeddingConfig, rows: int):
     n_rows/SB) — so SB* ~ sqrt(c * n_rows * 128/PP), c fitted on v5e
     (~3; for PP <= 64 the 128/PP ratio equals G up to pow2 rounding, so
     this reduces to the round-3 sqrt(3*G*n_rows)). A 10.5M-row table at
-    SB=4096 is 2560 mostly-empty grid steps (measured +2.6ms); the
-    bench's 557k-row table at SB=16384 wastes 4x MXU work (measured
-    +1.4ms)."""
+    SB=4096 is 2560 mostly-empty grid steps (measured +2.6ms); a
+    557k-row table at SB=16384 wastes 4x MXU work (measured +1.4ms)."""
     P = cfg.grad_width + 3
     PP = -(-P // 8) * 8
     if PP > _BP_MAX_PP:
@@ -362,9 +361,8 @@ def _binned_acc_kernel(rstart_ref, end_ref, packed_ref, acc_ref,
 # 12-lane payload onto the pad-first layout and silently halved headline
 # throughput (VERDICT r5 — reverting that one function restored 1.87x).
 # The engines below make the choice EXPLICIT, per width class, overridable
-# for in-composed-step A/Bs (flags.pack_engine) and recorded per bench
-# matrix point (pack_engine()) so a wrong choice alarms instead of
-# shipping:
+# for in-composed-step A/Bs (flags.pack_engine) and named by
+# pack_engine(), so a wrong choice can be read off instead of shipping:
 #
 #   narrow      (P < 14)       reorder at the logical payload width, pad
 #                              after — the fast-narrow-gather path.
@@ -391,8 +389,8 @@ def pack_width_class(P: int) -> str:
 
 def _resolve_pack_engine(P: int, premerged: bool) -> str:
     """THE pack-engine resolver — both the compiled path (_bp_pack) and
-    the per-point bench record (pack_engine) call this one function, so
-    the record can never name a code path the program does not contain
+    what a caller reports (pack_engine) call this one function, so a
+    report can never name a code path the program does not contain
     (the round-5 unattributable-regression failure mode). Raises on a
     typo'd forced engine: the flag exists for trustworthy A/Bs."""
     if premerged:
@@ -414,13 +412,12 @@ def pack_engine(cfg: EmbeddingConfig, n_rows: int,
     """Which _bp_pack code path the binned push compiles with for this
     (cfg, rows) — "narrow" | "gather_zone" | "wide", or None when the
     binned kernel does not engage (scatter-engine dispatch has no pack).
-    flags.pack_engine overrides for A/B runs. Recorded per bench matrix
-    point, so every engine choice stays measured round over round.
+    flags.pack_engine overrides for A/B runs.
 
     premerged: the dedup premerge feeds the pack already-sorted lanes
     (order=None), so NO reorder compiles regardless of width class —
-    reported as "premerged_no_reorder" so the per-point record names the
-    code path the program actually contains, not the one the width alone
+    reported as "premerged_no_reorder" so the answer names the code
+    path the program actually contains, not the one the width alone
     would pick."""
     if binned_push_geometry(cfg, n_rows) is None:
         return None
@@ -484,8 +481,8 @@ def _bp_pack(idx, grads, shows, clks, geom, TILE: int, n_rows: int,
     """Build the kernel's packed operand: tokens grouped by super-block,
     each row ``[payload_f32 (PP lanes) | id_hi | id_lo]`` padded to a
     multiple of 8 lanes (then to whole 128-lane tiles for the DMA).
-    Split out so bench.py's stage attribution can time the prep
-    separately from the kernel.
+    Split from the kernel so the prep shows as its own XLA ops in a
+    device trace.
 
     The token reorder is dispatched per payload width class (see the
     section comment above): narrow payloads gather at logical width and
@@ -573,33 +570,19 @@ def binned_push_geometry(cfg: EmbeddingConfig, n_rows: int):
 #
 # The resolver below is THE one selection function (the PR-2 pack_engine
 # discipline): the compiled dispatch (sharded.push / exchange.routed_push)
-# and the per-point bench record both call it, so the record can never
-# name an engine the program does not contain.
+# and what a run reports (Trainer.engines(), the run's `engines` line)
+# both call it, so a report can never name an engine the program does
+# not contain.
 # ---------------------------------------------------------------------------
 
 PUSH_ENGINES = ("xla_scatter", "binned_kernel", "scatter_accumulate")
 
-# legacy flag spellings from the pre-fused rounds (the VERDICT r5 A/B
-# notes used them); normalized so recorded run commands keep working
-_PUSH_ENGINE_ALIASES = {"kernel": "binned_kernel",
-                        "scatter": "xla_scatter",
-                        "fused": "scatter_accumulate"}
-
-
-def normalize_push_engine(eng: str) -> str:
-    """Canonical engine name for a flags.push_engine value ("auto" and
-    already-canonical names pass through; legacy aliases map)."""
-    return _PUSH_ENGINE_ALIASES.get(eng, eng)
-
-
 def _push_engine_flag() -> str:
     from paddlebox_tpu.config import flags as config_flags
-    eng = normalize_push_engine(config_flags.push_engine)
+    eng = config_flags.push_engine
     if eng != "auto" and eng not in PUSH_ENGINES:
         raise ValueError(
-            f"push_engine={config_flags.push_engine!r} (want 'auto', one "
-            f"of {PUSH_ENGINES}, or the legacy 'kernel'/'scatter'/'fused' "
-            f"aliases)")
+            f"push_engine={eng!r} (want 'auto' or one of {PUSH_ENGINES})")
     return eng
 
 
@@ -609,10 +592,10 @@ def resolve_push_engine(cfg: EmbeddingConfig, n_rows: int, *,
     """THE push merge-engine resolver — returns the PUSH_ENGINES member
     the push compiles with for this (cfg, rows, lane contract, storage)
     class. Both the compiled dispatch (sharded.push, exchange.
-    routed_push's apply tail) and the per-point bench record call this
-    one function, so the record can never name a code path the program
-    does not contain (the round-5 unattributable-regression failure
-    mode, and the PR-2 pack_engine discipline). Raises on a typo'd
+    routed_push's apply tail) and Trainer.engines() call this one
+    function, so a run's `engines` line can never name a code path the
+    program does not contain (the round-5 unattributable-regression
+    failure mode, and the PR-2 pack_engine discipline). Raises on a typo'd
     forced engine: the flag exists for trustworthy A/Bs.
 
     premerged : the lanes reaching the engine are one-lane-per-unique-row
@@ -730,8 +713,8 @@ def binned_push_supported(table, cfg: EmbeddingConfig) -> bool:
     while the kernel wins 22.9ms vs 39.3ms at dim 32 and 7.7ms vs
     15.5ms at dim 8. Both engines cover the reference's full dispatch
     envelope (box_wrapper.cc:444-461); this picks the faster one per
-    width, and bench.py's dim-64/128 matrix points keep the crossover
-    measured round over round."""
+    width as those rounds measured it — no cell of BENCHMARK.json sits
+    on either side of the crossover yet (ROADMAP D2)."""
     if not isinstance(table, jnp.ndarray) or table.dtype != jnp.float32:
         return False
     return binned_acc_supported(cfg, table.shape[0])
@@ -777,10 +760,10 @@ def binned_push(table: jnp.ndarray, idx: jnp.ndarray, grads: jnp.ndarray,
 # Multi-hot slots are bottlenecked by the (tokens, pull_width) pulled
 # matrix the unfused path materializes between the table gather and the
 # per-slot sum pool (the reference fuses exactly this in its
-# fused_seqpool_cvm* CUDA kernels): at the bench's mh4d32 point the step
+# fused_seqpool_cvm* CUDA kernels): at 4 ids a slot and dim 32 the step
 # moves 852k x 35 f32 rows to HBM, pools them, then moves the same-shape
-# gradient back — 37.7k examples/s/chip vs the 645k one-hot headline
-# (BENCH_r05). This kernel gathers rows from the (HBM-resident) device
+# gradient back — the first rounds read 37.7k examples/s/chip there
+# against 645k one-hot. This kernel gathers rows from the (HBM-resident) device
 # table with per-row async copies and sum-pools them per (example, slot)
 # segment while they sit in VMEM, emitting only the pooled
 # (B, num_slots, pull_width) output — the per-token matrix never exists
@@ -1090,12 +1073,10 @@ def binned_merge_acc(idx: jnp.ndarray, grads: jnp.ndarray,
 #
 # The scatter and binned engines both end in ONE fused XLA pass over the
 # WHOLE table (read + update + where(touched) + write), so their cost has
-# an O(table) term that dominates exactly where the recorded floors sit:
-# at dim 128 the 528k x ~134 f32 bench table moves ~0.6GB per step through
-# that pass while only ~200k unique rows changed, and the binned kernel
-# additionally pays one-hot dots that grow ~10x on the multi-hot points
-# (BENCH_BEST: dim128 252k, dim64 567k, multihot4_dim32 106k ex/s/chip
-# against a 1.2M headline). This kernel takes the premerged unique lanes
+# an O(table) term that dominates wide tables: at dim 128 a 528k x ~134
+# f32 table moves ~0.6GB per step through that pass while only ~200k
+# unique rows changed, and the binned kernel additionally pays one-hot
+# dots that grow ~10x on multi-hot batches. This kernel takes the premerged unique lanes
 # the dedup plan already produces (sharded.plan_premerge — one lane per
 # touched row, pads out-of-range) and touches ONLY those rows: per lane,
 # DMA the table row into VMEM (n_sem-deep pipelined, the gather_pool
@@ -1103,8 +1084,8 @@ def binned_merge_acc(idx: jnp.ndarray, grads: jnp.ndarray,
 # in VMEM — the identical update the XLA pass runs, so numerics match
 # bit-for-bit — and DMA the updated row back in place
 # (input_output_aliases keeps the table buffer donated). Traffic is
-# O(unique rows x row bytes x 2) instead of O(table): the analytic floor
-# step_probe.push_floor_analysis holds per bench point.
+# O(unique rows x row bytes x 2) instead of O(table); benchmark/work.py
+# counts those bytes for the cells' `step_hbm_roofline_pct`.
 #
 # Lane contract (the premerged form everywhere in this codebase): row ids
 # UNIQUE among touched lanes; pad lanes carry out-of-range ids or a zero

@@ -66,11 +66,5 @@ def replicated_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-def stacked_batch_sharding(mesh: Mesh) -> NamedSharding:
-    """Superstep operands (k, B, ...): the scan axis 0 replicated, the
-    batch axis 1 sharded over all mesh axes."""
-    return NamedSharding(mesh, P(None, shard_axes(mesh)))
-
-
 def num_shards(mesh: Mesh) -> int:
     return int(np.prod(mesh.devices.shape))

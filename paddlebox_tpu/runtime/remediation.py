@@ -83,7 +83,7 @@ class Action:
 # A builder returns None when the suggestion is not machine-applicable in
 # THIS run (flag already on, no spill tier, unsharded table…) — the rule
 # then stays advisory, exactly as before. Rules without a builder
-# (nan-guard, push-floor, serving-staleness, sink-health) are advisory by
+# (nan-guard, serving-staleness, sink-health) are advisory by
 # design: their fixes name code/data changes no flag flip can make.
 
 def _fix_boundary_wall(trainer, finding):

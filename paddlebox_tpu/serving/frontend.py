@@ -13,8 +13,7 @@ Latency accounting is the product: per-request wall time (submit →
 result) lands in a TIME-WINDOWED reservoir (``serving/obs.py`` —
 ISSUE 19: a since-start blend hides a swap-induced p99 step behind
 hours of pre-swap samples); :meth:`stats` reports recent-traffic
-p50/p99/max, batch-size distribution, and failures — the numbers
-bench.py's ``serving_drill`` records and the BENCH_BEST gate holds.
+p50/p99/max, batch-size distribution, and failures.
 ``flags.serving_trace_sample`` opens a ``serve/wait`` span around every
 Nth batch's coalesce window, splitting queue wait from score time in
 the merged world trace.

@@ -32,8 +32,8 @@ died mid-swap" from an outage into a routing decision:
   request outstanding past factor x the router's windowed p99 launches a
   second copy on another replica; first answer wins, the loser is
   cancelled and its late result discarded (counted, never returned) —
-  the tail-latency insurance the ``serving_fleet`` bench gate holds
-  under an injected slow replica. The trigger derives from a
+  the tail-latency insurance tests/test_fleet.py holds under an
+  injected slow replica. The trigger derives from a
   SERVICE-TIME window that excludes hedge-won requests: a rescued
   request's client latency is ~the threshold itself, and feeding it
   back would ratchet the threshold by factor-x per slow request until

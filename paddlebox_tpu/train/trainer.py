@@ -89,18 +89,6 @@ class TrainerConfig:
     sync_dense_moment: bool = False        # FLAGS_enable_sync_dense_moment
     async_merge_limit: int = 4             # async table grad-merge bound
     async_betas: tuple = (0.99, 0.9999)    # reference's hard-coded betas
-    # Microbatches trained per device dispatch: train_pass groups this
-    # many packed batches, stages them as ONE stacked H2D, and runs them
-    # through a lax.scan superstep — identical math to k sequential
-    # steps (tested bitwise-tight), but one program launch instead of k.
-    # Default 1: the python loop's async dispatch already overlaps
-    # launch with device compute, so the launch floor only bites when
-    # the host must block per step; no gain is measured on this code
-    # (ROADMAP D3 runs the pair on the chip and keeps or deletes it).
-    # Opt in (allreduce + flat dense transport only; tail groups fall
-    # back to the single-step program) for host-bound deployments where
-    # dispatch throughput, not device time, limits the step rate.
-    steps_per_dispatch: int = 1
 
 
 def _mean_replicated_grad(gp, axes):
@@ -120,28 +108,6 @@ def _mean_replicated_grad(gp, axes):
 
 
 _NO_PLAN = np.zeros(0, np.int32)   # zero-length = "no host binned plan"
-
-
-def _level_plan_lanes(host_tuples: list, n_rows: int) -> list:
-    """Host batch tuples of one stacked dispatch with their plans' `uniq`
-    and `segend` brought to the group's largest lane count (the lanes
-    grow inside a group when a batch crosses a rung, _plan_lane_count):
-    further pads under the plan's contract — rows ascending past the
-    last one and out of range, zero-width segments at the stream's end."""
-    iu, isg = 4 + PLAN_ARITY - 2, 4 + PLAN_ARITY - 1
-    lanes = max(len(ht[iu]) for ht in host_tuples)
-    out = []
-    for ht in host_tuples:
-        k = lanes - len(ht[iu])
-        if k:
-            u, sg = ht[iu], ht[isg]
-            first = max(int(u[-1]) + 1, n_rows)
-            ht = (*ht[:iu],
-                  np.concatenate([u, first + np.arange(k, dtype=np.int32)]),
-                  np.concatenate([sg, np.full(k, sg[-1], np.int32)]),
-                  *ht[isg + 1:])
-        out.append(ht)
-    return out
 
 
 def _dense_tx(cfg: TrainerConfig) -> optax.GradientTransformation:
@@ -273,17 +239,16 @@ class Trainer:
         # loss returns each step (counters of the flight record).
         self._feeds_auc = model_base.predicts(model)
         self._n_stats = len(model_base.stat_names(model))
-        if self._n_stats and (self.cfg.dense_sync_mode != "allreduce"
-                              or self.cfg.steps_per_dispatch > 1):
+        if self._n_stats and self.cfg.dense_sync_mode != "allreduce":
             raise NotImplementedError(
                 "models that declare stat_names support the allreduce "
-                "dense-sync mode with steps_per_dispatch=1 only")
+                "dense-sync mode only")
         # Table-layout engine (flags.table_layout): which embedding
         # exchange the step programs compile with. "sharded" routes the
         # dedup plan's unique rows through embedding/exchange.py (wire-
         # compressed push payload, per-shard fused pull after routing);
         # "single" keeps the legacy token-level routed path. Trace-time
-        # static and recorded per bench point / flight record, like
+        # static; on engines() and in the flight record, like
         # pull_engine.
         self.table_layout = self._select_table_layout()
         self.exchange_wire = (exchange.select_wire(self.store.cfg)
@@ -350,9 +315,7 @@ class Trainer:
         # backend (scatter_accumulate consumes the plan's premerged
         # unique lanes; off-TPU it runs the identical jnp math — the
         # CPU-parity/A/B knob). Read at trace time like the kernels.
-        from paddlebox_tpu.ops import pallas_kernels
-        fused_forced = (pallas_kernels.normalize_push_engine(
-            config_flags.push_engine) == "scatter_accumulate")
+        fused_forced = config_flags.push_engine == "scatter_accumulate"
         self._use_plan = (
             (self.n_shards == 1
              and ((config_flags.binned_push
@@ -366,7 +329,6 @@ class Trainer:
         # for the trainer's life, so a day compiles one step and one
         # apply per rung of working_set.bucket_size reached
         self._plan_lanes = 0
-        self._superstep_fn: Callable | None = None
         # Deferred sparse-push pipeline (flags.push_overlap): the step
         # returns packed push operands off the loss-producing path; the
         # apply program for step N dispatches while step N+1's pack and
@@ -453,18 +415,11 @@ class Trainer:
         return labels, dense
 
     # ------------------------------------------------------------------
-    def _fwd_bwd_push(self, ablate: tuple = (), defer: bool = False):
+    def _fwd_bwd_push(self, defer: bool = False):
         """Shared shard_map core: routed pull → fwd/bwd → routed push.
 
         Returns a fn(tshard, idx_l, mask_l, dense_l, labels_l, params_local)
         → (new_shard, local_dense_grads, local_loss, preds).
-
-        ablate: subset of {"lookup", "fwdbwd", "push"} — replaces that
-        stage with a shape-preserving no-op. Used by the bench's stage
-        attribution (step_probe.attribute_step): the marginal device cost
-        of a stage is full-step time minus the ablated step's time, the
-        only measurement that accounts for XLA's cross-stage overlap.
-        Never set in training.
 
         defer: the push stage returns its packed operands
         (sharded.deferred_push_operands — premerged in-step when the host
@@ -483,10 +438,6 @@ class Trainer:
         # the loss and the prediction are the model's to declare
         # (models/base.py): (loss, (preds, stats)) of one local batch
         model_loss = model_base.declared_loss(model, seg, num_slots)
-        # a model's declared statistics ride out of the step as one small
-        # vector (model_base.stat_names); none for a model that has none
-        no_stats = ((jnp.zeros((self._n_stats,), jnp.float32),)
-                    if self._n_stats else ())
 
         # FLAGS_enable_pullpush_dedup_keys (flags.cc:603): merge duplicate
         # tokens before the all_to_all so routed traffic carries each key
@@ -503,8 +454,8 @@ class Trainer:
         topo = self.exchange_topology or "flat"
 
         def push_tail(tshard, flat_idx, sgrad, mask_l, labels_l, plan):
-            """Push stage tail: deferred operands, ablated no-op, or the
-            inline routed merge-update. Deferred: the apply program
+            """Push stage tail: deferred operands, or the inline routed
+            merge-update. Deferred: the apply program
             replays the same inputs one step later (Trainer._apply_fn)."""
             if defer:
                 show_inc = mask_l.reshape(-1).astype(jnp.float32)
@@ -512,8 +463,6 @@ class Trainer:
                            * labels_l[:, None]).reshape(-1)
                 return sharded.deferred_push_operands(
                     flat_idx, sgrad, show_inc, clk_inc, plan)
-            if "push" in ablate:
-                return tshard
             show_inc = mask_l.reshape(-1).astype(jnp.float32)
             clk_inc = (mask_l.astype(jnp.float32)
                        * labels_l[:, None]).reshape(-1)
@@ -540,12 +489,7 @@ class Trainer:
                 # (B*T, P) token matrix exists in neither direction
                 # (backward expands the pooled cotangent per token
                 # straight into the premerge/binned push).
-                if "lookup" in ablate:
-                    pooled = lax.optimization_barrier(
-                        jnp.zeros((B_l, num_slots, emb_cfg.pull_width),
-                                  jnp.float32) + labels_l[0] * 0)
-                    dropped = jnp.zeros((), jnp.int32)
-                elif sharded_x:
+                if sharded_x:
                     # route the unique rows once, pool per shard from
                     # the received lanes (gather_pool after routing)
                     pooled, dropped = exchange.routed_pull_pooled(
@@ -560,33 +504,19 @@ class Trainer:
                     return model_loss(p, PooledSlots(pooled_in), mask_l,
                                       dense_l, labels_l, *extras_l)
 
-                stats = no_stats
-                if "fwdbwd" in ablate:
-                    loss = jnp.sum(pooled) * 1e-8
-                    preds = jnp.zeros((B_l,), jnp.float32)
-                    gp = jax.tree.map(jnp.zeros_like, params)
-                    sgrad = lax.optimization_barrier(
-                        jnp.zeros((B_l * T, emb_cfg.grad_width),
-                                  jnp.float32) + loss * 0)
-                else:
-                    grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1),
-                                                 has_aux=True)
-                    (loss, (preds, stats)), (gp, gpooled) = grad_fn(
-                        params, pooled)
-                    sgrad = sharded.pooled_grad_tokens(gpooled, mask_l,
-                                                       seg, num_slots)
-                    if cfg.scale_sparse_grad_by_global_mean:
-                        sgrad = sgrad / D
+                grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1),
+                                             has_aux=True)
+                (loss, (preds, stats)), (gp, gpooled) = grad_fn(
+                    params, pooled)
+                sgrad = sharded.pooled_grad_tokens(gpooled, mask_l,
+                                                   seg, num_slots)
+                if cfg.scale_sparse_grad_by_global_mean:
+                    sgrad = sgrad / D
                 new_shard = push_tail(tshard, flat_idx, sgrad, mask_l,
                                       labels_l, plan)
                 return (new_shard, gp, loss, preds, lax.psum(dropped, axes),
                         *stats)
-            if "lookup" in ablate:
-                pulled = lax.optimization_barrier(
-                    jnp.zeros((B_l * T, emb_cfg.pull_width), jnp.float32)
-                    + labels_l[0] * 0)
-                dropped = jnp.zeros((), jnp.int32)
-            elif sharded_x:
+            if sharded_x:
                 pulled, dropped = exchange.routed_pull(
                     tshard, flat_idx, emb_cfg, axes, capf, plan=plan,
                     dedup=dedup, return_dropped=True)
@@ -600,24 +530,14 @@ class Trainer:
                 return model_loss(p, pulled_in, mask_l, dense_l, labels_l,
                                   *extras_l)
 
-            stats = no_stats
-            if "fwdbwd" in ablate:
-                loss = jnp.sum(pulled) * 1e-8
-                preds = jnp.zeros((B_l,), jnp.float32)
-                gp = jax.tree.map(jnp.zeros_like, params)
-                sgrad = lax.optimization_barrier(
-                    jnp.zeros((B_l * T, emb_cfg.grad_width), jnp.float32)
-                    + loss * 0)
-            else:
-                grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1),
-                                             has_aux=True)
-                (loss, (preds, stats)), (gp, gpull) = grad_fn(params,
-                                                              pulled)
-                # sparse grads: only (w, embedx) columns train; show/clk
-                # are counters (CVM grads dropped, like cvm_op's grad)
-                sgrad = gpull[..., 2:].reshape(B_l * T, emb_cfg.grad_width)
-                if cfg.scale_sparse_grad_by_global_mean:
-                    sgrad = sgrad / D
+            grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1),
+                                         has_aux=True)
+            (loss, (preds, stats)), (gp, gpull) = grad_fn(params, pulled)
+            # sparse grads: only (w, embedx) columns train; show/clk
+            # are counters (CVM grads dropped, like cvm_op's grad)
+            sgrad = gpull[..., 2:].reshape(B_l * T, emb_cfg.grad_width)
+            if cfg.scale_sparse_grad_by_global_mean:
+                sgrad = sgrad / D
             new_shard = push_tail(tshard, flat_idx, sgrad, mask_l,
                                   labels_l, plan)
             # capacity-drop monitor: global count of tokens the fixed-size
@@ -628,17 +548,13 @@ class Trainer:
 
         return core
 
-    def _build_train_step(self, ablate: tuple = (), scan_steps: int = 1,
-                          defer: bool = False) -> Callable:
+    def _build_train_step(self, defer: bool = False) -> Callable:
         cfg = self.cfg
         axes = tuple(self.mesh.axis_names)
         tx = self.tx
-        if defer:
-            # deferred push (flags.push_overlap): allreduce single-step
-            # programs only, and ablation instruments the INLINE step
-            assert not ablate and scan_steps == 1 \
-                and cfg.dense_sync_mode == "allreduce"
-        core = self._fwd_bwd_push(ablate, defer=defer)
+        # deferred push (flags.push_overlap): allreduce programs only
+        assert not defer or cfg.dense_sync_mode == "allreduce"
+        core = self._fwd_bwd_push(defer=defer)
         batch_spec = P(axes)
         repl = mesh_lib.replicated_sharding(self.mesh)
         tbl_sh = mesh_lib.table_sharding(self.mesh)
@@ -775,31 +691,6 @@ class Trainer:
                     out_shardings=(repl,) * n_dense + (bat_sh,) * 3
                     + (repl,) * n_st + (repl, bat_sh, repl))
 
-            if scan_steps > 1:
-                # k-microbatch superstep: ONE dispatch runs k sequential
-                # steps via lax.scan over stacked batch operands — the
-                # same math in the same order as k step_flat calls, with
-                # the per-program launch floor paid once
-                stk_sh = mesh_lib.stacked_batch_sharding(self.mesh)
-
-                def superstep(table, *args):
-                    dstate = args[:n_dense]
-                    stacked = args[n_dense:]      # each (k, ...)
-
-                    def body(carry, xs):
-                        tbl, dst = carry
-                        out = step_flat(tbl, *dst, *xs)
-                        return ((out[0], out[1:1 + n_dense]),
-                                out[1 + n_dense:])
-                    (table, dstate), (loss, preds, drop_g) = lax.scan(
-                        body, (table, dstate), stacked)
-                    return (table, *dstate, loss, preds, drop_g)
-
-                return jax.jit(superstep, donate_argnums=(0, 1, 2),
-                               out_shardings=(tbl_sh,)
-                               + (repl,) * n_dense
-                               + (repl, stk_sh, repl))
-
             return jax.jit(step_flat, donate_argnums=(0, 1, 2),
                            out_shardings=(tbl_sh,) + (repl,) * n_dense
                            + (repl,) * n_st + (repl, bat_sh, repl))
@@ -890,24 +781,21 @@ class Trainer:
         """Whether training runs the deferred sparse-push pipeline
         (flags.push_overlap, read at construction — trace-time static,
         like the engine heuristics). "auto" = on where dense sync
-        permits: the allreduce single-step program (kstep trains
-        per-shard dense copies inside the step, async already decouples
-        dense through the host table, and the k-microbatch superstep
-        carries the table through a scan — all three need the inline
-        apply). Mirrors AsyncDenseTable's dispatch-decoupling semantics
+        permits: the allreduce mode (kstep trains per-shard dense copies
+        inside the step and async already decouples dense through the
+        host table — both need the inline apply). Mirrors AsyncDenseTable's dispatch-decoupling semantics
         on the sparse side with a hard one-step staleness bound."""
         po = config_flags.push_overlap
         if po not in ("auto", "on", "off"):
             raise ValueError(f"push_overlap={po!r}")
         if po == "off":
             return False
-        ok = (self.cfg.dense_sync_mode == "allreduce"
-              and self.cfg.steps_per_dispatch == 1)
+        ok = self.cfg.dense_sync_mode == "allreduce"
         if po == "on" and not ok:
             raise ValueError(
                 "flags.push_overlap='on' needs the allreduce dense-sync "
-                "mode with steps_per_dispatch=1 (the deferred apply is "
-                "sequenced between single-step programs)")
+                "mode (the deferred apply is sequenced between its step "
+                "programs)")
         return ok
 
     def split_defer_out(self, out: tuple):
@@ -1019,8 +907,8 @@ class Trainer:
                     else (np.zeros(0, np.int32),) * PLAN_ARITY)
             # embedding-plane traffic counters (flight-record deltas):
             # pull = tokens * pull_width rows out, push = grad + show/clk
-            # lanes back (approximate routed volume; exact per-engine
-            # numbers stay the bench's job)
+            # lanes back (approximate routed volume; benchmark/work.py
+            # counts what the step must move, from the shapes)
             ecfg = self.store.cfg
             monitor.counter_add("trainer.tokens", idx.size)
             monitor.counter_add("trainer.pull_bytes",
@@ -1037,31 +925,24 @@ class Trainer:
         return (idx, pb.mask, dense.astype(np.float32),
                 labels.astype(np.float32), *plan, *extras)
 
-    def _stage_device(self, host_tuple: tuple, sharding=None):
+    def _stage_device(self, host_tuple: tuple):
         # ONE device_put for all arrays: each put is its own
         # host->device dispatch
         with self.timers("h2d", span="h2d_stage"):
             return jax.device_put(
-                host_tuple, sharding or mesh_lib.batch_sharding(self.mesh))
+                host_tuple, mesh_lib.batch_sharding(self.mesh))
 
     def _put_batch(self, ws: PassWorkingSet, pb: PackedBatch,
                    with_plan: bool = True):
         return self._stage_device(self._pack_host(ws, pb, with_plan))
 
     def _pack_iter(self, dataset, ws: PassWorkingSet, batch_size: int,
-                   with_plan: bool = True, drop_last: bool = True,
-                   group: int = 1):
-        """Yield staged batches with translate + host plan + H2D
+                   with_plan: bool = True, drop_last: bool = True):
+        """Yield (pb, staged) batches with translate + host plan + H2D
         dispatched on a background thread, `flags.prefetch_batches`
         batches ahead of the training loop — the MiniBatchGpuPack
         pipeline (data_feed.h:1372-1535). The main thread's queue wait
         is timed as the "read" stage (starvation = host-bound pass).
-
-        group=1 yields (pb, staged). group=k yields
-        (pbs, staged, stacked): full groups carry k packed batches
-        stacked on a new leading axis and staged with ONE device_put
-        (the superstep's operands); the tail yields single-staged
-        batches with stacked=False.
 
         drop_last=False pads the tail batch instead (eval passes score
         every example; pb.num keeps the pre-pad valid count)."""
@@ -1130,37 +1011,8 @@ class Trainer:
 
         raw = raw_iter()
         try:
-            if group <= 1:
-                for pb, host_tuple in raw:
-                    yield pb, self._stage_device(host_tuple)
-                return
-            stk_sh = mesh_lib.stacked_batch_sharding(self.mesh)
-            n_sh = self.n_shards
-            buf: list = []
-            for item in raw:
-                buf.append(item)
-                if len(buf) == group:
-                    stacked = tuple(
-                        np.stack(cols) for cols in zip(*_level_plan_lanes(
-                            [ht for _, ht in buf], ws.padded_rows)))
-                    # the extras protocol requires batch-leading arrays
-                    # (the step's shard_map in_specs shard dim 0); a 0-d
-                    # or per-batch-scalar extra would stack to (k,) and
-                    # fail deep inside the scan trace — fail loudly here
-                    # instead, naming the protocol
-                    for a in stacked:
-                        if a.ndim < 2 or a.shape[1] % n_sh:
-                            raise ValueError(
-                                "steps_per_dispatch>1 requires every "
-                                "host-batch leaf (incl. model "
-                                "batch_extras) to be batch-leading with "
-                                f"a mesh-divisible axis 0; got stacked "
-                                f"shape {a.shape} on a {n_sh}-way mesh")
-                    yield ([pb for pb, _ in buf],
-                           self._stage_device(stacked, stk_sh), True)
-                    buf = []
-            for pb, host_tuple in buf:      # tail: single-step program
-                yield [pb], self._stage_device(host_tuple), False
+            for pb, host_tuple in raw:
+                yield pb, self._stage_device(host_tuple)
         finally:
             # closing this generator must shut the producer down NOW
             # (GeneratorExit propagates here, not into the suspended
@@ -1265,9 +1117,8 @@ class Trainer:
 
     def _select_table_layout(self) -> str:
         """Which embedding exchange the step programs compile with
-        (flags.table_layout; trace-time static, recorded per bench
-        matrix point as ``table_layout`` — same discipline as
-        pull_engine).
+        (flags.table_layout; trace-time static, reported by engines()
+        as ``table_layout`` — same discipline as pull_engine).
 
         "sharded" — the embedding/exchange.py subsystem over the mesh-
         partitioned table: the host dedup plan keys the all_to_all
@@ -1296,7 +1147,7 @@ class Trainer:
 
     def _select_pull_engine(self) -> str:
         """Which pull engine the step programs compile with (trace-time
-        static, recorded per bench matrix point like push_engine).
+        static, reported by engines() like push_engine).
 
         "fused_gather_pool" — rows pool per (example, slot) inside the
         pull (sharded.fused_pull_pool; the Pallas gather_pool kernel on
@@ -1377,8 +1228,8 @@ class Trainer:
         """Whether the host plan carries dedup pre-merge bounds
         (flags.push_dedup_premerge). "auto" = the geometries where the
         round-5 in-step A/B on one v5e measured a win: multi-hot
-        batches (duplicate-heavy: 852k tokens -> ~330k unique at the
-        bench's multihot4 point) and wide scatter-engine rows (G=1,
+        batches (duplicate-heavy: 852k tokens -> ~330k unique at 4 ids
+        a slot) and wide scatter-engine rows (G=1,
         where the per-token scatter is the bound). Single-hot
         narrow-row batches measured neutral-to-slower (the premerge's
         cumsum + boundary gathers cost more than the kernel saves at
@@ -1386,13 +1237,12 @@ class Trainer:
         dd = config_flags.push_dedup_premerge
         if dd != "auto":
             return dd == "on"
-        from paddlebox_tpu.ops import pallas_kernels
-        if (pallas_kernels.normalize_push_engine(config_flags.push_engine)
-                == "scatter_accumulate"):
+        if config_flags.push_engine == "scatter_accumulate":
             # the forced fused engine consumes premerged unique lanes —
             # without the premerge it would silently fall back to the
             # scatter and the A/B would measure nothing
             return True
+        from paddlebox_tpu.ops import pallas_kernels
         multi_hot = self.layout.total_len > self.layout.num_slots
         wide = pallas_kernels.lane_groups(
             self.store.cfg, ws.padded_rows) == 1
@@ -1411,8 +1261,8 @@ class Trainer:
         """Which push merge engine the step programs compile with for
         this working set — THE resolver's verdict at the per-shard
         geometry (the engine dispatches on rows_per_shard after
-        routing). Trace-time static; recorded per bench matrix point
-        and in the flight record, like pull_engine."""
+        routing). Trace-time static; on engines() and in the flight
+        record, like pull_engine."""
         from paddlebox_tpu.embedding import quant
         from paddlebox_tpu.ops import pallas_kernels
         return pallas_kernels.resolve_push_engine(
@@ -1513,8 +1363,7 @@ class Trainer:
             push_applies=(self.push_applies - applies0) or None,
             pull_engine=self.pull_engine,
             # which push merge engine this pass's steps compiled with
-            # (THE resolver's verdict — the doctor's push-floor rule
-            # names it when suggesting a forced A/B)
+            # (THE resolver's verdict)
             push_engine=self.engines()["push_engine"],
             # pass-boundary cost (this pass's working-set build) + its
             # split — the run doctor's boundary-wall rule reads both
@@ -1684,46 +1533,20 @@ class Trainer:
         dump_stream = (DumpStream(cfg.dump_fields_path, mode="a")
                        if cfg.dump_fields_path else None)
         dump_pending: tuple[int, Any, Any] | None = None
-        # k-microbatch supersteps: one dispatch + one stacked H2D per k
-        # batches (allreduce + flat transport only; see steps_per_dispatch)
-        use_super = (self._superstep_fn is not None and dstate is not None
-                     and mode == "allreduce")
-        k_sd = cfg.steps_per_dispatch if use_super else 1
-        if k_sd > 1 and int(skip_steps) % k_sd:
-            # the superstep cursor advances k steps per dispatched
-            # program — a resume can only land BETWEEN dispatches (the
-            # same boundary rule as the kstep sync-boundary refusal)
-            raise NotImplementedError(
-                f"mid-pass resume with steps_per_dispatch={k_sd} needs "
-                f"the cursor on a dispatch boundary: skip_steps="
-                f"{skip_steps} is not a multiple of {k_sd}")
-        if k_sd > 1 and self._midpass is not None \
-                and self._midpass[1] % k_sd:
-            raise NotImplementedError(
-                f"mid-pass snapshots with steps_per_dispatch={k_sd} need "
-                f"a cadence on the dispatch boundary: every_steps="
-                f"{self._midpass[1]} is not a multiple of {k_sd}")
         skip_remaining = int(skip_steps)
         head_open = True
         completed = False
-        pack_it = self._pack_iter(dataset, ws, cfg.global_batch_size,
-                                  group=k_sd)
+        pack_it = self._pack_iter(dataset, ws, cfg.global_batch_size)
         try:
-            for item in pack_it:
-                if k_sd > 1:
-                    pbs, staged, stacked = item
-                else:
-                    pbs, staged, stacked = [item[0]], item[1], False
+            for pb, staged in pack_it:
                 if skip_remaining > 0:
                     # mid-pass resume: these batches' effects already live
                     # in the restored planes — consume them (keeps the
                     # batch stream and step cadence aligned) but train
-                    # nothing. Superstep groups skip whole (the boundary
-                    # check above guarantees skip_remaining covers them).
-                    skip_remaining -= len(pbs)
-                    pass_step += len(pbs)
+                    # nothing.
+                    skip_remaining -= 1
+                    pass_step += 1
                     continue
-                pb = pbs[-1]
                 mon_ctx.set_step(self.global_step)
                 if self.peer_check is not None:
                     # elastic watchdog: a dead/stalled peer aborts HERE —
@@ -1752,18 +1575,12 @@ class Trainer:
                     self.timers.add("head", time.perf_counter() - pass_t0)
                     head_open = False
                 with self.timers("train", span="train_step"):
-                    if stacked:
-                        out = self._superstep_fn(table, *dstate, *staged)
-                        (table, dstate, loss, preds,
-                         dropped) = self.split_step_out(out)
-                        pass_step += len(pbs)   # loss/preds: (k,)/(k, B)
-                    elif mode == "async":
+                    if mode == "async":
                         params = jax.device_put(
                             self._unravel(self.dense_table.pull()), repl)
                         table, gp_flat, loss, preds, dropped = self._step_fn(
                             table, params, idx, mask, dense, labels, *plan)
                         self.dense_table.push(np.asarray(gp_flat))
-                        pass_step += 1
                     elif self.push_overlap:
                         # deferred push pipeline: dispatch step N-1's
                         # pending table apply FIRST (the next step's pull
@@ -1786,46 +1603,34 @@ class Trainer:
                         self._push_stager.put(
                             (idx, mask, labels,
                              tuple(plan[:PLAN_ARITY]), push_ops))
-                        pass_step += 1
                     elif dstate is not None:
                         out = self._step_fn(table, *dstate, idx, mask,
                                             dense, labels, *plan)
                         (table, dstate, loss, preds,
                          dropped) = self.split_step_out(out)
-                        pass_step += 1
                     else:
                         out = self._step_fn(
                             table, params, opt_state, idx, mask, dense,
                             labels, *plan)
                         (table, (params, opt_state), loss, preds,
                          dropped) = self.split_step_out(out)
-                        pass_step += 1
-                        if (mode == "kstep"
-                                and pass_step % cfg.param_sync_step == 0):
-                            params, opt_state = self._sync_fn(params,
-                                                              opt_state)
+                    pass_step += 1
+                    if (mode == "kstep"
+                            and pass_step % cfg.param_sync_step == 0):
+                        params, opt_state = self._sync_fn(params, opt_state)
                 if self._n_stats:
                     # the model's statistics sit just before the loss in
-                    # every allreduce single-step program's output
+                    # every allreduce step program's output
                     dev_stats.append(out[-4])
                 # keep the ws pointing at the live buffer: the step donates
                 # its input table, and a concurrent flush (store read/save
                 # from another thread) must never gather from a dead buffer
                 ws.table = table
                 with self.timers("auc", span="auc_update"):
-                    # the AUC histogram is order-invariant: a stacked
-                    # (k, B) group updates in one flattened call. A model
-                    # that declares no prediction feeds nothing.
+                    # a model that declares no prediction feeds nothing
                     if self._feeds_auc:
-                        auc_acc.update(self._auc_fn, preds.reshape(-1),
-                                       labels.reshape(-1))
-                    if metrics is not None and self._feeds_auc:
-                        if stacked:
-                            for i, gpb in enumerate(pbs):
-                                metrics.add_batch(preds[i], labels[i],
-                                                  cmatch=gpb.cmatch,
-                                                  rank=gpb.rank)
-                        else:
+                        auc_acc.update(self._auc_fn, preds, labels)
+                        if metrics is not None:
                             metrics.add_batch(preds, labels,
                                               cmatch=pb.cmatch,
                                               rank=pb.rank)
@@ -1833,19 +1638,8 @@ class Trainer:
                     if dump_pending is not None:
                         s, p, y, ex = dump_pending
                         dump_stream.write_fields(s, p, y, ex)
-                    if stacked:
-                        # all but the group's last batch flush now; the
-                        # last stays pending like the single-step path
-                        for i in range(len(pbs) - 1):
-                            dump_stream.write_fields(
-                                self.global_step + i, preds[i], labels[i],
-                                self._dump_extra_fields(pbs[i]))
-                        dump_pending = (self.global_step + len(pbs) - 1,
-                                        preds[-1], labels[-1],
-                                        self._dump_extra_fields(pb))
-                    else:
-                        dump_pending = (self.global_step, preds, labels,
-                                        self._dump_extra_fields(pb))
+                    dump_pending = (self.global_step, preds, labels,
+                                    self._dump_extra_fields(pb))
                 if cfg.check_nan_inf or config_flags.check_nan_inf:
                     lv = np.asarray(loss)
                     if not np.isfinite(lv).all():
@@ -1877,7 +1671,7 @@ class Trainer:
                                if dumped else ""))
                 dev_losses.append(loss)
                 dev_dropped.append(dropped)
-                self.global_step += len(pbs)
+                self.global_step += 1
                 mp = self._midpass
                 if (mp is not None and mp[1] > 0
                         and pass_step % mp[1] == 0):
@@ -1926,7 +1720,7 @@ class Trainer:
                         # as _midpass_save would store them — for kstep,
                         # BEFORE the finalize pmean below (k·x/k can round
                         # for non-power-of-2 shard counts, and the drain
-                        # snapshot must stay bit-identical to the stacked
+                        # snapshot must stay bit-identical to the per-shard
                         # loop state the uninterrupted run continues from)
                         self._last_dense = (self.unpack_dense(dstate)
                                             if dstate is not None
@@ -1968,9 +1762,7 @@ class Trainer:
         with self.timers("drain"):
             # one sync, post-loop: every queued step completes here, so
             # this is where async-dispatch wall time actually lands.
-            # Superstep entries are (k,) vectors; flatten to per-step.
-            losses = [float(x) for l in dev_losses
-                      for x in np.asarray(l).reshape(-1)]
+            losses = [float(l) for l in dev_losses]
         # every dispatched apply has drained; release the stager's
         # retired-slot buffer refs (the pipeline's leak invariant:
         # live() == 0 between passes)
@@ -2101,21 +1893,15 @@ class Trainer:
 
     def _rebuild_steps(self) -> None:
         """(Re)build the compiled step programs from the current config:
-        the single step, the deferred step + apply pair (push_overlap),
-        the k-microbatch superstep (allreduce + flat dense transport
-        only), and the eval step. _step_fn is ALWAYS the inline step —
-        external callers and the stage attribution instrument it; the
-        training loop uses the deferred pair when push_overlap is on."""
+        the step, the deferred step + apply pair (push_overlap), and the
+        eval step. _step_fn is ALWAYS the inline step — external callers
+        drive it; the training loop uses the deferred pair when
+        push_overlap is on."""
         self._step_fn = self._build_train_step()
         self._defer_step_fn = (self._build_train_step(defer=True)
                                if self.push_overlap else None)
         self._apply_fn = (self._build_apply_fn()
                           if self.push_overlap else None)
-        k = self.cfg.steps_per_dispatch
-        self._superstep_fn = (
-            self._build_train_step(scan_steps=k)
-            if (k > 1 and self.cfg.dense_sync_mode == "allreduce"
-                and self._dense_packer is not None) else None)
         self._eval_fn = self._build_eval_step()
 
     def _check_dropped(self, dev_dropped: list,
@@ -2131,8 +1917,7 @@ class Trainer:
         capacity/program — skew in an eval-only dataset must never
         inflate the train step's padding or force a train recompile."""
         import warnings
-        # superstep entries are (k,) vectors, single steps scalars
-        total = int(sum(int(np.asarray(d).sum()) for d in dev_dropped))
+        total = sum(int(d) for d in dev_dropped)
         if not total:
             return 0
         monitor.counter_add("trainer.routed_dropped", total)
@@ -2344,12 +2129,7 @@ class Trainer:
         Supported dense-sync modes:
 
         - ``allreduce``: any cadence; the live flat/pytree dense state
-          rides ``dense_override``. With ``steps_per_dispatch > 1`` the
-          cadence must land on the DISPATCH boundary (a multiple of
-          ``steps_per_dispatch`` — the cursor advances k steps per
-          dispatched superstep program, so snapshots/resume can only
-          land between dispatches; the same pattern as the kstep
-          sync-boundary rule below).
+          rides ``dense_override``.
         - ``kstep``: ``every_steps`` must land on the K-step sync
           boundary (a multiple of ``param_sync_step``) — that is where
           the per-shard replicas are consistent with the uninterrupted
@@ -2366,14 +2146,6 @@ class Trainer:
             self._midpass = None
             return
         mode = self.cfg.dense_sync_mode
-        if self.cfg.steps_per_dispatch > 1 \
-                and every_steps % self.cfg.steps_per_dispatch:
-            raise NotImplementedError(
-                f"mid-pass snapshots with steps_per_dispatch="
-                f"{self.cfg.steps_per_dispatch} must land on the "
-                f"dispatch boundary: every_steps={every_steps} is not a "
-                f"multiple of it — the k-microbatch program commits k "
-                f"steps atomically, so no cursor exists between them")
         if mode == "kstep" and every_steps % self.cfg.param_sync_step:
             raise NotImplementedError(
                 f"kstep mid-pass snapshots must land on the K-step sync "

@@ -2,8 +2,8 @@
 
 One binned-push train step costs the TPU compiler tens of seconds, and a
 cold chip call pays that for every program, so the entry points
-(``chip_smoke.py``, ``bench.py``, ``examples/train_ctr.py``) turn the
-persistent cache on first thing. The directory is part of the cache key's
+(``chip_smoke.py``, ``benchmark/run.py``, ``examples/train_ctr.py``) turn
+the persistent cache on first thing. The directory is part of the cache key's
 environment, so it must not move between runs: when
 ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and nothing is
 set in code; otherwise it is ``.jax_cache`` at the root of this checkout

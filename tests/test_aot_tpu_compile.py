@@ -6,7 +6,7 @@ mode — how every other kernel test in this suite runs — accepts what
 Mosaic refuses: row DMAs that break the HBM tiling, SMEM blocks off the
 1024-word tiling, scratch past the VMEM limit. These tests hand every
 Pallas kernel a resolver can select on a TPU to the real compiler at the
-widths the bench matrix names, and hold the geometry functions to the
+widths the first rounds measured (dims 8 to 128), and hold the geometry functions to the
 compiler's verdict: nothing they accept may be refused.
 
 All compiles run in this process (the worker that describes the topology
@@ -29,7 +29,7 @@ from paddlebox_tpu.config import flags
 from paddlebox_tpu.embedding.config import EmbeddingConfig
 from paddlebox_tpu.ops import pallas_kernels as pk
 
-ROWS = 1 << 19          # bench.py's device-step table
+ROWS = 1 << 19          # rows of a per-chip table
 BATCH, SLOTS = 8192, 26
 
 
@@ -81,7 +81,7 @@ def _compiled_text(fn, one_chip, *shapes, donate=()):
 
 
 # ---------------------------------------------------------------------------
-# every kernel a resolver can select, at the bench matrix's widths
+# every kernel a resolver can select, at dims 8 to 128
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("dim,hot,storage", [

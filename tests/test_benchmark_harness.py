@@ -1,30 +1,56 @@
 """The benchmark's harness under the driver's count: the tests of
-``benchmark/tests`` that a configuration added as files only stands on —
-the seam for a model's own loss over ordered tokens (``test_seam``), the
-traffic generator (``test_datagen``) and the work counts (``test_work``) —
-collected here as they are, plus a whole ``run.py --rehearse`` of the
-ordered-token cell at its own rehearsal sizes: traffic files, the program
-through BoxPS passes, the window, the read-back, the blocked reference and
-the comparison, on the CPU."""
+``benchmark/tests`` collected here as they are — the seam for a model's
+own loss over ordered tokens (``test_seam``), the traffic generator
+(``test_datagen``), the work counts (``test_work``), the per-layer readers
+held to the program's span and counter names (``test_stage_metrics``,
+``test_trace_reduce``), and what decides ``correct`` (``test_correct``: the
+program against the f32 reference, the bfloat16 control and the planted
+faults, at CPU size for every cell of ``BENCHMARK.json`` and the waiting
+one) — plus a whole ``run.py --rehearse`` of each cell at its own rehearsal
+sizes: traffic files, the program through BoxPS passes, the window, the
+read-back, the blocked reference and the comparison, on the CPU."""
 
 import os
 import sys
+
+import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from benchmark.tests import test_correct as _correct    # noqa: E402
+from benchmark.tests.test_correct import *      # noqa: E402,F401,F403
 from benchmark.tests.test_datagen import *      # noqa: E402,F401,F403
 from benchmark.tests.test_seam import *         # noqa: E402,F401,F403
+from benchmark.tests.test_stage_metrics import *    # noqa: E402,F401,F403
+from benchmark.tests.test_trace_reduce import *     # noqa: E402,F401,F403
 from benchmark.tests.test_work import *         # noqa: E402,F401,F403
 
+# The one case of benchmark/tests that fails at the parent: the fault
+# patches optax.sigmoid_binary_cross_entropy, which the ordered-token
+# tower's own loss never calls, so nothing is planted and the run comes
+# out correct (PERF.md section 7, "for a `benchmark` PR").
+_PLANTS_NOTHING = ("smallthinker_21b_ep4.seq8k", "_half_batch")
 
-def test_the_ordered_token_cell_rehearses_whole(capsys):
+
+@pytest.mark.parametrize("fault", [_correct._unchanged_state,
+                                   _correct._half_batch,
+                                   _correct._write_back_dropped])
+@pytest.mark.parametrize("cell", _correct.CELLS)
+def test_broken_timed_path_is_not_correct(cell, fault, monkeypatch):  # noqa: F811
+    if (cell, fault.__name__) == _PLANTS_NOTHING:
+        pytest.skip("the fault patches a loss this cell's model never calls")
+    _correct.test_broken_timed_path_is_not_correct(cell, fault, monkeypatch)
+
+
+@pytest.mark.parametrize("cell", _correct.CELLS)
+def test_the_cell_rehearses_whole(cell, capsys):
     import json
     from benchmark import run
-    code = run.main(["--workload", "smallthinker_21b_ep4.seq8k",
-                     "--seed", "2800000321", "--seconds", "1",
-                     "--rehearse"])
+    code = run.main(["--workload", cell, "--seed", "2800000321",
+                     "--seconds", "1", "--rehearse",
+                     "--waiting", _correct.WAITING])
     last = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert code == 0 and last["rehearsal"] == "passed"
     assert last["failed"] == 0 and last["attempted"] > 0

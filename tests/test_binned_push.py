@@ -2,8 +2,8 @@
 
 CPU coverage runs the Pallas interpreter; parity is against the XLA
 scatter+update path (summation ORDER differs, so tolerances not bitwise).
-The real-TPU Mosaic path is exercised by bench.py and measured there
-(see the kernel's module comment for numbers).
+The chip's compiler sees the kernel in tests/test_aot_tpu_compile.py; no
+cell of BENCHMARK.json resolves to it, so it has no reading on this code.
 """
 
 import numpy as np

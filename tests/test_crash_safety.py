@@ -9,8 +9,8 @@ and resume must fall back to the previous good one.
 
 The subprocess matrix mirrors the reference's preemption model (SIGKILL via
 ``os._exit`` — no atexit, no finally, buffers lost; SURVEY.md §5 pass-
-granularity restart). One point runs as a fast tier-1 smoke (the
-``bench.py --dryrun`` pattern); the full matrix is ``slow``.
+granularity restart). One point runs as a fast tier-1 smoke; the full
+matrix is ``slow``.
 """
 
 import os
@@ -159,7 +159,7 @@ def _kill_resume_roundtrip(point, tmp_path, golden):
 
 def test_kill_resume_smoke(tmp_path, golden):
     """Tier-1 fast path: one kill point end-to-end (the delta-file/manifest
-    commit window), mirroring the bench --dryrun smoke pattern."""
+    commit window)."""
     _kill_resume_roundtrip("store.save_delta.pre_manifest", tmp_path, golden)
 
 

@@ -144,29 +144,6 @@ RULE_FIXTURES: dict = {
             make_flight(2, stats={"exchange.tokens": 1000,
                                   "exchange.unique_lanes": 780})]),
     ),
-    "push-floor": (
-        dict(detail={"push_engine": "binned_kernel",
-                     "push_floor": {
-                         "engine": "binned_kernel",
-                         "floor_seconds": 0.001,
-                         "measured_push_seconds": 0.02,
-                         "closed": "measured 20.00ms > 3x floor 1.00ms",
-                         "engines": {
-                             "binned_kernel": {"floor_seconds": 0.001,
-                                               "closed": "measured ..."},
-                             "scatter_accumulate": {
-                                 "floor_seconds": 0.0004,
-                                 "closed": "measured ...",
-                                 "note": "requires premerged unique "
-                                         "lanes"}},
-                         "best_engine": "scatter_accumulate"}}),
-        dict(detail={"push_engine": "binned_kernel",
-                     "push_floor": {
-                         "engine": "binned_kernel",
-                         "floor_seconds": 0.001,
-                         "measured_push_seconds": 0.002,
-                         "closed": True}}),
-    ),
     "nan-guard": (
         dict(flights=[make_flight(1, stats={"trainer.nan_trips": 1})],
              evidence={"nan_guard": [{
@@ -319,32 +296,18 @@ def test_quarantined_rule_downgrades_to_info_and_is_surfaced():
     assert "quarantined_rules" not in rep2
 
 
-def test_push_floor_suggestion_names_concrete_engine():
-    """ISSUE 13: the push-floor finding consumes the per-point engine
-    record + the per-candidate-engine closure statements and names the
-    CONCRETE flags.push_engine to force — never a bare 'A/B the knobs'."""
-    rep = doctor.diagnose(**RULE_FIXTURES["push-floor"][0])
-    f = next(f for f in rep["findings"] if f["rule"] == "push-floor")
-    assert "flags.push_engine='scatter_accumulate'" in f["suggestion"]
-    assert "premerged" in f["suggestion"]       # the note rides along
-    assert f["evidence"]["engine"] == "binned_kernel"
-    assert f["evidence"]["engine_floors"]["scatter_accumulate"] == 0.0004
-    # the resolver already on the best engine: no force to suggest —
-    # the suggestion pivots to the companion knobs instead
-    fire = dict(detail={"push_engine": "scatter_accumulate",
-                        "push_floor": {
-                            "engine": "scatter_accumulate",
-                            "floor_seconds": 0.001,
-                            "measured_push_seconds": 0.02,
-                            "closed": "measured 20.00ms > 3x floor "
-                                      "1.00ms",
-                            "engines": {"scatter_accumulate":
-                                        {"floor_seconds": 0.001,
-                                         "closed": "measured ..."}},
-                            "best_engine": "scatter_accumulate"}})
-    rep2 = doctor.diagnose(**fire)
-    f2 = next(f for f in rep2["findings"] if f["rule"] == "push-floor")
-    assert "lowest-floor engine" in f2["suggestion"]
+@pytest.mark.parametrize("window,severity", [
+    (dict(promote_holds=1), "warn"),            # a full fleet that held
+    (dict(requests=90, sheds=10), "warn"),      # shed rate over 1 %
+    (dict(healthy=0), "critical"),              # nobody left to route to
+])
+def test_fleet_degraded_fires_on_each_kind_of_evidence(window, severity):
+    rep = doctor.diagnose(fleets=[make_fleet_window(100.0, **window)])
+    assert doctor.validate_report(rep) == []
+    f = next(f for f in rep["findings"] if f["rule"] == "fleet-degraded")
+    assert f["severity"] == severity
+    for key, value in window.items():
+        assert f["evidence"][key] == value
 
 
 def test_doctor_report_verdict_and_severity_order():

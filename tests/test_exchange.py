@@ -833,6 +833,25 @@ def test_wire_controller_flips_within_hysteresis_no_flap():
     assert ctl.switches == 1 and ctl.wire == "bf16"
 
 
+def test_wire_controller_on_a_drifting_stream_beats_every_pinned_wire():
+    """A busy duplication-heavy head (depth 32, 6x the traffic) then a
+    unique-heavy tail: the two passes the hysteresis spends on the old
+    wire must cost less than any pinned wire loses over the stream."""
+    c = _cfg()
+    ctl = exchange.WireController(c, "f32", hysteresis=2)
+    stream = [(6 * 3200, 6 * 100)] * 4 + [(100, 100)] * 5
+    adaptive, pinned, path = 0.0, dict.fromkeys(exchange.WIRES, 0.0), []
+    for tokens, unique in stream:
+        path.append(ctl.wire)
+        adaptive += exchange.wire_cost(c, tokens, unique, ctl.wire)
+        for w in pinned:
+            pinned[w] += exchange.wire_cost(c, tokens, unique, w)
+        ctl.observe(tokens, unique)
+    assert path == ["f32"] * 6 + ["bf16"] * 3 and ctl.switches == 1
+    assert all(adaptive <= cost for cost in pinned.values()), (adaptive,
+                                                               pinned)
+
+
 def test_wire_controller_holds_on_overflow_flow_and_silence():
     c = _cfg()
     ctl = exchange.WireController(c, "f32", hysteresis=1)

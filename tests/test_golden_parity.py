@@ -36,7 +36,7 @@ def _run_pair(storage, mode="allreduce", n_dev=1, golden_lr_mult=1.0,
     dense-sync mode / shard count AND through the NumPy twin; return the
     loss trajectories + final states.
 
-    - allreduce: the bench headline config (flat dense transport).
+    - allreduce: the default config (flat dense transport).
     - kstep: per-step local dense updates, _sync_fn every `sync_step`
       steps plus at the end (trainer Finalize) — on one device the sync
       is a numeric identity, so the golden adam trajectory must be

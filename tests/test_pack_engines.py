@@ -5,8 +5,8 @@ sweep is sharply non-monotone in source width — so the pack dispatches
 per payload width class (narrow <14 / gather_zone 14..63 / wide >=64).
 The contract: all three engines produce the IDENTICAL packed operand
 (only the gather's source width differs), the auto selection follows the
-sweep's zone boundaries, and the choice is recordable per bench point
-(pack_engine()) — the discipline whose absence let the round-5 _bp_pack
+sweep's zone boundaries, and the choice can be read off pack_engine()
+— the discipline whose absence let the round-5 _bp_pack
 rewrite halve headline throughput unnoticed.
 """
 
@@ -109,7 +109,7 @@ def test_auto_selection_per_width():
     # dim 64 -> G == 1 -> scatter engine keeps the push: no pack engine
     assert pk.pack_engine(EmbeddingConfig(dim=64), rows) is None
     # ...unless the kernel is forced, where the wide pack serves it
-    set_flags(push_engine="kernel")
+    set_flags(push_engine="binned_kernel")
     assert pk.pack_engine(EmbeddingConfig(dim=64), rows) == "wide"
     set_flags(push_engine="auto")
     # override is reported verbatim where a pack exists

@@ -7,8 +7,7 @@ batch of this trainer has had)), grow-only. These tests hold: (a) the
 plan's contract at every L; (b) that the merged operands and the table do
 not depend on L to the bit; (c) growth inside a pass against a run whose
 plan keeps a lane a token, and what the counters read; (d) a pass resumed
-mid-way by a fresh trainer, whose L starts over; (e) a stacked dispatch
-whose batches differ in L.
+mid-way by a fresh trainer, whose L starts over.
 """
 
 import contextlib
@@ -31,7 +30,6 @@ from paddlebox_tpu.monitor import names
 from paddlebox_tpu.native.key_index import dedup_plan, dedup_plan_counted
 from paddlebox_tpu.parallel import make_mesh
 from paddlebox_tpu.train import Trainer, TrainerConfig
-from paddlebox_tpu.train.trainer import PLAN_ARITY, _level_plan_lanes
 from tests.test_table_planes import (_as_rows, _cfg, _host_rows, _tokens,
                                      one_array)
 
@@ -92,21 +90,21 @@ def _lanes_per_batch(distinct):
             for m in np.maximum.accumulate(distinct)]
 
 
-def _trainer(schema, seed=3, **cfg_kw):
+def _trainer(schema, seed=3):
     store = HostEmbeddingStore(_cfg("adagrad", dim=DIM))
     model = DLRMModel(num_slots=NUM_SLOTS, emb_dim=DIM, dense_dim=2,
                       bottom_hidden=(16,), top_hidden=(16, 8),
                       use_cvm=False)
     tr = Trainer(model, store, schema, make_mesh(1),
-                 TrainerConfig(global_batch_size=BATCH, **cfg_kw),
+                 TrainerConfig(global_batch_size=BATCH),
                  seed=seed)
     assert tr._use_plan
     return tr, store
 
 
-def _train(vocabs, monkeypatch=None, untrimmed=False, **cfg_kw):
+def _train(vocabs, monkeypatch=None, untrimmed=False):
     ds, schema = _dataset(vocabs)
-    tr, store = _trainer(schema, **cfg_kw)
+    tr, store = _trainer(schema)
     if untrimmed:       # the plan as it was: one lane a token
         monkeypatch.setattr(Trainer, "_plan_lane_count",
                             lambda self, n_uniq, n_tokens: n_tokens)
@@ -309,43 +307,3 @@ def test_resumed_pass_lands_on_the_uninterrupted_table(tmp_path):
                     jax.tree.leaves(jax.tree.map(np.asarray, tr2.params))):
         np.testing.assert_array_equal(a, b)
     assert tr2.global_step == tr.global_step
-
-
-# ---------------------------------------------------------------------------
-# (e) one stacked dispatch over batches of different L
-# ---------------------------------------------------------------------------
-
-def test_stacked_dispatch_levels_the_group_to_its_largest(monkeypatch):
-    flags.push_overlap = "off"          # the superstep pushes inline
-    vocabs = (2, 9, 3, 400, 2, 5, 3)    # groups (16, 40) (40, 64) ...
-    one = _train(vocabs)
-    two = _train(vocabs, steps_per_dispatch=2)
-    with monkeypatch.context() as m:
-        ref = _train(vocabs, m, untrimmed=True, steps_per_dispatch=2)
-    assert two[4]._superstep_fn is not None and one[4]._superstep_fn is None
-    assert two[0]["steps"] == one[0]["steps"] == len(vocabs)
-    _assert_same_run(two, ref)
-    np.testing.assert_allclose(two[0]["losses"], one[0]["losses"],
-                               rtol=1e-6)
-    np.testing.assert_allclose(two[1], one[1], rtol=1e-5, atol=1e-7)
-    np.testing.assert_array_equal(two[1][:, :2], one[1][:, :2])
-    assert two[3] == one[3]             # the plan is the pack thread's
-
-
-def test_level_plan_lanes_pads_under_the_contract():
-    n_rows = 64
-    rng = np.random.default_rng(8)
-    idx = [rng.integers(0, hi, 40).astype(np.int32) for hi in (6, 30, 64)]
-    Z = np.zeros(0, np.int32)
-    group = []
-    for a in idx:
-        (o, u, s, _, _), m = dedup_plan_counted(a, n_rows, n_rows, 1)
-        L = m if a is idx[0] else min(40, bucket_size(m))  # one with no pad
-        group.append((a, Z, Z, Z, o, Z, Z, u[:L], s[:L], Z))
-    lanes = max(len(g[4 + PLAN_ARITY - 2]) for g in group)
-    for a, ht in zip(idx, _level_plan_lanes(group, n_rows)):
-        full = dedup_plan(a, n_rows, n_rows, 1)
-        assert len(ht) == 10 and ht[0] is a and ht[4] is not None
-        np.testing.assert_array_equal(ht[7], full[1][:lanes])
-        np.testing.assert_array_equal(ht[8], full[2][:lanes])
-        assert ht[7].dtype == ht[8].dtype == np.int32

@@ -224,8 +224,8 @@ def test_no_thread_or_slot_leaks():
 
 
 def test_auto_selection_rules():
-    """auto = on for allreduce single-step; off for kstep/async and the
-    superstep; 'on' raises where the pipeline cannot hold its bound."""
+    """auto = on for allreduce; off for kstep/async; 'on' raises where
+    the pipeline cannot hold its bound."""
     ds, schema = _dataset(2 * BATCH)
     mesh = make_mesh(8)
 
@@ -240,11 +240,8 @@ def test_auto_selection_rules():
     assert make().push_overlap
     assert not make(dense_sync_mode="kstep").push_overlap
     assert not make(dense_sync_mode="async").push_overlap
-    assert not make(steps_per_dispatch=4).push_overlap
     set_flags(push_overlap="on")
     with pytest.raises(ValueError, match="push_overlap"):
         make(dense_sync_mode="kstep")
-    with pytest.raises(ValueError, match="push_overlap"):
-        make(steps_per_dispatch=4)
     set_flags(push_overlap="off")
     assert not make().push_overlap

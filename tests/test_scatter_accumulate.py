@@ -8,11 +8,10 @@ exact bits, so parity is asserted bit-for-bit under EXACT arithmetic
 (lattice grads + a power-of-two SGD step), pinning lane routing, the
 premerge, pad skipping, and the in-kernel update exactly; an adagrad
 companion bounds the compile-fusion ulp variance at allclose. Covers the
-engine resolver (auto classes, forced values + legacy aliases, quantized
+engine resolver (auto classes, forced values, quantized
 tables filtered), the pad-clobber regression the predicated write-back
 exists for, empty/all-pad batches, the 2-shard routed apply (premerged
-lanes routed then cross-device-merged), and the per-engine floor
-statements in step_probe.push_floor_analysis.
+lanes routed then cross-device-merged).
 """
 
 import numpy as np
@@ -230,26 +229,24 @@ def test_geometry_bounds():
 # engine resolver (THE selection function — compiled dispatch == record)
 # ---------------------------------------------------------------------------
 
-def test_resolver_forced_and_aliases(engine_flag):
+def test_resolver_forced(engine_flag):
     c = _cfg()
-    for spelling in ("scatter_accumulate", "fused"):
-        flags.push_engine = spelling
-        assert pk.resolve_push_engine(c, 64, premerged=True) == \
-            "scatter_accumulate"
-        # the fused engine REQUIRES premerged unique lanes — forced
-        # without them falls back to the scatter, recorded truthfully
-        assert pk.resolve_push_engine(c, 64, premerged=False) == \
-            "xla_scatter"
-        # quantized tables filtered (the fused engine updates f32 rows)
-        assert pk.resolve_push_engine(c, 64, premerged=True,
-                                      storage_f32=False) == "xla_scatter"
-        # width past the per-row-DMA cap filtered
-        assert pk.resolve_push_engine(c, 64, premerged=True,
-                                      table_width=1024) == "xla_scatter"
-    for spelling in ("scatter", "xla_scatter"):
-        flags.push_engine = spelling
-        assert pk.resolve_push_engine(c, 64, premerged=True) == \
-            "xla_scatter"
+    flags.push_engine = "scatter_accumulate"
+    assert pk.resolve_push_engine(c, 64, premerged=True) == \
+        "scatter_accumulate"
+    # the fused engine REQUIRES premerged unique lanes — forced
+    # without them falls back to the scatter, reported truthfully
+    assert pk.resolve_push_engine(c, 64, premerged=False) == \
+        "xla_scatter"
+    # quantized tables filtered (the fused engine updates f32 rows)
+    assert pk.resolve_push_engine(c, 64, premerged=True,
+                                  storage_f32=False) == "xla_scatter"
+    # width past the per-row-DMA cap filtered
+    assert pk.resolve_push_engine(c, 64, premerged=True,
+                                  table_width=1024) == "xla_scatter"
+    flags.push_engine = "xla_scatter"
+    assert pk.resolve_push_engine(c, 64, premerged=True) == \
+        "xla_scatter"
     flags.push_engine = "nope"
     with pytest.raises(ValueError, match="push_engine"):
         pk.resolve_push_engine(c, 64, premerged=True)
@@ -457,7 +454,7 @@ def test_trainer_forced_fused_matches_auto(engine_flag):
 
 
 def test_trainer_records_push_engine(engine_flag):
-    """The trainer's resolver helper (the bench/flight record source)
+    """The trainer's resolver helper (the flight record's source)
     names the engine the compiled dispatch contains."""
     tr, ds, store = _trainer_fixture()
     keys = ds.unique_keys()
@@ -472,53 +469,11 @@ def test_trainer_records_push_engine(engine_flag):
     assert tr2.resolved_push_engine(ws2) == "scatter_accumulate"
 
 
-# ---------------------------------------------------------------------------
-# per-engine floor statements (step_probe.push_floor_analysis)
-# ---------------------------------------------------------------------------
-
-def test_push_floor_per_engine_statements(engine_flag):
-    from paddlebox_tpu.utils.step_probe import (finalize_push_floor,
-                                                push_floor_analysis)
-    c = _cfg(dim=8, optimizer="adagrad", learning_rate=0.05)
-    peaks = (1.97e14, 8.2e11)                # v5e-style peak table
-    fl = push_floor_analysis(c, 1 << 16, 213_000, peaks=peaks,
-                             premerged=True, unique_lanes=80_000)
-    # every candidate engine at this geometry carries a floor + closure
-    assert set(fl["engines"]) == set(pk.PUSH_ENGINES)
-    assert fl["engine"] in pk.PUSH_ENGINES
-    for e in fl["engines"].values():
-        assert "closed" in e and e["floor_seconds"] > 0
-    # the fused engine's floor scales with unique lanes, not the table —
-    # at this geometry it must undercut the O(table) engines
-    sa = fl["engines"]["scatter_accumulate"]["floor_seconds"]
-    assert sa < fl["engines"]["xla_scatter"]["floor_seconds"]
-    assert fl["best_engine"] == "scatter_accumulate"
-    # measured far off the floor: the active closure names the gap and
-    # every engine statement closes independently
-    finalize_push_floor(fl, measured_push=1.0)
-    assert isinstance(fl["closed"], str) and fl["closed"].startswith(
-        "measured")
-    assert all(isinstance(e["closed"], str)
-               for e in fl["engines"].values())
-    finalize_push_floor(fl, measured_push=sa * 2)
-    assert fl["engines"]["scatter_accumulate"]["closed"] is True
-
-
-def test_push_floor_unpremerged_names_the_premerge_requirement():
-    from paddlebox_tpu.utils.step_probe import push_floor_analysis
-    c = _cfg(dim=8, optimizer="adagrad", learning_rate=0.05)
-    fl = push_floor_analysis(c, 1 << 16, 213_000, peaks=(1.97e14, 8.2e11),
-                             premerged=False)
-    assert "premerged" in fl["engines"]["scatter_accumulate"]["note"]
-
-
 def test_binned_enable_knob_never_silently_voids_a_force(engine_flag):
     """flags.binned_push=False is an ablation knob, not a second silent
     gate on an explicit force: the forced binned_kernel resolution must
     not depend on it (geometry + backend are the contract — on CPU both
-    settings fall back identically), and the floor's candidate entry
-    names the knob so a doctor suggestion is actionable."""
-    from paddlebox_tpu.utils.step_probe import push_floor_analysis
+    settings fall back identically)."""
     c = _cfg(dim=8, optimizer="adagrad", learning_rate=0.05)
     flags.push_engine = "binned_kernel"
     old = flags.binned_push
@@ -528,9 +483,5 @@ def test_binned_enable_knob_never_silently_voids_a_force(engine_flag):
         flags.binned_push = False
         without = pk.resolve_push_engine(c, 1 << 16, premerged=False)
         assert with_knob == without
-        flags.push_engine = "auto"
-        fl = push_floor_analysis(c, 1 << 16, 213_000,
-                                 peaks=(1.97e14, 8.2e11))
-        assert "binned_push" in fl["engines"]["binned_kernel"]["note"]
     finally:
         flags.binned_push = old
