@@ -28,6 +28,7 @@ from paddlebox_tpu.data.slot_record import PackedBatch, SlotRecordBatch, batch_i
 from paddlebox_tpu.data.shuffle import LocalShuffler, RoutingMode, TcpShuffleService, route_records
 from paddlebox_tpu.monitor import counter_add as stat_add
 from paddlebox_tpu.monitor import span as mon_span
+from paddlebox_tpu.native.key_index import merge_sorted_runs
 
 
 class SlotDataset:
@@ -48,13 +49,17 @@ class SlotDataset:
         self._shuffler = LocalShuffler(seed)
         self._service = shuffle_service
         self._lock = threading.Lock()
+        # the records' key set beside the version of the records it was
+        # built for (unique_keys): (version, ascending distinct int64)
+        self._key_set: tuple[int, np.ndarray] | None = None
         # per-device slices set by prepare_train
         self._shards: list[SlotRecordBatch] = []
 
     # every rebind of the record batch bumps a version counter so pass-
     # level caches keyed on dataset content (Trainer._preplan_capacity's
     # capacity memo) invalidate when records are swapped behind an
-    # unchanged num_examples (ADVICE r4; auc_runner ablation rebinds)
+    # unchanged num_examples (ADVICE r4; auc_runner ablation rebinds); the
+    # key set kept by unique_keys is one of them
     @property
     def records(self) -> SlotRecordBatch | None:
         return self._records
@@ -85,9 +90,20 @@ class SlotDataset:
     def load_into_memory(self, global_shuffle: bool = True,
                          routing: RoutingMode = "random") -> None:
         n_threads = min(flags.dataset_load_thread_num, max(1, len(self.filelist)))
+        unroll = getattr(self.parser_plugin, "unroll", None)
+        # the files' key runs are the records' only while the records are
+        # the files' rows: a local shuffle permutes them, an exchange with
+        # other ranks or an unroll makes other records (no runs taken, and
+        # unique_keys builds its own from what is bound)
+        keyed = unroll is None and not (
+            global_shuffle and self._service is not None)
         with concurrent.futures.ThreadPoolExecutor(n_threads) as pool:
-            parts = list(pool.map(self._read_one, self.filelist))
-        parts = [p for p in parts if p.num > 0]
+            loaded = list(pool.map(
+                lambda path: self._read_one(path, keyed), self.filelist))
+            keys = self._merge_key_runs([run for _, run in loaded], pool) \
+                if keyed else None
+        parts = [p for p, _ in loaded if p.num > 0]
+        del loaded
         batch = (SlotRecordBatch.concat(parts) if parts
                  else SlotRecordBatch.empty(self.schema))
         if global_shuffle and batch.num > 0:
@@ -97,7 +113,6 @@ class SlotDataset:
         # carry an `unroll(SlotRecordBatch) -> SlotRecordBatch` attribute
         # (e.g. expanding PV-merged page views back into instances) applied
         # once after load/shuffle.
-        unroll = getattr(self.parser_plugin, "unroll", None)
         if unroll is not None and batch.num > 0:
             batch = unroll(batch)
         # STAT_ADD counters, like data_feed's feasign stats (monitor.h:129)
@@ -106,6 +121,8 @@ class SlotDataset:
                  float(sum(len(v) for v in batch.sparse_values)))
         with self._lock:
             self.records = batch
+            if keys is not None:
+                self._key_set = (self._records_version, keys)
 
     def preload_into_memory(self, **kw) -> None:
         """Overlap next pass ingest with training (PreLoadIntoMemory,
@@ -119,10 +136,27 @@ class SlotDataset:
             self._preload.result()
             self._preload = None
 
-    def _read_one(self, path: str) -> SlotRecordBatch:
-        return read_file(path, self.schema, pipe_command=self.pipe_command,
+    def _read_one(self, path: str, keyed: bool
+                  ) -> tuple[SlotRecordBatch, np.ndarray | None]:
+        """One file's records and, if `keyed`, its key run (its ascending
+        distinct keys: numpy's sort holds no GIL) — both on the loader
+        thread that read it."""
+        part = read_file(path, self.schema, pipe_command=self.pipe_command,
                          parser_plugin=self.parser_plugin,
                          with_ins_id=self.with_ins_id)
+        return part, part.unique_keys() if keyed else None
+
+    @staticmethod
+    def _merge_key_runs(runs: Sequence[np.ndarray],
+                        pool: concurrent.futures.Executor) -> np.ndarray:
+        """The key set of the records the sorted `runs` cover: one merge
+        (native/key_index.py: a pairwise tree, a round's pairs side by side
+        on the pool that made the runs), whatever they were taken from."""
+        with mon_span("ingest/key_merge"):
+            keys = merge_sorted_runs(runs, pool.map)
+        stat_add("dataset.key_runs", len(runs))
+        keys.flags.writeable = False    # one array answers every call
+        return keys
 
     def _global_shuffle(self, batch: SlotRecordBatch,
                         routing: RoutingMode) -> SlotRecordBatch:
@@ -142,7 +176,10 @@ class SlotDataset:
 
     def local_shuffle(self) -> None:
         if self.records is not None and self.records.num:
+            held = self._held_key_set()
             self.records = self._shuffler.shuffle(self.records)
+            if held is not None:    # the same rows in another order
+                self._key_set = (self._records_version, held)
 
     # ---- crash-recovery shuffle cursor (distributed/resilience.py) ----
 
@@ -280,7 +317,27 @@ class SlotDataset:
         """The pass's feature-sign working set (MergeInsKeys → PSAgent,
         data_set.cc:1786)."""
         assert self.records is not None
-        return self.records.unique_keys()
+        keys = self._held_key_set()
+        if keys is not None:
+            stat_add("dataset.key_set_reused")
+            return keys
+        # the records are not what a load left (rebound, or changed in
+        # place): the same routine, one run a sparse column
+        stat_add("dataset.key_set_rebuilt")
+        version, columns = self._records_version, self.records.sparse_values
+        n_threads = min(flags.dataset_load_thread_num, max(1, len(columns)))
+        with concurrent.futures.ThreadPoolExecutor(n_threads) as pool:
+            runs = list(pool.map(np.unique, columns))
+            keys = self._merge_key_runs(runs, pool)
+        self._key_set = (version, keys)
+        return keys
+
+    def _held_key_set(self) -> np.ndarray | None:
+        """The key set, if it was built for the records as they are."""
+        held = self._key_set
+        if held is not None and held[0] == self._records_version:
+            return held[1]
+        return None
 
     def prepare_train(self, num_shards: int) -> None:
         """Slice records round-robin into per-device shards
@@ -309,4 +366,5 @@ class SlotDataset:
 
     def release_memory(self) -> None:
         self.records = None
+        self._key_set = None
         self._shards = []

@@ -156,6 +156,7 @@ SPAN_NAMES: tuple[str, ...] = (
     # around the pass: ingest (the caller's or the preload thread) and
     # the BoxPS lifecycle calls
     "ingest",
+    "ingest/key_merge",
     "box_begin_pass",
     "box_end_pass",
     "publish",
@@ -189,6 +190,17 @@ PLAN_COUNTER_NAMES: tuple[str, ...] = (
     "trainer.plan_unique_tokens",
     "trainer.plan_lanes",
     "trainer.plan_lane_grows",
+)
+
+# the pass's key set (SlotDataset: sorted runs, one merge): runs merged
+# (counted by the load, before the pass opens: read from monitor.STATS),
+# and the calls of unique_keys() answered by the set the load left or by a
+# rebuild from the records as they are (counted inside train_pass: a
+# pass's sums reach the flight record as ``stats_delta``)
+KEY_SET_COUNTER_NAMES: tuple[str, ...] = (
+    "dataset.key_runs",
+    "dataset.key_set_reused",
+    "dataset.key_set_rebuilt",
 )
 
 ALL_NAMES: frozenset = frozenset(EVENT_NAMES) | frozenset(SPAN_NAMES)

@@ -307,4 +307,37 @@ int64_t pbtpu_dedup_plan(const int32_t* idx, int64_t n, int64_t n_rows,
   return u;
 }
 
+// ---------------------------------------------------------------------
+// Key-set merge: two ascending, duplicate-free int64 runs -> their
+// ascending, duplicate-free union (signed order, as np.unique gives
+// int64). The step of the pairwise tree that makes a pass's key set out
+// of one run a file (key_index.py merge_sorted_runs; MergeInsKeys,
+// reference data_set.cc:1786): a key both runs hold advances both, so
+// every round drops what its pairs share, and the loop's body has no
+// data-dependent branch.
+//   a, b : strictly ascending over na, nb
+//   out  : (na + nb,) the union lands in out[0:n]
+// Returns n.
+int64_t pbtpu_merge2(const int64_t* a, int64_t na, const int64_t* b,
+                     int64_t nb, int64_t* out) {
+  int64_t i = 0, j = 0, n = 0;
+  while (i < na && j < nb) {
+    const int64_t x = a[i], y = b[j];
+    out[n++] = x < y ? x : y;
+    i += (x <= y);
+    j += (y <= x);
+  }
+  if (i < na) {
+    std::memcpy(out + n, a + i,
+                static_cast<size_t>(na - i) * sizeof(int64_t));
+    n += na - i;
+  }
+  if (j < nb) {
+    std::memcpy(out + n, b + j,
+                static_cast<size_t>(nb - j) * sizeof(int64_t));
+    n += nb - j;
+  }
+  return n;
+}
+
 }  // extern "C"

@@ -10,6 +10,7 @@ backend loads.
 from __future__ import annotations
 
 import ctypes
+from typing import Callable, Sequence
 
 import numpy as np
 
@@ -40,6 +41,8 @@ def _configure(lib: ctypes.CDLL) -> None:
     lib.pbtpu_dedup_plan.argtypes = [i32p, c.c_int64, c.c_int64, c.c_int32,
                                      c.c_int64, i32p, i32p, i32p, i32p,
                                      i32p]
+    lib.pbtpu_merge2.restype = c.c_int64
+    lib.pbtpu_merge2.argtypes = [i64p, c.c_int64, i64p, c.c_int64, i64p]
 
 
 def get_lib() -> ctypes.CDLL | None:
@@ -130,6 +133,37 @@ def dedup_plan(idx: np.ndarray, n_rows: int, super_block: int,
     (`uniq` and `segend` n lanes: one a token, the most a batch can
     need)."""
     return dedup_plan_counted(idx, n_rows, super_block, n_blocks)[0]
+
+
+def merge_sorted_runs(runs: Sequence[np.ndarray],
+                      pmap: Callable = map) -> np.ndarray:
+    """The ascending, duplicate-free union of ascending, duplicate-free
+    int64 runs (signed order) — ``np.unique(np.concatenate(runs))`` to the
+    element, by linear merges where that would sort again: a pairwise
+    tree, each pair one native call that holds no GIL (key_index.cc
+    pbtpu_merge2), a round's pairs through `pmap` (a thread pool's ``map``
+    merges them side by side). One run alone comes back as it is. Without
+    the native library numpy's sort answers."""
+    level = [np.ascontiguousarray(r, dtype=np.int64) for r in runs if len(r)]
+    if not level:
+        return np.zeros(0, dtype=np.int64)
+    lib = get_lib()
+    if lib is None:
+        return np.unique(np.concatenate(level))
+
+    def merged(pair: list[np.ndarray]) -> np.ndarray:
+        if len(pair) == 1:          # the odd run of a round moves up
+            return pair[0]
+        a, b = pair
+        out = np.empty(len(a) + len(b), np.int64)
+        n = lib.pbtpu_merge2(a, len(a), b, len(b), out)
+        out.resize(n, refcheck=False)   # shrinks in place: no view is kept
+        return out
+
+    while len(level) > 1:
+        level = list(pmap(merged, [level[i:i + 2]
+                                   for i in range(0, len(level), 2)]))
+    return level[0]
 
 
 def native_available() -> bool:

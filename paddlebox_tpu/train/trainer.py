@@ -1501,7 +1501,6 @@ class Trainer:
         with self.timers("unique_keys", span="unique_keys"):
             keys = dataset.unique_keys()
         ws = self.feed_mgr.begin_pass(keys)
-        del keys        # megabytes at a real pass's size: not held through it
         self.feed_mgr.pass_opened()
         self._overlap_ws = ws if self.push_overlap else None
         if preload_keys is not None:
@@ -2392,7 +2391,9 @@ class Trainer:
 
     def _eval_pass_once(self, dataset) -> dict[str, float]:
         bs = self.cfg.global_batch_size
-        ws = self.feed_mgr.begin_pass(dataset.unique_keys(), test_mode=True)
+        with self.timers("unique_keys", span="unique_keys"):
+            keys = dataset.unique_keys()
+        ws = self.feed_mgr.begin_pass(keys, test_mode=True)
         self._preplan_capacity(dataset, ws, drop_last=False,
                                for_eval=True)
         auc_acc = auc_lib.AucAccumulator(self.cfg.auc_buckets)

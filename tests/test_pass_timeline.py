@@ -12,6 +12,7 @@ import json
 import time
 import warnings
 
+import numpy as np
 import pytest
 
 from paddlebox_tpu import monitor
@@ -389,3 +390,47 @@ def test_failing_start_trace_warns_once_counts_and_training_goes_on(
     assert STATS.get("trace.device_capture_errors") - errors0 == 2
     assert all(o["steps"] == 2 for o in outs)
     assert trace_lib._device_dir is None
+
+
+def test_eval_pass_takes_its_keys_under_the_train_pass_stage(tmp_path):
+    """ISSUE 31: an eval pass reads its key set under the same
+    ``unique_keys`` stage and span a train pass does, and from the same
+    set (the records have not changed between the two)."""
+    tr, ds = _tiny_trainer(tmp_path)
+    tr.train_pass(ds)
+    sink = monitor.MemorySink()
+    monitor.hub().enable(sink)
+    count0 = tr.timers.count["unique_keys"]
+    s0 = STATS.snapshot()
+    tr.eval_pass(ds)
+    s1 = STATS.snapshot()
+    assert tr.timers.count["unique_keys"] == count0 + 1
+    assert [r["name"] for r in sink.records
+            if r["type"] == "span"].count("unique_keys") == 1
+    assert s1.get("dataset.key_set_reused", 0) \
+        - s0.get("dataset.key_set_reused", 0) == 1
+    assert s1.get("dataset.key_set_rebuilt", 0) \
+        == s0.get("dataset.key_set_rebuilt", 0)
+
+
+def test_flight_record_says_the_loads_key_set_answered(tmp_path):
+    """``dataset.key_runs`` is counted by the load, before the pass opens
+    (monitor.STATS); the pass's own ``stats_delta`` holds one reuse and no
+    rebuild, and a rebuild once the records were rebound."""
+    from paddlebox_tpu.fleet import BoxPS
+    runs0 = STATS.get("dataset.key_runs")
+    tr, ds = _tiny_trainer(tmp_path)
+    assert STATS.get("dataset.key_runs") - runs0 == 1     # one file
+    box = BoxPS(tr.store)
+    deltas = []
+    for _ in range(2):
+        box.begin_pass()
+        tr.train_pass(ds, metrics=box.metrics)
+        deltas.append(box.end_pass()["flight_record"]["stats_delta"])
+        ds.records = ds.records.select(np.arange(ds.records.num)[::-1])
+    assert deltas[0]["dataset.key_set_reused"] == 1
+    assert "dataset.key_set_rebuilt" not in deltas[0]
+    assert "dataset.key_runs" not in deltas[0]
+    assert deltas[1]["dataset.key_set_rebuilt"] == 1
+    assert deltas[1]["dataset.key_runs"] == 3     # one run a sparse column
+    assert "dataset.key_set_reused" not in deltas[1]
