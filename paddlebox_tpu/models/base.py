@@ -103,26 +103,36 @@ def declared_loss(model, segment_ids, num_slots):
     return default if own is None else declared
 
 
-def _is_max(name: str) -> bool:
-    return name.endswith("_max")
+def _extreme(name: str):
+    """A gauge's reduction, by its name's ending: ``*_max`` the largest,
+    ``*_min`` the smallest; None for a counter (a sum)."""
+    return {"_max": "max", "_min": "min"}.get(name[-4:])
 
 
 def reduce_stats(names: tuple, stats: jnp.ndarray, axes) -> jnp.ndarray:
-    """One step's statistics over the mesh: ``*_max`` by max, else sums."""
+    """One step's statistics over the mesh: ``*_max`` by max, ``*_min``
+    by min, else sums."""
     from jax import lax
-    is_max = np.asarray([_is_max(n) for n in names])
-    return jnp.where(is_max, lax.pmax(stats, axes), lax.psum(stats, axes))
+    kinds = [_extreme(n) for n in names]
+    out = lax.psum(stats, axes)
+    for kind, reduce in (("max", lax.pmax), ("min", lax.pmin)):
+        if kind in kinds:
+            out = jnp.where(np.asarray([k == kind for k in kinds]),
+                            reduce(stats, axes), out)
+    return out
 
 
 def publish_stats(model, per_step: np.ndarray) -> dict:
     """A pass's statistics (steps, n) into the stat registry: counters for
-    the sums, gauges for the ``*_max`` names. Returns {name: value}."""
+    the sums, gauges for the ``*_max`` and ``*_min`` names (the pass's
+    extreme step). Returns {name: value}."""
     from paddlebox_tpu import monitor
     out = {}
     for j, name in enumerate(stat_names(model)):
         col = per_step[:, j].astype(np.float64)
-        if _is_max(name):
-            out[name] = float(col.max())
+        extreme = _extreme(name)
+        if extreme:
+            out[name] = float(getattr(col, extreme)())
             monitor.gauge_set(name, out[name])
         else:
             out[name] = float(col.sum())

@@ -13,6 +13,7 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def dense_init(key, in_dim: int, out_dim: int, scale: str = "glorot"):
@@ -49,3 +50,52 @@ def mlp_apply(layers, x: jnp.ndarray, final_activation: str | None = None,
         act = final_activation if last else "relu"
         x = dense_apply(p, x, activation=act, compute_dtype=compute_dtype)
     return x
+
+
+# -- what the ordered-token towers share ------------------------------------
+
+def rms_norm(x, weight, eps: float):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) \
+        * weight
+
+
+def next_token_loss(params, h, local_ids, mask, eps: float, head_chunk: int):
+    """Mean over positions t < T - 1 of the cross entropy of position
+    t's logits against the id at t + 1, one value an example; the
+    logits — ``RMSNorm(h, params["norm_f"]) @ params["head"]`` over the
+    vocabulary slice — of ``head_chunk`` positions at a time, recomputed
+    in the backward pass."""
+    B, T, d = h.shape
+    x = rms_norm(h, params["norm_f"], eps)
+    targets = jnp.concatenate(
+        [local_ids[:, 1:], jnp.zeros((B, 1), local_ids.dtype)], axis=1)
+    counted = jnp.concatenate(
+        [mask[:, 1:] & mask[:, :-1], jnp.zeros((B, 1), bool)], axis=1)
+    chunk = min(head_chunk, T)
+    if T % chunk:
+        raise ValueError(f"seq_len {T} does not divide into head chunks "
+                         f"of {chunk}")
+
+    @jax.checkpoint
+    def nll_of(xc, tc):
+        logits = xc @ params["head"]                  # (B, c, V)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        return lse - jnp.take_along_axis(logits, tc[..., None],
+                                         axis=-1)[..., 0]
+
+    parts = lambda a: jnp.moveaxis(
+        a.reshape(B, T // chunk, chunk, *a.shape[2:]), 1, 0)
+    nll = jax.lax.map(lambda c: nll_of(*c), (parts(x), parts(targets)))
+    nll = jnp.moveaxis(nll, 0, 1).reshape(B, T)
+    w = counted.astype(nll.dtype)
+    return jnp.sum(nll * w, axis=1) / jnp.maximum(jnp.sum(w, axis=1), 1)
+
+
+def vocabulary_ids(pb, key_index_bits: int):
+    """A sequence slot's ids within its vocabulary, the targets of a
+    next-token loss: the key's low ``key_index_bits`` bits less one, the
+    format of a slot file whose key is ``(slot + 1) << bits | (id + 1)``
+    (a model's ``batch_extras`` host stage)."""
+    ids = np.asarray(pb.ids, np.int64)
+    local = (ids & ((1 << key_index_bits) - 1)) - 1
+    return np.where(pb.mask, local, 0).astype(np.int32)

@@ -39,13 +39,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddlebox_tpu.models.nn import next_token_loss, rms_norm, vocabulary_ids
 from paddlebox_tpu.ops.flash_attention import attention
 from paddlebox_tpu.parallel.expert import held_expert_ffn, route_top_k
-
-
-def rms_norm(x, weight, eps: float):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) \
-        * weight
 
 
 def rope(x, theta: float):
@@ -122,9 +118,7 @@ class SmallThinkerModel:
     # -- the host stage ----------------------------------------------------
 
     def batch_extras(self, pb, n_shards: int = 1) -> tuple[np.ndarray]:
-        ids = np.asarray(pb.ids, np.int64)
-        local = (ids & ((1 << self.key_index_bits) - 1)) - 1
-        return (np.where(pb.mask, local, 0).astype(np.int32),)
+        return (vocabulary_ids(pb, self.key_index_bits),)
 
     # -- the tower ---------------------------------------------------------
 
@@ -154,36 +148,6 @@ class SmallThinkerModel:
                                   chunk_tokens=self.expert_chunk_tokens)
         return h + y.reshape(B, T, d), load
 
-    def _head_loss(self, params, h, local_ids, mask):
-        """Mean over positions t < T - 1 of the cross entropy of position
-        t's logits against the id at t + 1, one value an example; the
-        logits of ``head_chunk`` positions at a time, recomputed in the
-        backward pass."""
-        B, T, d = h.shape
-        x = rms_norm(h, params["norm_f"], self.eps)
-        targets = jnp.concatenate(
-            [local_ids[:, 1:], jnp.zeros((B, 1), local_ids.dtype)], axis=1)
-        counted = jnp.concatenate(
-            [mask[:, 1:] & mask[:, :-1], jnp.zeros((B, 1), bool)], axis=1)
-        chunk = min(self.head_chunk, T)
-        if T % chunk:
-            raise ValueError(f"seq_len {T} does not divide into head chunks "
-                             f"of {chunk}")
-
-        @jax.checkpoint
-        def nll_of(xc, tc):
-            logits = xc @ params["head"]                  # (B, c, V)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            return lse - jnp.take_along_axis(logits, tc[..., None],
-                                             axis=-1)[..., 0]
-
-        parts = lambda a: jnp.moveaxis(
-            a.reshape(B, T // chunk, chunk, *a.shape[2:]), 1, 0)
-        nll = jax.lax.map(lambda c: nll_of(*c), (parts(x), parts(targets)))
-        nll = jnp.moveaxis(nll, 0, 1).reshape(B, T)
-        w = counted.astype(nll.dtype)
-        return jnp.sum(nll * w, axis=1) / jnp.maximum(jnp.sum(w, axis=1), 1)
-
     def example_losses(self, params, pulled, mask, local_ids):
         """(one loss an example (B,), the assignments each held expert
         received in each layer (layers, experts_held))."""
@@ -193,7 +157,8 @@ class SmallThinkerModel:
             h, load = jax.checkpoint(self._layer, static_argnums=(2,))(
                 p, h, kind)
             loads.append(load)
-        return self._head_loss(params, h, local_ids, mask), jnp.stack(loads)
+        return next_token_loss(params, h, local_ids, mask, self.eps,
+                               self.head_chunk), jnp.stack(loads)
 
     def loss(self, params, pulled, mask, dense, labels, local_ids):
         """The declared loss (models/base.py): the batch's mean, no

@@ -168,8 +168,8 @@ SPAN_NAMES: tuple[str, ...] = (
 
 # statistics a model's loss may declare (models/base.py ``stat_names``):
 # one value a step, out of the step program, into the flight record's
-# counters at the pass's close. ``*_max``: the largest step of the pass
-# (a gauge); the others are sums (counters).
+# counters at the pass's close. ``*_max`` / ``*_min``: the largest /
+# smallest step of the pass (a gauge); the others are sums (counters).
 MODEL_STAT_NAMES: tuple[str, ...] = (
     # parallel/expert.py share layer: (token, choice) assignments routed,
     # those that fell on an expert this chip holds, and a step's busiest
@@ -177,6 +177,14 @@ MODEL_STAT_NAMES: tuple[str, ...] = (
     "moe.assignments",
     "moe.held_assignments",
     "moe.expert_load_max",
+    # ops/ssm_scan.py through a state-space mixer: tokens scanned (tokens x
+    # mixer blocks), chunks scanned, and the most negative cumulative
+    # ``Delta A`` within a chunk — how near exp of a chunk's decay is to
+    # float32's underflow (about -87; below it the chunk's first tokens no
+    # longer reach its end state)
+    "ssm.tokens",
+    "ssm.chunks",
+    "ssm.decay_log_min",
 )
 
 # the host plan's counters (Trainer._host_plan, a batch at a time on the

@@ -15,7 +15,7 @@ Tokens beyond a lane's capacity are dropped (standard MoE capacity-factor
 semantics; monitor with `dropped_tokens`). Numerics match `moe_reference`
 for all surviving tokens.
 
-The share layer (``route_top_k`` + ``held_expert_ffn``) is the other shape
+The share layer (a routing rule + ``held_expert_ffn``) is the other shape
 of expert parallelism: a chip is told which experts of a layer it holds
 (``held = (first, count)`` of ``n_experts``), every token is routed over
 ALL experts, and the chip computes the part of the layer's output that its
@@ -23,7 +23,12 @@ held experts contribute — grouped matrix products over the (token, choice)
 assignments sorted by held expert, no capacity and no drop at any
 imbalance. What the absent experts would add is another chip's part and is
 not computed, approximated or stood in for; on a mesh of one chip the layer
-runs without an exchange.
+runs without an exchange. Two routing rules (``route_top_k``: a softmax
+over the chosen logits; ``route_sigmoid_top_k``: sigmoid scores chosen
+with a correction bias, renormalised and scaled) and two expert bodies
+(gated ReGLU; non-gated relu squared) share the one layer. An expert that
+every chip computes alike (a shared expert) is the model's to add: the sum
+of the shares counts it once.
 """
 
 from __future__ import annotations
@@ -204,6 +209,21 @@ def route_top_k(router_logits: jnp.ndarray, top_k: int
     return jax.nn.softmax(vals, axis=-1), experts
 
 
+def route_sigmoid_top_k(router_logits: jnp.ndarray, bias: jnp.ndarray,
+                        top_k: int, scale: float
+                        ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(weights, experts), both (N, top_k): scores ``s = sigmoid(logits)``
+    over ALL experts; the top_k largest of ``s + bias`` are chosen (the
+    correction bias moves the choice and nothing else); their weights are
+    ``s`` renormalised over the chosen, held here or not, times ``scale``.
+    No gradient reaches ``bias``."""
+    scores = jax.nn.sigmoid(router_logits)
+    _, experts = lax.top_k(scores + bias, top_k)
+    picked = jnp.take_along_axis(scores, experts, axis=-1)
+    return picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) \
+        * scale, experts
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _tokens_by_expert(x, order, inv, k: int):
     """x[order // k]: every (token, choice) assignment's token row, in the
@@ -240,8 +260,9 @@ _permute_rows.defvjp(
 
 def _held_chunk(x, probs, experts, w_gate, w_up, w_down, first: int,
                 count: int):
-    """One chunk of tokens through the held experts (ReGLU). Returns the
-    chunk's output (n, D) and its assignments per held expert (count,)."""
+    """One chunk of tokens through the held experts (ReGLU, or relu
+    squared where ``w_gate`` is None). Returns the chunk's output (n, D)
+    and its assignments per held expert (count,)."""
     n, k = experts.shape
     local = experts - first
     held = (local >= 0) & (local < count)
@@ -257,10 +278,13 @@ def _held_chunk(x, probs, experts, w_gate, w_up, w_down, first: int,
     # through them in either direction
     in_group = (jnp.arange(n * k) < jnp.sum(sizes))[:, None]
     xs = jnp.where(in_group, _tokens_by_expert(
-        x.astype(w_gate.dtype), order, inv, k), 0)
+        x.astype(w_up.dtype), order, inv, k), 0)
     dot = functools.partial(lax.ragged_dot, group_sizes=sizes,
                             preferred_element_type=jnp.float32)
-    hidden = jax.nn.relu(dot(xs, w_gate)) * dot(xs, w_up)
+    if w_gate is None:
+        hidden = jnp.square(jax.nn.relu(dot(xs, w_up)))
+    else:
+        hidden = jax.nn.relu(dot(xs, w_gate)) * dot(xs, w_up)
     ys = dot(hidden.astype(w_down.dtype), w_down).astype(x.dtype)
     # back to (token, choice) order
     y = _permute_rows(jnp.where(in_group, ys, 0), inv, order)
@@ -270,15 +294,19 @@ def _held_chunk(x, probs, experts, w_gate, w_up, w_down, first: int,
 
 
 def held_expert_ffn(x: jnp.ndarray, probs: jnp.ndarray,
-                    experts: jnp.ndarray, w_gate: jnp.ndarray,
+                    experts: jnp.ndarray, w_gate: jnp.ndarray | None,
                     w_up: jnp.ndarray, w_down: jnp.ndarray,
                     held: tuple[int, int], chunk_tokens: int = 4096
                     ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """sum over (token, choice) with the chosen expert held here of
-    p * (relu(x W_gate_e) * (x W_up_e)) W_down_e.
+    p * body_e(x), the expert body being the call's: gated ReGLU,
+    ``(relu(x W_gate_e) * (x W_up_e)) W_down_e``, where ``w_gate`` is
+    given; non-gated relu squared, ``relu(x W_up_e)^2 W_down_e``, where it
+    is None.
 
-    x (N, D); probs, experts (N, k) from ``route_top_k``; the weights of
-    the ``count`` held experts stacked on axis 0, (count, D, H) twice and
+    x (N, D); probs, experts (N, k) from a routing rule (``route_top_k``,
+    ``route_sigmoid_top_k``); the weights of the ``count`` held experts
+    stacked on axis 0, (count, D, H) (twice for the gated body) and
     (count, H, D); ``held = (first, count)``: this chip holds the global
     experts first .. first + count - 1. Returns the held part of the
     layer's output (N, D) and the assignments each held expert received
@@ -291,9 +319,11 @@ def held_expert_ffn(x: jnp.ndarray, probs: jnp.ndarray,
     chunk's worst case (every choice held) and not the batch's; a chunk
     is recomputed in the backward pass instead of stored."""
     first, count = int(held[0]), int(held[1])
-    if w_gate.shape[0] != count:
-        raise ValueError(f"{w_gate.shape[0]} expert weights for "
-                         f"held={held}")
+    body = "relu squared" if w_gate is None else "ReGLU"
+    for w in (w_up, w_down) if w_gate is None else (w_gate, w_up, w_down):
+        if w.shape[0] != count:
+            raise ValueError(f"{body} experts: {w.shape[0]} expert weights "
+                             f"for held={held}")
     n = x.shape[0]
     chunk = min(int(chunk_tokens), n)
     if n % chunk:
@@ -303,7 +333,7 @@ def held_expert_ffn(x: jnp.ndarray, probs: jnp.ndarray,
         # bfloat16 operands, float32 sums (the grouped product would
         # otherwise take float32 operands in several passes); the weights
         # are cast once a call, not once a chunk
-        w_gate, w_up, w_down = (w.astype(jnp.bfloat16)
+        w_gate, w_up, w_down = (w if w is None else w.astype(jnp.bfloat16)
                                 for w in (w_gate, w_up, w_down))
     one = jax.checkpoint(
         lambda xc, pc, ec: _held_chunk(xc, pc, ec, w_gate, w_up, w_down,

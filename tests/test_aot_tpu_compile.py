@@ -308,3 +308,31 @@ def test_wide_row_programs_hold_no_table_sized_temporary(topo, on_tpu):
     half = apply_half.memory_analysis().temp_size_in_bytes
     table_growth = PLANE_ROWS // 2 * 133 * 4
     assert 0 <= m.temp_size_in_bytes - half <= table_growth // 10
+
+
+# ---------------------------------------------------------------------------
+# the state-space scan at the published Mamba-2 widths (64 heads of 64 in 8
+# groups, state 128, chunks of 128), bfloat16 operands as the chip runs it
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_ssm_scan_compiles_at_the_published_widths(one_chip, on_tpu,
+                                                   direction):
+    from paddlebox_tpu.ops import ssm_scan as ss
+    B, T, H, P, G, N = 1, 512, 64, 64, 8, 128
+    assert ss.scan_geometry(128, H // G, P, N) == (2, 128)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+
+    def scan(*args):
+        return ss.ssm_scan(*args, chunk=128, interpret=False)
+
+    def grads(*args):
+        return jax.grad(lambda *a: jnp.sum(scan(*a).astype(f32)),
+                        argnums=tuple(range(6)))(*args)
+
+    text = _compiled_text(
+        scan if direction == "forward" else grads, one_chip,
+        ((B, T, H, P), bf16), ((B, T, H), f32), ((H,), f32),
+        ((B, T, G, N), bf16), ((B, T, G, N), bf16), ((H,), f32))
+    assert "tpu_custom_call" in text and "pbtpu_ssm_fwd" in text
+    assert ("pbtpu_ssm_bwd" in text) == (direction == "backward")

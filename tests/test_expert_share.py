@@ -1,7 +1,10 @@
 """The share layer of parallel/expert.py: one chip's held experts of a
 layer routed over all experts — against a dense masked computation, at
-every imbalance, in chunks, and the shares of all chips adding up to the
-uncut reference's layer."""
+every imbalance, in chunks, for both expert bodies (gated ReGLU, non-gated
+relu squared) and both routing rules (softmax over the chosen logits;
+sigmoid scores chosen with a correction bias), and the shares of all chips
+adding up to the uncut reference's layer, what every chip computes alike
+counted once."""
 
 import importlib
 
@@ -11,10 +14,29 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from paddlebox_tpu.models.nemotron_h import NemotronHModel
 from paddlebox_tpu.models.smallthinker import SmallThinkerModel
-from paddlebox_tpu.parallel.expert import held_expert_ffn, route_top_k
+from paddlebox_tpu.parallel.expert import (held_expert_ffn,
+                                           route_sigmoid_top_k, route_top_k)
 
 N, D, F, E, K = 48, 16, 8, 8, 3
+BODIES = ("reglu", "relu2")
+RULES = ("softmax", "sigmoid")
+SCALE = 2.5
+
+
+def _atol(rule, base):
+    """The repo's own limit for the softmax rule, whose weights sum to 1.
+    The sigmoid rule's weights sum to SCALE, so its outputs, and the
+    float32 rounding of their sums, are SCALE times as large."""
+    return base * (SCALE if rule == "sigmoid" else 1.0)
+
+
+def _route(logits, rule, bias=None):
+    if rule == "softmax":
+        return route_top_k(logits, K)
+    bias = jnp.zeros(logits.shape[-1:]) if bias is None else bias
+    return route_sigmoid_top_k(logits, bias, K, SCALE)
 
 
 def _weights(seed=0):
@@ -26,34 +48,63 @@ def _weights(seed=0):
             jax.random.normal(ks[4], (E, F, D)) * F ** -0.5)
 
 
-def _dense(x, probs, experts, wg, wu, wd, first, count):
+def _dense(x, probs, experts, wg, wu, wd, first, count, body="reglu"):
     """Every held expert over every token, masked by the routing."""
     y = jnp.zeros_like(x)
     for e in range(first, first + count):
         weight = jnp.sum(jnp.where(experts == e, probs, 0), axis=-1)
-        out = (jnp.maximum(x @ wg[e], 0) * (x @ wu[e])) @ wd[e]
-        y = y + weight[:, None] * out
+        if body == "reglu":
+            hidden = jnp.maximum(x @ wg[e], 0) * (x @ wu[e])
+        else:
+            hidden = jnp.maximum(x @ wu[e], 0) ** 2
+        y = y + weight[:, None] * (hidden @ wd[e])
     return y
 
 
-def _share(x, probs, experts, wg, wu, wd, first, count, **kw):
+def _share(x, probs, experts, wg, wu, wd, first, count, body="reglu", **kw):
     sl = slice(first, first + count)
-    return held_expert_ffn(x, probs, experts, wg[sl], wu[sl], wd[sl],
-                           (first, count), **kw)
+    return held_expert_ffn(x, probs, experts,
+                           wg[sl] if body == "reglu" else None, wu[sl],
+                           wd[sl], (first, count), **kw)
 
 
+@pytest.mark.parametrize("rule", RULES)
+@pytest.mark.parametrize("body", BODIES)
 @pytest.mark.parametrize("held", [(0, 2), (2, 2), (5, 3), (0, 8)])
-def test_share_equals_dense_masked(held):
+def test_share_equals_dense_masked(held, body, rule):
     x, router, wg, wu, wd = _weights()
     with jax.default_matmul_precision("highest"):
-        probs, experts = route_top_k(x @ router, K)
-        y, sizes = _share(x, probs, experts, wg, wu, wd, *held)
-        want = _dense(x, probs, experts, wg, wu, wd, *held)
-    np.testing.assert_allclose(y, want, atol=2e-5)
+        probs, experts = _route(x @ router, rule)
+        y, sizes = _share(x, probs, experts, wg, wu, wd, *held, body)
+        want = _dense(x, probs, experts, wg, wu, wd, *held, body)
+    np.testing.assert_allclose(y, want, atol=_atol(rule, 2e-5))
     counts = np.bincount(np.asarray(experts).ravel(), minlength=E)
     np.testing.assert_array_equal(sizes, counts[held[0]:held[0] + held[1]])
-    # p is normalised over all K choices, held here or not
-    np.testing.assert_allclose(np.asarray(probs).sum(-1), 1.0, atol=1e-6)
+    # the weights are normalised over all K choices, held here or not
+    np.testing.assert_allclose(np.asarray(probs).sum(-1),
+                               1.0 if rule == "softmax" else SCALE,
+                               atol=_atol(rule, 1e-6))
+
+
+def test_sigmoid_route_chooses_by_the_bias_and_weighs_without_it():
+    x, router, *_ = _weights(1)
+    logits = x @ router
+    bias = jnp.zeros((E,)).at[5].set(10.0).at[0].set(-10.0)
+    weights, experts = _route(logits, "sigmoid", bias)
+    plain_w, plain_e = _route(logits, "sigmoid")
+    chosen = np.asarray(experts)
+    assert (chosen == 5).any(axis=1).all() and not (chosen == 0).any()
+    assert (np.asarray(plain_e) == 0).any()
+    # the weights are the scores themselves, renormalised and scaled: the
+    # bias is in none of them
+    s = jax.nn.sigmoid(logits)
+    picked = jnp.take_along_axis(s, experts, axis=-1)
+    np.testing.assert_allclose(
+        weights, SCALE * picked / picked.sum(-1, keepdims=True), rtol=1e-6)
+    g = jax.grad(lambda b: jnp.sum(_route(logits, "sigmoid", b)[0] ** 2))(
+        bias)
+    assert float(jnp.abs(g).max()) == 0.0
+    assert plain_w.shape == (N, K)
 
 
 def test_route_is_softmax_over_all_renormalised():
@@ -66,8 +117,10 @@ def test_route_is_softmax_over_all_renormalised():
         probs, picked / picked.sum(-1, keepdims=True), atol=1e-6)
 
 
+@pytest.mark.parametrize("rule", RULES)
+@pytest.mark.parametrize("body", BODIES)
 @pytest.mark.parametrize("case", ["all_to_one_held", "none_held"])
-def test_no_token_dropped_at_any_imbalance(case):
+def test_no_token_dropped_at_any_imbalance(case, body, rule):
     x, _, wg, wu, wd = _weights(2)
     held = (2, 2)
     logits = np.zeros((N, E), np.float32)
@@ -76,36 +129,56 @@ def test_no_token_dropped_at_any_imbalance(case):
     else:
         logits[:, [0, 1, 7]] = [9.0, 5.0, 4.0]      # nothing held here
     with jax.default_matmul_precision("highest"):
-        probs, experts = route_top_k(jnp.asarray(logits), K)
-        y, sizes = _share(x, probs, experts, wg, wu, wd, *held)
-        want = _dense(x, probs, experts, wg, wu, wd, *held)
+        probs, experts = _route(jnp.asarray(logits), rule)
+        y, sizes = _share(x, probs, experts, wg, wu, wd, *held, body)
+        want = _dense(x, probs, experts, wg, wu, wd, *held, body)
     if case == "all_to_one_held":
         np.testing.assert_array_equal(sizes, [N, 0])
         assert float(jnp.abs(want).max()) > 0.1
     else:
         np.testing.assert_array_equal(sizes, [0, 0])
         assert float(jnp.abs(y).max()) == 0.0
-    np.testing.assert_allclose(y, want, atol=2e-5)
+    np.testing.assert_allclose(y, want, atol=_atol(rule, 2e-5))
 
 
-def test_chunks_and_gradients_match_dense():
+@pytest.mark.parametrize("rule", RULES)
+@pytest.mark.parametrize("body", BODIES)
+def test_chunks_and_gradients_match_dense(body, rule):
     x, router, wg, wu, wd = _weights(3)
     held = (1, 4)
 
     def through(fn):
         def loss(x, router, wg, wu, wd):
-            probs, experts = route_top_k(x @ router, K)
+            probs, experts = _route(x @ router, rule)
             return jnp.sum(fn(x, probs, experts, wg, wu, wd) ** 2)
         with jax.default_matmul_precision("highest"):
             return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(
                 x, router, wg, wu, wd)
 
-    want = through(lambda *a: _dense(*a, *held))
+    want = through(lambda *a: _dense(*a, *held, body))
+    # the repo's own limit for the gated body, under either rule. The
+    # squared body's gradients are 3 to 6 times as large here (500 to 900
+    # against 156), so its limit is 8 float32 roundings of the largest:
+    # the sums are taken in another order
+    atol = 3e-4 if body == "reglu" else 1e-6 * max(
+        float(jnp.abs(g).max()) for g in want[1])
     for chunk in (N, 16):
-        got = through(lambda *a: _share(*a, *held, chunk_tokens=chunk)[0])
+        got = through(lambda *a: _share(*a, *held, body,
+                                        chunk_tokens=chunk)[0])
         np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
         for g, w in zip(got[1], want[1]):
-            np.testing.assert_allclose(g, w, atol=3e-4)
+            np.testing.assert_allclose(g, w, atol=atol)
+    if body == "relu2":
+        assert float(jnp.abs(want[1][2]).max()) == 0.0   # no gate: no grad
+
+
+def test_wrong_stack_names_the_expert_body():
+    x, router, wg, wu, wd = _weights()
+    probs, experts = route_top_k(x @ router, K)
+    with pytest.raises(ValueError, match="ReGLU experts: 3 expert weights"):
+        held_expert_ffn(x, probs, experts, wg[:3], wu[:2], wd[:2], (0, 2))
+    with pytest.raises(ValueError, match="relu squared experts: 3 expert"):
+        held_expert_ffn(x, probs, experts, None, wu[:2], wd[:3], (0, 2))
 
 
 def test_four_shares_add_up_to_the_uncut_reference_layer():
@@ -139,3 +212,43 @@ def test_four_shares_add_up_to_the_uncut_reference_layer():
     np.testing.assert_allclose(after_attention + sum(parts), uncut,
                                atol=3e-5)
     assert float(jnp.abs(sum(parts)).max()) > 1e-2
+
+
+def test_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
+    """One 'E' block cut over 16 chips (2 of 32 routed experts each): the
+    sixteen held parts, plus the shared expert every chip computes alike
+    counted once, add up to what the plain reference gives for the whole
+    block with every expert held."""
+    ref = importlib.import_module("benchmark.reference.nemotron_h")
+    args = dict(hidden_size=32, block_pattern="E", mamba_num_heads=2,
+                mamba_head_dim=8, n_groups=1, ssm_state_size=8,
+                conv_kernel=4, chunk_size=8, num_attention_heads=4,
+                num_key_value_heads=2, head_dim=8, moe_intermediate_size=16,
+                moe_shared_expert_intermediate_size=24, router_experts=32,
+                experts_per_token=6, experts_held=32, first_expert=0,
+                routed_scaling_factor=2.5, layer_norm_epsilon=1e-5,
+                vocab_size=64, seq_len=16)
+    block = ref.init_params(jax.random.PRNGKey(6), {"model_args": args}
+                            )["blocks"][0]
+    # a correction bias that moves choices, as a trained one would
+    block["b_corr"] = 0.2 * jax.random.normal(jax.random.PRNGKey(7), (32,))
+    h = jax.random.normal(jax.random.PRNGKey(8), (2, 16, 32)) * 0.5
+    with jax.default_matmul_precision("highest"):
+        uncut = jnp.stack([ref._block(block, h[b], "E", args)
+                           for b in range(2)])
+        parts, shared_alone, loads = [], None, []
+        for first in range(0, 32, 2):
+            model = NemotronHModel(**{**args, "experts_held": 2,
+                                      "first_expert": first})
+            mine = {**block, **{k: block[k][first:first + 2]
+                                for k in ("w_up", "w_down")}}
+            out, load = model._block(mine, h, "E")
+            none = {**mine, "w_down": jnp.zeros_like(mine["w_down"])}
+            shared_alone, _ = model._block(none, h, "E")  # h + shared(u)
+            parts.append(out - shared_alone)
+            loads.append(load)
+    np.testing.assert_allclose(shared_alone + sum(parts), uncut, atol=5e-5)
+    assert float(jnp.abs(sum(parts)).max()) > 1e-2
+    assert float(jnp.abs(shared_alone - h).max()) > 1e-2
+    # nothing dropped: every (token, choice) fell on exactly one share
+    assert int(sum(jnp.sum(l) for l in loads)) == 2 * 16 * 6

@@ -20,7 +20,7 @@ def mesh8():
 def test_registry_complete():
     assert set(MODEL_REGISTRY) == {"dnn_ctr", "deepfm", "wide_deep",
                                    "dcn_v2", "dlrm", "mmoe", "pv_rank",
-                                   "smallthinker"}
+                                   "smallthinker", "nemotron_h"}
 
 
 @pytest.mark.parametrize("model_cls,kw", [
