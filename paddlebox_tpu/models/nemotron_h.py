@@ -78,7 +78,8 @@ class NemotronHModel:
     predicts = False            # a language-model loss has no CTR prediction
     num_extras = 1              # local_ids, staged per batch (batch_extras)
     stat_names = ("moe.assignments", "moe.held_assignments",
-                  "moe.expert_load_max", "ssm.tokens", "ssm.chunks",
+                  "moe.expert_load_max", "moe.route_rows",
+                  "moe.whole_chunk_routes", "ssm.tokens", "ssm.chunks",
                   "ssm.decay_log_min")
 
     def __init__(self, hidden_size: int, block_pattern: str,
@@ -234,22 +235,23 @@ class NemotronHModel:
             @ p["wo"]
 
     def _experts(self, p, u):
-        """(the layer's output (B, T, d), assignments per held expert)."""
+        """(the layer's output (B, T, d), (assignments per held expert,
+        how the chunks were routed))."""
         B, T, d = u.shape
         m = u.reshape(B * T, d)
         logits = jnp.dot(m, p["router"], precision=jax.lax.Precision.HIGHEST)
         weights, experts = route_sigmoid_top_k(logits, p["b_corr"],
                                                self.top_k, self.scale)
-        y, load = held_expert_ffn(m, weights, experts, None, p["w_up"],
-                                  p["w_down"], self.held,
-                                  chunk_tokens=self.expert_chunk_tokens)
+        y, load, took = held_expert_ffn(
+            m, weights, experts, None, p["w_up"], p["w_down"], self.held,
+            self.router_experts, chunk_tokens=self.expert_chunk_tokens)
         shared = _relu2(m @ p["shared_up"]) @ p["shared_down"]
-        return (y + shared).reshape(B, T, d), load
+        return (y + shared).reshape(B, T, d), (load, took)
 
     def _block(self, p, h, kind: str):
         """One block over h (B, T, d): (h_next, what the kind counts —
-        ``M`` its decay gauge, ``E`` its held experts' load, ``*``
-        nothing)."""
+        ``M`` its decay gauge, ``E`` its held experts' load and how its
+        chunks were routed, ``*`` nothing)."""
         u = rms_norm(h, p["norm"], self.eps)
         if kind == "M":
             out, aux = self._mamba(p, u)
@@ -261,7 +263,8 @@ class NemotronHModel:
 
     def example_losses(self, params, pulled, mask, local_ids):
         """(one loss an example (B,), the assignments each held expert
-        received in each ``E`` block (blocks, experts_held), the ``M``
+        received in each ``E`` block (blocks, experts_held), each ``E``
+        block's sorted rows and whole-chunk routes (blocks, 2), the ``M``
         blocks' decay gauges (blocks,))."""
         h = pulled[..., 3:]
         aux = {kind: [] for kind in KINDS}
@@ -271,14 +274,16 @@ class NemotronHModel:
             aux[kind].append(a)
         stack = lambda v, width: jnp.stack(v) if v else jnp.zeros(
             (0,) + width, jnp.float32)
+        loads, took = zip(*aux["E"]) if aux["E"] else ((), ())
         return (next_token_loss(params, h, local_ids, mask, self.eps,
                                 self.head_chunk),
-                stack(aux["E"], (self.held[1],)), stack(aux["M"], ()))
+                stack(loads, (self.held[1],)), stack(took, (2,)),
+                stack(aux["M"], ()))
 
     def loss(self, params, pulled, mask, dense, labels, local_ids):
         """The declared loss (models/base.py): the batch's mean, no
         prediction, and the step's routing and scan statistics."""
-        per_example, loads, decays = self.example_losses(
+        per_example, loads, took, decays = self.example_losses(
             params, pulled, mask, local_ids)
         B, T = pulled.shape[:2]
         n_m, n_e = self.pattern.count("M"), self.pattern.count("E")
@@ -286,6 +291,7 @@ class NemotronHModel:
         stats = jnp.stack([
             jnp.float32(B * T * self.top_k * n_e),
             jnp.sum(loads), jnp.max(loads, initial=0.0),
+            *jnp.sum(took, axis=0).astype(jnp.float32),
             jnp.float32(B * T * n_m),
             jnp.float32(B * (T // min(self.chunk, T)) * n_m),
             jnp.min(decays, initial=0.0)])

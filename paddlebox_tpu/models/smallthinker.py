@@ -61,7 +61,8 @@ class SmallThinkerModel:
     predicts = False            # a language-model loss has no CTR prediction
     num_extras = 1              # local_ids, staged per batch (batch_extras)
     stat_names = ("moe.assignments", "moe.held_assignments",
-                  "moe.expert_load_max")
+                  "moe.expert_load_max", "moe.route_rows",
+                  "moe.whole_chunk_routes")
 
     def __init__(self, hidden_size: int, num_attention_heads: int,
                  num_key_value_heads: int, head_dim: int,
@@ -123,8 +124,8 @@ class SmallThinkerModel:
     # -- the tower ---------------------------------------------------------
 
     def _layer(self, p, h, kind: int):
-        """One layer over h (B, T, d): (h_next, assignments per held
-        expert)."""
+        """One layer over h (B, T, d): (h_next, (assignments per held
+        expert, how the chunks were routed))."""
         B, T, d = h.shape
         probs, experts = route_top_k(h.reshape(B * T, d) @ p["router"],
                                      self.top_k)
@@ -143,31 +144,35 @@ class SmallThinkerModel:
         o = jnp.swapaxes(o, 1, 2).reshape(B, T, -1).astype(h.dtype)
         h = h + o @ p["wo"]
         m = rms_norm(h, p["norm2"], self.eps).reshape(B * T, d)
-        y, load = held_expert_ffn(m, probs, experts, p["w_gate"], p["w_up"],
-                                  p["w_down"], self.held,
-                                  chunk_tokens=self.expert_chunk_tokens)
-        return h + y.reshape(B, T, d), load
+        y, load, took = held_expert_ffn(
+            m, probs, experts, p["w_gate"], p["w_up"], p["w_down"],
+            self.held, self.router_experts,
+            chunk_tokens=self.expert_chunk_tokens)
+        return h + y.reshape(B, T, d), (load, took)
 
     def example_losses(self, params, pulled, mask, local_ids):
         """(one loss an example (B,), the assignments each held expert
-        received in each layer (layers, experts_held))."""
+        received in each layer (layers, experts_held), each layer's sorted
+        rows and whole-chunk routes (layers, 2))."""
         h = pulled[..., 3:]
-        loads = []
+        routed = []
         for p, kind in zip(params["layers"], self.kinds):
-            h, load = jax.checkpoint(self._layer, static_argnums=(2,))(
+            h, route = jax.checkpoint(self._layer, static_argnums=(2,))(
                 p, h, kind)
-            loads.append(load)
+            routed.append(route)
+        loads, took = (jnp.stack(v) for v in zip(*routed))
         return next_token_loss(params, h, local_ids, mask, self.eps,
-                               self.head_chunk), jnp.stack(loads)
+                               self.head_chunk), loads, took
 
     def loss(self, params, pulled, mask, dense, labels, local_ids):
         """The declared loss (models/base.py): the batch's mean, no
         prediction, and the step's routing statistics."""
-        per_example, loads = self.example_losses(params, pulled, mask,
-                                                 local_ids)
+        per_example, loads, took = self.example_losses(params, pulled, mask,
+                                                       local_ids)
         n_tok = pulled.shape[0] * pulled.shape[1]
         loads = jax.lax.stop_gradient(loads).astype(jnp.float32)
         stats = jnp.stack([
             jnp.float32(n_tok * self.top_k * len(self.kinds)),
-            jnp.sum(loads), jnp.max(loads)])
+            jnp.sum(loads), jnp.max(loads),
+            *jnp.sum(took, axis=0).astype(jnp.float32)])
         return jnp.mean(per_example), None, stats

@@ -173,10 +173,14 @@ SPAN_NAMES: tuple[str, ...] = (
 MODEL_STAT_NAMES: tuple[str, ...] = (
     # parallel/expert.py share layer: (token, choice) assignments routed,
     # those that fell on an expert this chip holds, and a step's busiest
-    # held expert (imbalance)
+    # held expert (imbalance); the rows of the sorted copies the chunks
+    # took (pad share = 1 - held_assignments / route_rows) and the chunks
+    # whose held load fitted no bounded rung and took the whole chunk
     "moe.assignments",
     "moe.held_assignments",
     "moe.expert_load_max",
+    "moe.route_rows",
+    "moe.whole_chunk_routes",
     # ops/ssm_scan.py through a state-space mixer: tokens scanned (tokens x
     # mixer blocks), chunks scanned, and the most negative cumulative
     # ``Delta A`` within a chunk — how near exp of a chunk's decay is to

@@ -21,14 +21,21 @@ of expert parallelism: a chip is told which experts of a layer it holds
 ALL experts, and the chip computes the part of the layer's output that its
 held experts contribute — grouped matrix products over the (token, choice)
 assignments sorted by held expert, no capacity and no drop at any
-imbalance. What the absent experts would add is another chip's part and is
-not computed, approximated or stood in for; on a mesh of one chip the layer
-runs without an exchange. Two routing rules (``route_top_k``: a softmax
-over the chosen logits; ``route_sigmoid_top_k``: sigmoid scores chosen
-with a correction bias, renormalised and scaled) and two expert bodies
-(gated ReGLU; non-gated relu squared) share the one layer. An expert that
-every chip computes alike (a shared expert) is the model's to add: the sum
-of the shares counts it once.
+imbalance. Only the rows a held expert will multiply are moved: the sorted
+copy of a chunk is bounded by what its held experts received, on a short
+ladder of static row counts from the fair load up to the whole chunk
+(``route_rungs``), and the chunk picks its rung on the device from its own
+sizes. The last rung holds every assignment of the chunk, which is why
+nothing is dropped at any imbalance: a load that no bounded rung holds
+costs time, never a token. What the absent experts would add is another
+chip's part and is not computed, approximated or stood in for; on a mesh
+of one chip the layer runs without an exchange. Two routing rules
+(``route_top_k``: a softmax over the chosen logits;
+``route_sigmoid_top_k``: sigmoid scores chosen with a correction bias,
+renormalised and scaled) and two expert bodies (gated ReGLU; non-gated
+relu squared) share the one layer. An expert that every chip computes
+alike (a shared expert) is the model's to add: the sum of the shares
+counts it once.
 """
 
 from __future__ import annotations
@@ -258,20 +265,43 @@ _permute_rows.defvjp(
     lambda inv, g: (jnp.take(g, inv, axis=0), None, None))
 
 
-def _held_chunk(x, probs, experts, w_gate, w_up, w_down, first: int,
-                count: int):
-    """One chunk of tokens through the held experts (ReGLU, or relu
-    squared where ``w_gate`` is None). Returns the chunk's output (n, D)
-    and its assignments per held expert (count,)."""
-    n, k = experts.shape
-    local = experts - first
-    held = (local >= 0) & (local < count)
-    # not-held assignments sort past the last group: never computed
-    key = jnp.where(held, local, count).reshape(-1).astype(jnp.int32)
-    order = jnp.argsort(key)                      # stable: by expert
-    inv = jnp.zeros_like(order).at[order].set(
-        jnp.arange(n * k, dtype=order.dtype))
-    sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
+# the sorted copy's row bounds: this many (each is a copy of the chunk's
+# program: 5 MB of device code a rung and layer at the published widths),
+# in whole row tiles
+_RUNGS = 4
+_ROW_TILE = 128
+
+
+def route_rungs(rows: int, count: int, n_experts: int) -> tuple[int, ...]:
+    """The static row bounds a chunk of ``rows`` (token, choice)
+    assignments may take for its sorted copy, ascending: ``_RUNGS`` bounds
+    in equal ratios from the fair load of ``count`` held experts of
+    ``n_experts`` — or from rows / 2^(_RUNGS - 1) where the fair load is
+    less, so that adjacent bounds never differ by more than 2x — up to
+    the whole chunk, each rounded up to whole row tiles."""
+    least = max(rows * count / n_experts, rows / 2 ** (_RUNGS - 1))
+    tiles = lambda r: -(-int(np.ceil(r)) // _ROW_TILE) * _ROW_TILE
+    return tuple(sorted({
+        min(rows, tiles(rows * (least / rows) ** (1 - i / (_RUNGS - 1))))
+        for i in range(_RUNGS)}))
+
+
+def _expert_rows(xs, sizes, w_gate, w_up, w_down, dtype):
+    """Sorted rows through their experts' bodies: (rows, D) -> (rows, D)
+    in `dtype`; rows past the last group are not computed."""
+    dot = functools.partial(lax.ragged_dot, group_sizes=sizes,
+                            preferred_element_type=jnp.float32)
+    if w_gate is None:
+        hidden = jnp.square(jax.nn.relu(dot(xs, w_up)))
+    else:
+        hidden = jax.nn.relu(dot(xs, w_gate)) * dot(xs, w_up)
+    return dot(hidden.astype(w_down.dtype), w_down).astype(dtype)
+
+
+def _whole_chunk(x, probs, order, inv, sizes, w_gate, w_up, w_down):
+    """The last rung: every assignment of the chunk in the sorted copy,
+    whatever the imbalance."""
+    n, k = probs.shape
     # rows past the last group belong to no held expert: the grouped
     # products neither compute them nor their cotangents (on the chip what
     # they leave there is whatever the buffer held), so nothing may flow
@@ -279,25 +309,155 @@ def _held_chunk(x, probs, experts, w_gate, w_up, w_down, first: int,
     in_group = (jnp.arange(n * k) < jnp.sum(sizes))[:, None]
     xs = jnp.where(in_group, _tokens_by_expert(
         x.astype(w_up.dtype), order, inv, k), 0)
-    dot = functools.partial(lax.ragged_dot, group_sizes=sizes,
-                            preferred_element_type=jnp.float32)
-    if w_gate is None:
-        hidden = jnp.square(jax.nn.relu(dot(xs, w_up)))
-    else:
-        hidden = jax.nn.relu(dot(xs, w_gate)) * dot(xs, w_up)
-    ys = dot(hidden.astype(w_down.dtype), w_down).astype(x.dtype)
+    ys = _expert_rows(xs, sizes, w_gate, w_up, w_down, x.dtype)
     # back to (token, choice) order
     y = _permute_rows(jnp.where(in_group, ys, 0), inv, order)
-    out = jnp.einsum("nkd,nk->nd", y.reshape(n, k, -1),
-                     probs.astype(y.dtype))
-    return out, sizes
+    return jnp.einsum("nkd,nk->nd", y.reshape(n, k, -1),
+                      probs.astype(y.dtype))
+
+
+def _token_order(held, inv):
+    """The held assignments in token order, (token, choice) ascending:
+    (the sorted row of the j-th one, its token), both (n * k,), the token
+    n past the last held one. A token's sorted rows are a run of at most
+    k in this order."""
+    n, k = held.shape
+    flat = held.reshape(-1)
+    at = jnp.where(flat, jnp.cumsum(flat) - 1, n * k)
+    ids = jnp.full((n * k,), n * k, jnp.int32).at[at].set(
+        jnp.arange(n * k, dtype=jnp.int32), mode="drop", unique_indices=True)
+    return jnp.take(inv, ids, mode="clip"), ids // k
+
+
+@jax.custom_vjp
+def _rows_of_tokens(x, plan):
+    """x (n, D) -> (C, D): each sorted row's token row, zero past the last
+    group. The cotangent is `_sum_by_token`: gathers, never a scatter-add
+    over repeated rows."""
+    token = plan[0]
+    live = (token < x.shape[0])[:, None]
+    return jnp.where(live, jnp.take(x, token, axis=0, mode="clip"), 0)
+
+
+@jax.custom_vjp
+def _sum_by_token(rows, plan):
+    """rows (C, D) -> (n, D): the sum of each token's sorted rows, float32
+    sums; rows past the last group are never read (on the chip they hold
+    whatever the buffer held). The cotangent is `_rows_of_tokens`."""
+    _, perm, same, start, has = plan
+    c = rows.shape[0]
+    z = jnp.take(rows, perm, axis=0, mode="clip").astype(jnp.float32)
+    after = jnp.pad(z, ((0, len(same)), (0, 0)))
+    for s, eq in enumerate(same, 1):
+        z = z + jnp.where(eq[:, None], after[s:s + c], 0)
+    heads = jnp.take(z, start, axis=0, mode="clip")
+    return jnp.where(has[:, None], heads, 0).astype(rows.dtype)
+
+
+_rows_of_tokens.defvjp(
+    lambda x, plan: (_rows_of_tokens(x, plan), plan),
+    lambda plan, g: (_sum_by_token(g, plan), None))
+_sum_by_token.defvjp(
+    lambda rows, plan: (_sum_by_token(rows, plan), plan),
+    lambda plan, g: (_rows_of_tokens(g, plan), None))
+
+
+def _bounded_chunk(bound: int, x, probs, order, sizes, by_token, held_choices,
+                   w_gate, w_up, w_down):
+    """A rung under the whole chunk: the held assignments are the first
+    sum(sizes) <= bound entries of `order`, and only `order[:bound]` is
+    brought into the sorted order; every array on the sorted side has
+    `bound` rows. `by_token` is `_token_order`'s pair, `held_choices`
+    (n,) how many sorted rows each token has."""
+    n, k = probs.shape
+    rows = order[:bound]
+    # rows past the last group: as in the whole chunk, nothing flows
+    # through them in either direction
+    live = jnp.arange(bound) < jnp.sum(sizes)
+    token = jnp.concatenate([by_token[1][:bound],
+                             jnp.full((k - 1,), n, jnp.int32)])
+    plan = (jnp.where(live, rows // k, n), by_token[0][:bound],
+            tuple(token[s:s + bound] == token[:bound] for s in range(1, k)),
+            jnp.cumsum(held_choices) - held_choices, held_choices > 0)
+    xs = _rows_of_tokens(x.astype(w_up.dtype), plan)
+    ys = _expert_rows(xs, sizes, w_gate, w_up, w_down, x.dtype)
+    weigh = probs.reshape(-1).at[rows].get(
+        unique_indices=True, mode="promise_in_bounds").astype(ys.dtype)
+    return _sum_by_token(jnp.where(live[:, None], ys, 0) * weigh[:, None],
+                         plan)
+
+
+def _rung(bound: int, x, probs, routed, weights):
+    order, inv, sizes, by_token, held_choices = routed
+    if bound == probs.shape[0] * probs.shape[1]:
+        return _whole_chunk(x, probs, order, inv, sizes, *weights)
+    return _bounded_chunk(bound, x, probs, order, sizes, by_token,
+                          held_choices, *weights)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _ladder(rungs, rung, x, probs, routed, weights):
+    """The chunk through rung `rung` of `rungs`; `routed` is its sort, the
+    same for every rung (`_held_chunk`). Differentiated by hand
+    so that the backward pass switches once and runs the taken rung's
+    forward and backward inside its branch: nothing is kept but the
+    arguments (the chunk is recomputed, not stored), and the rungs not
+    taken leave no residuals to fill with zeros."""
+    return lax.switch(rung, [functools.partial(_rung, b) for b in rungs],
+                      x, probs, routed, weights)
+
+
+def _ladder_fwd(rungs, rung, x, probs, routed, weights):
+    return (_ladder(rungs, rung, x, probs, routed, weights),
+            (rung, x, probs, routed, weights))
+
+
+def _ladder_bwd(rungs, kept, g):
+    rung, x, probs, routed, weights = kept
+
+    def back(bound, x, probs, routed, weights, g):
+        return jax.vjp(lambda x, p, w: _rung(bound, x, p, routed, w),
+                       x, probs, weights)[1](g)
+
+    dx, dprobs, dweights = lax.switch(
+        rung, [functools.partial(back, b) for b in rungs],
+        x, probs, routed, weights, g)
+    return None, dx, dprobs, None, dweights
+
+
+_ladder.defvjp(_ladder_fwd, _ladder_bwd)
+
+
+def _held_chunk(x, probs, experts, w_gate, w_up, w_down, first: int,
+                count: int, rungs: tuple[int, ...]):
+    """One chunk of tokens through the held experts (ReGLU, or relu
+    squared where ``w_gate`` is None). Returns the chunk's output (n, D),
+    its assignments per held expert (count,) and the rung it took as
+    (rows of the sorted copy, 1 if that was the whole chunk)."""
+    local = experts - first
+    held = (local >= 0) & (local < count)
+    # not-held assignments sort past the last group: never computed
+    key = jnp.where(held, local, count).reshape(-1).astype(jnp.int32)
+    order = jnp.argsort(key)                      # stable: by expert
+    sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
+    # the least rung that holds what the held experts received
+    rung = jnp.sum(jnp.sum(sizes) > jnp.asarray(rungs[:-1], jnp.int32))
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    routed = (order, inv, sizes, _token_order(held, inv),
+              jnp.sum(held, axis=1, dtype=jnp.int32))
+    out = _ladder(rungs, rung, x, probs, routed, (w_gate, w_up, w_down))
+    took = jnp.stack([jnp.asarray(rungs, jnp.int32)[rung],
+                      (rung == len(rungs) - 1).astype(jnp.int32)])
+    return out, sizes, took
 
 
 def held_expert_ffn(x: jnp.ndarray, probs: jnp.ndarray,
                     experts: jnp.ndarray, w_gate: jnp.ndarray | None,
                     w_up: jnp.ndarray, w_down: jnp.ndarray,
-                    held: tuple[int, int], chunk_tokens: int = 4096
-                    ) -> tuple[jnp.ndarray, jnp.ndarray]:
+                    held: tuple[int, int], n_experts: int,
+                    chunk_tokens: int = 4096
+                    ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """sum over (token, choice) with the chosen expert held here of
     p * body_e(x), the expert body being the call's: gated ReGLU,
     ``(relu(x W_gate_e) * (x W_up_e)) W_down_e``, where ``w_gate`` is
@@ -305,19 +465,26 @@ def held_expert_ffn(x: jnp.ndarray, probs: jnp.ndarray,
     is None.
 
     x (N, D); probs, experts (N, k) from a routing rule (``route_top_k``,
-    ``route_sigmoid_top_k``); the weights of the ``count`` held experts
-    stacked on axis 0, (count, D, H) (twice for the gated body) and
-    (count, H, D); ``held = (first, count)``: this chip holds the global
-    experts first .. first + count - 1. Returns the held part of the
-    layer's output (N, D) and the assignments each held expert received
-    (count,) int32 — nothing is dropped, whatever the imbalance.
+    ``route_sigmoid_top_k``) over ``n_experts`` experts; the weights of
+    the ``count`` held experts stacked on axis 0, (count, D, H) (twice for
+    the gated body) and (count, H, D); ``held = (first, count)``: this
+    chip holds the global experts first .. first + count - 1. Returns the
+    held part of the layer's output (N, D), the assignments each held
+    expert received (count,) int32, and how the chunks were routed (2,)
+    int32: the rows of the sorted copies taken, summed over the chunks,
+    and the chunks that took the whole-chunk copy.
 
     The assignments are sorted by held expert and taken through
     ``lax.ragged_dot`` (on a TPU XLA's grouped matrix product, whose row
     tiles past the last group are not computed). Tokens go through in
-    chunks of ``chunk_tokens``, so the sorted copy is bounded by the
-    chunk's worst case (every choice held) and not the batch's; a chunk
-    is recomputed in the backward pass instead of stored."""
+    chunks of ``chunk_tokens``, and a chunk's sorted copy is bounded by
+    what its held experts received: the held assignments sort first, and
+    the chunk takes the least rung of ``route_rungs`` that holds them all
+    — a few static row counts from the fair load up to the whole chunk,
+    chosen on the device by the chunk's own sizes. The last rung is the
+    whole chunk (every choice held), so nothing is dropped, whatever the
+    imbalance: a load that no bounded rung holds costs time, never a
+    token. A chunk is recomputed in the backward pass instead of stored."""
     first, count = int(held[0]), int(held[1])
     body = "relu squared" if w_gate is None else "ReGLU"
     for w in (w_up, w_down) if w_gate is None else (w_gate, w_up, w_down):
@@ -335,12 +502,12 @@ def held_expert_ffn(x: jnp.ndarray, probs: jnp.ndarray,
         # are cast once a call, not once a chunk
         w_gate, w_up, w_down = (w if w is None else w.astype(jnp.bfloat16)
                                 for w in (w_gate, w_up, w_down))
-    one = jax.checkpoint(
-        lambda xc, pc, ec: _held_chunk(xc, pc, ec, w_gate, w_up, w_down,
-                                       first, count))
+    rungs = route_rungs(chunk * experts.shape[1], count, int(n_experts))
+    one = lambda xc, pc, ec: _held_chunk(xc, pc, ec, w_gate, w_up, w_down,
+                                         first, count, rungs)
     if chunk == n:
         return one(x, probs, experts)
     parts = lambda a: a.reshape(n // chunk, chunk, *a.shape[1:])
-    out, sizes = lax.map(lambda c: one(*c),
-                         (parts(x), parts(probs), parts(experts)))
-    return out.reshape(n, -1), jnp.sum(sizes, axis=0)
+    out, sizes, took = lax.map(lambda c: one(*c),
+                               (parts(x), parts(probs), parts(experts)))
+    return out.reshape(n, -1), jnp.sum(sizes, axis=0), jnp.sum(took, axis=0)

@@ -6,6 +6,7 @@ sigmoid scores chosen with a correction bias), and the shares of all chips
 adding up to the uncut reference's layer, what every chip computes alike
 counted once."""
 
+import functools
 import importlib
 
 import numpy as np
@@ -16,6 +17,7 @@ import jax.numpy as jnp
 
 from paddlebox_tpu.models.nemotron_h import NemotronHModel
 from paddlebox_tpu.models.smallthinker import SmallThinkerModel
+from paddlebox_tpu.parallel import expert
 from paddlebox_tpu.parallel.expert import (held_expert_ffn,
                                            route_sigmoid_top_k, route_top_k)
 
@@ -62,10 +64,16 @@ def _dense(x, probs, experts, wg, wu, wd, first, count, body="reglu"):
 
 
 def _share(x, probs, experts, wg, wu, wd, first, count, body="reglu", **kw):
+    return _share_and_route(x, probs, experts, wg, wu, wd, first, count,
+                            body, **kw)[:2]
+
+
+def _share_and_route(x, probs, experts, wg, wu, wd, first, count,
+                     body="reglu", **kw):
     sl = slice(first, first + count)
     return held_expert_ffn(x, probs, experts,
                            wg[sl] if body == "reglu" else None, wu[sl],
-                           wd[sl], (first, count), **kw)
+                           wd[sl], (first, count), wu.shape[0], **kw)
 
 
 @pytest.mark.parametrize("rule", RULES)
@@ -172,13 +180,109 @@ def test_chunks_and_gradients_match_dense(body, rule):
         assert float(jnp.abs(want[1][2]).max()) == 0.0   # no gate: no grad
 
 
+# the ladder of the sorted copy's row bounds, forced rung by rung: chunks of
+# 256 tokens x 3 choices = 768 assignments, 3 of 16 experts held (a fair
+# load of 144), so a chunk takes 256, 512 or all 768 sorted rows
+LN, LCHUNK, LE, LHELD = 512, 256, 16, (2, 3)
+LRUNGS = (256, 512, 768)
+HELD_IN_CHUNK_0 = {"none_held": 0, "under_the_first_rung": 100,
+                   "at_the_first_rung": 256, "one_over_the_first_rung": 257,
+                   "at_the_second_rung": 512, "one_over_the_second_rung": 513,
+                   "all_to_one_held": 256, "every_choice_held": 768}
+
+
+def _constructed_logits(case, seed):
+    """(LN, LE) router logits: chunk 0 sends exactly HELD_IN_CHUNK_0[case]
+    assignments to the held experts (all of them to the first held expert
+    in ``all_to_one_held``), chunk 1 is whatever a random router sends."""
+    rng = np.random.default_rng(seed)
+    logits = rng.normal(size=(LN, LE)).astype(np.float32)
+    held = np.arange(LHELD[0], LHELD[0] + LHELD[1])
+    others = np.setdiff1d(np.arange(LE), held)
+    per_token = np.clip(HELD_IN_CHUNK_0[case] - K * np.arange(LCHUNK), 0, K)
+    if case == "all_to_one_held":
+        per_token[:] = 1
+    for t, c in enumerate(per_token):
+        mine = held[:1] if case == "all_to_one_held" else np.roll(held, t)[:c]
+        chosen = np.concatenate([mine, rng.permutation(others)[:K - c]])
+        logits[t] = -9.0
+        logits[t, chosen] = 4.0 + rng.uniform(0, 2, K)
+    return jnp.asarray(logits)
+
+
+def test_rungs_are_whole_tiles_at_most_twice_apart_and_end_at_the_chunk():
+    assert expert.route_rungs(LCHUNK * K, LHELD[1], LE) == LRUNGS
+    # both towers' chunks of 4,096 tokens x 6 choices: from the fair load
+    # (a quarter) to the whole chunk; a sixteenth is under an eighth, the
+    # least from which four rungs reach the chunk in steps of at most 2x
+    small = expert.route_rungs(24576, 16, 64)
+    hybrid = expert.route_rungs(24576, 8, 128)
+    assert small == (6144, 9856, 15488, 24576)
+    assert hybrid == (3072, 6144, 12288, 24576)
+    for rows, rungs in ((24576, small), (24576, hybrid), (768, LRUNGS),
+                        (144, expert.route_rungs(144, 2, 8)),
+                        (24576, expert.route_rungs(24576, 1, 256))):
+        assert rungs[-1] == rows and len(rungs) <= 4
+        assert all(b <= 2 * a and a % 128 == 0
+                   for a, b in zip(rungs, rungs[1:]))
+    # every expert held: the whole chunk is the fair load
+    assert expert.route_rungs(144, 8, 8) == (144,)
+
+
+@pytest.mark.parametrize("rule", RULES)
+@pytest.mark.parametrize("body", BODIES)
+@pytest.mark.parametrize("case", list(HELD_IN_CHUNK_0))
+def test_every_rung_gives_the_whole_chunk_path(case, body, rule, monkeypatch):
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    x = jax.random.normal(ks[0], (LN, D))
+    wg, wu, wd = (jax.random.normal(ks[1], (LE, D, F)) * D ** -0.5,
+                  jax.random.normal(ks[2], (LE, D, F)) * D ** -0.5,
+                  jax.random.normal(ks[3], (LE, F, D)) * F ** -0.5)
+    probs, experts = _route(_constructed_logits(case, 12), rule)
+
+    def through(fn):
+        def loss(x, probs, wg, wu, wd):
+            out, *rest = fn(x, probs, experts, wg, wu, wd, *LHELD, body)
+            return jnp.sum(out ** 2), rest
+        with jax.default_matmul_precision("highest"):
+            return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
+                                      has_aux=True)(x, probs, wg, wu, wd)
+
+    laddered = functools.partial(_share_and_route, chunk_tokens=LCHUNK)
+    (got, (sizes, took)), got_grads = through(laddered)
+    # what the constructed routing implies
+    held = (np.asarray(experts) >= LHELD[0]) & (
+        np.asarray(experts) < LHELD[0] + LHELD[1])
+    totals = held.reshape(LN // LCHUNK, -1).sum(axis=1)
+    assert totals[0] == HELD_IN_CHUNK_0[case]
+    rungs = [min(r for r in LRUNGS if r >= t) for t in totals]
+    np.testing.assert_array_equal(
+        took, [sum(rungs), sum(r == LRUNGS[-1] for r in rungs)])
+    assert int(jnp.sum(sizes)) == totals.sum()
+    if case == "all_to_one_held":
+        assert int(sizes[0]) >= LCHUNK
+    # the same chunks through the whole-chunk path alone, and the dense
+    # masked computation
+    monkeypatch.setattr(expert, "route_rungs", lambda rows, *_: (rows,))
+    (whole, (_, whole_took)), whole_grads = through(laddered)
+    np.testing.assert_array_equal(whole_took, [LN * K, LN // LCHUNK])
+    (dense, _), dense_grads = through(
+        lambda *a: (_dense(*a),))
+    scale = max(float(jnp.abs(g).max()) for g in dense_grads)
+    atol = max(3e-4 if body == "reglu" else 0.0, 1e-6 * scale)
+    for want, want_grads in ((whole, whole_grads), (dense, dense_grads)):
+        np.testing.assert_allclose(got, want, rtol=1e-5)
+        for g, w in zip(got_grads, want_grads):
+            np.testing.assert_allclose(g, w, atol=atol)
+
+
 def test_wrong_stack_names_the_expert_body():
     x, router, wg, wu, wd = _weights()
     probs, experts = route_top_k(x @ router, K)
     with pytest.raises(ValueError, match="ReGLU experts: 3 expert weights"):
-        held_expert_ffn(x, probs, experts, wg[:3], wu[:2], wd[:2], (0, 2))
+        held_expert_ffn(x, probs, experts, wg[:3], wu[:2], wd[:2], (0, 2), E)
     with pytest.raises(ValueError, match="relu squared experts: 3 expert"):
-        held_expert_ffn(x, probs, experts, None, wu[:2], wd[:3], (0, 2))
+        held_expert_ffn(x, probs, experts, None, wu[:2], wd[:3], (0, 2), E)
 
 
 def test_four_shares_add_up_to_the_uncut_reference_layer():
@@ -242,7 +346,7 @@ def test_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
                                       "first_expert": first})
             mine = {**block, **{k: block[k][first:first + 2]
                                 for k in ("w_up", "w_down")}}
-            out, load = model._block(mine, h, "E")
+            out, (load, _) = model._block(mine, h, "E")
             none = {**mine, "w_down": jnp.zeros_like(mine["w_down"])}
             shared_alone, _ = model._block(none, h, "E")  # h + shared(u)
             parts.append(out - shared_alone)

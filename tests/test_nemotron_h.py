@@ -203,6 +203,11 @@ def test_two_passes_train_and_their_statistics_reach_the_flight_record(
     assert st["moe.assignments"] == (steps_run * tokens
                                      * a["experts_per_token"] * n_e)
     assert 0 < st["moe.held_assignments"] < st["moe.assignments"]
+    # the ladder's counters, after two passes: the sorted copies held every
+    # held assignment and never more than the whole chunks' rows
+    assert st["moe.held_assignments"] <= st["moe.route_rows"] \
+        <= st["moe.assignments"]
+    assert 0 <= st["moe.whole_chunk_routes"] <= steps_run * n_e
     # the gauge: a pass's most negative chunk of Delta A (dt up to 0.1, A
     # down to -16, 8 positions a chunk)
     assert -8 * 0.2 * 16 < followed["gauge"] < 0

@@ -117,6 +117,11 @@ def test_routing_statistics_reach_the_flight_record(followed):
     st = followed["stats"]
     assert st["moe.assignments"] == want
     assert 0 < st["moe.held_assignments"] < want
+    # the ladder's counters: the sorted copies held every held assignment,
+    # in whole row tiles, and never more than the whole chunk's rows
+    assert st["moe.held_assignments"] <= st["moe.route_rows"] <= want
+    assert 0 <= st["moe.whole_chunk_routes"] <= steps_run * len(
+        a["layer_kinds"])
     assert rec["timers"]["extras"] > 0
     from paddlebox_tpu.monitor import names
     assert set(MODEL_REGISTRY["smallthinker"].stat_names) <= set(
@@ -166,7 +171,7 @@ def test_model_loss_equals_reference_and_order_matters():
                              ids[:, perm])[0]
     assert abs(float(swapped) - float(a)) > 1e-4
     loss, preds, stats = model.loss(params, pulled, mask, None, labels, ids)
-    assert preds is None and stats.shape == (3,)
+    assert preds is None and stats.shape == (len(model.stat_names),)
 
 
 @pytest.mark.parametrize("window", [None, 5, 12])
