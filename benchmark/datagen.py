@@ -21,6 +21,12 @@ sizes of a public data set and the source's own cut of scale:
 * Passes A and B are two independent draws of ``steps_per_pass`` batches
   (two stretches of one log): what they share follows from the field
   sizes and the pass length alone.
+* ``pool_seed`` (optional): the draw is this seed's in every run — one
+  pool of examples a pass — and ``--seed`` orders each pass's examples.
+  Every seed then gives the same set of examples in another order: for a
+  cell whose speed follows what its examples hold (an expert layer's load
+  follows the tokens), so that a run's reading is the tree's and not the
+  draw's. Absent, ``--seed`` makes the draw and the order is the draw's.
 * Dense values are multiples of 0.001 in [-9.999, 9.999] (a clipped
   normal), so the text holds them exactly as the float32 the reference
   uses; labels are Bernoulli(sigmoid(2 * dense @ w / sqrt(F))).
@@ -174,7 +180,10 @@ def _slot_columns(mix: dict, tag: int, s: int, h: int, n: int, seed: int,
 
 def make_passes(mix: dict, n_slots: int, dense_dim: int, batch: int,
                 seed: int, threads: int = 8) -> tuple[Pass, Pass]:
-    """Passes A and B of one run. The same seed gives the same passes."""
+    """Passes A and B of one run. The same seed gives the same passes; with
+    the mix's ``pool_seed``, every seed gives that seed's draw, each pass's
+    examples in an order of its own."""
+    order_seed, seed = seed, int(mix.get("pool_seed", seed))
     hot = slot_hotness(mix, n_slots)
     sizes = field_sizes(mix, n_slots)
     if (n_slots + 1) << SLOT_SHIFT >= 10 ** ID_DIGITS:
@@ -197,6 +206,11 @@ def make_passes(mix: dict, n_slots: int, dense_dim: int, batch: int,
     for k in range(2):
         milli, labels = _dense_and_labels(
             np.random.default_rng([int(seed), 0xE, k]), n, dense_dim, w)
+        if "pool_seed" in mix:
+            order = np.random.default_rng(
+                [int(order_seed), 0xF, k]).permutation(n)
+            ids[k], lens[k] = ids[k][order], lens[k][order]
+            milli, labels = milli[order], labels[order]
         out.append(Pass(ids[k], lens[k], milli, labels, hot))
     return out[0], out[1]
 

@@ -335,16 +335,39 @@ def ssm_scan_bytes(cfg):
     return _count(cfg, "M") * tokens * (forward + backward)
 
 
-def attention_macs(cfg):
-    """Multiply-adds of one example's scores and values in one '*' block
-    (forward; the masked part is not counted)."""
-    a = _a(cfg)
+def _block_attention_macs(a):
     T = a["seq_len"]
     return T * 2 * a["num_attention_heads"] * a["head_dim"] * (T + 1) / 2
 
 
+def attention_macs(cfg):
+    """Multiply-adds of one example's scores and values in all the '*'
+    blocks of the cut (forward; the masked part is not counted)."""
+    return _count(cfg, "*") * _block_attention_macs(_a(cfg))
+
+
 def _held_share(a):
     return a["experts_per_token"] * a["experts_held"] / a["router_experts"]
+
+
+def expert_gmm_macs(cfg):
+    """Multiply-adds of one example's held routed experts in all the 'E'
+    blocks (forward): the expected held share of the experts_per_token
+    choices, up and down; the shared expert is a dense product."""
+    a = _a(cfg)
+    return _count(cfg, "E") * a["seq_len"] * _held_share(a) \
+        * 2 * a["hidden_size"] * a["moe_intermediate_size"]
+
+
+def route_rows(cfg):
+    """(rows, held, experts): the (token, choice) assignments of one chunk
+    the expert blocks route at a time, the experts this chip holds and the
+    experts the router chooses among — what names the route's operations
+    in a trace."""
+    a = _a(cfg)
+    tokens = cfg["trainer"]["global_batch_size"] * a["seq_len"]
+    return (min(a["expert_chunk_tokens"], tokens) * a["experts_per_token"],
+            a["experts_held"], a["router_experts"])
 
 
 def macs_per_example(cfg):
@@ -356,11 +379,11 @@ def macs_per_example(cfg):
     attn = 2 * d * a["num_attention_heads"] * hd \
         + 2 * d * a["num_key_value_heads"] * hd
     experts = d * a["router_experts"] \
-        + _held_share(a) * 2 * d * a["moe_intermediate_size"] \
         + 2 * d * a["moe_shared_expert_intermediate_size"]
     return (T * (_count(cfg, "M") * mamba + _count(cfg, "*") * attn
                  + _count(cfg, "E") * experts + d * a["vocab_size"])
-            + ssm_scan_macs(cfg) + _count(cfg, "*") * attention_macs(cfg))
+            + ssm_scan_macs(cfg) + attention_macs(cfg)
+            + expert_gmm_macs(cfg))
 
 
 def tower_sizes(cfg):

@@ -208,22 +208,41 @@ def _mean_keys(T, window):
     return (window * (window + 1) / 2 + (T - window) * window) / T
 
 
-def attention_macs(cfg, kind):
-    """Multiply-adds of one example's scores and values in one layer of
-    `kind` (forward)."""
-    a = _a(cfg)
+def _layer_attention_macs(a, kind):
     T = a["seq_len"]
     keys = _mean_keys(T, a["sliding_window_size"] if kind else None)
     return T * 2 * a["num_attention_heads"] * a["head_dim"] * keys
 
 
-def expert_gmm_macs(cfg):
-    """Multiply-adds of one example's held experts in one layer (forward):
-    the expected held share of the experts_per_token choices."""
+def attention_macs(cfg):
+    """Multiply-adds of one example's scores and values in all the
+    attention layers of the cut, each of its own kind (forward; the masked
+    part is not counted)."""
     a = _a(cfg)
-    share = a["experts_per_token"] * a["experts_held"] / a["router_experts"]
-    return a["seq_len"] * share * 3 * a["hidden_size"] \
-        * a["moe_ffn_hidden_size"]
+    return sum(_layer_attention_macs(a, kind) for kind in a["layer_kinds"])
+
+
+def _held_share(a):
+    return a["experts_per_token"] * a["experts_held"] / a["router_experts"]
+
+
+def expert_gmm_macs(cfg):
+    """Multiply-adds of one example's held experts in all the layers
+    (forward): the expected held share of the experts_per_token choices."""
+    a = _a(cfg)
+    return len(a["layer_kinds"]) * a["seq_len"] * _held_share(a) \
+        * 3 * a["hidden_size"] * a["moe_ffn_hidden_size"]
+
+
+def route_rows(cfg):
+    """(rows, held, experts): the (token, choice) assignments of one chunk
+    the expert layer routes at a time, the experts this chip holds and the
+    experts the router chooses among — what names the route's operations
+    in a trace."""
+    a = _a(cfg)
+    tokens = cfg["trainer"]["global_batch_size"] * a["seq_len"]
+    return (min(a["expert_chunk_tokens"], tokens) * a["experts_per_token"],
+            a["experts_held"], a["router_experts"])
 
 
 def macs_per_example(cfg):
@@ -232,9 +251,8 @@ def macs_per_example(cfg):
     proj = 2 * d * a["num_attention_heads"] * hd \
         + 2 * d * a["num_key_value_heads"] * hd
     per_layer = a["seq_len"] * (proj + d * a["router_experts"])
-    return (sum(per_layer + attention_macs(cfg, k) + expert_gmm_macs(cfg)
-                for k in a["layer_kinds"])
-            + a["seq_len"] * d * a["vocab_size"])
+    return (len(a["layer_kinds"]) * per_layer + attention_macs(cfg)
+            + expert_gmm_macs(cfg) + a["seq_len"] * d * a["vocab_size"])
 
 
 def tower_sizes(cfg):
@@ -250,8 +268,7 @@ def tower_sizes(cfg):
                     + a["experts_held"] * 3 * d * f)
     n_params = len(a["layer_kinds"]) * layer_params + d \
         + d * a["vocab_size"]
-    share = a["experts_per_token"] * a["experts_held"] / a["router_experts"]
     per_token = len(a["layer_kinds"]) * (
         6 * d + 2 * nh * hd + 2 * nkv * hd + a["router_experts"]
-        + share * 3 * f) + a["vocab_size"]
+        + _held_share(a) * 3 * f) + a["vocab_size"]
     return n_params, a["seq_len"] * per_token

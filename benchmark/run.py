@@ -18,11 +18,19 @@ each, ``benchmark/metrics/<metric>.py``) and its model's plain reference
 The window drives the user's day loop per pass — ``SlotDataset`` load of
 slot-text files, ``BoxPS.begin_pass``, ``Trainer.train_pass``,
 ``BoxPS.end_pass`` — over two sets of pass files A, B, A, B, ... made from
-``--seed`` (two draws of the traffic mix's public distribution). Set-up (``setup_s``: process start to window start) writes
-the files, builds the trainer once and runs one full warm-up cycle. A
-pass starts while the window has time left; the window ends when the last
-started pass's ``end_pass`` has returned and the table and dense state
-are ready on the device. Every rate divides by that whole time.
+``--seed`` (two draws of the traffic mix's public distribution; where the
+mix states a ``pool_seed`` the draws are that seed's in every run and
+``--seed`` orders each pass's examples). The
+initial state — the dense weights, a new key's row, the trainer's seed —
+comes from the configuration's ``weights_seed`` where its file has one
+(training continues from one checkpoint, whatever the day's traffic) and
+from ``--seed`` where it has none. Set-up (``setup_s``: process start to
+window start) writes the files, builds the trainer once and runs one full
+warm-up cycle. A pass starts while the window has time left and fewer
+than the mix's ``max_passes_per_window`` have started (``window_has_room``;
+no such key: time alone); the window ends when the last started pass's
+``end_pass`` has returned and the table and dense state are ready on the
+device. Every rate divides by that whole time.
 
 ``correct`` compares the first steps of the first pass with the plain
 reference (``correct.py``), and, once the window has closed, the show and
@@ -108,6 +116,13 @@ def rehearsal_sizes(cfg: dict, mix: dict) -> tuple[dict, dict]:
     return cfg, {**mix, **REHEARSAL, **mix.get("rehearsal", {})}
 
 
+def window_has_room(elapsed: float, seconds: float, started: int,
+                    cap: int | None) -> bool:
+    """The window's rule: another pass starts while the window has time
+    left and fewer than `cap` have started; no cap, time alone."""
+    return elapsed < seconds and (cap is None or started < cap)
+
+
 def metrics_of(bench: dict, cell: dict, kind: str) -> list[dict]:
     """The cell's metrics of one kind: those that name it, or name none."""
     return [m for m in bench[kind]
@@ -184,9 +199,13 @@ def run(args) -> tuple[int, dict | None]:
     n_sparse, dense_dim = datagen.slot_counts(cfg)
     hot = datagen.slot_hotness(mix, n_sparse)
     n_follow = FOLLOWED_STEPS
+    # what is initial state follows the configuration's seed where its
+    # file has one; what is traffic always follows the run's
+    weights_seed = int(cfg.get("weights_seed", args.seed))
+    cap = mix.get("max_passes_per_window")
     say(phase="start", workload=cell["name"], seed=args.seed,
-        seconds=args.seconds, trace=args.trace, rehearse=args.rehearse,
-        jax=jax.__version__, compile_cache=cache,
+        weights_seed=weights_seed, seconds=args.seconds, trace=args.trace,
+        rehearse=args.rehearse, jax=jax.__version__, compile_cache=cache,
         device={"platform": devs[0].platform, "kind": devs[0].device_kind,
                 "count": len(devs)})
 
@@ -214,8 +233,8 @@ def run(args) -> tuple[int, dict | None]:
                 mix, n_sparse, batch)),
             bytes_written=sum(os.path.getsize(f) for fl in files for f in fl))
 
-        params0 = ref_steps.initial_params(cfg, args.seed)
-        system = sut.System(cfg, hot, args.seed, dense_params=params0,
+        params0 = ref_steps.initial_params(cfg, weights_seed)
+        system = sut.System(cfg, hot, weights_seed, dense_params=params0,
                             n_devices=len(devs))
         keys = np.unique(np.concatenate(
             [b["ids"][b["mask"]] for b in followed]))
@@ -257,7 +276,8 @@ def run(args) -> tuple[int, dict | None]:
             finally:
                 jax.profiler.stop_trace()
         else:
-            while time.perf_counter() - t0 < args.seconds:
+            while window_has_room(time.perf_counter() - t0, args.seconds,
+                                  k - n_warm, cap):
                 system.run_pass(files[k % 2],
                                 files[(k + 1) % 2] if overlap else None)
                 k += 1
@@ -274,7 +294,7 @@ def run(args) -> tuple[int, dict | None]:
                 h2d_bytes=rec["boundary_h2d_bytes"], timers=rec["timers"],
                 loss_last=rec["losses"][-1])
         say(phase="window", seconds=window_s, passes=len(measured),
-            examples_per_pass=written[0],
+            max_passes_per_window=cap, examples_per_pass=written[0],
             passes_at=[[p["t0"] - t0, p["seconds"]] for p in measured],
             compiled_in_window=in_window)
         if in_window["compilations"] - in_window["cache_hits"] > 0 \
@@ -313,7 +333,7 @@ def run(args) -> tuple[int, dict | None]:
         system = None
         gc.collect()
         t = time.perf_counter()
-        ref = ref_steps.follow(cfg, params0, followed, hot, args.seed)
+        ref = ref_steps.follow(cfg, params0, followed, hot, weights_seed)
         numbers, notes = correct.compare(got, ref,
                                          int(cfg["embedding"]["dim"]))
         numbers["ingest_mismatch"] = correct.ingest_mismatch(

@@ -5,7 +5,8 @@ Everything the benchmark touches of ``paddlebox_tpu`` is in this file:
 the public entry points of the loop (``SlotDataset``, ``BoxPS``,
 ``Trainer.train_pass``), the counters the per-layer metrics read
 (``Trainer.timers``, ``feed_mgr.last_*``), ``Trainer.engines()`` and
-``Trainer.block_until_ready()``, and — for the comparison that decides
+``Trainer.block_until_ready()``, the expert layer's ``route_rungs`` (the
+shapes a trace shows its route by), and — for the comparison that decides
 ``correct`` — the trainer's own mid-pass snapshot hook, in whose place
 ``StepProbe`` stands.
 """
@@ -61,6 +62,16 @@ def adam_first_moment(opt_state):
         if hasattr(part, "mu"):
             return part.mu
     raise ValueError("the dense optimizer's state holds no first moment")
+
+
+def route_rungs(rows: int, held: int, n_experts: int) -> tuple[int, ...]:
+    """The static row bounds the program may give the sorted copy of a
+    chunk of `rows` (token, choice) assignments where `held` of
+    `n_experts` experts are this chip's; the whole chunk alone in a
+    program whose expert layer has no such ladder."""
+    from paddlebox_tpu.parallel import expert
+    rungs = getattr(expert, "route_rungs", None)
+    return tuple(rungs(rows, held, n_experts)) if rungs else (rows,)
 
 
 def build_schema(cfg: dict, hotness: np.ndarray):

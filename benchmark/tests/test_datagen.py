@@ -86,3 +86,28 @@ def test_same_seed_same_traffic_other_seed_other_traffic():
     assert np.array_equal(a1.labels, a2.labels)
     assert np.array_equal(b1.dense_milli, b2.dense_milli)
     assert not np.array_equal(a1.ids, a3.ids)
+
+
+def test_a_pool_seed_gives_every_seed_the_same_examples_in_another_order():
+    mix = {**MIXES["multihot"], "pool_seed": 5}
+    runs = [datagen.make_passes(mix, 26, DENSE, BATCH, 2 ** 31 + n)
+            for n in (7, 7, 8)]
+    drawn = datagen.make_passes(MIXES["multihot"], 26, DENSE, BATCH, 5)
+
+    def rows(p):        # each example whole: ids, lens, dense and label
+        return np.concatenate([p.ids, p.lens, p.dense_milli,
+                               p.labels[:, None]], axis=1)
+
+    for k in range(2):
+        same, again, other = (rows(r[k]) for r in runs)
+        assert np.array_equal(same, again)
+        assert not np.array_equal(same, other)
+        pool = rows(drawn[k])
+        assert not np.array_equal(same, pool)
+        for got in (same, other):       # the pool's examples, every one
+            assert np.array_equal(got[np.lexsort(got.T[::-1])],
+                                  pool[np.lexsort(pool.T[::-1])])
+    # A and B are ordered apart
+    assert not np.array_equal(
+        np.argsort(runs[0][0].ids[:, 0], kind="stable"),
+        np.argsort(runs[0][1].ids[:, 0], kind="stable"))

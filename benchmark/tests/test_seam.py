@@ -129,6 +129,86 @@ def test_a_slots_further_keys_reach_the_programs_slot():
         sut.build_schema(cfg, np.array([32]))
 
 
+# ---- the window's rule, and which seed makes what ------------------------
+
+@pytest.mark.parametrize("elapsed,seconds,started,cap,another", [
+    (0.0, 50.0, 0, None, True),      # the first pass always starts
+    (49.9, 50.0, 4, None, True),     # no cap: time alone, as before
+    (50.0, 50.0, 2, None, False),
+    (39.0, 50.0, 2, 3, True),        # time left and the cap not reached
+    (39.0, 50.0, 3, 3, False),       # time left, the cap reached
+    (50.8, 50.0, 2, 3, False),       # the cap not reached, no time left
+    (0.0, 1.0, 0, 3, True),          # --seconds shorter than one pass:
+    (17.0, 1.0, 1, 3, False),        # one pass, whatever the cap
+])
+def test_a_pass_starts_while_the_window_has_time_and_the_cap_has_room(
+        elapsed, seconds, started, cap, another):
+    from benchmark import run
+    assert run.window_has_room(elapsed, seconds, started, cap) is another
+
+
+class _Stop(Exception):
+    pass
+
+
+def _handed_to_the_program(monkeypatch, cell, seeds):
+    """What ``run.py`` makes from the seeds and hands over, a run a seed,
+    up to the moment the program would be built: the pass files' ids, the
+    seed and the dense weights the program gets, the rows the reference
+    gives new keys."""
+    from benchmark import datagen, run, sut
+    from benchmark.reference import steps
+    runs = []
+    make = datagen.make_passes
+
+    def passes(*a):
+        made = make(*a)
+        runs.append({"ids": [p.ids.copy() for p in made]})
+        return made
+
+    def system(cfg, hot, seed, dense_params=None, **_kw):
+        runs[-1].update(cfg=cfg, seed=seed, params0=dense_params)
+        raise _Stop
+
+    monkeypatch.setattr(datagen, "make_passes", passes)
+    monkeypatch.setattr(sut, "System", system)
+    for seed in seeds:
+        with pytest.raises(_Stop):
+            run.run(argparse.Namespace(
+                workload=cell, seed=seed, seconds=0.2, trace=0,
+                rehearse=True, keep_trace=None, waiting=None))
+    keys = (np.uint64(1) << np.uint64(27)) + np.arange(1, 65, dtype=np.uint64)
+    for seen in runs:
+        seen["rows"] = steps.init_rows(keys, seen["cfg"]["embedding"],
+                                       seen["seed"])
+    return runs
+
+
+@pytest.mark.parametrize("cell,own_weights", [
+    ("smallthinker_21b_ep4.seq8k", True),
+    ("nemotron3_nano_ep16.seq4k", True),
+    ("dlrm_mlperf.onehot", False)])
+def test_the_run_seeds_the_traffic_and_the_configuration_the_weights(
+        monkeypatch, cell, own_weights):
+    """Under two ``--seed``s a configuration that states ``weights_seed``
+    starts from the same dense weights and rows over different pass
+    files; one that states none follows ``--seed`` in both."""
+    import jax
+    a, b = _handed_to_the_program(monkeypatch, cell,
+                                  (2 ** 31 + 808, 2 ** 31 + 809))
+    assert ("weights_seed" in a["cfg"]) is own_weights
+    assert not all(np.array_equal(x, y) for x, y in zip(a["ids"], b["ids"]))
+    same = (a["seed"] == b["seed"]
+            and all(np.array_equal(x, y) for x, y in zip(
+                jax.tree.leaves(a["params0"]), jax.tree.leaves(b["params0"]))))
+    assert same is own_weights
+    assert np.array_equal(a["rows"], b["rows"]) is own_weights
+    if own_weights:
+        assert a["seed"] == a["cfg"]["weights_seed"]
+    else:
+        assert (a["seed"], b["seed"]) == (2 ** 31 + 808, 2 ** 31 + 809)
+
+
 # ---- (b) ordered tokens and a loss of the configuration's own -----------
 
 @pytest.fixture(scope="module")

@@ -10,8 +10,7 @@ multiply-add is two operations, so a step is 6 * batch * macs.
 Bytes: what the step's sparse work must move through HBM whatever
 implements it — the pull reads ``pull_width`` floats per token, the push
 reads and writes ``row_width`` floats per unique row — plus the tower's
-weights and activations once (arithmetic of ``bench.py``'s self-audit and
-``step_probe.push_floor_analysis``, reduced to what no engine can avoid).
+weights and activations once: what no engine can avoid.
 
 The peaks are in ``peaks.json`` with their source; a device that is not
 there is an error, never a default.
