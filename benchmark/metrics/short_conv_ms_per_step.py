@@ -1,0 +1,12 @@
+"""Layer kernels: milliseconds a training step spends in the gated short
+convolution's kernels, forward, recomputed forward and backward
+(``pbtpu_short_conv_fwd`` / ``pbtpu_short_conv_bwd``,
+``ops/short_conv.py``), from the trace's ``XLA Ops`` line over the steps of
+the traced pass. None where no such kernel ran."""
+
+from benchmark.metrics.attention_ms_per_step import kernel_seconds
+
+
+def read(record):
+    s = kernel_seconds(record, "pbtpu_short_conv")
+    return None if s is None else s * 1e3

@@ -72,7 +72,7 @@ from paddlebox_tpu.embedding import quant, tiering
 from paddlebox_tpu.embedding.store import HostEmbeddingStore
 from paddlebox_tpu.embedding.working_set import (PassWorkingSet, bucket_size,
                                                  fetch_rows, plane_layout,
-                                                 transfer_bytes,
+                                                 shard_rows, transfer_bytes,
                                                  _put_compressed)
 from paddlebox_tpu.monitor import context as mon_ctx
 from paddlebox_tpu.monitor import counter_add as stat_add
@@ -746,7 +746,7 @@ class FeedPassManager:
         pos = staged.pos_prev
         n_shards = self._n_shards()
         need = len(keys) + 1
-        rps = bucket_size(max(self.min_rows_per_shard, -(-need // n_shards)))
+        rps = shard_rows(cfg, need, n_shards, self.min_rows_per_shard)
         n_pad = rps * n_shards
         src = np.zeros(n_pad, np.int32)
         is_fresh = np.zeros(n_pad, bool)

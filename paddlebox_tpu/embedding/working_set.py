@@ -150,6 +150,32 @@ def bucket_size(x: int) -> int:
     return -(-int(x) // step) * step
 
 
+def shard_rows(cfg, need: int, n_shards: int, min_rows_per_shard: int,
+               bucket_rows: bool = True) -> int:
+    """Rows a shard of a pass's table holds for `need` rows in all (the
+    null row counted): the one rule of a full build and of an incremental
+    boundary, so that a pass set meets the same table shape however its
+    table came to be (a first build of another size than the boundaries
+    that follow it is a step program and a boundary program compiled
+    after the warm-up)."""
+    rps = max(min_rows_per_shard, -(-need // n_shards))
+    if bucket_rows:
+        rps = bucket_size(rps)
+    # align shard rows to the super-block the binned-push geometry
+    # would target for a table of THIS SHARD's size (the kernel runs
+    # per shard on rps rows, so the alignment target is rps, not the
+    # global row count) — big tables get big-block divisibility,
+    # small ones keep the cheap 4096 alignment; the waste is zero
+    # rows that are never indexed. Quantized storage rides the same
+    # merge accumulator (binned_merge_acc), so it gets the same
+    # alignment — _bp_lanes is the shared source of truth.
+    if rps >= 4096:
+        from paddlebox_tpu.ops.pallas_kernels import bp_row_alignment
+        align = bp_row_alignment(cfg, rps)
+        rps = -(-rps // align) * align
+    return rps
+
+
 @functools.lru_cache(maxsize=8)  # bounded: each entry retains its Mesh
 def _combine_jit(lo: int, hi: int, sharding):
     def combine(rest, emb):
@@ -395,21 +421,8 @@ class PassWorkingSet:
                 else store.lookup_or_init(keys))
         n_shards = mesh_lib.num_shards(mesh) if mesh is not None else 1
         need = len(keys) + 1                       # +1 for the null row
-        rps = max(min_rows_per_shard, -(-need // n_shards))
-        if bucket_rows:
-            rps = bucket_size(rps)
-        # align shard rows to the super-block the binned-push geometry
-        # would target for a table of THIS SHARD's size (the kernel runs
-        # per shard on rps rows, so the alignment target is rps, not the
-        # global row count) — big tables get big-block divisibility,
-        # small ones keep the cheap 4096 alignment; the waste is zero
-        # rows that are never indexed. Quantized storage rides the same
-        # merge accumulator (binned_merge_acc), so it gets the same
-        # alignment — _bp_lanes is the shared source of truth.
-        if rps >= 4096:
-            from paddlebox_tpu.ops.pallas_kernels import bp_row_alignment
-            align = bp_row_alignment(cfg, rps)
-            rps = -(-rps // align) * align
+        rps = shard_rows(cfg, need, n_shards, min_rows_per_shard,
+                         bucket_rows)
         n_pad = rps * n_shards
         host_table = np.zeros((n_pad, cfg.row_width), dtype=np.float32)
         host_table[1:1 + len(keys)] = rows

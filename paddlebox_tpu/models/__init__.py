@@ -7,9 +7,10 @@ from paddlebox_tpu.models.mmoe import MMoEModel  # noqa: F401
 from paddlebox_tpu.models.pv_rank import PVRankModel  # noqa: F401
 from paddlebox_tpu.models.smallthinker import SmallThinkerModel  # noqa: F401
 from paddlebox_tpu.models.nemotron_h import NemotronHModel  # noqa: F401
+from paddlebox_tpu.models.lfm2_moe import Lfm2MoeModel  # noqa: F401
 
 MODEL_REGISTRY = {
     m.name: m for m in (DNNCTRModel, DeepFMModel, WideDeepModel,
                         DCNv2Model, DLRMModel, MMoEModel, PVRankModel,
-                        SmallThinkerModel, NemotronHModel)
+                        SmallThinkerModel, NemotronHModel, Lfm2MoeModel)
 }

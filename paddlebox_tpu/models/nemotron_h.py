@@ -60,8 +60,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from paddlebox_tpu.models.nn import next_token_loss, rms_norm, vocabulary_ids
-from paddlebox_tpu.ops.flash_attention import attention
+from paddlebox_tpu.models.nn import (causal_attention, next_token_loss,
+                                     rms_norm, vocabulary_ids)
 from paddlebox_tpu.ops.ssm_scan import ssm_scan
 from paddlebox_tpu.parallel.expert import (held_expert_ffn,
                                            route_sigmoid_top_k)
@@ -227,12 +227,7 @@ class NemotronHModel:
         q, k, v = (heads(u @ p["wq"], self.heads),
                    heads(u @ p["wk"], self.kv_heads),
                    heads(u @ p["wv"], self.kv_heads))
-        # as SmallThinker's full layers: bfloat16 operands on the chip
-        cd = jnp.bfloat16 if jax.default_backend() == "tpu" else u.dtype
-        o = attention(*(jnp.swapaxes(t, 1, 2).astype(cd) for t in (q, k, v)),
-                      window=None)
-        return jnp.swapaxes(o, 1, 2).reshape(B, T, -1).astype(u.dtype) \
-            @ p["wo"]
+        return causal_attention(q, k, v) @ p["wo"]
 
     def _experts(self, p, u):
         """(the layer's output (B, T, d), (assignments per held expert,
@@ -241,10 +236,11 @@ class NemotronHModel:
         m = u.reshape(B * T, d)
         logits = jnp.dot(m, p["router"], precision=jax.lax.Precision.HIGHEST)
         weights, experts = route_sigmoid_top_k(logits, p["b_corr"],
-                                               self.top_k, self.scale)
+                                               self.top_k, self.scale, 1e-20)
         y, load, took = held_expert_ffn(
             m, weights, experts, None, p["w_up"], p["w_down"], self.held,
-            self.router_experts, chunk_tokens=self.expert_chunk_tokens)
+            self.router_experts, chunk_tokens=self.expert_chunk_tokens,
+            body="relu2")
         shared = _relu2(m @ p["shared_up"]) @ p["shared_down"]
         return (y + shared).reshape(B, T, d), (load, took)
 

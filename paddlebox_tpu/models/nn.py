@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddlebox_tpu.ops.flash_attention import attention
+
 
 def dense_init(key, in_dim: int, out_dim: int, scale: str = "glorot"):
     if scale == "glorot":
@@ -57,6 +59,20 @@ def mlp_apply(layers, x: jnp.ndarray, final_activation: str | None = None,
 def rms_norm(x, weight, eps: float):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) \
         * weight
+
+
+def causal_attention(q, k, v, window=None):
+    """Causal grouped-query attention over token-major heads: q (B, T,
+    heads, head_dim), k, v (B, T, kv_heads, head_dim) -> (B, T, heads *
+    head_dim) in q's dtype, by ``ops/flash_attention.attention`` (a window
+    of ``window`` positions where given). On the chip the kernel's
+    products take bfloat16 operands (the device's default precision for a
+    float32 product) and float32 sums."""
+    B, T = q.shape[:2]
+    cd = jnp.bfloat16 if jax.default_backend() == "tpu" else q.dtype
+    o = attention(*(jnp.swapaxes(t, 1, 2).astype(cd) for t in (q, k, v)),
+                  window=window)
+    return jnp.swapaxes(o, 1, 2).reshape(B, T, -1).astype(q.dtype)
 
 
 def next_token_loss(params, h, local_ids, mask, eps: float, head_chunk: int):

@@ -39,8 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from paddlebox_tpu.models.nn import next_token_loss, rms_norm, vocabulary_ids
-from paddlebox_tpu.ops.flash_attention import attention
+from paddlebox_tpu.models.nn import (causal_attention, next_token_loss,
+                                     rms_norm, vocabulary_ids)
 from paddlebox_tpu.parallel.expert import held_expert_ffn, route_top_k
 
 
@@ -136,18 +136,13 @@ class SmallThinkerModel:
                    heads(a @ p["wv"], self.kv_heads))
         if kind:
             q, k = rope(q, self.theta), rope(k, self.theta)
-        # on the chip the kernel's products take bfloat16 operands (the
-        # device's default precision for a float32 product), float32 sums
-        cd = jnp.bfloat16 if jax.default_backend() == "tpu" else h.dtype
-        o = attention(*(jnp.swapaxes(t, 1, 2).astype(cd) for t in (q, k, v)),
-                      window=self.window if kind else None)
-        o = jnp.swapaxes(o, 1, 2).reshape(B, T, -1).astype(h.dtype)
+        o = causal_attention(q, k, v, self.window if kind else None)
         h = h + o @ p["wo"]
         m = rms_norm(h, p["norm2"], self.eps).reshape(B * T, d)
         y, load, took = held_expert_ffn(
             m, probs, experts, p["w_gate"], p["w_up"], p["w_down"],
             self.held, self.router_experts,
-            chunk_tokens=self.expert_chunk_tokens)
+            chunk_tokens=self.expert_chunk_tokens, body="reglu")
         return h + y.reshape(B, T, d), (load, took)
 
     def example_losses(self, params, pulled, mask, local_ids):

@@ -171,7 +171,10 @@ SPAN_NAMES: tuple[str, ...] = (
 # counters at the pass's close. ``*_max`` / ``*_min``: the largest /
 # smallest step of the pass (a gauge); the others are sums (counters).
 MODEL_STAT_NAMES: tuple[str, ...] = (
-    # parallel/expert.py share layer: (token, choice) assignments routed,
+    # parallel/expert.py share layer (SmallThinker, Nemotron-H and LFM2-MoE
+    # report these five; LFM2-MoE reports no other: its short convolution
+    # has nothing to count that is not a constant of the shapes):
+    # (token, choice) assignments routed,
     # those that fell on an expert this chip holds, and a step's busiest
     # held expert (imbalance); the rows of the sorted copies the chunks
     # took (pad share = 1 - held_assignments / route_rows) and the chunks
@@ -213,6 +216,31 @@ KEY_SET_COUNTER_NAMES: tuple[str, ...] = (
     "dataset.key_runs",
     "dataset.key_set_reused",
     "dataset.key_set_rebuilt",
+)
+
+# the Pallas kernels' names, as a device trace's ``XLA Ops`` line shows
+# them (a recomputed forward also as ``jvp_<name>_``): the only names a
+# device operation carries (``jax.named_scope`` reaches no such event), so
+# the benchmark's kernel readers find a kernel's seconds by them
+# (benchmark/metrics/*_ms_per_step.py)
+KERNEL_NAMES: tuple[str, ...] = (
+    # ops/pallas_kernels.py: the sparse engines a resolver may select
+    "pbtpu_gather_pool",
+    "pbtpu_binned_merge_acc",
+    "pbtpu_merge_update",
+    "pbtpu_scatter_accumulate",
+    # ops/flash_attention.py: blocked causal attention (head sizes of whole
+    # lane tiles, and of 64)
+    "pbtpu_attention_fwd",
+    "pbtpu_attention_dq",
+    "pbtpu_attention_dkv",
+    # ops/ssm_scan.py: the Mamba-2 scan in chunks
+    "pbtpu_ssm_fwd",
+    "pbtpu_ssm_bwd",
+    # ops/short_conv.py: the gated short convolution between an LFM2
+    # mixer's projections
+    "pbtpu_short_conv_fwd",
+    "pbtpu_short_conv_bwd",
 )
 
 ALL_NAMES: frozenset = frozenset(EVENT_NAMES) | frozenset(SPAN_NAMES)

@@ -23,9 +23,13 @@ interpreter (tests: tiny shapes only) — except inside a ``check_vma``
 shard_map, where the interpreter cannot run (``pallas_kernels.
 merge_update`` has the reason): a trainer on a CPU mesh takes the plain
 ``attention_reference``. On a TPU the geometry must be lane-aligned (``T``
-a multiple of the block, blocks and ``D`` multiples of 128); where it is
-not, ``attention`` is the reference too — never a kernel under another
-name.
+a multiple of the block, blocks multiples of 128, ``D`` a multiple of 128
+or 64); where it is not, ``attention`` is the reference too — never a
+kernel under another name. A head of 64 channels is half a lane tile: its
+blocks take the whole head size as their last dimension (``(block, 64)``
+of an array whose last dimension is 64), so the same three kernels run it
+with the products' contraction (scores) or output (values, ``dq``, ``dk``,
+``dv``) half as wide as the MXU.
 """
 
 from __future__ import annotations
@@ -38,9 +42,10 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_LANES = 128
+LANES = 128
+_HALF_TILE_HEADS = (64,)    # head sizes under a lane tile the chip takes
 _MASK = -0.7 * float(jnp.finfo(jnp.float32).max)   # exp(_MASK - m) == 0
-_NT = (((1,), (1,)), ((), ()))                     # a @ b.T
+NT = (((1,), (1,)), ((), ()))                      # a @ b.T
 
 
 def attention_reference(q, k, v, *, window: int | None = None,
@@ -67,7 +72,8 @@ def block_geometry(T: int, D: int, block: int = 512):
     b = min(block, T)
     if T % b:
         return None
-    if jax.default_backend() == "tpu" and (b % _LANES or D % _LANES):
+    if jax.default_backend() == "tpu" and (
+            b % LANES or (D % LANES and D not in _HALF_TILE_HEADS)):
         return None
     return b, b
 
@@ -100,7 +106,7 @@ def _tile_mask(i, j, bq: int, bk: int, window):
 
 def _lanes(x, n: int):
     """(rows, 128) lane-replicated statistics broadcast to n columns."""
-    return x[:, :1] if n % _LANES else jnp.tile(x, (1, n // _LANES))
+    return x[:, :1] if n % LANES else jnp.tile(x, (1, n // LANES))
 
 
 # -- forward ---------------------------------------------------------------
@@ -119,7 +125,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
     @pl.when((j >= lo) & (j <= hi))
     def _tile():
         q, k, v = q_ref[...], k_ref[...], v_ref[...]
-        s = lax.dot_general(q, k, _NT,
+        s = lax.dot_general(q, k, NT,
                             preferred_element_type=jnp.float32) * scale
         s = jnp.where(_tile_mask(i, j, bq, bk, window), s, _MASK)
         m_prev, l_prev = m_s[...], l_s[...]
@@ -157,7 +163,7 @@ def _specs(bq, bk, D, G, window, *, q_major: bool, nq: int):
 
     return (pl.BlockSpec((None, None, bq, D), q_index),
             pl.BlockSpec((None, None, bk, D), kv_index),
-            pl.BlockSpec((None, None, bq, _LANES), q_index))
+            pl.BlockSpec((None, None, bq, LANES), q_index))
 
 
 def _params(interpret: bool):
@@ -168,7 +174,7 @@ def _params(interpret: bool):
                              "arbitrary"))}
 
 
-def _out(shape, dtype, like):
+def out_struct(shape, dtype, like):
     """An output's type: inside shard_map it varies over the mesh axes
     its operands do."""
     return jax.ShapeDtypeStruct(
@@ -188,10 +194,10 @@ def _forward(q, k, v, window, scale, blocks, interpret):
         grid=(B, H, nq, nk),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, row_spec],
-        out_shape=[_out(q.shape, q.dtype, q),
-                   _out((B, H, T, _LANES), jnp.float32, q)],
-        scratch_shapes=[pltpu.VMEM((bq, _LANES), jnp.float32),
-                        pltpu.VMEM((bq, _LANES), jnp.float32),
+        out_shape=[out_struct(q.shape, q.dtype, q),
+                   out_struct((B, H, T, LANES), jnp.float32, q)],
+        scratch_shapes=[pltpu.VMEM((bq, LANES), jnp.float32),
+                        pltpu.VMEM((bq, LANES), jnp.float32),
                         pltpu.VMEM((bq, D), jnp.float32)],
         name="pbtpu_attention_fwd", **_params(interpret),
     )(q, k, v)
@@ -200,7 +206,7 @@ def _forward(q, k, v, window, scale, blocks, interpret):
 # -- backward --------------------------------------------------------------
 
 def _probs(q, k, lse, i, j, bq, bk, window, scale):
-    s = lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32) * scale
+    s = lax.dot_general(q, k, NT, preferred_element_type=jnp.float32) * scale
     p = jnp.exp(s - _lanes(lse, bk))
     return jnp.where(_tile_mask(i, j, bq, bk, window), p, 0.0)
 
@@ -218,7 +224,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, acc_s,
     def _tile():
         k, v, do = k_ref[...], v_ref[...], do_ref[...]
         p = _probs(q_ref[...], k, lse_ref[...], i, j, bq, bk, window, scale)
-        dp = lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
+        dp = lax.dot_general(do, v, NT, preferred_element_type=jnp.float32)
         ds = p * (dp - _lanes(dl_ref[...], bk)) * scale
         acc_s[...] += lax.dot(ds.astype(k.dtype), k,
                               preferred_element_type=jnp.float32)
@@ -244,7 +250,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref, dv_ref,
         p = _probs(q, k_ref[...], lse_ref[...], i, j, bq, bk, window, scale)
         dv_s[...] += lax.dot(p.T.astype(do.dtype), do,
                              preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
+        dp = lax.dot_general(do, v, NT, preferred_element_type=jnp.float32)
         ds = p * (dp - _lanes(dl_ref[...], bk)) * scale
         dk_s[...] += lax.dot(ds.T.astype(q.dtype), q,
                              preferred_element_type=jnp.float32)
@@ -263,7 +269,7 @@ def _backward(q, k, v, o, lse, do, window, scale, blocks, interpret):
     nq, nk = T // bq, T // bk
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1,
                     keepdims=True)
-    delta = jnp.broadcast_to(delta, (B, H, T, _LANES))
+    delta = jnp.broadcast_to(delta, (B, H, T, LANES))
     q_spec, kv_spec, row_spec = _specs(bq, bk, D, G, window, q_major=True,
                                        nq=nq)
     dq = pl.pallas_call(
@@ -272,7 +278,7 @@ def _backward(q, k, v, o, lse, do, window, scale, blocks, interpret):
         grid=(B, H, nq, nk),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
-        out_shape=_out(q.shape, q.dtype, q),
+        out_shape=out_struct(q.shape, q.dtype, q),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         name="pbtpu_attention_dq", **_params(interpret),
     )(q, k, v, do, lse, delta)
@@ -288,7 +294,7 @@ def _backward(q, k, v, o, lse, do, window, scale, blocks, interpret):
         grid=(B, H, nk, nq),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[out_spec, out_spec],
-        out_shape=[_out((B, H, T, D), jnp.float32, q)] * 2,
+        out_shape=[out_struct((B, H, T, D), jnp.float32, q)] * 2,
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32)] * 2,
         name="pbtpu_attention_dkv", **_params(interpret),
     )(q, k, v, do, lse, delta)

@@ -53,7 +53,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddlebox_tpu.ops.flash_attention import _LANES, _NT, _out
+from paddlebox_tpu.ops.flash_attention import LANES, NT, out_struct
 
 _FAR = -1e30        # exp(_FAR) == 0: the masked half of a chunk's decays
 
@@ -85,11 +85,11 @@ def ssm_scan_reference(x, dt, A_log, Bm, Cm, D):
 def scan_geometry(chunk: int, heads_per_group: int, P: int, N: int):
     """(heads a lane tile, lanes a tile) for the kernels, or None where
     the chip's tile layout refuses the shape (``ssm_scan`` then raises)."""
-    hp = 1 if P >= _LANES else min(heads_per_group, _LANES // P)
+    hp = 1 if P >= LANES else min(heads_per_group, LANES // P)
     while heads_per_group % hp:
         hp -= 1
     if jax.default_backend() == "tpu" and (
-            chunk % _LANES or N % _LANES or (hp * P) % _LANES):
+            chunk % LANES or N % LANES or (hp * P) % LANES):
         return None
     return hp, hp * P
 
@@ -101,7 +101,7 @@ def _dot(a, b):
 
 
 def _dot_nt(a, b):
-    return lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+    return lax.dot_general(a, b, NT, preferred_element_type=jnp.float32)
 
 
 class _Chunk:
@@ -220,8 +220,8 @@ def _forward(x, dtc, cumc, cumr, bm, cm, geom, interpret):
         grid=(B, G, nc),
         in_specs=[x_spec, col, col, row, n_spec, n_spec],
         out_specs=[x_spec, st],
-        out_shape=[_out(x.shape, x.dtype, x),
-                   _out((B, nc, HP, N), jnp.float32, x)],
+        out_shape=[out_struct(x.shape, x.dtype, x),
+                   out_struct((B, nc, HP, N), jnp.float32, x)],
         scratch_shapes=[pltpu.VMEM((W, N), jnp.float32)],
         name="pbtpu_ssm_fwd", **_params(interpret),
     )(x, dtc, cumc, cumr, bm, cm)
@@ -318,11 +318,12 @@ def _backward(x, dtc, cumc, cumr, bm, cm, states, dy, geom, interpret):
         grid=(B, G, nc),
         in_specs=[x_spec, col, col, row, n_spec, n_spec, st, x_spec],
         out_specs=[x_spec, col, col, row, n_spec, n_spec],
-        out_shape=[_out(x.shape, x.dtype, x),
-                   _out(dtc.shape, jnp.float32, x),
-                   _out(cumc.shape, jnp.float32, x),
-                   _out(cumr.shape, jnp.float32, x),
-                   _out(bm.shape, bm.dtype, x), _out(cm.shape, cm.dtype, x)],
+        out_shape=[out_struct(x.shape, x.dtype, x),
+                   out_struct(dtc.shape, jnp.float32, x),
+                   out_struct(cumc.shape, jnp.float32, x),
+                   out_struct(cumr.shape, jnp.float32, x),
+                   out_struct(bm.shape, bm.dtype, x),
+                   out_struct(cm.shape, cm.dtype, x)],
         scratch_shapes=[pltpu.VMEM((W, N), jnp.float32)],
         name="pbtpu_ssm_bwd", **_params(interpret),
     )(x, dtc, cumc, cumr, bm, cm, states, dy)

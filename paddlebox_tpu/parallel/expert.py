@@ -32,7 +32,8 @@ chip's part and is not computed, approximated or stood in for; on a mesh
 of one chip the layer runs without an exchange. Two routing rules
 (``route_top_k``: a softmax over the chosen logits;
 ``route_sigmoid_top_k``: sigmoid scores chosen with a correction bias,
-renormalised and scaled) and two expert bodies (gated ReGLU; non-gated
+renormalised and scaled) and three expert bodies, chosen by name
+(``EXPERT_BODIES``: gated ``reglu`` and ``swiglu``; non-gated ``relu2``,
 relu squared) share the one layer. An expert that every chip computes
 alike (a shared expert) is the model's to add: the sum of the shares
 counts it once.
@@ -217,17 +218,18 @@ def route_top_k(router_logits: jnp.ndarray, top_k: int
 
 
 def route_sigmoid_top_k(router_logits: jnp.ndarray, bias: jnp.ndarray,
-                        top_k: int, scale: float
+                        top_k: int, scale: float, eps: float
                         ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """(weights, experts), both (N, top_k): scores ``s = sigmoid(logits)``
     over ALL experts; the top_k largest of ``s + bias`` are chosen (the
     correction bias moves the choice and nothing else); their weights are
-    ``s`` renormalised over the chosen, held here or not, times ``scale``.
-    No gradient reaches ``bias``."""
+    ``s`` over the chosen, held here or not, divided by their sum +
+    ``eps`` (the family's own: Nemotron-H 1e-20, LFM2 1e-6), times
+    ``scale``. No gradient reaches ``bias``."""
     scores = jax.nn.sigmoid(router_logits)
     _, experts = lax.top_k(scores + bias, top_k)
     picked = jnp.take_along_axis(scores, experts, axis=-1)
-    return picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) \
+    return picked / (jnp.sum(picked, axis=-1, keepdims=True) + eps) \
         * scale, experts
 
 
@@ -286,19 +288,28 @@ def route_rungs(rows: int, count: int, n_experts: int) -> tuple[int, ...]:
         for i in range(_RUNGS)}))
 
 
-def _expert_rows(xs, sizes, w_gate, w_up, w_down, dtype):
+# the expert bodies by name: (what an error calls it, the gate's activation
+# — None: no gate, ``relu(x W_up)^2 W_down``; else
+# ``(act(x W_gate) * (x W_up)) W_down``)
+EXPERT_BODIES = {"reglu": ("ReGLU", jax.nn.relu),
+                 "swiglu": ("SwiGLU", jax.nn.silu),
+                 "relu2": ("relu squared", None)}
+
+
+def _expert_rows(xs, sizes, body, w_gate, w_up, w_down, dtype):
     """Sorted rows through their experts' bodies: (rows, D) -> (rows, D)
     in `dtype`; rows past the last group are not computed."""
     dot = functools.partial(lax.ragged_dot, group_sizes=sizes,
                             preferred_element_type=jnp.float32)
-    if w_gate is None:
+    gate = EXPERT_BODIES[body][1]
+    if gate is None:
         hidden = jnp.square(jax.nn.relu(dot(xs, w_up)))
     else:
-        hidden = jax.nn.relu(dot(xs, w_gate)) * dot(xs, w_up)
+        hidden = gate(dot(xs, w_gate)) * dot(xs, w_up)
     return dot(hidden.astype(w_down.dtype), w_down).astype(dtype)
 
 
-def _whole_chunk(x, probs, order, inv, sizes, w_gate, w_up, w_down):
+def _whole_chunk(body, x, probs, order, inv, sizes, w_gate, w_up, w_down):
     """The last rung: every assignment of the chunk in the sorted copy,
     whatever the imbalance."""
     n, k = probs.shape
@@ -309,7 +320,7 @@ def _whole_chunk(x, probs, order, inv, sizes, w_gate, w_up, w_down):
     in_group = (jnp.arange(n * k) < jnp.sum(sizes))[:, None]
     xs = jnp.where(in_group, _tokens_by_expert(
         x.astype(w_up.dtype), order, inv, k), 0)
-    ys = _expert_rows(xs, sizes, w_gate, w_up, w_down, x.dtype)
+    ys = _expert_rows(xs, sizes, body, w_gate, w_up, w_down, x.dtype)
     # back to (token, choice) order
     y = _permute_rows(jnp.where(in_group, ys, 0), inv, order)
     return jnp.einsum("nkd,nk->nd", y.reshape(n, k, -1),
@@ -362,8 +373,8 @@ _sum_by_token.defvjp(
     lambda plan, g: (_rows_of_tokens(g, plan), None))
 
 
-def _bounded_chunk(bound: int, x, probs, order, sizes, by_token, held_choices,
-                   w_gate, w_up, w_down):
+def _bounded_chunk(bound: int, body, x, probs, order, sizes, by_token,
+                   held_choices, w_gate, w_up, w_down):
     """A rung under the whole chunk: the held assignments are the first
     sum(sizes) <= bound entries of `order`, and only `order[:bound]` is
     brought into the sorted order; every array on the sorted side has
@@ -380,43 +391,45 @@ def _bounded_chunk(bound: int, x, probs, order, sizes, by_token, held_choices,
             tuple(token[s:s + bound] == token[:bound] for s in range(1, k)),
             jnp.cumsum(held_choices) - held_choices, held_choices > 0)
     xs = _rows_of_tokens(x.astype(w_up.dtype), plan)
-    ys = _expert_rows(xs, sizes, w_gate, w_up, w_down, x.dtype)
+    ys = _expert_rows(xs, sizes, body, w_gate, w_up, w_down, x.dtype)
     weigh = probs.reshape(-1).at[rows].get(
         unique_indices=True, mode="promise_in_bounds").astype(ys.dtype)
     return _sum_by_token(jnp.where(live[:, None], ys, 0) * weigh[:, None],
                          plan)
 
 
-def _rung(bound: int, x, probs, routed, weights):
+def _rung(bound: int, body: str, x, probs, routed, weights):
     order, inv, sizes, by_token, held_choices = routed
     if bound == probs.shape[0] * probs.shape[1]:
-        return _whole_chunk(x, probs, order, inv, sizes, *weights)
-    return _bounded_chunk(bound, x, probs, order, sizes, by_token,
+        return _whole_chunk(body, x, probs, order, inv, sizes, *weights)
+    return _bounded_chunk(bound, body, x, probs, order, sizes, by_token,
                           held_choices, *weights)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _ladder(rungs, rung, x, probs, routed, weights):
-    """The chunk through rung `rung` of `rungs`; `routed` is its sort, the
-    same for every rung (`_held_chunk`). Differentiated by hand
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _ladder(rungs, body, rung, x, probs, routed, weights):
+    """The chunk through rung `rung` of `rungs`, its experts of the body
+    `body`; `routed` is its sort, the same for every rung
+    (`_held_chunk`). Differentiated by hand
     so that the backward pass switches once and runs the taken rung's
     forward and backward inside its branch: nothing is kept but the
     arguments (the chunk is recomputed, not stored), and the rungs not
     taken leave no residuals to fill with zeros."""
-    return lax.switch(rung, [functools.partial(_rung, b) for b in rungs],
+    return lax.switch(rung,
+                      [functools.partial(_rung, b, body) for b in rungs],
                       x, probs, routed, weights)
 
 
-def _ladder_fwd(rungs, rung, x, probs, routed, weights):
-    return (_ladder(rungs, rung, x, probs, routed, weights),
+def _ladder_fwd(rungs, body, rung, x, probs, routed, weights):
+    return (_ladder(rungs, body, rung, x, probs, routed, weights),
             (rung, x, probs, routed, weights))
 
 
-def _ladder_bwd(rungs, kept, g):
+def _ladder_bwd(rungs, body, kept, g):
     rung, x, probs, routed, weights = kept
 
     def back(bound, x, probs, routed, weights, g):
-        return jax.vjp(lambda x, p, w: _rung(bound, x, p, routed, w),
+        return jax.vjp(lambda x, p, w: _rung(bound, body, x, p, routed, w),
                        x, probs, weights)[1](g)
 
     dx, dprobs, dweights = lax.switch(
@@ -428,10 +441,10 @@ def _ladder_bwd(rungs, kept, g):
 _ladder.defvjp(_ladder_fwd, _ladder_bwd)
 
 
-def _held_chunk(x, probs, experts, w_gate, w_up, w_down, first: int,
-                count: int, rungs: tuple[int, ...]):
-    """One chunk of tokens through the held experts (ReGLU, or relu
-    squared where ``w_gate`` is None). Returns the chunk's output (n, D),
+def _held_chunk(x, probs, experts, body: str, w_gate, w_up, w_down,
+                first: int, count: int, rungs: tuple[int, ...]):
+    """One chunk of tokens through the held experts of the body `body`
+    (``EXPERT_BODIES``). Returns the chunk's output (n, D),
     its assignments per held expert (count,) and the rung it took as
     (rows of the sorted copy, 1 if that was the whole chunk)."""
     local = experts - first
@@ -446,7 +459,8 @@ def _held_chunk(x, probs, experts, w_gate, w_up, w_down, first: int,
         jnp.arange(order.shape[0], dtype=order.dtype))
     routed = (order, inv, sizes, _token_order(held, inv),
               jnp.sum(held, axis=1, dtype=jnp.int32))
-    out = _ladder(rungs, rung, x, probs, routed, (w_gate, w_up, w_down))
+    out = _ladder(rungs, body, rung, x, probs, routed,
+                  (w_gate, w_up, w_down))
     took = jnp.stack([jnp.asarray(rungs, jnp.int32)[rung],
                       (rung == len(rungs) - 1).astype(jnp.int32)])
     return out, sizes, took
@@ -456,13 +470,14 @@ def held_expert_ffn(x: jnp.ndarray, probs: jnp.ndarray,
                     experts: jnp.ndarray, w_gate: jnp.ndarray | None,
                     w_up: jnp.ndarray, w_down: jnp.ndarray,
                     held: tuple[int, int], n_experts: int,
-                    chunk_tokens: int = 4096
+                    chunk_tokens: int = 4096, *, body: str
                     ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """sum over (token, choice) with the chosen expert held here of
-    p * body_e(x), the expert body being the call's: gated ReGLU,
-    ``(relu(x W_gate_e) * (x W_up_e)) W_down_e``, where ``w_gate`` is
-    given; non-gated relu squared, ``relu(x W_up_e)^2 W_down_e``, where it
-    is None.
+    p * body_e(x), the expert body being the call's, by name
+    (``EXPERT_BODIES``): gated ``reglu``,
+    ``(relu(x W_gate_e) * (x W_up_e)) W_down_e``, and ``swiglu``, the
+    same with ``silu`` for ``relu``; non-gated ``relu2``,
+    ``relu(x W_up_e)^2 W_down_e``, which takes ``w_gate=None``.
 
     x (N, D); probs, experts (N, k) from a routing rule (``route_top_k``,
     ``route_sigmoid_top_k``) over ``n_experts`` experts; the weights of
@@ -486,10 +501,16 @@ def held_expert_ffn(x: jnp.ndarray, probs: jnp.ndarray,
     imbalance: a load that no bounded rung holds costs time, never a
     token. A chunk is recomputed in the backward pass instead of stored."""
     first, count = int(held[0]), int(held[1])
-    body = "relu squared" if w_gate is None else "ReGLU"
+    if body not in EXPERT_BODIES:
+        raise ValueError(f"expert body {body!r}: one of "
+                         f"{sorted(EXPERT_BODIES)}")
+    called, gate = EXPERT_BODIES[body]
+    if (gate is None) != (w_gate is None):
+        raise ValueError(f"{called} experts take "
+                         f"{'no w_gate' if gate is None else 'a w_gate'}")
     for w in (w_up, w_down) if w_gate is None else (w_gate, w_up, w_down):
         if w.shape[0] != count:
-            raise ValueError(f"{body} experts: {w.shape[0]} expert weights "
+            raise ValueError(f"{called} experts: {w.shape[0]} expert weights "
                              f"for held={held}")
     n = x.shape[0]
     chunk = min(int(chunk_tokens), n)
@@ -503,8 +524,8 @@ def held_expert_ffn(x: jnp.ndarray, probs: jnp.ndarray,
         w_gate, w_up, w_down = (w if w is None else w.astype(jnp.bfloat16)
                                 for w in (w_gate, w_up, w_down))
     rungs = route_rungs(chunk * experts.shape[1], count, int(n_experts))
-    one = lambda xc, pc, ec: _held_chunk(xc, pc, ec, w_gate, w_up, w_down,
-                                         first, count, rungs)
+    one = lambda xc, pc, ec: _held_chunk(xc, pc, ec, body, w_gate, w_up,
+                                         w_down, first, count, rungs)
     if chunk == n:
         return one(x, probs, experts)
     parts = lambda a: a.reshape(n // chunk, chunk, *a.shape[1:])

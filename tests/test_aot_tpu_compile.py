@@ -336,3 +336,60 @@ def test_ssm_scan_compiles_at_the_published_widths(one_chip, on_tpu,
         ((B, T, G, N), bf16), ((B, T, G, N), bf16), ((H,), f32))
     assert "tpu_custom_call" in text and "pbtpu_ssm_fwd" in text
     assert ("pbtpu_ssm_bwd" in text) == (direction == "backward")
+
+
+# ---------------------------------------------------------------------------
+# LFM2's mixers at the published widths: attention at a head size of 64 (32
+# query heads over 8 key-value heads, 8192 positions, bfloat16 operands as
+# the chip runs it: the blocks' last dimension is the whole head) and the
+# gated short convolution (float32, 2048 channels, three taps)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_head_64_attention_compiles_at_the_published_widths(one_chip, on_tpu,
+                                                            direction):
+    from paddlebox_tpu.ops import flash_attention as fa
+    B, H, KV, T, D = 2, 32, 8, 8192, 64
+    assert fa.block_geometry(T, D) == (512, 512)
+    assert fa.block_geometry(T, 128) == (512, 512)
+    assert fa.block_geometry(T, 96) is None         # no tile, no half tile
+    bf16, f32 = jnp.bfloat16, jnp.float32
+
+    def attend(q, k, v):
+        return fa.attention(q, k, v, interpret=False)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(attend(*a).astype(f32)),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(
+        attend if direction == "forward" else grads, one_chip,
+        ((B, H, T, D), bf16), ((B, KV, T, D), bf16), ((B, KV, T, D), bf16))
+    assert "tpu_custom_call" in text and "pbtpu_attention_fwd" in text
+    for name in ("pbtpu_attention_dq", "pbtpu_attention_dkv"):
+        assert (name in text) == (direction == "backward")
+    # the scores never exist whole
+    assert f"{T},{T}]" not in text
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_short_conv_compiles_at_the_published_widths(one_chip, on_tpu,
+                                                     direction):
+    from paddlebox_tpu.ops import short_conv as sc
+    n, T, d, K = 2, 8192, 2048, 3
+    assert sc.conv_geometry(T, d, K) == (256, 512)
+    f32 = jnp.float32
+
+    def conv(*args):
+        return sc.short_conv(*args, interpret=False)
+
+    def grads(*args):
+        return jax.grad(lambda *a: jnp.sum(conv(*a)),
+                        argnums=(0, 1, 2, 3))(*args)
+
+    text = _compiled_text(
+        conv if direction == "forward" else grads, one_chip,
+        ((n, T, d), f32), ((n, T, d), f32), ((n, T, d), f32), ((K, d), f32))
+    assert "tpu_custom_call" in text
+    assert ("pbtpu_short_conv_fwd" in text) == (direction == "forward")
+    assert ("pbtpu_short_conv_bwd" in text) == (direction == "backward")

@@ -30,9 +30,10 @@ from benchmark.tests.test_work import *         # noqa: E402,F401,F403
 # The cases of benchmark/tests in which nothing is planted: the fault
 # patches optax.sigmoid_binary_cross_entropy, which a tower that declares
 # its own loss never calls, so the run comes out correct (PERF.md section
-# 7, "for a `benchmark` PR"): the two token cells, by name.
+# 7, "for a `benchmark` PR"): the three token cells, by name.
 _PLANTS_NOTHING = {("smallthinker_21b_ep4.seq8k", "_half_batch"),
-                   ("nemotron3_nano_ep16.seq4k", "_half_batch")}
+                   ("nemotron3_nano_ep16.seq4k", "_half_batch"),
+                   ("lfm2_24b_a2b_ep8.seq8k", "_half_batch")}
 
 
 @pytest.mark.parametrize("fault", [_correct._unchanged_state,
@@ -122,9 +123,55 @@ def _nemotron_cut(entry, cfg):
     assert round(ref.ssm_scan_bytes(cfg) / 1e9, 2) == 1.80
 
 
+def _lfm2_cut(entry, cfg):
+    a = cfg["model_args"]
+    # every width as published: hidden 2048, 32 / 8 heads of 64, the dense
+    # MLP 11776, experts 1536, three taps, theta 1e6, eps 1e-5
+    published = {"hidden_size": 2048, "num_attention_heads": 32,
+                 "num_key_value_heads": 8, "intermediate_size": 11776,
+                 "moe_intermediate_size": 1536, "conv_L_cache": 3,
+                 "norm_eps": 1e-5, "routed_scaling_factor": 1}
+    for key, value in published.items():
+        assert cfg[key] == value and a[key] == value, key
+    assert a["head_dim"] == 2048 // 32 and "head_dim" in cfg["assumed"]
+    assert a["rope_theta"] == cfg["rope_parameters"]["rope_theta"] == 1000000
+    assert (a["router_experts"], a["experts_per_token"]) == (64, 4)
+    assert cfg["num_experts_per_tok"] == 4 and cfg["norm_topk_prob"]
+    assert cfg["use_expert_bias"] and not cfg["conv_bias"]
+    assert a["experts_held"] == cfg["num_experts"] == 8
+    assert a["vocab_size"] == cfg["vocab_size"] == 65536 // 8
+    # layers 1-5 of the 40 published: one leading dense layer (they count
+    # once) and the whole period that follows it
+    kinds = cfg["layer_types"]
+    assert len(kinds) == 40 and kinds.count("full_attention") == 10
+    assert a["layer_types"] == kinds[1:6] == [
+        "conv", "full_attention", "conv", "conv", "conv"]
+    assert cfg["num_hidden_layers"] == len(a["layer_types"]) == 5
+    assert a["dense_layers"] == cfg["num_dense_layers"] == 1
+    assert set(entry["reduced"]) == set(cfg["reduced"]) == {
+        "num_hidden_layers", "num_dense_layers", "num_experts",
+        "vocab_size", "steps_per_pass"}
+    assert set(cfg["reduced_from"]) == set(cfg["reduced"])
+    assert "8 chips" in cfg["deployment"]
+    assert {"expert_bias", "untied head", "initialisation", "sequences",
+            "trainer.dense_lr"} <= set(cfg["assumed"])
+    from benchmark.reference import lfm2_moe as ref
+    # 469.3 M dense parameters (7.5 GB at 16 B); 8192 x 202.9 M
+    # multiply-adds an example (19.95 TFLOP a step of 2); the short
+    # convolutions move 5.9 GB a step at the least
+    assert round(ref.tower_sizes(cfg)[0] / 1e6, 1) == 469.3
+    assert round(ref.macs_per_example(cfg) / 8192 / 1e6, 1) == 202.9
+    assert round(ref.attention_macs(cfg) / 8192 / 1e6, 2) == 16.78
+    assert round(ref.expert_gmm_macs(cfg) / 8192 / 1e6, 2) == 18.87
+    assert round(ref.short_conv_bytes(cfg) / 1e9, 2) == 5.91
+    assert ref.short_conv_macs(cfg) == 4 * 8192 * 2048 * 5
+    assert ref.route_rows(cfg) == (16384, 8, 64)
+
+
 @pytest.mark.parametrize("config,holds", [
     ("smallthinker_21b_ep4", _smallthinker_cut),
-    ("nemotron3_nano_ep16", _nemotron_cut)])
+    ("nemotron3_nano_ep16", _nemotron_cut),
+    ("lfm2_24b_a2b_ep8", _lfm2_cut)])
 def test_the_cells_files_state_the_cut_and_the_published_widths(config,
                                                                 holds):
     import json
@@ -143,6 +190,7 @@ def test_the_scan_roofline_reader_takes_the_records_own_cell():
     the record's work, and its counts from that cell's reference."""
     import json
     from benchmark import work
+    from benchmark.metrics import _cell
     from benchmark.metrics import ssm_scan_roofline_pct as reader
     from benchmark.reference import nemotron_h as ref
 
@@ -158,7 +206,7 @@ def test_the_scan_roofline_reader_takes_the_records_own_cell():
               "trace": {"devices": 1, "by_op": {
                   "pbtpu_ssm_fwd": 0.02, "jvp_pbtpu_ssm_fwd_ x": 0.02,
                   "pbtpu_ssm_bwd": 0.04, "fusion.1": 9.0}}}
-    assert reader.cell_config(record, "ssm_scan_roofline_pct") == cfg
+    assert _cell.cell_config(record, "ssm_scan_roofline_pct") == cfg
     # bandwidth bounds the scan: its bytes over the peak, over 20 ms a step
     least = ref.ssm_scan_bytes(cfg) / 819e9
     assert 6.0 * 2 * ref.ssm_scan_macs(cfg) / 197e12 < least
@@ -168,3 +216,56 @@ def test_the_scan_roofline_reader_takes_the_records_own_cell():
     assert reader.read({**record, "work": {"flops": other}}) is None
     assert reader.read({**record, "trace": {
         "devices": 1, "by_op": {"fusion.1": 9.0}}}) is None
+
+
+def test_the_short_conv_readers_take_the_records_own_cell():
+    """Both new readers on a fixture record: the kernels' milliseconds a
+    step; their share of the roofline bandwidth sets, the configuration
+    found from the record's work through ``_cell.cell_config``; nothing on
+    a record without the kernels or of another cell."""
+    import json
+    from benchmark import work
+    from benchmark.metrics import (_cell, short_conv_ms_per_step,
+                                   short_conv_roofline_pct)
+    from benchmark.reference import lfm2_moe as ref
+
+    def config(name):
+        with open(os.path.join(ROOT, "benchmark", "configs",
+                               name + ".json")) as f:
+            return json.load(f)
+
+    cfg = config("lfm2_24b_a2b_ep8")
+    peaks = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    record = {"passes": [{"steps": 4}], "peaks": peaks,
+              "work": {"flops": work.step_flops(cfg)},
+              "trace": {"devices": 1, "by_op": {
+                  "pbtpu_short_conv_fwd": 0.016,
+                  "jvp_pbtpu_short_conv_fwd_ x": 0.016,
+                  "pbtpu_short_conv_bwd": 0.032,
+                  "pbtpu_attention_fwd": 0.5, "fusion.1": 9.0}}}
+    assert _cell.cell_config(record, "short_conv_roofline_pct") == cfg
+    assert short_conv_ms_per_step.read(record) == pytest.approx(16.0)
+    # bandwidth bounds it: 5.9 GB over 819 GB/s, over 16 ms a step
+    least = ref.short_conv_bytes(cfg) / 819e9
+    assert 6.0 * 2 * ref.short_conv_macs(cfg) / 197e12 < least
+    assert short_conv_roofline_pct.read(record) == pytest.approx(
+        100.0 * least / 0.016)
+    assert 0 < short_conv_roofline_pct.read(record) < 100
+    # a trace with no such kernel, or another cell's work: nothing to read
+    bare = {**record, "trace": {"devices": 1, "by_op": {"fusion.1": 9.0}}}
+    assert short_conv_ms_per_step.read(bare) is None
+    assert short_conv_roofline_pct.read(bare) is None
+    other = work.step_flops(config("nemotron3_nano_ep16"))
+    assert short_conv_roofline_pct.read(
+        {**record, "work": {"flops": other}}) is None
+    # and the readers of the kernels this tower shares find its cell too
+    from benchmark.metrics import attention_roofline_pct, moe_route_ms_per_step
+    assert attention_roofline_pct.read(record) == pytest.approx(
+        100.0 * 6 * 2 * ref.attention_macs(cfg) / 197e12 / 0.125)
+    # ... but not the route's: every rung of this cell's ladder (2,048 /
+    # 4,096 / 8,192 / 16,384 rows) is also a length of the model (the
+    # hidden size, the MLPs' token chunks, the sequence, a step's tokens),
+    # so the cell is not listed under that metric and its reader is silent
+    from benchmark import sut
+    assert sut.route_rungs(*ref.route_rows(cfg)) == (2048, 4096, 8192, 16384)
+    assert moe_route_ms_per_step.read(record) is None

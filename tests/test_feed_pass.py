@@ -226,3 +226,27 @@ def test_feed_error_surfaces_at_wait():
             mgr.wait_feed_pass_done()
     finally:
         store.lookup_or_init = orig
+
+
+def test_a_full_build_and_an_incremental_boundary_size_the_table_alike():
+    """A vocabulary that every pass fills (8192 keys): the first build and
+    every boundary after it give the table the same rows, so the window
+    meets no table shape the warm-up did not (a bucket of 10,240 rows is
+    no multiple of the 4,096-row alignment a full build applies)."""
+    from paddlebox_tpu.embedding.working_set import shard_rows
+    cfg = EmbeddingConfig(dim=8, optimizer="adagrad", learning_rate=0.05)
+    store = HostEmbeddingStore(cfg)
+    mgr = FeedPassManager(store, make_mesh(1))
+    keys = np.arange(1, 8193, dtype=np.uint64)
+    rows = []
+    for _ in range(3):
+        ws = mgr.begin_pass(keys)
+        mgr.pass_opened()
+        mgr.pass_closed()
+        mgr.end_pass(ws)
+        rows.append(int(ws.padded_rows))
+    assert mgr.last_reused_rows == 8192 and mgr.last_fresh_rows == 0
+    assert rows == [12288] * 3 == [shard_rows(cfg, 8193, 1, 8)] * 3
+    # both token cells' sizes, and DLRM's, are multiples already
+    assert [shard_rows(cfg, n + 1, 1, 8) for n in (37984, 16384, 2516000)] \
+        == [40960, 20480, 2621440]

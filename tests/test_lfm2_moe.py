@@ -1,11 +1,12 @@
-"""The Nemotron-H tower on the normal path, at a small size on the CPU:
-the program's loss and gradients against the plain reference
-(``benchmark/reference/nemotron_h.py``) on seeded random weights, one case
-a block kind and one for the whole MEMEM*EME tower (outside a trainer, so
-the scan and attention kernels run in the Pallas interpreter against the
-reference's literal recurrence); through ``Trainer.train_pass`` for two
-passes; and what the model declares (its loss, no prediction, the routing
-and scan statistics, one of them a ``*_min`` gauge)."""
+"""The LFM2-MoE tower on the normal path, at a small size on the CPU: the
+program's loss and gradients against the plain reference
+(``benchmark/reference/lfm2_moe.py``) on seeded random weights, one case a
+layer kind and one for the five-layer tower (outside a trainer, so the
+short-convolution and attention kernels run in the Pallas interpreter
+against the reference's shifted products and whole rows); the order of the
+tokens; through ``Trainer.train_pass`` for two passes with the reference
+followed step by step; and what the model declares (its loss, no
+prediction, the five routing statistics)."""
 
 import importlib
 import os
@@ -27,20 +28,25 @@ from paddlebox_tpu.monitor import names                   # noqa: E402
 from token_tower_common import (follow_two_passes,    # noqa: E402
                                 rehearsal_cell)
 
-CELL = "nemotron3_nano_ep16.seq4k"
+CELL = "lfm2_24b_a2b_ep8.seq8k"
+TOWER = ("conv", "full_attention", "conv", "conv", "conv")
+# (layer_types, dense_layers): one case a layer kind, then the cell's tower
+CASES = {"conv_dense": (("conv",), 1), "conv_experts": (("conv",), 0),
+         "attention_experts": (("full_attention",), 0), "tower": (TOWER, 1)}
 
 
 def _cell():
     return rehearsal_cell(CELL)
 
 
-def _model_and_reference(pattern, seed=0):
+def _model_and_reference(layer_types, dense_layers, seed=0):
     cfg, _ = _cell()
     cfg = {**cfg, "model_args": {**cfg["model_args"],
-                                 "block_pattern": pattern}}
-    ref = importlib.import_module("benchmark.reference.nemotron_h")
-    model = MODEL_REGISTRY["nemotron_h"](**cfg["model_args"])
+                                 "layer_types": list(layer_types),
+                                 "dense_layers": dense_layers}}
+    ref = importlib.import_module("benchmark.reference.lfm2_moe")
     a = cfg["model_args"]
+    model = MODEL_REGISTRY["lfm2_moe"](**{**a, "layer_types": layer_types})
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
     params = ref.init_params(k1, cfg)
     B, T = 2, a["seq_len"]
@@ -49,11 +55,15 @@ def _model_and_reference(pattern, seed=0):
     return cfg, ref, model, params, pulled, ids
 
 
-@pytest.mark.parametrize("pattern", ["M", "E", "*", "MEMEM*EME"])
-def test_model_loss_and_gradients_equal_the_reference(pattern):
-    cfg, ref, model, params, pulled, ids = _model_and_reference(pattern)
+@pytest.mark.parametrize("case", list(CASES))
+def test_model_loss_and_gradients_equal_the_reference(case):
+    cfg, ref, model, params, pulled, ids = _model_and_reference(*CASES[case])
     mask = jnp.ones(ids.shape, bool)
     labels = jnp.zeros((ids.shape[0],))
+    # the program's own initial state has the reference's names and shapes
+    mine0 = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    assert jax.tree.map(lambda x: x.shape, mine0) \
+        == jax.tree.map(lambda x: x.shape, params)
 
     def mine(p, x):
         return model.loss(p, x, mask, None, labels, ids)[0]
@@ -67,26 +77,29 @@ def test_model_loss_and_gradients_equal_the_reference(pattern):
             params, pulled)
         b, gb = jax.jit(jax.value_and_grad(theirs, argnums=(0, 1)))(
             params, pulled)
-    # float32 throughout, sums in another order: the chunked scan against
-    # 32 single steps, blocked attention against whole rows, sorted grouped
-    # products against a masked scan over experts
+    # float32 throughout, sums in another order: blocked attention against
+    # whole rows, sorted grouped products against a masked scan over
+    # experts, the MLP in two chunks of tokens against one
     np.testing.assert_allclose(a, b, rtol=2e-6)
     flat = lambda g: jax.tree_util.tree_flatten_with_path(g)[0]
     for (path, x), (_, y) in zip(flat(ga), flat(gb)):
         np.testing.assert_allclose(
             x, y, atol=3e-5 * max(float(jnp.abs(y).max()), 1.0),
             err_msg=jax.tree_util.keystr(path))
-    # dense: every leaf gets a gradient but the correction bias; rows:
-    # w, show and clk are not read, the embedding is
+    # dense: every leaf gets a gradient but the expert bias; rows: w, show
+    # and clk are not read, the embedding is
     for path, g in flat(ga[0]):
         name = jax.tree_util.keystr(path)
-        assert (float(jnp.abs(g).max()) == 0.0) == ("b_corr" in name), name
+        assert (float(jnp.abs(g).max()) == 0.0) == ("expert_bias" in name), \
+            name
     assert float(jnp.abs(ga[1][..., :3]).max()) == 0.0
     assert float(jnp.abs(ga[1][..., 3:]).max()) > 0.0
 
 
-def test_order_matters_and_the_declaration():
-    cfg, ref, model, params, pulled, ids = _model_and_reference("M*", 1)
+@pytest.mark.parametrize("mixer", ["conv", "full_attention"])
+def test_order_matters_and_the_declaration(mixer):
+    cfg, ref, model, params, pulled, ids = _model_and_reference(
+        (mixer, "conv"), 1, seed=1)
     mask = jnp.ones(ids.shape, bool)
     labels = jnp.zeros((ids.shape[0],))
     loss, preds, stats = model.loss(params, pulled, mask, None, labels, ids)
@@ -97,33 +110,25 @@ def test_order_matters_and_the_declaration():
     assert abs(float(swapped) - float(loss)) > 1e-4
     assert preds is None and stats.shape == (len(model.stat_names),)
     assert not base.predicts(model)
-    assert set(model.stat_names) <= set(names.MODEL_STAT_NAMES)
+    assert model.stat_names == names.MODEL_STAT_NAMES[:5]
     got = dict(zip(model.stat_names, np.asarray(stats)))
     a = cfg["model_args"]
-    tokens = ids.size
-    assert got["ssm.tokens"] == tokens            # one 'M' block
-    assert got["ssm.chunks"] == tokens // a["chunk_size"]
-    assert got["ssm.decay_log_min"] < 0
-    assert got["moe.assignments"] == 0 == got["moe.expert_load_max"]
+    # one expert layer (the second; the first is dense)
+    assert got["moe.assignments"] == ids.size * a["experts_per_token"]
+    assert 0 < got["moe.held_assignments"] <= got["moe.route_rows"] \
+        <= got["moe.assignments"]
+    assert got["moe.expert_load_max"] <= got["moe.held_assignments"]
     with pytest.raises(ValueError, match="kinds"):
-        MODEL_REGISTRY["nemotron_h"](**{**a, "block_pattern": "MXE"})
+        MODEL_REGISTRY["lfm2_moe"](**{**a, "layer_types": ("conv", "scan")})
+    with pytest.raises(ValueError, match="dense layers"):
+        MODEL_REGISTRY["lfm2_moe"](**{**a, "dense_layers": 9})
 
 
-def test_min_gauges_reduce_by_min_and_publish_the_smallest_step():
-    class Scan:
-        name = "scan"
-        stat_names = ("ssm.tokens", "ssm.decay_log_min",
-                      "moe.expert_load_max")
-    per_step = np.array([[8.0, -3.0, 5.0], [8.0, -7.5, 2.0]])
-    out = base.publish_stats(Scan(), per_step)
-    assert out == {"ssm.tokens": 16.0, "ssm.decay_log_min": -7.5,
-                   "moe.expert_load_max": 5.0}
-    mesh = jax.make_mesh((2,), ("dp",), devices=jax.devices()[:2])
-    reduced = jax.shard_map(
-        lambda s: base.reduce_stats(Scan.stat_names, s[0], ("dp",)),
-        mesh=mesh, in_specs=jax.sharding.PartitionSpec("dp"),
-        out_specs=jax.sharding.PartitionSpec())(jnp.asarray(per_step))
-    np.testing.assert_array_equal(reduced, [16.0, -7.5, 5.0])
+def test_a_tower_of_dense_layers_alone_counts_no_assignment():
+    _, _, model, params, pulled, ids = _model_and_reference(("conv",), 1)
+    stats = model.loss(params, pulled, jnp.ones(ids.shape, bool), None,
+                       jnp.zeros((ids.shape[0],)), ids)[2]
+    np.testing.assert_array_equal(stats, np.zeros(5))
 
 
 @pytest.fixture(scope="module")
@@ -131,22 +136,22 @@ def followed():
     """Two passes (files A, then B) through ``Trainer.train_pass``; the
     first pass's three first steps followed by the reference, as run.py
     follows them (rehearsal sizes: T 32, 512 ids, 16 experts with 2 held,
-    4 Mamba heads of 8 in 2 groups, chunks of 8)."""
-    return follow_two_passes(CELL, 32001)
+    4 / 2 heads of 16, the MLPs' tokens in two chunks)."""
+    return follow_two_passes(CELL, 36001)
 
 
 def test_program_follows_the_reference_through_train_pass(followed):
     n = followed["numbers"]
     assert n["ingest_mismatch"] == 0          # order kept, parser to packer
     assert n["counter_mismatch"] == 0         # the rows' show and clk
-    # float32 on both sides (the trainer's scan on a CPU mesh is the
-    # literal recurrence too): round-off of sums taken in another order
+    # float32 on both sides (the trainer's convolution and attention on a
+    # CPU mesh are the plain twins): round-off of sums in another order
     assert n["loss_gap_1"] < 1e-5 and n["loss_gap_3"] < 1e-4
     assert n["grad_gap"] < 1e-4               # first gradient, every leaf
     assert n["change_gap"] < 1e-3             # three steps' change
     left_out = followed["notes"]["leaves_left_out_of_change"]
     assert "table.w" in left_out              # w is not read by the tower
-    assert sum("b_corr" in leaf for leaf in left_out) == 4
+    assert sum("expert_bias" in leaf for leaf in left_out) == 4
     tr = followed["trainer"]
     assert followed["engines"]["pull_engine"] == "gather_seqpool"
     assert tr.schema.has_sequence and not tr._feeds_auc
@@ -160,10 +165,8 @@ def test_two_passes_train_and_their_statistics_reach_the_flight_record(
     assert all(np.isfinite(r["losses"]).all() for r in recs)
     steps_run = sum(r["steps"] for r in recs)
     tokens = cfg["trainer"]["global_batch_size"] * a["seq_len"]
-    n_m, n_e = (a["block_pattern"].count(k) for k in "ME")
+    n_e = len(a["layer_types"]) - a["dense_layers"]
     st = followed["stats"]
-    assert st["ssm.tokens"] == steps_run * tokens * n_m
-    assert st["ssm.chunks"] == steps_run * tokens // a["chunk_size"] * n_m
     assert st["moe.assignments"] == (steps_run * tokens
                                      * a["experts_per_token"] * n_e)
     assert 0 < st["moe.held_assignments"] < st["moe.assignments"]
@@ -171,8 +174,8 @@ def test_two_passes_train_and_their_statistics_reach_the_flight_record(
     # held assignment and never more than the whole chunks' rows
     assert st["moe.held_assignments"] <= st["moe.route_rows"] \
         <= st["moe.assignments"]
-    assert 0 <= st["moe.whole_chunk_routes"] <= steps_run * n_e
-    # the gauge: a pass's most negative chunk of Delta A (dt up to 0.1, A
-    # down to -16, 8 positions a chunk)
-    assert -8 * 0.2 * 16 < followed["snapshot"]["ssm.decay_log_min"] < 0
+    chunks = tokens // a["expert_chunk_tokens"]
+    assert 0 <= st["moe.whole_chunk_routes"] <= steps_run * n_e * chunks
+    # this tower reports the five routing statistics and no other
+    assert not any(k.startswith("ssm.") and v for k, v in st.items())
     assert recs[1]["timers"]["extras"] > 0

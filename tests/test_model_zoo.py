@@ -20,7 +20,8 @@ def mesh8():
 def test_registry_complete():
     assert set(MODEL_REGISTRY) == {"dnn_ctr", "deepfm", "wide_deep",
                                    "dcn_v2", "dlrm", "mmoe", "pv_rank",
-                                   "smallthinker", "nemotron_h"}
+                                   "smallthinker", "nemotron_h",
+                                   "lfm2_moe"}
 
 
 @pytest.mark.parametrize("model_cls,kw", [

@@ -518,3 +518,21 @@ def test_flight_validator_rejects_bad_exchange_extras():
                            "exchange_wire_next": "bf16",
                            "exchange_topology": "hier"})
     assert flight.validate_flight_record(ok) == []
+
+
+def test_kernel_names_are_the_pallas_calls_own():
+    """``names.KERNEL_NAMES`` is closed over the ``name=`` of every
+    ``pallas_call`` in ``paddlebox_tpu/ops``: a device trace shows a
+    kernel by that name and the benchmark's readers look for it."""
+    import re
+    from paddlebox_tpu.monitor import names
+    ops = os.path.join(os.path.dirname(os.path.abspath(monitor.__file__)),
+                       "..", "ops")
+    found = set()
+    for fname in os.listdir(ops):
+        if fname.endswith(".py"):
+            with open(os.path.join(ops, fname)) as f:
+                found |= set(re.findall(r'name="(pbtpu_\w+)"', f.read()))
+    assert found == set(names.KERNEL_NAMES)
+    assert len(set(names.KERNEL_NAMES)) == len(names.KERNEL_NAMES)
+    assert {"pbtpu_short_conv_fwd", "pbtpu_short_conv_bwd"} <= found

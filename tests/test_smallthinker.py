@@ -174,9 +174,13 @@ def test_model_loss_equals_reference_and_order_matters():
     assert preds is None and stats.shape == (len(model.stat_names),)
 
 
+@pytest.mark.parametrize("D", [16, 64])
 @pytest.mark.parametrize("window", [None, 5, 12])
-def test_blocked_kernel_equals_unblocked_and_the_mask_says_where(window):
-    B, H, KV, T, D = 2, 4, 2, 32, 16
+def test_blocked_kernel_equals_unblocked_and_the_mask_says_where(window, D):
+    """Grouped heads (4 over 2), full and windowed, forward and backward;
+    a head of 64 channels is half a lane tile on the chip (its blocks take
+    the whole head size), one of 16 stands for the whole tiles."""
+    B, H, KV, T = 2, 4, 2, 32
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
     q = jax.random.normal(ks[0], (B, H, T, D))
     k = jax.random.normal(ks[1], (B, KV, T, D))
