@@ -44,8 +44,9 @@ The chip holds experts ``first_expert .. first_expert + experts_held - 1``
 for the experts other chips hold) and a slice of the vocabulary (table and
 head alike). ``expert_bias`` is a parameter at zero that receives no
 gradient. Each layer is recomputed in the backward pass
-(``jax.checkpoint``); the dense MLP and the experts take their tokens in
-chunks of ``expert_chunk_tokens``.
+(``nn.recomputed``: all but what an attention kernel read and wrote); the
+dense MLP and the experts take their tokens in chunks of
+``expert_chunk_tokens``.
 """
 
 from __future__ import annotations
@@ -55,7 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddlebox_tpu.models.nn import (causal_attention, next_token_loss,
-                                     rms_norm, vocabulary_ids)
+                                     recomputed, rms_norm, vocabulary_ids)
 from paddlebox_tpu.models.smallthinker import rope
 from paddlebox_tpu.ops.short_conv import short_conv
 from paddlebox_tpu.parallel.expert import (held_expert_ffn,
@@ -226,7 +227,7 @@ class Lfm2MoeModel:
         h = pulled[..., 3:]
         routed = []
         for i, (p, mixer) in enumerate(zip(params["layers"], self.mixers)):
-            h, route = jax.checkpoint(self._layer, static_argnums=(2, 3))(
+            h, route = recomputed(self._layer, static_argnums=(2, 3))(
                 p, h, mixer, i < self.dense_layers)
             if route is not None:
                 routed.append(route)

@@ -49,7 +49,7 @@ The chip holds experts ``first_expert .. first_expert + experts_held - 1``
 for the experts other chips hold), the whole shared expert, and a slice of
 the vocabulary (table and head alike). ``b_corr`` is a parameter at zero
 that receives no gradient. Each block is recomputed in the backward pass
-(``jax.checkpoint``).
+(``nn.recomputed``: all but what an attention kernel read and wrote).
 """
 
 from __future__ import annotations
@@ -61,7 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddlebox_tpu.models.nn import (causal_attention, next_token_loss,
-                                     rms_norm, vocabulary_ids)
+                                     recomputed, rms_norm, vocabulary_ids)
 from paddlebox_tpu.ops.ssm_scan import ssm_scan
 from paddlebox_tpu.parallel.expert import (held_expert_ffn,
                                            route_sigmoid_top_k)
@@ -265,7 +265,7 @@ class NemotronHModel:
         h = pulled[..., 3:]
         aux = {kind: [] for kind in KINDS}
         for p, kind in zip(params["blocks"], self.pattern):
-            h, a = jax.checkpoint(self._block, static_argnums=(2,))(
+            h, a = recomputed(self._block, static_argnums=(2,))(
                 p, h, kind)
             aux[kind].append(a)
         stack = lambda v, width: jnp.stack(v) if v else jnp.zeros(

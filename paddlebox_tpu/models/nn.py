@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from paddlebox_tpu.ops.flash_attention import attention
+from paddlebox_tpu.ops.flash_attention import RESIDUAL_NAMES, attention
 
 
 def dense_init(key, in_dim: int, out_dim: int, scale: str = "glorot"):
@@ -73,6 +73,21 @@ def causal_attention(q, k, v, window=None):
     o = attention(*(jnp.swapaxes(t, 1, 2).astype(cd) for t in (q, k, v)),
                   window=window)
     return jnp.swapaxes(o, 1, 2).reshape(B, T, -1).astype(q.dtype)
+
+
+def recomputed(fn, static_argnums=()):
+    """``fn`` with its intermediates rebuilt in the backward pass
+    (``jax.checkpoint``), but for what an attention op inside it names
+    (``flash_attention.RESIDUAL_NAMES``): the backward kernels read q, k,
+    v, the output and the row statistics the forward pass left, so the
+    recomputation runs neither the forward kernel nor the projections,
+    norms and rotations ahead of it. The same for every tower; a layer
+    with no attention inside keeps nothing. (PERF.md section 6, PR 37, has
+    the readings with the output and statistics alone and with all five.)"""
+    return jax.checkpoint(
+        fn, static_argnums=static_argnums,
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *RESIDUAL_NAMES))
 
 
 def next_token_loss(params, h, local_ids, mask, eps: float, head_chunk: int):

@@ -25,7 +25,8 @@ The chip holds experts ``first_expert .. first_expert + experts_held - 1``
 (``parallel/expert.py``'s share layer: routed over all, nothing dropped,
 nothing standing in for the experts other chips hold) and a slice of the
 vocabulary (table and output head alike). Each layer is recomputed in the
-backward pass (``jax.checkpoint``).
+backward pass (``nn.recomputed``: all but what its attention kernel read
+and wrote).
 
 Host stage: ``batch_extras`` turns the batch's keys into ids within the
 vocabulary — the key's low ``key_index_bits`` bits less one, the format of
@@ -40,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddlebox_tpu.models.nn import (causal_attention, next_token_loss,
-                                     rms_norm, vocabulary_ids)
+                                     recomputed, rms_norm, vocabulary_ids)
 from paddlebox_tpu.parallel.expert import held_expert_ffn, route_top_k
 
 
@@ -152,7 +153,7 @@ class SmallThinkerModel:
         h = pulled[..., 3:]
         routed = []
         for p, kind in zip(params["layers"], self.kinds):
-            h, route = jax.checkpoint(self._layer, static_argnums=(2,))(
+            h, route = recomputed(self._layer, static_argnums=(2,))(
                 p, h, kind)
             routed.append(route)
         loads, took = (jnp.stack(v) for v in zip(*routed))

@@ -219,7 +219,8 @@ KEY_SET_COUNTER_NAMES: tuple[str, ...] = (
 )
 
 # the Pallas kernels' names, as a device trace's ``XLA Ops`` line shows
-# them (a recomputed forward also as ``jvp_<name>_``): the only names a
+# them (a forward kernel called under differentiation as ``jvp_<name>_``;
+# under its plain name it is a recomputation's call): the only names a
 # device operation carries (``jax.named_scope`` reaches no such event), so
 # the benchmark's kernel readers find a kernel's seconds by them
 # (benchmark/metrics/*_ms_per_step.py)

@@ -42,7 +42,8 @@ wall clock and eyesight. This module makes one pass ONE causal timeline:
   ``pbtpu/<name>`` annotation with its ``pass_id`` and ``step``
   (``hub.annotate``), on the device events' clock. ``--device`` reads
   such a capture back (:func:`reduce_capture`): seconds and self
-  seconds per span, the device's busy and idle time inside
+  seconds per span, launches and seconds per Pallas kernel
+  (``names.KERNEL_NAMES``), the device's busy and idle time inside
   ``train_pass``, and each idle gap under the innermost span of the
   training thread that covers it.
 
@@ -73,6 +74,7 @@ import zlib
 from paddlebox_tpu.config import flags as config_flags
 from paddlebox_tpu.monitor import aggregate as agg_lib
 from paddlebox_tpu.monitor.names import ANNOTATION_PREFIX as SPAN_PREFIX
+from paddlebox_tpu.monitor.names import KERNEL_NAMES
 from paddlebox_tpu.monitor.registry import STATS
 
 # ---------------------------------------------------------------------------
@@ -655,13 +657,33 @@ def find_xplane(path: str) -> str:
     return found[-1]
 
 
+def kernel_launches(events) -> dict:
+    """``{kernel: {"launches", "seconds"}}`` over a device line's
+    ``(event name, seconds)``, for the ``names.KERNEL_NAMES`` kernels that
+    ran. An ``XLA Ops`` event is named by its whole HLO instruction
+    (``%jvp_pbtpu_attention_fwd_.1 = (bf16[...], ...) custom-call(...)``):
+    the kernel's name is looked for in the instruction's own name, not in
+    its operands."""
+    out: dict[str, dict] = {}
+    for name, seconds in events:
+        head = name.split(" = ", 1)[0]
+        kernel = next((k for k in KERNEL_NAMES if k in head), None)
+        if kernel is not None:
+            acc = out.setdefault(kernel, {"launches": 0, "seconds": 0.0})
+            acc["launches"] += 1
+            acc["seconds"] += seconds
+    return out
+
+
 def read_capture(xplane_path: str) -> dict:
-    """The two things the reduction needs, in seconds on the capture's
-    one clock: ``threads`` — per host thread that holds any, its
-    ``pbtpu/`` spans as ``(start, end, name)`` — and ``device_ops`` —
-    the intervals in which an operation ran on the first device (a TPU
-    plane's ``XLA Ops`` line; on the CPU backend, where the device is
-    the host, the host events that carry an ``hlo_op``).
+    """What the reduction needs, in seconds on the capture's one clock:
+    ``threads`` — per host thread that holds any, its ``pbtpu/`` spans as
+    ``(start, end, name)`` — ``device_ops`` — the intervals in which an
+    operation ran on the first device (a TPU plane's ``XLA Ops`` line; on
+    the CPU backend, where the device is the host, the host events that
+    carry an ``hlo_op``) — and ``kernels``, that device's
+    :func:`kernel_launches` (empty off a TPU: the Pallas interpreter runs
+    no operation under a kernel's name).
     ``device_source`` says which of the two was read: every report
     prints it, so host events never pass for the chip's."""
     import jax
@@ -671,9 +693,11 @@ def read_capture(xplane_path: str) -> dict:
         if plane.name.startswith("/device:TPU:"):
             for ln in plane.lines:
                 if ln.name == DEVICE_OPS_LINE:
+                    events = list(ln.events)
                     devices.append((plane.name, [
                         (e.start_ns / 1e9, (e.start_ns + e.duration_ns) / 1e9)
-                        for e in ln.events]))
+                        for e in events], kernel_launches(
+                            (e.name, e.duration_ns / 1e9) for e in events)))
         elif plane.name.startswith("/host:"):
             for ln in plane.lines:
                 host_lines.append(ln)
@@ -684,8 +708,10 @@ def read_capture(xplane_path: str) -> dict:
                          if e.name.startswith(SPAN_PREFIX)]
                 if spans:
                     threads.append(spans)
+    kernels: dict = {}
     if devices:
-        plane_name, device_ops = min(devices)
+        plane_name, device_ops, kernels = min(devices,
+                                              key=lambda d: d[0])
         source = f"{plane_name} {DEVICE_OPS_LINE}"
     else:
         source = NO_DEVICE_PLANE
@@ -699,7 +725,8 @@ def read_capture(xplane_path: str) -> dict:
                 if e.duration_ns > 0
                 and any(k == "hlo_op" for k, _ in e.stats)]
     return {"threads": threads, "device_ops": device_ops,
-            "devices": len(devices), "device_source": source}
+            "kernels": kernels, "devices": len(devices),
+            "device_source": source}
 
 
 def nest_spans(spans: "list[tuple]") -> "tuple[list[dict], list[tuple]]":
@@ -828,6 +855,12 @@ def render_capture_text(report: dict) -> str:
                             key=lambda kv: -kv[1]["seconds"]):
         lines.append(f"{name:<24}{acc['count']:>7}{acc['seconds']:>12.6f}"
                      f"{acc['self_s']:>12.6f}")
+    if report.get("kernels"):
+        lines.append(f"{'kernel':<28}{'launches':>9}{'seconds':>12}")
+        for name, acc in sorted(report["kernels"].items(),
+                                key=lambda kv: -kv[1]["seconds"]):
+            lines.append(f"{name:<28}{acc['launches']:>9}"
+                         f"{acc['seconds']:>12.6f}")
     tp = report.get("train_pass")
     if tp is None:
         lines.append(f"no {SPAN_PREFIX}{ROOT_SPAN} span in the capture: "
@@ -872,6 +905,7 @@ def device_main(argv: "list[str]") -> int:
     report["xplane"] = xplane
     report["devices"] = capture["devices"]
     report["device_source"] = capture["device_source"]
+    report["kernels"] = capture["kernels"]
     print(json.dumps(report) if as_json
           else render_capture_text(report), flush=True)
     return 0

@@ -39,6 +39,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -46,6 +47,12 @@ LANES = 128
 _HALF_TILE_HEADS = (64,)    # head sizes under a lane tile the chip takes
 _MASK = -0.7 * float(jnp.finfo(jnp.float32).max)   # exp(_MASK - m) == 0
 NT = (((1,), (1,)), ((), ()))                      # a @ b.T
+# what the op's VJP keeps, by the name a ``jax.checkpoint`` policy can save
+# it under (``models/nn.recomputed``): the operands as they enter the
+# kernel, the output, and the row log-sum-exp as one value a row
+RESIDUAL_NAMES = ("pbtpu_attention_q", "pbtpu_attention_k",
+                  "pbtpu_attention_v", "pbtpu_attention_o",
+                  "pbtpu_attention_lse")
 
 
 def attention_reference(q, k, v, *, window: int | None = None,
@@ -262,6 +269,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref, dv_ref,
 
 
 def _backward(q, k, v, o, lse, do, window, scale, blocks, interpret):
+    """``lse`` (B, H, T): one value a row, replicated over the lanes here
+    as ``delta`` is."""
     B, H, T, D = q.shape
     KV = k.shape[1]
     G = H // KV
@@ -270,6 +279,7 @@ def _backward(q, k, v, o, lse, do, window, scale, blocks, interpret):
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1,
                     keepdims=True)
     delta = jnp.broadcast_to(delta, (B, H, T, LANES))
+    lse = jnp.broadcast_to(lse[..., None], (B, H, T, LANES))
     q_spec, kv_spec, row_spec = _specs(bq, bk, D, G, window, q_major=True,
                                        nq=nq)
     dq = pl.pallas_call(
@@ -310,7 +320,14 @@ def _attention(q, k, v, window, scale, blocks, interpret):
 
 
 def _attention_fwd(q, k, v, window, scale, blocks, interpret):
+    """The residuals carry ``RESIDUAL_NAMES``: a layer recomputed under a
+    policy that saves them runs no second forward kernel (``o``, ``lse``)
+    and nothing that only led up to it (``q``, ``k``, ``v``). The kernel
+    writes ``lse`` replicated over 128 lanes; its first lane is what is
+    kept."""
     o, lse = _forward(q, k, v, window, scale, blocks, interpret)
+    q, k, v, o, lse = (checkpoint_name(x, name) for x, name in zip(
+        (q, k, v, o, lse[..., 0]), RESIDUAL_NAMES))
     return o, (q, k, v, o, lse)
 
 

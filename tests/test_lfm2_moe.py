@@ -8,7 +8,6 @@ tokens; through ``Trainer.train_pass`` for two passes with the reference
 followed step by step; and what the model declares (its loss, no
 prediction, the five routing statistics)."""
 
-import importlib
 import os
 import sys
 
@@ -26,7 +25,7 @@ from paddlebox_tpu.models import MODEL_REGISTRY, base     # noqa: E402
 from paddlebox_tpu.monitor import names                   # noqa: E402
 
 from token_tower_common import (follow_two_passes,    # noqa: E402
-                                rehearsal_cell)
+                                rehearsal_cell, tower)
 
 CELL = "lfm2_24b_a2b_ep8.seq8k"
 TOWER = ("conv", "full_attention", "conv", "conv", "conv")
@@ -40,19 +39,8 @@ def _cell():
 
 
 def _model_and_reference(layer_types, dense_layers, seed=0):
-    cfg, _ = _cell()
-    cfg = {**cfg, "model_args": {**cfg["model_args"],
-                                 "layer_types": list(layer_types),
-                                 "dense_layers": dense_layers}}
-    ref = importlib.import_module("benchmark.reference.lfm2_moe")
-    a = cfg["model_args"]
-    model = MODEL_REGISTRY["lfm2_moe"](**{**a, "layer_types": layer_types})
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
-    params = ref.init_params(k1, cfg)
-    B, T = 2, a["seq_len"]
-    pulled = jax.random.normal(k2, (B, T, 3 + a["hidden_size"])) * 0.3
-    ids = jax.random.randint(k3, (B, T), 0, a["vocab_size"])
-    return cfg, ref, model, params, pulled, ids
+    return tower(CELL, seed, layer_types=list(layer_types),
+                 dense_layers=dense_layers)
 
 
 @pytest.mark.parametrize("case", list(CASES))

@@ -7,7 +7,6 @@ reference's literal recurrence); through ``Trainer.train_pass`` for two
 passes; and what the model declares (its loss, no prediction, the routing
 and scan statistics, one of them a ``*_min`` gauge)."""
 
-import importlib
 import os
 import sys
 
@@ -25,7 +24,7 @@ from paddlebox_tpu.models import MODEL_REGISTRY, base     # noqa: E402
 from paddlebox_tpu.monitor import names                   # noqa: E402
 
 from token_tower_common import (follow_two_passes,    # noqa: E402
-                                rehearsal_cell)
+                                rehearsal_cell, tower)
 
 CELL = "nemotron3_nano_ep16.seq4k"
 
@@ -35,18 +34,7 @@ def _cell():
 
 
 def _model_and_reference(pattern, seed=0):
-    cfg, _ = _cell()
-    cfg = {**cfg, "model_args": {**cfg["model_args"],
-                                 "block_pattern": pattern}}
-    ref = importlib.import_module("benchmark.reference.nemotron_h")
-    model = MODEL_REGISTRY["nemotron_h"](**cfg["model_args"])
-    a = cfg["model_args"]
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
-    params = ref.init_params(k1, cfg)
-    B, T = 2, a["seq_len"]
-    pulled = jax.random.normal(k2, (B, T, 3 + a["hidden_size"])) * 0.3
-    ids = jax.random.randint(k3, (B, T), 0, a["vocab_size"])
-    return cfg, ref, model, params, pulled, ids
+    return tower(CELL, seed, block_pattern=pattern)
 
 
 @pytest.mark.parametrize("pattern", ["M", "E", "*", "MEMEM*EME"])

@@ -302,6 +302,37 @@ def test_idle_gaps_go_to_the_innermost_span():
     assert "train_pass" not in trace_lib.reduce_capture([pack], device)
 
 
+def test_kernel_launches_count_a_kernel_by_its_instructions_own_name():
+    """An ``XLA Ops`` event is a whole HLO instruction: the forward pass's
+    call under differentiation (``jvp_<name>_``) and a recomputation's
+    (the plain name) are the same kernel, an instruction that only reads a
+    kernel's output is not that kernel, and ``dq`` is not ``dkv``."""
+    fwd = "(bf16[2,32,8192,64]{3,2,1,0}, f32[2,32,8192,128]{3,2,1,0})"
+    events = [
+        (f"%pbtpu_attention_fwd.1 = {fwd} custom-call(%a, %b, %c)", 0.014),
+        (f"%jvp_pbtpu_attention_fwd_.1 = {fwd} custom-call(%a, %b)", 0.0139),
+        ("%get-tuple-element.7 = bf16[2,32,8192,64]{3,2,1,0} "
+         "get-tuple-element(%pbtpu_attention_fwd.1), index=0", 0.5),
+        ("%pbtpu_attention_dq.1 = bf16[2,32,8192,64]{3,2,1,0} "
+         "custom-call(%q, %pbtpu_attention_fwd.1)", 0.013),
+        ("%pbtpu_attention_dkv.1 = (f32[2,32,8192,64]{3,2,1,0}) "
+         "custom-call(%q)", 0.017),
+        ("%fusion.12 = f32[16384,2048]{1,0} fusion(%p)", 0.2)]
+    got = trace_lib.kernel_launches(events + events[:1])
+    assert got == {
+        "pbtpu_attention_fwd": {"launches": 3,
+                                "seconds": pytest.approx(0.0419)},
+        "pbtpu_attention_dq": {"launches": 1, "seconds": 0.013},
+        "pbtpu_attention_dkv": {"launches": 1, "seconds": 0.017}}
+    assert set(got) <= set(trace_lib.KERNEL_NAMES)
+    text = trace_lib.render_capture_text({
+        "device_source": "/device:TPU:0 XLA Ops", "spans": {},
+        "kernels": got})
+    assert [ln.split()[:2] for ln in text.splitlines()[2:6]] == [
+        ["kernel", "launches"], ["pbtpu_attention_fwd", "3"],
+        ["pbtpu_attention_dkv", "1"], ["pbtpu_attention_dq", "1"]]
+
+
 def test_reader_cli_on_the_capture(captured, capsys):
     assert trace_lib.main(["--device", captured["logdir"], "--json"]) == 0
     rep = json.loads(capsys.readouterr().out)
@@ -318,8 +349,10 @@ def test_reader_cli_on_the_capture(captured, capsys):
     b = rep["spans"]["boundary"]
     assert b["self_s"] < b["seconds"]
     assert rep["longest_gaps"] and rep["longest_gaps"][0]["spans"]
-    # no device plane on the CPU: both reports say what was read instead
+    # no device plane on the CPU: both reports say what was read instead,
+    # and no kernel ran under its name
     assert rep["device_source"] == trace_lib.NO_DEVICE_PLANE
+    assert rep["kernels"] == {}
     assert trace_lib.main(["--device", captured["logdir"]]) == 0
     text = capsys.readouterr().out
     assert "device idle by innermost span" in text

@@ -5,7 +5,6 @@ attention kernel's two kinds, blocked against unblocked; the order of the
 tokens; what the model declares (its loss, no prediction, its routing
 statistics) and what a sequence slot does to the pull."""
 
-import importlib
 import os
 import shutil
 import sys
@@ -28,6 +27,8 @@ from paddlebox_tpu.data.schema import (DataFeedSchema, Slot,  # noqa: E402
 from paddlebox_tpu.models import MODEL_REGISTRY, base     # noqa: E402
 from paddlebox_tpu.models.dlrm import DLRMModel           # noqa: E402
 from paddlebox_tpu.ops import flash_attention as fa       # noqa: E402
+
+from token_tower_common import tower                      # noqa: E402
 
 CELL = "smallthinker_21b_ep4.seq8k"
 
@@ -130,18 +131,7 @@ def test_routing_statistics_reach_the_flight_record(followed):
 
 
 def _model_and_reference(seed=0):
-    cfg, _ = _cell()
-    ref = importlib.import_module("benchmark.reference.smallthinker")
-    model = MODEL_REGISTRY["smallthinker"](**{
-        k: tuple(v) if isinstance(v, list) else v
-        for k, v in cfg["model_args"].items()})
-    a = cfg["model_args"]
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
-    params = ref.init_params(k1, cfg)
-    B, T = 2, a["seq_len"]
-    pulled = jax.random.normal(k2, (B, T, 3 + a["hidden_size"])) * 0.3
-    ids = jax.random.randint(k3, (B, T), 0, a["vocab_size"])
-    return cfg, ref, model, params, pulled, ids
+    return tower(CELL, seed)
 
 
 def test_model_loss_equals_reference_and_order_matters():

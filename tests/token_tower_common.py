@@ -52,3 +52,26 @@ def follow_two_passes(cell: str, seed: int, n: int = 3) -> dict:
                 "snapshot": stats1}
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
+
+
+def tower(cell: str, seed: int = 0, **model_args):
+    """(cfg, reference module, model, params, pulled rows, ids) of a
+    cell's tower at its rehearsal sizes — `model_args` over the cell's
+    own — on seeded random weights and inputs, two examples."""
+    import importlib
+
+    import jax
+
+    from paddlebox_tpu.models import MODEL_REGISTRY
+    cfg, _ = rehearsal_cell(cell)
+    a = {**cfg["model_args"], **model_args}
+    cfg = {**cfg, "model_args": a}
+    ref = importlib.import_module("benchmark.reference." + cfg["model"])
+    model = MODEL_REGISTRY[cfg["model"]](**{
+        k: tuple(v) if isinstance(v, list) else v for k, v in a.items()})
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    params = ref.init_params(k1, cfg)
+    B, T = 2, a["seq_len"]
+    pulled = jax.random.normal(k2, (B, T, 3 + a["hidden_size"])) * 0.3
+    ids = jax.random.randint(k3, (B, T), 0, a["vocab_size"])
+    return cfg, ref, model, params, pulled, ids
