@@ -1,0 +1,12 @@
+"""Layer kernels: milliseconds a training step spends under the program's
+device scope ``pull``: the rows gathered for the batch's tokens (``routed_lookup`` /
+``routed_pull`` / ``fused_pull_pool``, with the pull's gate).
+From the traced cycle's ``by_op`` joined with the program's own table of
+its instructions' stages (``_scopes.py``). None where the program has no
+table or nothing ran under the scope."""
+
+from benchmark.metrics import _scopes
+
+
+def read(record):
+    return _scopes.ms_per_step(record, "pull")

@@ -79,6 +79,7 @@ from paddlebox_tpu.monitor import counter_add as stat_add
 from paddlebox_tpu.monitor import event as mon_event
 from paddlebox_tpu.monitor import gauge_set as stat_set
 from paddlebox_tpu.monitor import span as mon_span
+from paddlebox_tpu.monitor import device_scope, device_scopes
 from paddlebox_tpu.parallel import mesh as mesh_lib
 from paddlebox_tpu.utils import faultpoint
 
@@ -93,6 +94,7 @@ def _combine_jit(out_sharding, donate: bool):
     the H2D path only ever carries fresh rows. Cached per (sharding,
     donate); shapes retrace inside jit and are bounded by bucket_size.
     """
+    @device_scope("boundary")
     def combine(prev, fresh, src, is_fresh):
         def one(p, f):
             if f.shape[1] < p.shape[1]:
@@ -121,6 +123,7 @@ def _replica_fill_jit(out_sharding):
     staged bytes reproduce the conversion rounding bit-for-bit. Pads
     repeat the last (dst, src) pair, so duplicate writes are benign
     (same idiom as _patch_jit)."""
+    @device_scope("boundary")
     def fill(staged, plane, dst, src):
         return staged.at[dst].set(plane[src])
 
@@ -137,6 +140,7 @@ def _patch_jit(out_sharding):
     arrive at logical width; resident planes may carry zero pad
     columns. Cached per sharding; shapes retrace inside jit and are
     bounded by bucket_size."""
+    @device_scope("boundary")
     def patch(table, rows, idx):
         def one(t, r):
             if r.shape[1] < t.shape[1]:
@@ -446,9 +450,9 @@ class FeedPassManager:
                 dst_p[:k] = dst
                 src_p = np.full(k_pad, served.src[k - 1], np.int32)
                 src_p[:k] = served.src
-                fresh_dev = _replica_fill_jit(repl)(fresh_dev, served.plane,
-                                                    jnp.asarray(dst_p),
-                                                    jnp.asarray(src_p))
+                fresh_dev = device_scopes.run(
+                    _replica_fill_jit(repl), fresh_dev, served.plane,
+                    jnp.asarray(dst_p), jnp.asarray(src_p))
             # barrier before the clock stops: device_put is async and the
             # h2d component must carry the transfer, not the dispatch (this
             # runs on the feed thread under begin_feed_pass, so blocking
@@ -607,8 +611,8 @@ class FeedPassManager:
             rows_dev = jax.device_put(rows_p, repl)
         else:
             rows_dev = jnp.asarray(rows_p)
-        ws.table = _patch_jit(self._tbl_sharding())(ws.table, rows_dev,
-                                                    idx_p)
+        ws.table = device_scopes.run(_patch_jit(self._tbl_sharding()),
+                                     ws.table, rows_dev, idx_p)
         if carried is not None:
             carried[idx] = False       # store value is authoritative now
         stat_add("feed_pass.patched_rows", k)
@@ -755,7 +759,8 @@ class FeedPassManager:
         src[1:1 + k] = np.where(pos >= 0, pos + 1, fresh_slot)
         is_fresh[1:1 + k] = pos < 0
         fn = _combine_jit(self._tbl_sharding(), donate=not test_mode)
-        table = fn(prev.table, staged.fresh_dev, src, is_fresh)
+        table = device_scopes.run(fn, prev.table, staged.fresh_dev, src,
+                                  is_fresh)
         # carry the unsynced marks of resident rows into their new slots —
         # their only fresh copy still lives on device
         carried = np.zeros(n_pad, bool)

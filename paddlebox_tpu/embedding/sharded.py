@@ -41,6 +41,7 @@ from paddlebox_tpu.config import flags as config_flags
 from paddlebox_tpu.embedding.config import EmbeddingConfig
 from paddlebox_tpu.embedding.optim import apply_updates
 from paddlebox_tpu.embedding import gating, quant
+from paddlebox_tpu.monitor import device_scope
 from paddlebox_tpu.ops import pallas_kernels
 
 NULL_INDEX = 0  # reserved all-zero row; padding tokens point here
@@ -169,6 +170,7 @@ def pooled_grad_tokens(gpooled: jnp.ndarray, mask: jnp.ndarray,
 _CS_BLOCK = 4096
 
 
+@device_scope("premerge")
 def plan_premerge(idx: jnp.ndarray, grads: jnp.ndarray,
                   shows: jnp.ndarray, clks: jnp.ndarray, plan):
     """Device half of the host dedup plan: segment-sum per-token payloads

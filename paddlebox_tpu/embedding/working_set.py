@@ -31,6 +31,7 @@ from paddlebox_tpu.config import flags
 from paddlebox_tpu.embedding import quant
 from paddlebox_tpu.embedding.config import EmbeddingConfig
 from paddlebox_tpu.embedding.store import HostEmbeddingStore
+from paddlebox_tpu.monitor import device_scope, device_scopes
 from paddlebox_tpu.native.key_index import KeyIndex
 from paddlebox_tpu.parallel import mesh as mesh_lib
 
@@ -116,6 +117,7 @@ def plane_layout(cfg: EmbeddingConfig) -> bool:
 
 @functools.lru_cache(maxsize=8)
 def _pad_width_jit(extra: int, sharding):
+    @device_scope("boundary")
     def pad(t):
         return jnp.pad(t, ((0, 0), (0, extra)))
     if sharding is not None:
@@ -125,7 +127,10 @@ def _pad_width_jit(extra: int, sharding):
 
 @functools.lru_cache(maxsize=8)
 def _slice_width_jit(rw: int):
-    return jax.jit(lambda t: t[:, :rw])
+    @device_scope("boundary")
+    def slice_width(t):
+        return t[:, :rw]
+    return jax.jit(slice_width)
 
 
 def bucket_size(x: int) -> int:
@@ -178,6 +183,7 @@ def shard_rows(cfg, need: int, n_shards: int, min_rows_per_shard: int,
 
 @functools.lru_cache(maxsize=8)  # bounded: each entry retains its Mesh
 def _combine_jit(lo: int, hi: int, sharding):
+    @device_scope("boundary")
     def combine(rest, emb):
         return jnp.concatenate(
             [rest[:, :lo], emb.astype(jnp.float32), rest[:, lo:]], axis=1)
@@ -190,6 +196,7 @@ def _combine_jit(lo: int, hi: int, sharding):
 
 @functools.lru_cache(maxsize=None)
 def _split_jit(lo: int, hi: int, rw: int):
+    @device_scope("boundary")
     def split(t):
         # t may carry pad columns past rw (device_width) — never ship them
         rest = jnp.concatenate([t[:, :lo], t[:, hi:rw]], axis=1)
@@ -206,12 +213,13 @@ def _put_compressed(host_table: np.ndarray, cfg: EmbeddingConfig, sharding):
         emb_d = jax.device_put(emb, sharding)
     else:
         rest_d, emb_d = jnp.asarray(rest), jnp.asarray(emb)
-    return _combine_jit(lo, hi, sharding)(rest_d, emb_d)
+    return device_scopes.run(_combine_jit(lo, hi, sharding), rest_d, emb_d)
 
 
 def _get_compressed(table, cfg: EmbeddingConfig) -> np.ndarray:
     lo, hi = _split_cols(cfg)
-    rest_d, emb_d = _split_jit(lo, hi, cfg.row_width)(table)
+    rest_d, emb_d = device_scopes.run(_split_jit(lo, hi, cfg.row_width),
+                                      table)
     rest = np.asarray(jax.device_get(rest_d))
     emb = np.asarray(jax.device_get(emb_d)).astype(np.float32)
     out = np.empty((table.shape[0], hi - lo + rest.shape[1]), np.float32)
@@ -230,6 +238,7 @@ def _get_compressed(table, cfg: EmbeddingConfig) -> np.ndarray:
 
 @functools.lru_cache(maxsize=8)
 def _gather_rows_jit(compress: bool, lo: int, hi: int, rw: int):
+    @device_scope("boundary")
     def gather(table, idx):
         # barrier between gather and slice: the full-row gather is the
         # fast path (see sharded.lookup); the slice drops pad columns so
@@ -246,7 +255,10 @@ def _gather_rows_jit(compress: bool, lo: int, hi: int, rw: int):
 def _gather_rows_planes_jit():
     # one dispatch for both planes; paired with a single device_get so a
     # pass-boundary flush pays one D2H round trip, not two serialized ones
-    return jax.jit(lambda fp, qx, idx: (fp[idx], qx[idx]))
+    @device_scope("boundary")
+    def gather_planes(fp, qx, idx):
+        return fp[idx], qx[idx]
+    return jax.jit(gather_planes)
 
 
 def fetch_rows(table: jax.Array, row_idx: np.ndarray,
@@ -270,13 +282,15 @@ def fetch_rows(table: jax.Array, row_idx: np.ndarray,
     idxp = np.zeros(k_pad, np.int32)
     idxp[:k] = row_idx
     if quant.is_planes(table):
-        fp_d, qx_d = _gather_rows_planes_jit()(table.fp, table.qx, idxp)
+        fp_d, qx_d = device_scopes.run(_gather_rows_planes_jit(), table.fp,
+                                       table.qx, idxp)
         fp, qx = (np.asarray(a) for a in jax.device_get((fp_d, qx_d)))
         rows = quant.decode_rows_np(fp, qx, cfg)
         return rows[:k], transfer_bytes(cfg, k_pad)
     compress = bool(flags.transfer_compress_embedx and cfg.total_dim)
     lo, hi = _split_cols(cfg)
-    out = _gather_rows_jit(compress, lo, hi, cfg.row_width)(table, idxp)
+    out = device_scopes.run(
+        _gather_rows_jit(compress, lo, hi, cfg.row_width), table, idxp)
     if compress:
         rest_d, emb_d = out
         rest = np.asarray(jax.device_get(rest_d))
@@ -446,7 +460,8 @@ class PassWorkingSet:
         # above carried logical bytes only (see device_width)
         W = device_width(cfg)
         if W > cfg.row_width:
-            table = _pad_width_jit(W - cfg.row_width, sharding)(table)
+            table = device_scopes.run(
+                _pad_width_jit(W - cfg.row_width, sharding), table)
         if timing_out is not None:
             # device_put returns before bytes move; without this barrier
             # the h2d component would read near-zero and the transfer
@@ -516,7 +531,8 @@ class PassWorkingSet:
             n_rows = t.shape[0]
         else:
             if t.shape[1] > self.cfg.row_width:   # drop pad columns first
-                t = _slice_width_jit(self.cfg.row_width)(t)
+                t = device_scopes.run(
+                    _slice_width_jit(self.cfg.row_width), t)
             host = np.asarray(jax.device_get(t))
             n_rows = t.shape[0]
         nbytes = transfer_bytes(self.cfg, n_rows)

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddlebox_tpu.monitor import device_scope, device_scopes
+
 DEFAULT_BUCKETS = 1 << 20  # reference uses 1M buckets (_table_size)
 
 
@@ -39,6 +41,7 @@ def new_state(n_buckets: int = DEFAULT_BUCKETS) -> dict[str, jnp.ndarray]:
 AucState = dict[str, jnp.ndarray]
 
 
+@device_scope("auc")
 def auc_update(state: AucState, preds: jnp.ndarray, labels: jnp.ndarray,
                mask: jnp.ndarray | None = None,
                sample_scale: jnp.ndarray | None = None) -> AucState:
@@ -91,7 +94,7 @@ class AucAccumulator:
     def update(self, fn, *args) -> None:
         """dev_state = fn(dev_state, *args); fn is typically a jitted
         auc_update partial. Non-blocking except on drain boundaries."""
-        self.dev = fn(self.dev, *args)
+        self.dev = device_scopes.run(fn, self.dev, *args)
         self._updates += 1
         if self._updates >= self.drain_every:
             self.drain()

@@ -58,6 +58,7 @@ import numpy as np
 from paddlebox_tpu.models.nn import (causal_attention, next_token_loss,
                                      recomputed, rms_norm, vocabulary_ids)
 from paddlebox_tpu.models.smallthinker import rope
+from paddlebox_tpu.monitor import device_scope
 from paddlebox_tpu.ops.short_conv import short_conv
 from paddlebox_tpu.parallel.expert import (held_expert_ffn,
                                            route_sigmoid_top_k)
@@ -166,10 +167,12 @@ class Lfm2MoeModel:
 
     # -- the tower ---------------------------------------------------------
 
+    @device_scope("mixer")
     def _conv(self, p, u):
         gate_in, gate_out, x = jnp.split(u @ p["in_proj"], 3, axis=-1)
         return short_conv(gate_in, gate_out, x, p["conv_w"]) @ p["out_proj"]
 
+    @device_scope("attention")
     def _attention(self, p, u):
         B, T, _ = u.shape
         heads = lambda y, n: y.reshape(B, T, n, self.head_dim)
@@ -179,6 +182,7 @@ class Lfm2MoeModel:
         return causal_attention(rope(q, self.theta), rope(k, self.theta),
                                 v) @ p["wo"]
 
+    @device_scope("dense_mlp")
     def _dense(self, p, m):
         """The dense SwiGLU MLP over m (N, d), ``expert_chunk_tokens`` at a
         time (one chunk size for both feed-forward kinds, the experts' and
@@ -199,7 +203,9 @@ class Lfm2MoeModel:
     def _experts(self, p, m):
         """(the held experts' part of the layer's output (N, d),
         (assignments per held expert, how the chunks were routed))."""
-        logits = jnp.dot(m, p["router"], precision=jax.lax.Precision.HIGHEST)
+        with device_scope("route"):
+            logits = jnp.dot(m, p["router"],
+                             precision=jax.lax.Precision.HIGHEST)
         weights, experts = route_sigmoid_top_k(
             logits, p["expert_bias"], self.top_k, self.scale, 1e-6)
         y, load, took = held_expert_ffn(

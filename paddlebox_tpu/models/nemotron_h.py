@@ -62,6 +62,7 @@ import numpy as np
 
 from paddlebox_tpu.models.nn import (causal_attention, next_token_loss,
                                      recomputed, rms_norm, vocabulary_ids)
+from paddlebox_tpu.monitor import device_scope
 from paddlebox_tpu.ops.ssm_scan import ssm_scan
 from paddlebox_tpu.parallel.expert import (held_expert_ffn,
                                            route_sigmoid_top_k)
@@ -190,6 +191,7 @@ class NemotronHModel:
 
     # -- the tower ---------------------------------------------------------
 
+    @device_scope("mixer")
     def _mamba(self, p, u):
         """(the mixer's output (B, T, d), the most negative cumulative
         ``Delta A`` within a chunk)."""
@@ -221,6 +223,7 @@ class NemotronHModel:
                              ).reshape(B, T // L, L, H), axis=2)
         return y @ p["w_out"], jnp.min(jax.lax.stop_gradient(log_decay))
 
+    @device_scope("attention")
     def _attention(self, p, u):
         B, T, _ = u.shape
         heads = lambda y, n: y.reshape(B, T, n, self.head_dim)
@@ -234,14 +237,17 @@ class NemotronHModel:
         how the chunks were routed))."""
         B, T, d = u.shape
         m = u.reshape(B * T, d)
-        logits = jnp.dot(m, p["router"], precision=jax.lax.Precision.HIGHEST)
+        with device_scope("route"):
+            logits = jnp.dot(m, p["router"],
+                             precision=jax.lax.Precision.HIGHEST)
         weights, experts = route_sigmoid_top_k(logits, p["b_corr"],
                                                self.top_k, self.scale, 1e-20)
         y, load, took = held_expert_ffn(
             m, weights, experts, None, p["w_up"], p["w_down"], self.held,
             self.router_experts, chunk_tokens=self.expert_chunk_tokens,
             body="relu2")
-        shared = _relu2(m @ p["shared_up"]) @ p["shared_down"]
+        with device_scope("dense_mlp"):
+            shared = _relu2(m @ p["shared_up"]) @ p["shared_down"]
         return (y + shared).reshape(B, T, d), (load, took)
 
     def _block(self, p, h, kind: str):

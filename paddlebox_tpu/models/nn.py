@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddlebox_tpu.monitor import device_scope
 from paddlebox_tpu.ops.flash_attention import RESIDUAL_NAMES, attention
 
 
@@ -90,6 +91,7 @@ def recomputed(fn, static_argnums=()):
             *RESIDUAL_NAMES))
 
 
+@device_scope("head_loss")
 def next_token_loss(params, h, local_ids, mask, eps: float, head_chunk: int):
     """Mean over positions t < T - 1 of the cross entropy of position
     t's logits against the id at t + 1, one value an example; the

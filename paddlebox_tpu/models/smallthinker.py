@@ -42,6 +42,7 @@ import numpy as np
 
 from paddlebox_tpu.models.nn import (causal_attention, next_token_loss,
                                      recomputed, rms_norm, vocabulary_ids)
+from paddlebox_tpu.monitor import device_scope
 from paddlebox_tpu.parallel.expert import held_expert_ffn, route_top_k
 
 
@@ -128,17 +129,20 @@ class SmallThinkerModel:
         """One layer over h (B, T, d): (h_next, (assignments per held
         expert, how the chunks were routed))."""
         B, T, d = h.shape
-        probs, experts = route_top_k(h.reshape(B * T, d) @ p["router"],
-                                     self.top_k)
+        with device_scope("route"):
+            logits = h.reshape(B * T, d) @ p["router"]
+        probs, experts = route_top_k(logits, self.top_k)
         a = rms_norm(h, p["norm1"], self.eps)
-        heads = lambda y, n: y.reshape(B, T, n, self.head_dim)
-        q, k, v = (heads(a @ p["wq"], self.heads),
-                   heads(a @ p["wk"], self.kv_heads),
-                   heads(a @ p["wv"], self.kv_heads))
-        if kind:
-            q, k = rope(q, self.theta), rope(k, self.theta)
-        o = causal_attention(q, k, v, self.window if kind else None)
-        h = h + o @ p["wo"]
+        with device_scope("attention"):
+            heads = lambda y, n: y.reshape(B, T, n, self.head_dim)
+            q, k, v = (heads(a @ p["wq"], self.heads),
+                       heads(a @ p["wk"], self.kv_heads),
+                       heads(a @ p["wv"], self.kv_heads))
+            if kind:
+                q, k = rope(q, self.theta), rope(k, self.theta)
+            o = causal_attention(q, k, v, self.window if kind else None) \
+                @ p["wo"]
+        h = h + o
         m = rms_norm(h, p["norm2"], self.eps).reshape(B * T, d)
         y, load, took = held_expert_ffn(
             m, probs, experts, p["w_gate"], p["w_up"], p["w_down"],

@@ -22,3 +22,4 @@ from paddlebox_tpu.monitor.hub import (TelemetryHub, counter_add,  # noqa: F401
                                        event, gauge_set, hub, span,
                                        start_metrics_endpoint)
 from paddlebox_tpu.monitor.timers import StageTimers  # noqa: F401
+from paddlebox_tpu.monitor.device_scopes import device_scope  # noqa: F401

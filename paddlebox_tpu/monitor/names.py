@@ -160,6 +160,19 @@ SPAN_NAMES: tuple[str, ...] = (
     "box_begin_pass",
     "box_end_pass",
     "publish",
+    # the stages around a train pass that share no other span: the next
+    # pass's keys handed to the feed thread (Trainer.preload_pass), an
+    # eval pass as a root of its own (the stages it shares with
+    # train_pass nest in it under their names), a mid-pass snapshot (the
+    # loop stalls for it: the thread runs steps ahead of the device)
+    "preload_pass",
+    "eval_pass",
+    "midpass_save",
+    # the device-scope table (monitor/device_scopes.py; only under an open
+    # capture): one program lowered and read on the builder thread, and
+    # the training thread's wait for that thread at the pass's close
+    "device_scopes",
+    "pass_close/device_scopes",
     # serving request spans (serving/frontend.py + server.py, sampled by
     # flags.serving_trace_sample): batch-coalesce wait vs. score time
     "serve/wait",
@@ -221,9 +234,11 @@ KEY_SET_COUNTER_NAMES: tuple[str, ...] = (
 # the Pallas kernels' names, as a device trace's ``XLA Ops`` line shows
 # them (a forward kernel called under differentiation as ``jvp_<name>_``;
 # under its plain name it is a recomputation's call): the only names a
-# device operation carries (``jax.named_scope`` reaches no such event), so
-# the benchmark's kernel readers find a kernel's seconds by them
-# (benchmark/metrics/*_ms_per_step.py)
+# device EVENT carries, so the benchmark's kernel readers find a kernel's
+# seconds by them (benchmark/metrics/*_ms_per_step.py). A
+# ``jax.named_scope`` reaches no event, but it reaches the compiled
+# program: every instruction's ``op_name`` metadata holds it, and an event
+# is named by its instruction — DEVICE_SCOPE_NAMES below
 KERNEL_NAMES: tuple[str, ...] = (
     # ops/pallas_kernels.py: the sparse engines a resolver may select
     "pbtpu_gather_pool",
@@ -242,6 +257,46 @@ KERNEL_NAMES: tuple[str, ...] = (
     # mixer's projections
     "pbtpu_short_conv_fwd",
     "pbtpu_short_conv_bwd",
+)
+
+# the device's stages (monitor.device_scope): a step is written under
+# ``jax.named_scope("pbtpu.<name>")``, the scope is a path component of
+# every instruction's ``op_name`` in the optimized HLO (it survives
+# differentiation, transposition and ``jax.checkpoint``), the innermost
+# registered one is the instruction's stage, and a capture's ``XLA Ops``
+# events are joined with that table by instruction name
+# (monitor/device_scopes.py; ``monitor.trace --device``; the benchmark's
+# ``*_ms_per_step`` readers over ``metrics/_scopes.py``)
+DEVICE_SCOPE_PREFIX = "pbtpu."
+DEVICE_SCOPE_NAMES: tuple[str, ...] = (
+    # the sparse engine around the tower (train/trainer.py, embedding/):
+    # rows gathered for the batch's tokens; token gradients merged onto
+    # the plan's unique lanes; the merged update written to the table
+    # (inline, or the deferred ``jit_apply``)
+    "pull",
+    "premerge",
+    "push",
+    # the model's loss, forward and backward: what no finer scope claims
+    # (a CTR tower whole; a token tower's norms and residual adds)
+    "tower",
+    # the token towers' layers (models/, parallel/expert.py): an attention
+    # half (projections, q/k norms, RoPE, the kernels); a state-space or
+    # short-convolution mixer; the experts' routing (rule, sort, the moves
+    # into and out of the sorted copy, the ladder's switch) and their
+    # grouped products; a dense MLP or shared expert; the head and loss
+    "attention",
+    "mixer",
+    "route",
+    "experts",
+    "dense_mlp",
+    "head_loss",
+    # the dense optimizer's update and the dense sync of any mode
+    "dense_update",
+    # the AUC accumulator's two programs (metrics/auc.py)
+    "auc",
+    # the pass boundary's programs (embedding/feed_pass.py,
+    # working_set.py: combine, fill, patch, gather, pad, split)
+    "boundary",
 )
 
 ALL_NAMES: frozenset = frozenset(EVENT_NAMES) | frozenset(SPAN_NAMES)

@@ -45,7 +45,11 @@ wall clock and eyesight. This module makes one pass ONE causal timeline:
   seconds per span, launches and seconds per Pallas kernel
   (``names.KERNEL_NAMES``), the device's busy and idle time inside
   ``train_pass``, and each idle gap under the innermost span of the
-  training thread that covers it.
+  training thread that covers it. The capture's programs' device-scope
+  table (``monitor/device_scopes.py``: which stage each instruction of
+  each compiled program belongs to) is written beside it as
+  ``device_scopes.json``, and ``--device`` joins the two: seconds,
+  share and launches by scope and program.
 
 Cost discipline: tracing disabled costs ONE module-flag check per scope
 (``_ACTIVE``) — the same contract as the hub's disabled event path,
@@ -57,15 +61,18 @@ CLI::
     python -m paddlebox_tpu.monitor.trace RANK_DIR... \
         [-o world_trace.json] [--rank-names 4,5,7] [--json]
     python -m paddlebox_tpu.monitor.trace --device \
-        <file.xplane.pb | trace_device_dir/pass-NNNNN> [--json]
+        <file.xplane.pb | trace_device_dir/pass-NNNNN> [--json] \
+        [--scopes device_scopes.json]
 """
 
 from __future__ import annotations
 
+import bisect
 import contextvars
 import glob
 import json
 import os
+import re
 import sys
 import uuid
 import warnings
@@ -73,6 +80,7 @@ import zlib
 
 from paddlebox_tpu.config import flags as config_flags
 from paddlebox_tpu.monitor import aggregate as agg_lib
+from paddlebox_tpu.monitor import device_scopes
 from paddlebox_tpu.monitor.names import ANNOTATION_PREFIX as SPAN_PREFIX
 from paddlebox_tpu.monitor.names import KERNEL_NAMES
 from paddlebox_tpu.monitor.registry import STATS
@@ -291,6 +299,14 @@ def _stop_device_capture() -> None:
     except Exception as e:
         _capture_failed("stop_trace", e)
         return
+    if device_scopes.TABLE:
+        # the programs' stages, beside the capture they are joined with
+        try:
+            with open(os.path.join(os.path.dirname(find_xplane(logdir)),
+                                   device_scopes.TABLE_FILE), "w") as f:
+                json.dump(device_scopes.TABLE, f)
+        except OSError as e:
+            _capture_failed("writing " + device_scopes.TABLE_FILE, e)
     from paddlebox_tpu.monitor.hub import event as hub_event
     hub_event("trace.device_capture", type="flow", logdir=logdir,
               state="stopped")
@@ -642,7 +658,13 @@ def records_to_stream(records: "list[dict]") -> dict:
 ROOT_SPAN = "train_pass"
 PATH_SEP = " > "
 DEVICE_OPS_LINE = "XLA Ops"
+DEVICE_MODULES_LINE = "XLA Modules"
 NO_DEVICE_PLANE = "host hlo_op events (no device plane in the capture)"
+# rows of the by-scope table that are no scope's: an instruction the table
+# holds under no scope; an event whose program or instruction it lacks
+UNSCOPED, UNKNOWN = "unscoped", "unknown"
+_INSTRUCTION = re.compile(r"%?([\w.\-]+) = ")
+_PROGRAM_RUN = re.compile(r"\(\d+\)$")
 
 
 def find_xplane(path: str) -> str:
@@ -675,15 +697,52 @@ def kernel_launches(events) -> dict:
     return out
 
 
+def _own_time(events, into: dict) -> None:
+    """Add one line's events ``(start, end, key)`` — they nest or follow,
+    never cross — to ``into[key] = [launches, seconds]``, an enclosing
+    event (a loop, a call) counting only what its children leave
+    (:func:`nest_spans`' rule without its records: a token cell's capture
+    holds a million events)."""
+    stack: list[list] = []              # [end, key, own seconds]
+
+    def close(upto: float) -> None:
+        while stack and stack[-1][0] <= upto:
+            _, key, own = stack.pop()
+            acc = into.setdefault(key, [0, 0.0])
+            acc[0] += 1
+            acc[1] += own
+
+    for a, b, key in sorted(events, key=lambda t: (t[0], -t[1])):
+        close(a)
+        if stack:
+            stack[-1][2] -= min(b, stack[-1][0]) - a
+        stack.append([b, key, b - a])
+    close(float("inf"))
+
+
+def _by_program(ops: dict) -> dict:
+    """``{(program, instruction): [launches, seconds]}`` ->
+    ``{program: {instruction: [launches, seconds]}}`` (JSON's shape)."""
+    out: dict[str, dict] = {}
+    for (program, instruction), acc in ops.items():
+        out.setdefault(program, {})[instruction] = acc
+    return out
+
+
 def read_capture(xplane_path: str) -> dict:
     """What the reduction needs, in seconds on the capture's one clock:
     ``threads`` — per host thread that holds any, its ``pbtpu/`` spans as
     ``(start, end, name)`` — ``device_ops`` — the intervals in which an
     operation ran on the first device (a TPU plane's ``XLA Ops`` line; on
     the CPU backend, where the device is the host, the host events that
-    carry an ``hlo_op``) — and ``kernels``, that device's
+    carry an ``hlo_op``) — ``kernels``, that device's
     :func:`kernel_launches` (empty off a TPU: the Pallas interpreter runs
-    no operation under a kernel's name).
+    no operation under a kernel's name) — and ``ops``, that device's own
+    time by program and instruction, ``{program: {instruction: [launches,
+    seconds]}}``: an ``XLA Ops`` event is named by its instruction and
+    belongs to the ``XLA Modules`` event it starts in (on the CPU backend
+    the host event's ``hlo_op`` and ``hlo_module``), which is what
+    :func:`by_scope` joins with the programs' device-scope table.
     ``device_source`` says which of the two was read: every report
     prints it, so host events never pass for the chip's."""
     import jax
@@ -691,13 +750,32 @@ def read_capture(xplane_path: str) -> dict:
     threads, devices, host_lines = [], [], []   # lines: read twice at most
     for plane in data.planes:
         if plane.name.startswith("/device:TPU:"):
-            for ln in plane.lines:
-                if ln.name == DEVICE_OPS_LINE:
-                    events = list(ln.events)
-                    devices.append((plane.name, [
-                        (e.start_ns / 1e9, (e.start_ns + e.duration_ns) / 1e9)
-                        for e in events], kernel_launches(
-                            (e.name, e.duration_ns / 1e9) for e in events)))
+            lines = {ln.name: ln for ln in plane.lines}
+            if DEVICE_OPS_LINE in lines:
+                events = list(lines[DEVICE_OPS_LINE].events)
+                runs = sorted(
+                    (e.start_ns, _PROGRAM_RUN.sub("", e.name))
+                    for e in (lines[DEVICE_MODULES_LINE].events
+                              if DEVICE_MODULES_LINE in lines else ()))
+                starts = [a for a, _ in runs]
+
+                def program(t, runs=runs, starts=starts):
+                    k = bisect.bisect_right(starts, t) - 1
+                    return runs[k][1] if k >= 0 else ""
+
+                def instruction(name):
+                    m = _INSTRUCTION.match(name)
+                    return m.group(1) if m else name
+                ops: dict = {}
+                _own_time(((e.start_ns / 1e9,
+                            (e.start_ns + e.duration_ns) / 1e9,
+                            (program(e.start_ns), instruction(e.name)))
+                           for e in events), ops)
+                devices.append((plane.name, [
+                    (e.start_ns / 1e9, (e.start_ns + e.duration_ns) / 1e9)
+                    for e in events], kernel_launches(
+                        (e.name, e.duration_ns / 1e9) for e in events),
+                    ops))
         elif plane.name.startswith("/host:"):
             for ln in plane.lines:
                 host_lines.append(ln)
@@ -709,24 +787,32 @@ def read_capture(xplane_path: str) -> dict:
                 if spans:
                     threads.append(spans)
     kernels: dict = {}
+    ops: dict = {}
     if devices:
-        plane_name, device_ops, kernels = min(devices,
-                                              key=lambda d: d[0])
+        plane_name, device_ops, kernels, ops = min(devices,
+                                                   key=lambda d: d[0])
         source = f"{plane_name} {DEVICE_OPS_LINE}"
     else:
         source = NO_DEVICE_PLANE
+        device_ops = []
         with warnings.catch_warnings():
             # jaxlib's stats iterator warns about its own missing
             # __module__ (DeprecationWarning) on first use
             warnings.simplefilter("ignore", DeprecationWarning)
-            device_ops = [
-                (e.start_ns / 1e9, (e.start_ns + e.duration_ns) / 1e9)
-                for ln in host_lines for e in ln.events
-                if e.duration_ns > 0
-                and any(k == "hlo_op" for k, _ in e.stats)]
+            for ln in host_lines:
+                held = []
+                for e in ln.events:
+                    stats = dict(e.stats) if e.duration_ns > 0 else {}
+                    if "hlo_op" in stats:
+                        held.append((e.start_ns / 1e9,
+                                     (e.start_ns + e.duration_ns) / 1e9,
+                                     (str(stats.get("hlo_module", "")),
+                                      str(stats["hlo_op"]))))
+                device_ops += [(a, b) for a, b, _ in held]
+                _own_time(held, ops)
     return {"threads": threads, "device_ops": device_ops,
             "kernels": kernels, "devices": len(devices),
-            "device_source": source}
+            "device_source": source, "ops": _by_program(ops)}
 
 
 def nest_spans(spans: "list[tuple]") -> "tuple[list[dict], list[tuple]]":
@@ -848,6 +934,32 @@ def reduce_capture(threads: "list[list[tuple]]",
     return out
 
 
+def by_scope(ops: dict, table: dict) -> list[dict]:
+    """The device's own time by device scope and program: `ops` is
+    :func:`read_capture`'s ``{program: {instruction: [launches,
+    seconds]}}``, `table` the programs' ``{program: {instruction:
+    {"scope"}}}`` (``device_scopes.TABLE``, or the ``device_scopes.json``
+    a capture was written with). Rows ``{"scope", "program", "launches",
+    "seconds", "share"}``, the longest first; ``unscoped`` is an
+    instruction the table holds under no scope, ``unknown`` an event
+    whose program or instruction the table does not hold. The rows'
+    seconds sum to the own time of every event read."""
+    rows: dict[tuple, list] = {}
+    for program, held in ops.items():
+        known = table.get(program)
+        for instruction, (launches, seconds) in held.items():
+            row = (known or {}).get(instruction)
+            scope = UNKNOWN if row is None else row["scope"] or UNSCOPED
+            acc = rows.setdefault((scope, program), [0, 0.0])
+            acc[0] += launches
+            acc[1] += seconds
+    total = sum(s for _, s in rows.values()) or 1.0
+    return sorted(({"scope": scope, "program": program, "launches": n,
+                    "seconds": s, "share": s / total}
+                   for (scope, program), (n, s) in rows.items()),
+                  key=lambda r: -r["seconds"])
+
+
 def render_capture_text(report: dict) -> str:
     lines = [f"device intervals read from: {report['device_source']}",
              f"{'span':<24}{'count':>7}{'seconds':>12}{'self':>12}"]
@@ -861,6 +973,20 @@ def render_capture_text(report: dict) -> str:
                                 key=lambda kv: -kv[1]["seconds"]):
             lines.append(f"{name:<28}{acc['launches']:>9}"
                          f"{acc['seconds']:>12.6f}")
+    if report.get("scopes"):
+        lines.append(
+            f"device time by scope and program (table: "
+            f"{report['scopes_from']}; the whole capture, busy "
+            f"{report['device_busy_capture_s']:.6f} s):")
+        lines.append(f"{'scope':<14}{'program':<28}{'launches':>9}"
+                     f"{'seconds':>12}{'share':>8}")
+        for r in report["scopes"]:
+            lines.append(f"{r['scope']:<14}{r['program']:<28}"
+                         f"{r['launches']:>9}{r['seconds']:>12.6f}"
+                         f"{100 * r['share']:>7.2f}%")
+    else:
+        lines.append(f"no {device_scopes.TABLE_FILE} beside the capture "
+                     "(--scopes <file>): no device time by scope")
     tp = report.get("train_pass")
     if tp is None:
         lines.append(f"no {SPAN_PREFIX}{ROOT_SPAN} span in the capture: "
@@ -885,15 +1011,28 @@ def render_capture_text(report: dict) -> str:
 
 def device_main(argv: "list[str]") -> int:
     as_json = "--json" in argv
+    scopes_path = None
+    if "--scopes" in argv:
+        i = argv.index("--scopes")
+        scopes_path = argv[i + 1] if i + 1 < len(argv) else ""
+        del argv[i:i + 2]
     paths = [a for a in argv if not a.startswith("-")]
-    if len(paths) != 1:
+    if len(paths) != 1 or scopes_path == "":
         print("usage: python -m paddlebox_tpu.monitor.trace --device "
-              "<file.xplane.pb | trace_device_dir/pass-NNNNN> [--json]",
-              file=sys.stderr)
+              "<file.xplane.pb | trace_device_dir/pass-NNNNN> [--json] "
+              "[--scopes device_scopes.json]", file=sys.stderr)
         return 2
     try:
         xplane = find_xplane(paths[0])
         capture = read_capture(xplane)
+        if scopes_path is None:
+            beside = os.path.join(os.path.dirname(xplane),
+                                  device_scopes.TABLE_FILE)
+            scopes_path = beside if os.path.isfile(beside) else None
+        table = None
+        if scopes_path is not None:
+            with open(scopes_path) as f:
+                table = json.load(f)
     except (OSError, ValueError) as e:
         print(f"trace: cannot read the capture: {e}", file=sys.stderr)
         return 2
@@ -906,6 +1045,11 @@ def device_main(argv: "list[str]") -> int:
     report["devices"] = capture["devices"]
     report["device_source"] = capture["device_source"]
     report["kernels"] = capture["kernels"]
+    report["device_busy_capture_s"] = sum(
+        b - a for a, b in _union(capture["device_ops"]))
+    if table is not None:
+        report["scopes"] = by_scope(capture["ops"], table)
+        report["scopes_from"] = scopes_path
     print(json.dumps(report) if as_json
           else render_capture_text(report), flush=True)
     return 0

@@ -50,6 +50,8 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from paddlebox_tpu.monitor import device_scope
+
 EP_AXIS = "ep"
 
 
@@ -207,6 +209,15 @@ def make_moe(mesh: Mesh, num_experts: int, top_k: int = 2,
 # the share layer: one chip's held experts of a layer routed over all
 # ---------------------------------------------------------------------------
 
+# The share layer's device scopes (monitor/names.py): the whole layer is
+# ``experts``; inside it the routing rule, the sort and everything that
+# moves rows into and out of the sorted copy — the ladder's switch too —
+# is ``route``, and the grouped products with the expert body between them
+# ``experts`` again (the innermost scope wins). A backward pass written by
+# hand here is traced under its call's scopes, and re-enters the ones its
+# own calls open.
+
+@device_scope("route")
 def route_top_k(router_logits: jnp.ndarray, top_k: int
                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """(probs, experts), both (N, top_k): the top_k largest of each
@@ -217,6 +228,7 @@ def route_top_k(router_logits: jnp.ndarray, top_k: int
     return jax.nn.softmax(vals, axis=-1), experts
 
 
+@device_scope("route")
 def route_sigmoid_top_k(router_logits: jnp.ndarray, bias: jnp.ndarray,
                         top_k: int, scale: float, eps: float
                         ) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -296,6 +308,7 @@ EXPERT_BODIES = {"reglu": ("ReGLU", jax.nn.relu),
                  "relu2": ("relu squared", None)}
 
 
+@device_scope("experts")
 def _expert_rows(xs, sizes, body, w_gate, w_up, w_down, dtype):
     """Sorted rows through their experts' bodies: (rows, D) -> (rows, D)
     in `dtype`; rows past the last group are not computed."""
@@ -398,6 +411,7 @@ def _bounded_chunk(bound: int, body, x, probs, order, sizes, by_token,
                          plan)
 
 
+@device_scope("route")
 def _rung(bound: int, body: str, x, probs, routed, weights):
     order, inv, sizes, by_token, held_choices = routed
     if bound == probs.shape[0] * probs.shape[1]:
@@ -415,9 +429,10 @@ def _ladder(rungs, body, rung, x, probs, routed, weights):
     forward and backward inside its branch: nothing is kept but the
     arguments (the chunk is recomputed, not stored), and the rungs not
     taken leave no residuals to fill with zeros."""
-    return lax.switch(rung,
-                      [functools.partial(_rung, b, body) for b in rungs],
-                      x, probs, routed, weights)
+    with device_scope("route"):
+        return lax.switch(rung,
+                          [functools.partial(_rung, b, body) for b in rungs],
+                          x, probs, routed, weights)
 
 
 def _ladder_fwd(rungs, body, rung, x, probs, routed, weights):
@@ -432,9 +447,10 @@ def _ladder_bwd(rungs, body, kept, g):
         return jax.vjp(lambda x, p, w: _rung(bound, body, x, p, routed, w),
                        x, probs, weights)[1](g)
 
-    dx, dprobs, dweights = lax.switch(
-        rung, [functools.partial(back, b) for b in rungs],
-        x, probs, routed, weights, g)
+    with device_scope("route"):
+        dx, dprobs, dweights = lax.switch(
+            rung, [functools.partial(back, b) for b in rungs],
+            x, probs, routed, weights, g)
     return None, dx, dprobs, None, dweights
 
 
@@ -447,25 +463,29 @@ def _held_chunk(x, probs, experts, body: str, w_gate, w_up, w_down,
     (``EXPERT_BODIES``). Returns the chunk's output (n, D),
     its assignments per held expert (count,) and the rung it took as
     (rows of the sorted copy, 1 if that was the whole chunk)."""
-    local = experts - first
-    held = (local >= 0) & (local < count)
-    # not-held assignments sort past the last group: never computed
-    key = jnp.where(held, local, count).reshape(-1).astype(jnp.int32)
-    order = jnp.argsort(key)                      # stable: by expert
-    sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
-    # the least rung that holds what the held experts received
-    rung = jnp.sum(jnp.sum(sizes) > jnp.asarray(rungs[:-1], jnp.int32))
-    inv = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=order.dtype))
-    routed = (order, inv, sizes, _token_order(held, inv),
-              jnp.sum(held, axis=1, dtype=jnp.int32))
+    with device_scope("route"):
+        local = experts - first
+        held = (local >= 0) & (local < count)
+        # not-held assignments sort past the last group: never computed
+        key = jnp.where(held, local, count).reshape(-1).astype(jnp.int32)
+        order = jnp.argsort(key)                  # stable: by expert
+        sizes = jnp.bincount(key, length=count + 1)[:count].astype(
+            jnp.int32)
+        # the least rung that holds what the held experts received
+        rung = jnp.sum(jnp.sum(sizes) > jnp.asarray(rungs[:-1], jnp.int32))
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        routed = (order, inv, sizes, _token_order(held, inv),
+                  jnp.sum(held, axis=1, dtype=jnp.int32))
     out = _ladder(rungs, body, rung, x, probs, routed,
                   (w_gate, w_up, w_down))
-    took = jnp.stack([jnp.asarray(rungs, jnp.int32)[rung],
-                      (rung == len(rungs) - 1).astype(jnp.int32)])
+    with device_scope("route"):
+        took = jnp.stack([jnp.asarray(rungs, jnp.int32)[rung],
+                          (rung == len(rungs) - 1).astype(jnp.int32)])
     return out, sizes, took
 
 
+@device_scope("experts")
 def held_expert_ffn(x: jnp.ndarray, probs: jnp.ndarray,
                     experts: jnp.ndarray, w_gate: jnp.ndarray | None,
                     w_up: jnp.ndarray, w_down: jnp.ndarray,

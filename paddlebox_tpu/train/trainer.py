@@ -45,6 +45,7 @@ from paddlebox_tpu.train import optimizers
 from paddlebox_tpu.parallel import mesh as mesh_lib
 from paddlebox_tpu import monitor
 from paddlebox_tpu.monitor import context as mon_ctx
+from paddlebox_tpu.monitor import device_scopes
 from paddlebox_tpu.monitor import trace as mon_trace
 from paddlebox_tpu.monitor.timers import StageTimers
 from paddlebox_tpu.utils import faultpoint
@@ -362,6 +363,9 @@ class Trainer:
         self._last_dense: tuple | None = None
         self._pass_aborted = False
         self.feed_mgr.register_pre_flush(self.flush_push)
+        # the programs the last pass under a profiler capture ran, each
+        # with its call's argument specs (device_scope_table)
+        self._scope_programs: dict = {}
         self._rebuild_steps()
         self._auc_fn = jax.jit(auc_lib.auc_update)
         self._auc_masked_fn = jax.jit(
@@ -453,16 +457,20 @@ class Trainer:
         wire = self.exchange_wire
         topo = self.exchange_topology or "flat"
 
+        @monitor.device_scope("push")
         def push_tail(tshard, flat_idx, sgrad, mask_l, labels_l, plan):
             """Push stage tail: deferred operands, or the inline routed
             merge-update. Deferred: the apply program
             replays the same inputs one step later (Trainer._apply_fn)."""
             if defer:
-                show_inc = mask_l.reshape(-1).astype(jnp.float32)
-                clk_inc = (mask_l.astype(jnp.float32)
-                           * labels_l[:, None]).reshape(-1)
-                return sharded.deferred_push_operands(
-                    flat_idx, sgrad, show_inc, clk_inc, plan)
+                # the step's half of a deferred push is its operands: the
+                # premerge's, with the counters' increments it merges
+                with monitor.device_scope("premerge"):
+                    show_inc = mask_l.reshape(-1).astype(jnp.float32)
+                    clk_inc = (mask_l.astype(jnp.float32)
+                               * labels_l[:, None]).reshape(-1)
+                    return sharded.deferred_push_operands(
+                        flat_idx, sgrad, show_inc, clk_inc, plan)
             show_inc = mask_l.reshape(-1).astype(jnp.float32)
             clk_inc = (mask_l.astype(jnp.float32)
                        * labels_l[:, None]).reshape(-1)
@@ -489,55 +497,62 @@ class Trainer:
                 # (B*T, P) token matrix exists in neither direction
                 # (backward expands the pooled cotangent per token
                 # straight into the premerge/binned push).
-                if sharded_x:
-                    # route the unique rows once, pool per shard from
-                    # the received lanes (gather_pool after routing)
-                    pooled, dropped = exchange.routed_pull_pooled(
-                        tshard, idx_l, emb_cfg, axes, num_slots, L_hot,
-                        capf, plan=plan, return_dropped=True)
-                else:
-                    pooled = sharded.fused_pull_pool(
-                        tshard, idx_l, emb_cfg, num_slots, L_hot)
-                    dropped = jnp.zeros((), jnp.int32)
+                with monitor.device_scope("pull"):
+                    if sharded_x:
+                        # route the unique rows once, pool per shard from
+                        # the received lanes (gather_pool after routing)
+                        pooled, dropped = exchange.routed_pull_pooled(
+                            tshard, idx_l, emb_cfg, axes, num_slots, L_hot,
+                            capf, plan=plan, return_dropped=True)
+                    else:
+                        pooled = sharded.fused_pull_pool(
+                            tshard, idx_l, emb_cfg, num_slots, L_hot)
+                        dropped = jnp.zeros((), jnp.int32)
 
                 def loss_fn(p, pooled_in):
                     return model_loss(p, PooledSlots(pooled_in), mask_l,
                                       dense_l, labels_l, *extras_l)
 
-                grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1),
-                                             has_aux=True)
-                (loss, (preds, stats)), (gp, gpooled) = grad_fn(
-                    params, pooled)
-                sgrad = sharded.pooled_grad_tokens(gpooled, mask_l,
-                                                   seg, num_slots)
-                if cfg.scale_sparse_grad_by_global_mean:
-                    sgrad = sgrad / D
+                # the tower's scope reaches its backward pass, and the
+                # token gradients that pass ends in
+                with monitor.device_scope("tower"):
+                    grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1),
+                                                 has_aux=True)
+                    (loss, (preds, stats)), (gp, gpooled) = grad_fn(
+                        params, pooled)
+                    sgrad = sharded.pooled_grad_tokens(gpooled, mask_l,
+                                                       seg, num_slots)
+                    if cfg.scale_sparse_grad_by_global_mean:
+                        sgrad = sgrad / D
                 new_shard = push_tail(tshard, flat_idx, sgrad, mask_l,
                                       labels_l, plan)
                 return (new_shard, gp, loss, preds, lax.psum(dropped, axes),
                         *stats)
-            if sharded_x:
-                pulled, dropped = exchange.routed_pull(
-                    tshard, flat_idx, emb_cfg, axes, capf, plan=plan,
-                    dedup=dedup, return_dropped=True)
-            else:
-                pulled, dropped = sharded.routed_lookup(
-                    tshard, flat_idx, emb_cfg, axes, capf, dedup=dedup,
-                    return_dropped=True)
-            pulled = pulled.reshape(B_l, T, emb_cfg.pull_width)
+            with monitor.device_scope("pull"):
+                if sharded_x:
+                    pulled, dropped = exchange.routed_pull(
+                        tshard, flat_idx, emb_cfg, axes, capf, plan=plan,
+                        dedup=dedup, return_dropped=True)
+                else:
+                    pulled, dropped = sharded.routed_lookup(
+                        tshard, flat_idx, emb_cfg, axes, capf, dedup=dedup,
+                        return_dropped=True)
+                pulled = pulled.reshape(B_l, T, emb_cfg.pull_width)
 
             def loss_fn(p, pulled_in):
                 return model_loss(p, pulled_in, mask_l, dense_l, labels_l,
                                   *extras_l)
 
-            grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1),
-                                         has_aux=True)
-            (loss, (preds, stats)), (gp, gpull) = grad_fn(params, pulled)
-            # sparse grads: only (w, embedx) columns train; show/clk
-            # are counters (CVM grads dropped, like cvm_op's grad)
-            sgrad = gpull[..., 2:].reshape(B_l * T, emb_cfg.grad_width)
-            if cfg.scale_sparse_grad_by_global_mean:
-                sgrad = sgrad / D
+            with monitor.device_scope("tower"):
+                grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1),
+                                             has_aux=True)
+                (loss, (preds, stats)), (gp, gpull) = grad_fn(params,
+                                                              pulled)
+                # sparse grads: only (w, embedx) columns train; show/clk
+                # are counters (CVM grads dropped, like cvm_op's grad)
+                sgrad = gpull[..., 2:].reshape(B_l * T, emb_cfg.grad_width)
+                if cfg.scale_sparse_grad_by_global_mean:
+                    sgrad = sgrad / D
             new_shard = push_tail(tshard, flat_idx, sgrad, mask_l,
                                   labels_l, plan)
             # capacity-drop monitor: global count of tokens the fixed-size
@@ -571,8 +586,9 @@ class Trainer:
                 new_shard, gp, loss, preds, drop_g = core(
                     tshard, idx_l, mask_l, dense_l, labels_l, p,
                     order, rstart, endb, uniq, segb)
-                updates, new_o = tx.update(gp, o, p)
-                new_p = optax.apply_updates(p, updates)
+                with monitor.device_scope("dense_update"):
+                    updates, new_o = tx.update(gp, o, p)
+                    new_p = optax.apply_updates(p, updates)
                 loss_g = lax.pmean(loss, axes)
                 lift = lambda t: jax.tree.map(lambda a: a[None], t)
                 return (new_shard, lift(new_p), lift(new_o), loss_g, preds,
@@ -607,7 +623,8 @@ class Trainer:
                 new_shard, gp, loss, preds, drop_g = core(
                     tshard, idx_l, mask_l, dense_l, labels_l, params,
                     order, rstart, endb, uniq, segb)
-                gp = _mean_replicated_grad(gp, axes)
+                with monitor.device_scope("dense_update"):
+                    gp = _mean_replicated_grad(gp, axes)
                 loss_g = lax.pmean(loss, axes)
                 return new_shard, gp, loss_g, preds, drop_g
 
@@ -622,7 +639,8 @@ class Trainer:
                     out_specs=(batch_spec, P(), P(), batch_spec, P()),
                 )(table, idx, mask, dense, labels, params,
                   order, rstart, endb, uniq, segb)
-                gp_flat = ravel_pytree(gp)[0]
+                with monitor.device_scope("dense_update"):
+                    gp_flat = ravel_pytree(gp)[0]
                 return new_table, gp_flat, loss, preds, drop_g
 
             return jax.jit(step, donate_argnums=(0,),
@@ -641,7 +659,8 @@ class Trainer:
             head, gp, loss, preds, drop_g, *stats = core(
                 tshard, idx_l, mask_l, dense_l, labels_l, params,
                 order, rstart, endb, uniq, segb, *extras_l)
-            gp = _mean_replicated_grad(gp, axes)
+            with monitor.device_scope("dense_update"):
+                gp = _mean_replicated_grad(gp, axes)
             loss_g = lax.pmean(loss, axes)
             head = head if defer else (head,)
             stats = [model_base.reduce_stats(stat_names, st, axes)
@@ -661,8 +680,9 @@ class Trainer:
             )(table, idx, mask, dense, labels, params,
               order, rstart, endb, uniq, segb, *extras)
             head, gp, tail = out[:n_head], out[n_head], out[n_head + 1:]
-            updates, new_opt = tx.update(gp, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
+            with monitor.device_scope("dense_update"):
+                updates, new_opt = tx.update(gp, opt_state, params)
+                new_params = optax.apply_updates(params, updates)
             # tail: (*stats, loss, preds, drop_g)
             return head, new_params, new_opt, tail
 
@@ -673,17 +693,21 @@ class Trainer:
                 dstate = args[:n_dense]
                 (idx, mask, dense, labels, order, rstart,
                  endb, uniq, segb, *extras) = args[n_dense:]
-                params, opt_state = unpack_fn(dstate)
+                # the flat transport's copies are the dense update's
+                with monitor.device_scope("dense_update"):
+                    params, opt_state = unpack_fn(dstate)
                 head, new_params, new_opt, tail = \
                     run_body(table, params, opt_state, idx, mask, dense,
                              labels, order, rstart, endb, uniq, segb,
                              *extras)
+                with monitor.device_scope("dense_update"):
+                    packed = pack_fn(new_params, new_opt)
                 if defer:
                     # (*dstate, g0, g1, g2, [stats,] loss, preds,
                     # dropped): the table is read, never written — the
                     # apply program owns the update (split_defer_out)
-                    return (*pack_fn(new_params, new_opt), *head, *tail)
-                return (head[0], *pack_fn(new_params, new_opt), *tail)
+                    return (*packed, *head, *tail)
+                return (head[0], *packed, *tail)
 
             if defer:
                 return jax.jit(
@@ -735,6 +759,7 @@ class Trainer:
         batch_spec = P(axes)
         tbl_sh = mesh_lib.table_sharding(self.mesh)
 
+        @monitor.device_scope("push")       # the whole program is the push
         def body(tshard, idx_l, mask_l, labels_l, order, rstart, endb,
                  uniq, segb, g0, g1, g2):
             if uniq.shape[0] and g1.shape[0]:
@@ -818,9 +843,10 @@ class Trainer:
         sync_moment = self.cfg.sync_dense_moment
 
         def body(p_st, o_st):
-            avg = jax.tree.map(lambda a: lax.pmean(a, axes), p_st)
-            if sync_moment:  # FLAGS_enable_sync_dense_moment
-                o_st = jax.tree.map(lambda a: lax.pmean(a, axes), o_st)
+            with monitor.device_scope("dense_update"):
+                avg = jax.tree.map(lambda a: lax.pmean(a, axes), p_st)
+                if sync_moment:  # FLAGS_enable_sync_dense_moment
+                    o_st = jax.tree.map(lambda a: lax.pmean(a, axes), o_st)
             return avg, o_st
 
         def sync(params, opt_state):
@@ -851,32 +877,37 @@ class Trainer:
         def body(tshard, idx_l, mask_l, dense_l, params, *extras_l):
             B_l = idx_l.shape[0]
             if fused_pull:
-                if sharded_x:
-                    # eval packs no plan: the pooled route dedups on
-                    # device, pools per shard from the received lanes
-                    pooled, fdrop = exchange.routed_pull_pooled(
-                        tshard, idx_l, emb_cfg, axes, num_slots, L_hot,
-                        capf, return_dropped=True)
-                else:
-                    pooled = sharded.fused_pull_pool(tshard, idx_l,
-                                                     emb_cfg, num_slots,
-                                                     L_hot)
-                    fdrop = jnp.zeros((), jnp.int32)
-                logits = model.apply(params, PooledSlots(pooled), mask_l,
-                                     dense_l, seg, num_slots, *extras_l)
-                return jax.nn.sigmoid(logits), lax.psum(fdrop, axes)
-            pulled, dropped = (
-                exchange.routed_pull(tshard, idx_l.reshape(-1), emb_cfg,
-                                     axes, capf, dedup=dedup,
-                                     return_dropped=True)
-                if sharded_x else
-                sharded.routed_lookup(tshard, idx_l.reshape(-1), emb_cfg,
-                                      axes, capf, dedup=dedup,
-                                      return_dropped=True))
-            pulled = pulled.reshape(B_l, T, emb_cfg.pull_width)
-            logits = model.apply(params, pulled, mask_l, dense_l, seg,
-                                 num_slots, *extras_l)
-            return jax.nn.sigmoid(logits), lax.psum(dropped, axes)
+                with monitor.device_scope("pull"):
+                    if sharded_x:
+                        # eval packs no plan: the pooled route dedups on
+                        # device, pools per shard from the received lanes
+                        pooled, fdrop = exchange.routed_pull_pooled(
+                            tshard, idx_l, emb_cfg, axes, num_slots, L_hot,
+                            capf, return_dropped=True)
+                    else:
+                        pooled = sharded.fused_pull_pool(tshard, idx_l,
+                                                         emb_cfg, num_slots,
+                                                         L_hot)
+                        fdrop = jnp.zeros((), jnp.int32)
+                with monitor.device_scope("tower"):
+                    logits = model.apply(params, PooledSlots(pooled),
+                                         mask_l, dense_l, seg, num_slots,
+                                         *extras_l)
+                    return jax.nn.sigmoid(logits), lax.psum(fdrop, axes)
+            with monitor.device_scope("pull"):
+                pulled, dropped = (
+                    exchange.routed_pull(tshard, idx_l.reshape(-1), emb_cfg,
+                                         axes, capf, dedup=dedup,
+                                         return_dropped=True)
+                    if sharded_x else
+                    sharded.routed_lookup(tshard, idx_l.reshape(-1),
+                                          emb_cfg, axes, capf, dedup=dedup,
+                                          return_dropped=True))
+                pulled = pulled.reshape(B_l, T, emb_cfg.pull_width)
+            with monitor.device_scope("tower"):
+                logits = model.apply(params, pulled, mask_l, dense_l, seg,
+                                     num_slots, *extras_l)
+                return jax.nn.sigmoid(logits), lax.psum(dropped, axes)
 
         batch_spec = P(axes)
 
@@ -1294,6 +1325,21 @@ class Trainer:
                               for p in jax.tree.leaves(ws.table)]
                              if live else None)}
 
+    def device_scope_table(self) -> dict[str, dict]:
+        """``{module name: {instruction: {"result", "scope"}}}`` of every
+        program the last pass under a profiler capture ran — the step,
+        the apply, the AUC pair, the boundary's — lowered and compiled
+        again from that call's shapes, dtypes and shardings (a hit in
+        jit's own cache) and read by ``device_scopes.scopes_of_hlo``;
+        also kept in ``device_scopes.TABLE``. Empty where no pass ran
+        under a capture."""
+        out: dict[str, dict] = {}
+        for fn, specs in self._scope_programs.items():
+            if specs is not None:
+                out.update(device_scopes.table_of(fn, specs))
+        device_scopes.TABLE.update(out)
+        return out
+
     def block_until_ready(self) -> None:
         """Wait for everything the loop has dispatched: the table of the
         live working set (the last deferred apply lands after the last
@@ -1498,6 +1544,13 @@ class Trainer:
                          skip_steps: int = 0, *,
                          pass_t0: float) -> dict[str, float]:
         cfg = self.cfg
+        # the device-scope table (monitor/device_scopes.py): only where a
+        # profiler capture is open — the benchmark's own, or
+        # flags.trace_device's, started in open_pass_auto — are this
+        # pass's programs noted at their first call, and read at the
+        # close; with none open the pass pays this one static call
+        build = (device_scopes.open_build()
+                 if jax.profiler.TraceAnnotation.is_enabled() else None)
         with self.timers("unique_keys", span="unique_keys"):
             keys = dataset.unique_keys()
         ws = self.feed_mgr.begin_pass(keys)
@@ -1577,8 +1630,10 @@ class Trainer:
                     if mode == "async":
                         params = jax.device_put(
                             self._unravel(self.dense_table.pull()), repl)
-                        table, gp_flat, loss, preds, dropped = self._step_fn(
-                            table, params, idx, mask, dense, labels, *plan)
+                        table, gp_flat, loss, preds, dropped = \
+                            device_scopes.run(self._step_fn, table, params,
+                                              idx, mask, dense, labels,
+                                              *plan)
                         self.dense_table.push(np.asarray(gp_flat))
                     elif self.push_overlap:
                         # deferred push pipeline: dispatch step N-1's
@@ -1591,8 +1646,9 @@ class Trainer:
                         table = self._dispatch_pending_apply(table)
                         dst = (dstate if dstate is not None
                                else (params, opt_state))
-                        out = self._defer_step_fn(table, *dst, idx, mask,
-                                                  dense, labels, *plan)
+                        out = device_scopes.run(
+                            self._defer_step_fn, table, *dst, idx, mask,
+                            dense, labels, *plan)
                         (dst, push_ops, loss, preds,
                          dropped) = self.split_defer_out(out)
                         if dstate is not None:
@@ -1603,20 +1659,22 @@ class Trainer:
                             (idx, mask, labels,
                              tuple(plan[:PLAN_ARITY]), push_ops))
                     elif dstate is not None:
-                        out = self._step_fn(table, *dstate, idx, mask,
-                                            dense, labels, *plan)
+                        out = device_scopes.run(
+                            self._step_fn, table, *dstate, idx, mask,
+                            dense, labels, *plan)
                         (table, dstate, loss, preds,
                          dropped) = self.split_step_out(out)
                     else:
-                        out = self._step_fn(
-                            table, params, opt_state, idx, mask, dense,
-                            labels, *plan)
+                        out = device_scopes.run(
+                            self._step_fn, table, params, opt_state, idx,
+                            mask, dense, labels, *plan)
                         (table, (params, opt_state), loss, preds,
                          dropped) = self.split_step_out(out)
                     pass_step += 1
                     if (mode == "kstep"
                             and pass_step % cfg.param_sync_step == 0):
-                        params, opt_state = self._sync_fn(params, opt_state)
+                        params, opt_state = device_scopes.run(
+                            self._sync_fn, params, opt_state)
                 if self._n_stats:
                     # the model's statistics sit just before the loss in
                     # every allreduce step program's output
@@ -1706,6 +1764,11 @@ class Trainer:
                     # before anything reads or persists the table
                     table = self._dispatch_pending_apply(table)
                 ws.table = table
+                if build is not None:
+                    # the pass's last dispatch is out: the table of the
+                    # programs it ran is read on a thread of its own
+                    # while this one closes the pass and drains
+                    build.start()
                 self.feed_mgr.pass_closed()
                 with monitor.span("pass_close/rebind"):
                     if mode == "async":
@@ -1726,8 +1789,8 @@ class Trainer:
                                             else (params, opt_state))
                         if mode == "kstep":
                             # end-of-pass sync (trainer Finalize)
-                            params, opt_state = self._sync_fn(params,
-                                                              opt_state)
+                            params, opt_state = device_scopes.run(
+                                self._sync_fn, params, opt_state)
                         if dstate is not None:
                             params, opt_state = self.unpack_dense(dstate)
                         self.params, self.opt_state = params, opt_state
@@ -1746,9 +1809,18 @@ class Trainer:
                     except Exception as e:
                         import warnings
                         warnings.warn(f"dump stream failed: {e}")
-                if completed:
-                    out = self._read_pass(ws, table, dev_losses,
-                                          dev_dropped, auc_acc, dev_stats)
+                try:
+                    if completed:
+                        out = self._read_pass(ws, table, dev_losses,
+                                              dev_dropped, auc_acc,
+                                              dev_stats)
+                finally:
+                    if build is not None:
+                        # after the drain and the read: what is left of
+                        # the builder thread's work
+                        with monitor.span("pass_close/device_scopes"):
+                            device_scopes.close_build(build)
+                        self._scope_programs = build.seen
         return out
 
     def _read_pass(self, ws: PassWorkingSet, table, dev_losses: list,
@@ -1997,7 +2069,8 @@ class Trainer:
         fetch, H2D of fresh rows) on a background thread while the current
         pass trains — box_wrapper.h:994-1072, paired with the dataset's
         preload_into_memory (data_set.cc:1712)."""
-        self.feed_mgr.begin_feed_pass(keys)
+        with monitor.span("preload_pass"):
+            self.feed_mgr.begin_feed_pass(keys)
 
     def wait_feed_pass_done(self) -> None:
         """Join the background feed pass (BoxHelper::WaitFeedPassDone)."""
@@ -2026,7 +2099,8 @@ class Trainer:
         faultpoint.hit("trainer.push_apply.pre")
         idx, mask, labels, plan, ops = item
         with monitor.span("push_apply"):
-            table = self._apply_fn(table, idx, mask, labels, *plan, *ops)
+            table = device_scopes.run(self._apply_fn, table, idx, mask,
+                                      labels, *plan, *ops)
         self.push_applies += 1
         monitor.counter_add("trainer.push_applies")
         return table
@@ -2157,6 +2231,7 @@ class Trainer:
                              "(the cursor's pass identity)")
         self._midpass = (checkpointer, int(every_steps), box, metrics)
 
+    @monitor.span("midpass_save")
     def _midpass_save(self, table, ws, dstate, params, opt_state,
                       pass_step: int):
         """Commit a MID-pass snapshot: land the pending deferred push,
@@ -2353,6 +2428,11 @@ class Trainer:
                 checkpointer, self, collectives, box=box, metrics=metrics)
         return checkpointer.resume(self, box=box, metrics=metrics)
 
+    # the root of an eval pass's timeline, as train_pass is of a train
+    # pass's: the stages the two share nest in it under their own names
+    # (unique_keys, boundary, preplan, the pack thread's, h2d_stage,
+    # auc_update, pass_close/read)
+    @monitor.span("eval_pass")
     def eval_pass(self, dataset) -> dict[str, float]:
         """Test-mode pass: no pushes, no dense updates, and the store is
         neither grown nor dirtied by unseen keys (SetTestMode).
@@ -2394,8 +2474,9 @@ class Trainer:
         with self.timers("unique_keys", span="unique_keys"):
             keys = dataset.unique_keys()
         ws = self.feed_mgr.begin_pass(keys, test_mode=True)
-        self._preplan_capacity(dataset, ws, drop_last=False,
-                               for_eval=True)
+        with self.timers("preplan", span="preplan"):
+            self._preplan_capacity(dataset, ws, drop_last=False,
+                                   for_eval=True)
         auc_acc = auc_lib.AucAccumulator(self.cfg.auc_buckets)
         dev_dropped = []
         # same background pack pipeline as train_pass (translate + H2D
@@ -2413,14 +2494,17 @@ class Trainer:
                                                self.eval_params(),
                                                idx, mask, dense, *extras)
                 valid = jnp.arange(bs) < pb.num    # pre-pad valid count
-                auc_acc.update(self._auc_masked_fn, preds, labels, valid)
+                with self.timers("auc", span="auc_update"):
+                    auc_acc.update(self._auc_masked_fn, preds, labels,
+                                   valid)
                 dev_dropped.append(dropped)
         finally:
             pack_it.close()
-        out = auc_acc.compute()
-        # drops poison eval predictions too — same non-silent policy,
-        # but adaptation stays on the eval program only (and eval_pass
-        # re-runs this whole body at the grown factor)
-        out["routed_dropped"] = self._check_dropped(dev_dropped,
-                                                   for_eval=True)
+        with monitor.span("pass_close/read"):
+            out = auc_acc.compute()
+            # drops poison eval predictions too — same non-silent policy,
+            # but adaptation stays on the eval program only (and eval_pass
+            # re-runs this whole body at the grown factor)
+            out["routed_dropped"] = self._check_dropped(dev_dropped,
+                                                       for_eval=True)
         return out

@@ -7,8 +7,10 @@ the persistent cache on first thing. The directory is part of the cache key's
 environment, so it must not move between runs: when
 ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and nothing is
 set in code; otherwise it is ``.jax_cache`` at the root of this checkout
-(listed in ``.gitignore``) — never a temp name, a pid or a time. Tests do
-not call this: they compile small programs and leave the cache off.
+(listed in ``.gitignore``) — never a temp name, a pid or a time. The key
+holds each program's metadata too (``enable_compile_cache``), so an entry
+serves only the tree it was compiled from. Tests do not call this: they
+compile small programs and leave the cache off.
 """
 
 from __future__ import annotations
@@ -63,11 +65,19 @@ def enable_compile_cache() -> dict:
     Returns ``{"dir", "from", "warm"}``: the directory in use, whether
     the environment or this checkout named it, and whether it already
     held entries when this process started."""
+    import jax
+    # An executable's metadata is read since PR 38 (monitor/device_scopes.py
+    # takes each instruction's stage from its op_name), and JAX leaves
+    # metadata out of the cache's key by default: a tree that differs from
+    # the one that filled the cache in scopes alone would run that tree's
+    # executables and read its names. With metadata in the key an entry
+    # answers only the source it was compiled from (file, line and scope
+    # of every operation), at the price of a miss where lines moved.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env_dir = os.environ.get(ENV_VAR)
     if env_dir:
         cache_dir, source = env_dir, "env"
     else:
-        import jax
         cache_dir, source = os.path.join(_CHECKOUT, ".jax_cache"), "checkout"
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     try:

@@ -393,3 +393,48 @@ def test_short_conv_compiles_at_the_published_widths(one_chip, on_tpu,
     assert "tpu_custom_call" in text
     assert ("pbtpu_short_conv_fwd" in text) == (direction == "forward")
     assert ("pbtpu_short_conv_bwd" in text) == (direction == "backward")
+
+
+# ---------------------------------------------------------------------------
+# the device scopes in a program the chip's compiler optimized (ISSUE 38):
+# a small attention tower's differentiated step — the kernels' custom calls
+# under ``attention``, and little of the program under no scope at all
+# ---------------------------------------------------------------------------
+
+def test_the_optimized_step_holds_its_instructions_under_scopes(one_chip,
+                                                                on_tpu):
+    from paddlebox_tpu import monitor
+    from paddlebox_tpu.monitor import device_scopes
+    from token_tower_common import tower
+    _, _, model, params, pulled, ids = tower(
+        "smallthinker_21b_ep4.seq8k", hidden_size=256,
+        num_attention_heads=2, num_key_value_heads=1, head_dim=128,
+        moe_ffn_hidden_size=128, sliding_window_size=256, vocab_size=1024,
+        seq_len=512, head_chunk=256, expert_chunk_tokens=512)
+
+    def step(params, pulled, ids):
+        mask = jnp.ones(ids.shape, bool)
+        with monitor.device_scope("tower"):
+            loss, grads = jax.value_and_grad(
+                lambda p: model.loss(p, pulled, mask, None, None, ids)[0])(
+                    params)
+        with monitor.device_scope("dense_update"):
+            return loss, jax.tree.map(lambda w, g: w - 1e-3 * g, params,
+                                      grads)
+
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=one_chip)
+    text = jax.jit(step).lower(
+        *jax.tree.map(like, (params, pulled, ids))).compile().as_text()
+    rows = device_scopes.scopes_of_hlo(text)
+    kernels = {name: row["scope"] for name, row in rows.items()
+               if "pbtpu_attention" in name}
+    for kernel in ("pbtpu_attention_fwd", "pbtpu_attention_dq",
+                   "pbtpu_attention_dkv"):
+        assert any(kernel in name for name in kernels), sorted(kernels)
+    assert set(kernels.values()) == {"attention"}, kernels
+    held = {row["scope"] for row in rows.values()}
+    assert {"attention", "route", "experts", "head_loss", "tower",
+            "dense_update"} <= held
+    bare = [name for name, row in rows.items() if row["scope"] is None]
+    assert len(bare) < 0.05 * len(rows), (len(bare), len(rows), bare[:40])

@@ -536,3 +536,24 @@ def test_kernel_names_are_the_pallas_calls_own():
     assert found == set(names.KERNEL_NAMES)
     assert len(set(names.KERNEL_NAMES)) == len(names.KERNEL_NAMES)
     assert {"pbtpu_short_conv_fwd", "pbtpu_short_conv_bwd"} <= found
+
+
+def test_device_scope_names_are_the_scopes_the_code_opens():
+    """``names.DEVICE_SCOPE_NAMES`` is closed over every literal
+    ``device_scope("...")`` in the package, as ``KERNEL_NAMES`` is over
+    the kernels: the capture reader's and the benchmark's by-scope rows
+    are those names and no other."""
+    import re
+    from paddlebox_tpu.monitor import names
+    root = os.path.join(os.path.dirname(os.path.abspath(monitor.__file__)),
+                        "..")
+    opened = set()
+    for dirpath, _, files in os.walk(root):
+        for fname in files:
+            if fname.endswith(".py"):
+                with open(os.path.join(dirpath, fname)) as f:
+                    opened |= set(re.findall(
+                        r'device_scope\(\s*"(\w+)"', f.read()))
+    assert opened == set(names.DEVICE_SCOPE_NAMES)
+    assert len(set(names.DEVICE_SCOPE_NAMES)) == len(names.DEVICE_SCOPE_NAMES)
+    assert names.DEVICE_SCOPE_PREFIX + "x" != names.ANNOTATION_PREFIX + "x"

@@ -113,12 +113,19 @@ def test_capture_nests_the_pass_under_train_pass(captured):
         assert ("train_pass", "pass_close", name) in paths, name
     # the BoxPS lifecycle calls are the root's siblings, not its children
     assert ("box_begin_pass",) in paths and ("box_end_pass",) in paths
-    # the pack thread's work is on its own line, outside the root
+    # the pack thread's work is on its own line, outside the root (the
+    # device-scope table's builder starts after the pack thread has ended
+    # and may be given its thread id, so its spans its line: ISSUE 38)
     pack = [recs for recs in nested
             if any(r["name"] == "stage/translate" for r in recs)]
-    assert pack and all(r["path"] == ("stage/translate",)
+    assert pack and all(r["path"] in (("stage/translate",),
+                                      ("device_scopes",))
                         for recs in pack for r in recs)
     assert main[0] is not pack[0]
+    built = [r for recs in nested for r in recs
+             if r["name"] == "device_scopes"]
+    assert built and all(r["path"] == ("device_scopes",) for r in built)
+    assert ("train_pass", "pass_close", "pass_close/device_scopes") in paths
 
 
 def test_every_annotation_carries_the_pass_and_is_registered(captured):
@@ -467,3 +474,32 @@ def test_flight_record_says_the_loads_key_set_answered(tmp_path):
     assert deltas[1]["dataset.key_set_rebuilt"] == 1
     assert deltas[1]["dataset.key_runs"] == 3     # one run a sparse column
     assert "dataset.key_set_reused" not in deltas[1]
+
+
+def test_an_eval_pass_is_a_root_of_its_own(tmp_path):
+    """ISSUE 38: past its ``unique_keys`` an eval pass had no span; it is
+    a root now, and the stages it shares with a train pass nest in it
+    under the names they have there. ``preload_pass`` spans the hand-over
+    of the next pass's keys."""
+    import jax
+    tr, ds = _tiny_trainer(tmp_path)
+    tr.train_pass(ds)
+    logdir = str(tmp_path / "capture")
+    jax.profiler.start_trace(logdir)
+    try:
+        out = tr.eval_pass(ds)
+        tr.preload_pass(ds.unique_keys())
+        tr.wait_feed_pass_done()
+    finally:
+        jax.profiler.stop_trace()
+    assert 0.0 <= out["auc"] <= 1.0
+    capture = trace_lib.read_capture(trace_lib.find_xplane(logdir))
+    paths = {r["path"] for t in capture["threads"]
+             for r in trace_lib.nest_spans(t)[0]}
+    for name in ("unique_keys", "boundary", "preplan", "stage/read",
+                 "h2d_stage", "auc_update", "pass_close/read"):
+        assert ("eval_pass", name) in paths, (name, sorted(paths))
+    assert ("preload_pass",) in paths
+    assert not any(p[0] == "train_pass" for p in paths)
+    assert {"eval_pass", "preload_pass", "midpass_save"} <= set(
+        names.SPAN_NAMES)
