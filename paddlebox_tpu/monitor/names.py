@@ -257,6 +257,11 @@ KERNEL_NAMES: tuple[str, ...] = (
     # mixer's projections
     "pbtpu_short_conv_fwd",
     "pbtpu_short_conv_bwd",
+    # ops/grouped_matmul.py: the held experts' grouped products (forward
+    # and the rows' cotangent; the weights' cotangent), under scope
+    # ``experts``; their tile metadata is the ``route``'s, once a chunk
+    "pbtpu_gmm",
+    "pbtpu_tgmm",
 )
 
 # the device's stages (monitor.device_scope): a step is written under

@@ -51,6 +51,8 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from paddlebox_tpu.monitor import device_scope
+from paddlebox_tpu.ops.grouped_matmul import (group_tiles, grouped_matmul,
+                                              row_tile)
 
 EP_AXIS = "ep"
 
@@ -309,11 +311,12 @@ EXPERT_BODIES = {"reglu": ("ReGLU", jax.nn.relu),
 
 
 @device_scope("experts")
-def _expert_rows(xs, sizes, body, w_gate, w_up, w_down, dtype):
+def _expert_rows(xs, visits, body, w_gate, w_up, w_down, dtype):
     """Sorted rows through their experts' bodies: (rows, D) -> (rows, D)
-    in `dtype`; rows past the last group are not computed."""
-    dot = functools.partial(lax.ragged_dot, group_sizes=sizes,
-                            preferred_element_type=jnp.float32)
+    in `dtype`; rows past the last group are not computed. The products
+    take their operands as they come and sum in float32; `visits` is the
+    chunk's tile metadata (``group_tiles`` of its sizes)."""
+    dot = functools.partial(grouped_matmul, meta=visits)
     gate = EXPERT_BODIES[body][1]
     if gate is None:
         hidden = jnp.square(jax.nn.relu(dot(xs, w_up)))
@@ -322,7 +325,8 @@ def _expert_rows(xs, sizes, body, w_gate, w_up, w_down, dtype):
     return dot(hidden.astype(w_down.dtype), w_down).astype(dtype)
 
 
-def _whole_chunk(body, x, probs, order, inv, sizes, w_gate, w_up, w_down):
+def _whole_chunk(body, x, probs, order, inv, sizes, visits, w_gate, w_up,
+                 w_down):
     """The last rung: every assignment of the chunk in the sorted copy,
     whatever the imbalance."""
     n, k = probs.shape
@@ -333,7 +337,7 @@ def _whole_chunk(body, x, probs, order, inv, sizes, w_gate, w_up, w_down):
     in_group = (jnp.arange(n * k) < jnp.sum(sizes))[:, None]
     xs = jnp.where(in_group, _tokens_by_expert(
         x.astype(w_up.dtype), order, inv, k), 0)
-    ys = _expert_rows(xs, sizes, body, w_gate, w_up, w_down, x.dtype)
+    ys = _expert_rows(xs, visits, body, w_gate, w_up, w_down, x.dtype)
     # back to (token, choice) order
     y = _permute_rows(jnp.where(in_group, ys, 0), inv, order)
     return jnp.einsum("nkd,nk->nd", y.reshape(n, k, -1),
@@ -386,8 +390,8 @@ _sum_by_token.defvjp(
     lambda plan, g: (_rows_of_tokens(g, plan), None))
 
 
-def _bounded_chunk(bound: int, body, x, probs, order, sizes, by_token,
-                   held_choices, w_gate, w_up, w_down):
+def _bounded_chunk(bound: int, body, x, probs, order, sizes, visits,
+                   by_token, held_choices, w_gate, w_up, w_down):
     """A rung under the whole chunk: the held assignments are the first
     sum(sizes) <= bound entries of `order`, and only `order[:bound]` is
     brought into the sorted order; every array on the sorted side has
@@ -404,7 +408,7 @@ def _bounded_chunk(bound: int, body, x, probs, order, sizes, by_token,
             tuple(token[s:s + bound] == token[:bound] for s in range(1, k)),
             jnp.cumsum(held_choices) - held_choices, held_choices > 0)
     xs = _rows_of_tokens(x.astype(w_up.dtype), plan)
-    ys = _expert_rows(xs, sizes, body, w_gate, w_up, w_down, x.dtype)
+    ys = _expert_rows(xs, visits, body, w_gate, w_up, w_down, x.dtype)
     weigh = probs.reshape(-1).at[rows].get(
         unique_indices=True, mode="promise_in_bounds").astype(ys.dtype)
     return _sum_by_token(jnp.where(live[:, None], ys, 0) * weigh[:, None],
@@ -413,11 +417,12 @@ def _bounded_chunk(bound: int, body, x, probs, order, sizes, by_token,
 
 @device_scope("route")
 def _rung(bound: int, body: str, x, probs, routed, weights):
-    order, inv, sizes, by_token, held_choices = routed
+    order, inv, sizes, visits, by_token, held_choices = routed
     if bound == probs.shape[0] * probs.shape[1]:
-        return _whole_chunk(body, x, probs, order, inv, sizes, *weights)
-    return _bounded_chunk(bound, body, x, probs, order, sizes, by_token,
-                          held_choices, *weights)
+        return _whole_chunk(body, x, probs, order, inv, sizes, visits,
+                            *weights)
+    return _bounded_chunk(bound, body, x, probs, order, sizes, visits,
+                          by_token, held_choices, *weights)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -458,9 +463,10 @@ _ladder.defvjp(_ladder_fwd, _ladder_bwd)
 
 
 def _held_chunk(x, probs, experts, body: str, w_gate, w_up, w_down,
-                first: int, count: int, rungs: tuple[int, ...]):
+                first: int, count: int, rungs: tuple[int, ...], tile: int):
     """One chunk of tokens through the held experts of the body `body`
-    (``EXPERT_BODIES``). Returns the chunk's output (n, D),
+    (``EXPERT_BODIES``), the grouped products in row tiles of `tile`.
+    Returns the chunk's output (n, D),
     its assignments per held expert (count,) and the rung it took as
     (rows of the sorted copy, 1 if that was the whole chunk)."""
     with device_scope("route"):
@@ -475,7 +481,10 @@ def _held_chunk(x, probs, experts, body: str, w_gate, w_up, w_down,
         rung = jnp.sum(jnp.sum(sizes) > jnp.asarray(rungs[:-1], jnp.int32))
         inv = jnp.zeros_like(order).at[order].set(
             jnp.arange(order.shape[0], dtype=order.dtype))
-        routed = (order, inv, sizes, _token_order(held, inv),
+        # the products' tile metadata, once for all of the chunk's calls:
+        # whatever rung it takes, forward, recomputed and backward
+        routed = (order, inv, sizes, group_tiles(sizes, rungs[-1], tile),
+                  _token_order(held, inv),
                   jnp.sum(held, axis=1, dtype=jnp.int32))
     out = _ladder(rungs, body, rung, x, probs, routed,
                   (w_gate, w_up, w_down))
@@ -510,8 +519,10 @@ def held_expert_ffn(x: jnp.ndarray, probs: jnp.ndarray,
     and the chunks that took the whole-chunk copy.
 
     The assignments are sorted by held expert and taken through
-    ``lax.ragged_dot`` (on a TPU XLA's grouped matrix product, whose row
-    tiles past the last group are not computed). Tokens go through in
+    ``ops/grouped_matmul.py``'s grouped matrix products (on a TPU the
+    ``pbtpu_gmm`` / ``pbtpu_tgmm`` kernels, whose row tiles past the last
+    group are not visited; their tile metadata is computed once a chunk,
+    with the sort). Tokens go through in
     chunks of ``chunk_tokens``, and a chunk's sorted copy is bounded by
     what its held experts received: the held assignments sort first, and
     the chunk takes the least rung of ``route_rungs`` that holds them all
@@ -543,9 +554,12 @@ def held_expert_ffn(x: jnp.ndarray, probs: jnp.ndarray,
         # are cast once a call, not once a chunk
         w_gate, w_up, w_down = (w if w is None else w.astype(jnp.bfloat16)
                                 for w in (w_gate, w_up, w_down))
-    rungs = route_rungs(chunk * experts.shape[1], count, int(n_experts))
+    rows = chunk * experts.shape[1]
+    rungs = route_rungs(rows, count, int(n_experts))
+    # the products' row tile is the one a held expert's fair load holds
+    tile = row_tile(rows * count // int(n_experts), count)
     one = lambda xc, pc, ec: _held_chunk(xc, pc, ec, body, w_gate, w_up,
-                                         w_down, first, count, rungs)
+                                         w_down, first, count, rungs, tile)
     if chunk == n:
         return one(x, probs, experts)
     parts = lambda a: a.reshape(n // chunk, chunk, *a.shape[1:])

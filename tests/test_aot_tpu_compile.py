@@ -396,6 +396,44 @@ def test_short_conv_compiles_at_the_published_widths(one_chip, on_tpu,
 
 
 # ---------------------------------------------------------------------------
+# the held experts' grouped products (ISSUE 39): the pair at the three token
+# cells' operands, in the tiles the rule gives, at every rung of their
+# ladders — Mosaic takes the tiles and the VMEM they ask for
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("cell", ["smallthinker_21b_ep4",
+                                  "nemotron3_nano_ep16", "lfm2_24b_a2b_ep8"])
+def test_grouped_products_compile_at_the_cells_operands(one_chip, cell):
+    from paddlebox_tpu.ops import grouped_matmul as gm
+    from paddlebox_tpu.parallel.expert import route_rungs
+    from test_grouped_matmul import CELLS
+    tokens, choices, experts, held, d, h = CELLS[cell]
+    rows = tokens * choices
+    tm = gm.row_tile(rows * held // experts, held)
+    bf16 = jnp.bfloat16
+
+    def chunk(x, w_up, w_down, sizes, dy):
+        """Both products and all their cotangents, as a rung runs them."""
+        meta = gm.group_tiles(sizes, rows, tm)
+        dot = functools.partial(gm.grouped_matmul, meta=meta,
+                                interpret=False)
+        y, back = jax.vjp(
+            lambda x, w_up, w_down: dot(dot(x, w_up).astype(bf16), w_down),
+            x, w_up, w_down)
+        return y, back(dy)
+
+    for rung in route_rungs(rows, held, experts):
+        text = _compiled_text(
+            chunk, one_chip, ((rung, d), bf16), ((held, d, h), bf16),
+            ((held, h, d), bf16), ((held,), jnp.int32),
+            ((rung, d), jnp.float32))
+        # forward twice, the rows' cotangents twice; the weights' twice
+        assert text.count('custom_call_target="tpu_custom_call"') == 6
+        assert "pbtpu_gmm" in text and "pbtpu_tgmm" in text
+        assert "ragged-dot" not in text
+
+
+# ---------------------------------------------------------------------------
 # the device scopes in a program the chip's compiler optimized (ISSUE 38):
 # a small attention tower's differentiated step — the kernels' custom calls
 # under ``attention``, and little of the program under no scope at all

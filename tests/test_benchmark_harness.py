@@ -3,7 +3,8 @@
 own loss over ordered tokens (``test_seam``), the traffic generator
 (``test_datagen``), the work counts (``test_work``), the per-layer readers
 held to the program's span, counter and device-scope names
-(``test_stage_metrics``, ``test_trace_reduce``, ``test_scope_metrics``), and what decides ``correct`` (``test_correct``: the
+(``test_stage_metrics``, ``test_trace_reduce``, ``test_scope_metrics``,
+``test_grouped_product``), and what decides ``correct`` (``test_correct``: the
 program against the f32 reference, the bfloat16 control and the planted
 faults, at CPU size for every cell of ``BENCHMARK.json`` and the waiting
 one) — plus a whole ``run.py --rehearse`` of each cell at its own rehearsal
@@ -22,6 +23,7 @@ if ROOT not in sys.path:
 from benchmark.tests import test_correct as _correct    # noqa: E402
 from benchmark.tests.test_correct import *      # noqa: E402,F401,F403
 from benchmark.tests.test_datagen import *      # noqa: E402,F401,F403
+from benchmark.tests.test_grouped_product import *  # noqa: E402,F401,F403
 from benchmark.tests.test_seam import *         # noqa: E402,F401,F403
 from benchmark.tests.test_scope_metrics import *    # noqa: E402,F401,F403
 from benchmark.tests.test_stage_metrics import *    # noqa: E402,F401,F403
