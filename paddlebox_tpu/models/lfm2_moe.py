@@ -26,7 +26,7 @@ sequence, no bias and no activation (``ops/short_conv.py``);
 ``full_attention``: ``q, k, v = u W_q, u W_k, u W_v`` (no bias);
 ``q <- RMSNorm(q; g_q)``, ``k <- RMSNorm(k; g_k)`` over each head's
 channels (one weight of ``head_dim`` each, shared by the heads), then RoPE
-on all channels (``models/smallthinker.py::rope``), causal full attention
+on all channels (``models/nn.py::rope``), causal full attention
 over grouped-query heads (``ops/flash_attention.py``), ``out = o W_o``.
 
 dense feed-forward: ``(silu(m W_1) * (m W_3)) W_2``.
@@ -55,9 +55,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from paddlebox_tpu.models.nn import (causal_attention, next_token_loss,
-                                     recomputed, rms_norm, vocabulary_ids)
-from paddlebox_tpu.models.smallthinker import rope
+from paddlebox_tpu.models.nn import (causal_attention, chunked_swiglu,
+                                     next_token_loss, recomputed, rms_norm,
+                                     rope, vocabulary_ids)
 from paddlebox_tpu.monitor import device_scope
 from paddlebox_tpu.ops.short_conv import short_conv
 from paddlebox_tpu.parallel.expert import (held_expert_ffn,
@@ -188,17 +188,8 @@ class Lfm2MoeModel:
         time (one chunk size for both feed-forward kinds, the experts' and
         this: a bound on memory, not mathematics), a chunk recomputed in
         the backward pass."""
-        n = m.shape[0]
-        chunk = min(self.expert_chunk_tokens, n)
-        if n % chunk:
-            raise ValueError(f"{n} tokens do not divide into chunks of "
-                             f"{chunk}")
-        one = jax.checkpoint(
-            lambda mc: (jax.nn.silu(mc @ p["w1"]) * (mc @ p["w3"])) @ p["w2"])
-        if chunk == n:
-            return one(m)
-        return jax.lax.map(one, m.reshape(n // chunk, chunk, -1)
-                           ).reshape(n, -1)
+        return chunked_swiglu(m, p["w1"], p["w3"], p["w2"],
+                              self.expert_chunk_tokens)
 
     def _experts(self, p, m):
         """(the held experts' part of the layer's output (N, d),

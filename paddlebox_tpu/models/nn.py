@@ -62,6 +62,29 @@ def rms_norm(x, weight, eps: float):
         * weight
 
 
+def rope(x, theta: float, interleave: bool = False):
+    """Rotary positions on (B, T, heads, head_dim), all dims, half-split:
+    the pair (x[i], x[i + head_dim / 2]) turns by t * theta^(-2 i / dim);
+    ``interleave``, adjacent pairs: (x[2 i], x[2 i + 1]) by the same
+    angle."""
+    T, dim = x.shape[1], x.shape[-1]
+    inv = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
+    if interleave:
+        # each angle twice, and each channel's partner by a roll along the
+        # channels: no axis of two, which the chip would pad to a lane tile
+        cos, sin = (jnp.repeat(f(ang), 2, axis=-1)[None, :, None, :]
+                    for f in (jnp.cos, jnp.sin))
+        even = jnp.arange(dim) % 2 == 0
+        partner = jnp.where(even, -jnp.roll(x, -1, axis=-1),
+                            jnp.roll(x, 1, axis=-1))
+        return x * cos + partner * sin
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)[None, :, None, :]
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)[None, :, None, :]
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+
+
 def causal_attention(q, k, v, window=None):
     """Causal grouped-query attention over token-major heads: q (B, T,
     heads, head_dim), k, v (B, T, kv_heads, head_dim) -> (B, T, heads *
@@ -76,19 +99,38 @@ def causal_attention(q, k, v, window=None):
     return jnp.swapaxes(o, 1, 2).reshape(B, T, -1).astype(q.dtype)
 
 
-def recomputed(fn, static_argnums=()):
+def chunked_swiglu(m, w1, w3, w2, chunk_tokens: int):
+    """The dense SwiGLU MLP ``(silu(m w1) * (m w3)) w2`` over m (N, d),
+    ``chunk_tokens`` at a time (a bound on memory, not mathematics), a
+    chunk recomputed in the backward pass."""
+    n = m.shape[0]
+    chunk = min(chunk_tokens, n)
+    if n % chunk:
+        raise ValueError(f"{n} tokens do not divide into chunks of "
+                         f"{chunk}")
+    one = jax.checkpoint(
+        lambda mc: (jax.nn.silu(mc @ w1) * (mc @ w3)) @ w2)
+    if chunk == n:
+        return one(m)
+    return jax.lax.map(one, m.reshape(n // chunk, chunk, -1)
+                       ).reshape(n, -1)
+
+
+def recomputed(fn, static_argnums=(), keep=RESIDUAL_NAMES):
     """``fn`` with its intermediates rebuilt in the backward pass
     (``jax.checkpoint``), but for what an attention op inside it names
     (``flash_attention.RESIDUAL_NAMES``): the backward kernels read q, k,
     v, the output and the row statistics the forward pass left, so the
     recomputation runs neither the forward kernel nor the projections,
-    norms and rotations ahead of it. The same for every tower; a layer
-    with no attention inside keeps nothing. (PERF.md section 6, PR 37, has
-    the readings with the output and statistics alone and with all five.)"""
+    norms and rotations ahead of it. A layer with no attention inside
+    keeps nothing. ``keep``, a subset of the names, rebuilds the others: a
+    tower whose keys and values are cheap to make again and large to keep
+    names what it keeps. (PERF.md section 6 has the readings with the
+    output and statistics alone and with all five, and the memory of
+    multi-head latent attention's three.)"""
     return jax.checkpoint(
         fn, static_argnums=static_argnums,
-        policy=jax.checkpoint_policies.save_only_these_names(
-            *RESIDUAL_NAMES))
+        policy=jax.checkpoint_policies.save_only_these_names(*keep))
 
 
 @device_scope("head_loss")

@@ -41,21 +41,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddlebox_tpu.models.nn import (causal_attention, next_token_loss,
-                                     recomputed, rms_norm, vocabulary_ids)
+                                     recomputed, rms_norm, rope,
+                                     vocabulary_ids)
 from paddlebox_tpu.monitor import device_scope
 from paddlebox_tpu.parallel.expert import held_expert_ffn, route_top_k
-
-
-def rope(x, theta: float):
-    """Rotary positions on (B, T, heads, head_dim), all dims, half-split:
-    the pair (x[i], x[i + head_dim / 2]) turns by t * theta^(-2 i / dim)."""
-    T, dim = x.shape[1], x.shape[-1]
-    inv = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
-    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)[None, :, None, :]
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)[None, :, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
 
 
 class SmallThinkerModel:

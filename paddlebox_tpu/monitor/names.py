@@ -246,7 +246,9 @@ KERNEL_NAMES: tuple[str, ...] = (
     "pbtpu_merge_update",
     "pbtpu_scatter_accumulate",
     # ops/flash_attention.py: blocked causal attention (head sizes of whole
-    # lane tiles, and of 64)
+    # lane tiles, and of 64 and 192; values may have a head size of their
+    # own, as multi-head latent attention's 128 beside queries and keys of
+    # 192)
     "pbtpu_attention_fwd",
     "pbtpu_attention_dq",
     "pbtpu_attention_dkv",
@@ -285,11 +287,16 @@ DEVICE_SCOPE_NAMES: tuple[str, ...] = (
     # (a CTR tower whole; a token tower's norms and residual adds)
     "tower",
     # the token towers' layers (models/, parallel/expert.py): an attention
-    # half (projections, q/k norms, RoPE, the kernels); a state-space or
-    # short-convolution mixer; the experts' routing (rule, sort, the moves
-    # into and out of the sorted copy, the ladder's switch) and their
-    # grouped products; a dense MLP or shared expert; the head and loss
+    # half (projections, q/k norms, RoPE, the kernels) and, inside it,
+    # multi-head latent attention's path to its keys and values (the
+    # latent's projection and norm, its expansion to the heads, the
+    # rotations, the shared rotary key's broadcast: models/deepseek_v3.py);
+    # a state-space or short-convolution mixer; the experts' routing (rule,
+    # sort, the moves into and out of the sorted copy, the ladder's switch)
+    # and their grouped products; a dense MLP or shared expert; the head
+    # and loss
     "attention",
+    "latent",
     "mixer",
     "route",
     "experts",

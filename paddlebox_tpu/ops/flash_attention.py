@@ -6,16 +6,19 @@ full and sliding-window layers, grouped-query heads, forward and backward.
           s >  t - window             (a token sees itself and the
                                        window - 1 tokens before it)
 
-``q (B, H, T, D)``, ``k, v (B, KV, T, D)`` with ``H = G * KV``: query head
-``h`` reads key-value head ``h // G``. The ``T x T`` scores never exist:
-each program instance holds one ``(block_q, block_k)`` tile, keeps the
-running softmax (max, sum, accumulator) in VMEM, and writes ``o`` and the
-row log-sum-exp. Blocks that the mask empties — above the diagonal, and
-with a window those more than ``window`` behind — are neither computed nor
-fetched (the index maps clamp to the nearest needed block, so the pipeline
-sees no new block to copy). The backward pass is two kernels of the same
-shape (``dq``; ``dk, dv`` per query head, summed over each group outside),
-recomputing the tile's probabilities from the saved log-sum-exp.
+``q, k (B, H | KV, T, D)``, ``v (B, KV, T, Dv)`` with ``H = G * KV``: query
+head ``h`` reads key-value head ``h // G``; the output is ``(B, H, T, Dv)``
+(``Dv = D`` but in multi-head latent attention, whose queries and keys
+carry a rotary part the values lack: 192 and 128). The ``T x T`` scores
+never exist: each program instance holds one ``(block_q, block_k)`` tile,
+keeps the running softmax (max, sum, accumulator) in VMEM, and writes
+``o`` and the row log-sum-exp. Blocks that the mask empties — above the
+diagonal, and with a window those more than ``window`` behind — are
+neither computed nor fetched (the index maps clamp to the nearest needed
+block, so the pipeline sees no new block to copy). The backward pass is
+two kernels of the same shape (``dq``; ``dk, dv`` per query head, summed
+over each group outside), recomputing the tile's probabilities from the
+saved log-sum-exp.
 
 Names in a profile: ``pbtpu_attention_fwd``, ``pbtpu_attention_dq``,
 ``pbtpu_attention_dkv``. Off a TPU the same kernels run in the Pallas
@@ -23,13 +26,15 @@ interpreter (tests: tiny shapes only) — except inside a ``check_vma``
 shard_map, where the interpreter cannot run (``pallas_kernels.
 merge_update`` has the reason): a trainer on a CPU mesh takes the plain
 ``attention_reference``. On a TPU the geometry must be lane-aligned (``T``
-a multiple of the block, blocks multiples of 128, ``D`` a multiple of 128
-or 64); where it is not, ``attention`` is the reference too — never a
-kernel under another name. A head of 64 channels is half a lane tile: its
-blocks take the whole head size as their last dimension (``(block, 64)``
-of an array whose last dimension is 64), so the same three kernels run it
-with the products' contraction (scores) or output (values, ``dq``, ``dk``,
-``dv``) half as wide as the MXU.
+a multiple of the block, blocks multiples of 128, each head size a
+multiple of 128, or 64 or 192); where it is not, ``attention`` is the
+reference too — never a kernel under another name. A head of 64 or 192
+channels is not whole lane tiles: its blocks take the whole head size as
+their last dimension (``(block, 64)`` of an array whose last dimension is
+64), so the same three kernels run it, a head of 64 with the products'
+contraction (scores) or output (values, ``dq``, ``dk``, ``dv``) half as
+wide as the MXU, one of 192 with the last of its two lane tiles half
+full.
 """
 
 from __future__ import annotations
@@ -44,7 +49,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
-_HALF_TILE_HEADS = (64,)    # head sizes under a lane tile the chip takes
+# head sizes that are not whole lane tiles the chip takes (a block's last
+# dimension the whole head)
+_PART_TILE_HEADS = (64, 192)
 _MASK = -0.7 * float(jnp.finfo(jnp.float32).max)   # exp(_MASK - m) == 0
 NT = (((1,), (1,)), ((), ()))                      # a @ b.T
 # what the op's VJP keeps, by the name a ``jax.checkpoint`` policy can save
@@ -57,7 +64,8 @@ RESIDUAL_NAMES = ("pbtpu_attention_q", "pbtpu_attention_k",
 
 def attention_reference(q, k, v, *, window: int | None = None,
                         scale: float | None = None):
-    """The unblocked form: the whole (T, T) score matrix, masked."""
+    """The unblocked form: the whole (T, T) score matrix, masked; ``v``
+    may have a head size of its own."""
     B, H, T, D = q.shape
     G = H // k.shape[1]
     scale = D ** -0.5 if scale is None else scale
@@ -80,7 +88,7 @@ def block_geometry(T: int, D: int, block: int = 512):
     if T % b:
         return None
     if jax.default_backend() == "tpu" and (
-            b % LANES or (D % LANES and D not in _HALF_TILE_HEADS)):
+            b % LANES or (D % LANES and D not in _PART_TILE_HEADS)):
         return None
     return b, b
 
@@ -152,10 +160,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
         lse_ref[...] = m_s[...] + jnp.log(l)
 
 
-def _specs(bq, bk, D, G, window, *, q_major: bool, nq: int):
-    """BlockSpecs of (q-like, kv-like, row-statistics) arrays for a grid
-    (B, H, major, minor): q blocks follow the query axis, kv blocks the
-    key axis, each clamped to the blocks the other axis' block needs."""
+def _specs(bq, bk, G, window, *, q_major: bool, nq: int):
+    """BlockSpecs for a grid (B, H, major, minor): of a q-like and of a
+    kv-like array whose last dimension is ``D`` (two functions of ``D``),
+    and of the row statistics. q blocks follow the query axis, kv blocks
+    the key axis, each clamped to the blocks the other axis' block
+    needs."""
     def q_index(b, h, x, y):
         if q_major:
             return (b, h, x, 0)
@@ -168,8 +178,8 @@ def _specs(bq, bk, D, G, window, *, q_major: bool, nq: int):
         lo, hi = _kv_range(x, bq, bk, window)
         return (b, h // G, jnp.clip(y, lo, hi), 0)
 
-    return (pl.BlockSpec((None, None, bq, D), q_index),
-            pl.BlockSpec((None, None, bk, D), kv_index),
+    return (lambda D: pl.BlockSpec((None, None, bq, D), q_index),
+            lambda D: pl.BlockSpec((None, None, bk, D), kv_index),
             pl.BlockSpec((None, None, bq, LANES), q_index))
 
 
@@ -190,22 +200,22 @@ def out_struct(shape, dtype, like):
 
 def _forward(q, k, v, window, scale, blocks, interpret):
     B, H, T, D = q.shape
-    G = H // k.shape[1]
+    G, Dv = H // k.shape[1], v.shape[-1]
     bq, bk = blocks
     nq, nk = T // bq, T // bk
-    q_spec, kv_spec, row_spec = _specs(bq, bk, D, G, window, q_major=True,
+    q_spec, kv_spec, row_spec = _specs(bq, bk, G, window, q_major=True,
                                        nq=nq)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, bq=bq, bk=bk, nk=nk, window=window,
                           scale=scale),
         grid=(B, H, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, row_spec],
-        out_shape=[out_struct(q.shape, q.dtype, q),
+        in_specs=[q_spec(D), kv_spec(D), kv_spec(Dv)],
+        out_specs=[q_spec(Dv), row_spec],
+        out_shape=[out_struct((B, H, T, Dv), q.dtype, q),
                    out_struct((B, H, T, LANES), jnp.float32, q)],
         scratch_shapes=[pltpu.VMEM((bq, LANES), jnp.float32),
                         pltpu.VMEM((bq, LANES), jnp.float32),
-                        pltpu.VMEM((bq, D), jnp.float32)],
+                        pltpu.VMEM((bq, Dv), jnp.float32)],
         name="pbtpu_attention_fwd", **_params(interpret),
     )(q, k, v)
 
@@ -272,7 +282,7 @@ def _backward(q, k, v, o, lse, do, window, scale, blocks, interpret):
     """``lse`` (B, H, T): one value a row, replicated over the lanes here
     as ``delta`` is."""
     B, H, T, D = q.shape
-    KV = k.shape[1]
+    KV, Dv = k.shape[1], v.shape[-1]
     G = H // KV
     bq, bk = blocks
     nq, nk = T // bq, T // bk
@@ -280,35 +290,40 @@ def _backward(q, k, v, o, lse, do, window, scale, blocks, interpret):
                     keepdims=True)
     delta = jnp.broadcast_to(delta, (B, H, T, LANES))
     lse = jnp.broadcast_to(lse[..., None], (B, H, T, LANES))
-    q_spec, kv_spec, row_spec = _specs(bq, bk, D, G, window, q_major=True,
+    q_spec, kv_spec, row_spec = _specs(bq, bk, G, window, q_major=True,
                                        nq=nq)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, bq=bq, bk=bk, nk=nk, window=window,
                           scale=scale),
         grid=(B, H, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
+        in_specs=[q_spec(D), kv_spec(D), kv_spec(Dv), q_spec(Dv), row_spec,
+                  row_spec],
+        out_specs=q_spec(D),
         out_shape=out_struct(q.shape, q.dtype, q),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         name="pbtpu_attention_dq", **_params(interpret),
     )(q, k, v, do, lse, delta)
-    q_spec, kv_spec, row_spec = _specs(bq, bk, D, G, window, q_major=False,
+    q_spec, kv_spec, row_spec = _specs(bq, bk, G, window, q_major=False,
                                        nq=nq)
     # one dk, dv per QUERY head (its own output block, so heads of a group
     # never write the same block); the group's sum is taken outside
-    out_spec = pl.BlockSpec((None, None, bk, D),
-                            lambda b, h, x, y: (b, h, x, 0))
+    out_spec = lambda D: pl.BlockSpec((None, None, bk, D),
+                                      lambda b, h, x, y: (b, h, x, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, bq=bq, bk=bk, nq=nq, window=window,
                           scale=scale),
         grid=(B, H, nk, nq),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[out_spec, out_spec],
-        out_shape=[out_struct((B, H, T, D), jnp.float32, q)] * 2,
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32)] * 2,
+        in_specs=[q_spec(D), kv_spec(D), kv_spec(Dv), q_spec(Dv), row_spec,
+                  row_spec],
+        out_specs=[out_spec(D), out_spec(Dv)],
+        out_shape=[out_struct((B, H, T, D), jnp.float32, q),
+                   out_struct((B, H, T, Dv), jnp.float32, q)],
+        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                        pltpu.VMEM((bk, Dv), jnp.float32)],
         name="pbtpu_attention_dkv", **_params(interpret),
     )(q, k, v, do, lse, delta)
-    fold = lambda g: g.reshape(B, KV, G, T, D).sum(axis=2).astype(k.dtype)
+    fold = lambda g: g.reshape(B, KV, G, T, g.shape[-1]).sum(axis=2).astype(
+        k.dtype)
     return dq, fold(dk), fold(dv)
 
 
@@ -341,16 +356,18 @@ _attention.defvjp(_attention_fwd, _attention_bwd)
 def attention(q, k, v, *, window: int | None = None,
               scale: float | None = None, block: int = 512,
               interpret: bool | None = None):
-    """Causal (optionally sliding-window) grouped-query attention, blocked.
+    """Causal (optionally sliding-window) grouped-query attention, blocked;
+    the scale defaults to the query head's ``D ** -0.5``.
     ``interpret``: None = the Mosaic kernels on a TPU, the Pallas
     interpreter elsewhere."""
     B, H, T, D = q.shape
-    if H % k.shape[1] or k.shape != v.shape:
-        raise ValueError(f"q {q.shape} does not group over k {k.shape}")
+    if H % k.shape[1] or k.shape[-1] != D or k.shape[:3] != v.shape[:3]:
+        raise ValueError(f"q {q.shape} does not group over k {k.shape} "
+                         f"and v {v.shape}")
     scale = float(D ** -0.5 if scale is None else scale)
     window = int(window) if window and window < T else None
     blocks = block_geometry(T, D, block)
-    if blocks is None:
+    if blocks is None or block_geometry(T, v.shape[-1], block) is None:
         return attention_reference(q, k, v, window=window, scale=scale)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"

@@ -373,6 +373,39 @@ def test_head_64_attention_compiles_at_the_published_widths(one_chip, on_tpu,
 
 
 @pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_latent_attention_compiles_at_the_published_widths(one_chip, on_tpu,
+                                                           direction):
+    """Kanana-2's multi-head latent attention as the kernels take it: 32
+    heads whose queries and keys are 192 channels (the blocks' last
+    dimension the whole head: a lane tile and a half) beside values of
+    128, one sequence of 16384 positions, bfloat16 operands."""
+    from paddlebox_tpu.ops import flash_attention as fa
+    B, H, T, D, Dv = 1, 32, 16384, 192, 128
+    assert fa.block_geometry(T, D) == fa.block_geometry(T, Dv) == (512, 512)
+    assert fa.block_geometry(T, 160) is None
+    bf16, f32 = jnp.bfloat16, jnp.float32
+
+    def attend(q, k, v):
+        return fa.attention(q, k, v, interpret=False)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(attend(*a).astype(f32)),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(
+        attend if direction == "forward" else grads, one_chip,
+        ((B, H, T, D), bf16), ((B, H, T, D), bf16), ((B, H, T, Dv), bf16))
+    assert "tpu_custom_call" in text and "pbtpu_attention_fwd" in text
+    for name in ("pbtpu_attention_dq", "pbtpu_attention_dkv"):
+        assert (name in text) == (direction == "backward")
+    assert f"{T},{T}]" not in text
+    # the output is the values' width, each gradient its operand's
+    assert f"bf16[{B},{H},{T},{Dv}]" in text
+    if direction == "backward":
+        assert f"bf16[{B},{H},{T},{D}]" in text
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
 def test_short_conv_compiles_at_the_published_widths(one_chip, on_tpu,
                                                      direction):
     from paddlebox_tpu.ops import short_conv as sc
@@ -402,7 +435,8 @@ def test_short_conv_compiles_at_the_published_widths(one_chip, on_tpu,
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("cell", ["smallthinker_21b_ep4",
-                                  "nemotron3_nano_ep16", "lfm2_24b_a2b_ep8"])
+                                  "nemotron3_nano_ep16", "lfm2_24b_a2b_ep8",
+                                  "kanana2_30b_a3b_ep8"])
 def test_grouped_products_compile_at_the_cells_operands(one_chip, cell):
     from paddlebox_tpu.ops import grouped_matmul as gm
     from paddlebox_tpu.parallel.expert import route_rungs

@@ -4,6 +4,7 @@ kernel runs once a layer and step, the gradients are those of a layer
 recomputed whole, and the row statistics are kept one value a row. Tiny
 shapes, the Pallas interpreter, outside shard_map."""
 
+import collections
 import os
 import sys
 
@@ -24,7 +25,7 @@ from token_tower_common import tower                      # noqa: E402
 
 # cell -> attention layers of its rehearsal tower
 CELLS = {"smallthinker_21b_ep4.seq8k": 4, "nemotron3_nano_ep16.seq4k": 1,
-         "lfm2_24b_a2b_ep8.seq8k": 1}
+         "lfm2_24b_a2b_ep8.seq8k": 1, "kanana2_30b_a3b_ep8.seq16k": 5}
 KERNELS = ("pbtpu_attention_fwd", "pbtpu_attention_dq",
            "pbtpu_attention_dkv")
 
@@ -84,8 +85,10 @@ def test_a_recomputed_layer_runs_its_forward_kernel_once(cell, monkeypatch):
     got = jax.jit(grad())(params, pulled)
     # the same tower, each layer under a plain jax.checkpoint: it runs the
     # forward kernel again to rebuild what the first call wrote
-    monkeypatch.setattr(sys.modules[type(model).__module__], "recomputed",
-                        jax.checkpoint)
+    monkeypatch.setattr(
+        sys.modules[type(model).__module__], "recomputed",
+        lambda fn, static_argnums=(), keep=None: jax.checkpoint(
+            fn, static_argnums=static_argnums))
     whole = kernel_calls(jax.make_jaxpr(grad())(params, pulled))
     want = jax.jit(grad())(params, pulled)
     n = CELLS[cell]
@@ -100,25 +103,42 @@ def test_a_recomputed_layer_runs_its_forward_kernel_once(cell, monkeypatch):
 @pytest.mark.parametrize("cell", list(CELLS))
 def test_the_kept_row_statistics_are_one_value_a_row(cell, capsys):
     """What a layer's recomputation saves of each attention op: q, k, v as
-    they enter the kernel, ``o`` (as the op's output it is listed by the
-    operation that hands it on, not by its name) and ``lse`` as (B, H, T),
-    never the kernel's lane-replicated (B, H, T, 128)."""
+    they enter the kernel (q alone in multi-head latent attention), ``o``
+    (as the op's output it is listed by the operation that hands it on,
+    not by its name) and ``lse`` as (B, H, T), never the kernel's
+    lane-replicated (B, H, T, 128)."""
     cfg, _, model, params, pulled, ids = tower(cell)
-    a = cfg["model_args"]
     B, T = ids.shape
-    H, KV, D = (a["num_attention_heads"], a["num_key_value_heads"],
-                a["head_dim"])
+    H, KV, D, Dv = _heads(cfg["model_args"])
     saved = saved_residuals(capsys, _loss_of(model, ids), params, pulled)
     named = {name: [shape for shape, why in saved if f"'{name}'" in why]
              for name in fa.RESIDUAL_NAMES}
     shapes = [shape for shape, _ in saved]
     n = CELLS[cell]
+    # multi-head latent attention keeps q, o and lse and makes its keys and
+    # values again from the latent (models/deepseek_v3.py::KEPT)
+    kv_kept = "kv_lora_rank" not in cfg["model_args"]
     assert named["pbtpu_attention_lse"] == [(B, H, T)] * n
     assert named["pbtpu_attention_q"] == [(B, H, T, D)] * n
-    assert named["pbtpu_attention_k"] == [(B, KV, T, D)] * n
-    assert named["pbtpu_attention_v"] == [(B, KV, T, D)] * n
-    assert shapes.count((B, H, T, D)) == 2 * n          # q and o
+    assert named["pbtpu_attention_k"] == [(B, KV, T, D)] * n * kv_kept
+    assert named["pbtpu_attention_v"] == [(B, KV, T, Dv)] * n * kv_kept
+    # q and o; and k and v where kept and every query head has its own
+    want = collections.Counter([(B, H, T, D), (B, H, T, Dv)] + [
+        (B, KV, T, D), (B, KV, T, Dv)] * (KV == H and kv_kept))
+    for shape, times in want.items():
+        assert shapes.count(shape) == times * n, shape
     assert (B, H, T, fa.LANES) not in shapes
+
+
+def _heads(a):
+    """(query heads, key-value heads, the query and key head size, the
+    value head size) of a tower's model_args."""
+    if "kv_lora_rank" in a:          # multi-head latent attention
+        return (a["num_attention_heads"], a["num_attention_heads"],
+                a["qk_nope_head_dim"] + a["qk_rope_head_dim"],
+                a["v_head_dim"])
+    return (a["num_attention_heads"], a["num_key_value_heads"],
+            a["head_dim"], a["head_dim"])
 
 
 def test_the_op_alone_keeps_five_residuals_and_gives_the_gradients_it_gave(

@@ -4,10 +4,10 @@ own loss over ordered tokens (``test_seam``), the traffic generator
 (``test_datagen``), the work counts (``test_work``), the per-layer readers
 held to the program's span, counter and device-scope names
 (``test_stage_metrics``, ``test_trace_reduce``, ``test_scope_metrics``,
-``test_grouped_product``), and what decides ``correct`` (``test_correct``: the
-program against the f32 reference, the bfloat16 control and the planted
-faults, at CPU size for every cell of ``BENCHMARK.json`` and the waiting
-one) — plus a whole ``run.py --rehearse`` of each cell at its own rehearsal
+``test_grouped_product``, ``test_latent_kv_metric``), and what decides
+``correct`` (``test_correct``: the program against the f32 reference, the
+bfloat16 control and the planted faults, at CPU size for every cell of
+``BENCHMARK.json`` and the waiting one) — plus a whole ``run.py --rehearse`` of each cell at its own rehearsal
 sizes: traffic files, the program through BoxPS passes, the window, the
 read-back, the blocked reference and the comparison, on the CPU."""
 
@@ -24,6 +24,7 @@ from benchmark.tests import test_correct as _correct    # noqa: E402
 from benchmark.tests.test_correct import *      # noqa: E402,F401,F403
 from benchmark.tests.test_datagen import *      # noqa: E402,F401,F403
 from benchmark.tests.test_grouped_product import *  # noqa: E402,F401,F403
+from benchmark.tests.test_latent_kv_metric import *  # noqa: E402,F401,F403
 from benchmark.tests.test_seam import *         # noqa: E402,F401,F403
 from benchmark.tests.test_scope_metrics import *    # noqa: E402,F401,F403
 from benchmark.tests.test_stage_metrics import *    # noqa: E402,F401,F403
@@ -33,10 +34,11 @@ from benchmark.tests.test_work import *         # noqa: E402,F401,F403
 # The cases of benchmark/tests in which nothing is planted: the fault
 # patches optax.sigmoid_binary_cross_entropy, which a tower that declares
 # its own loss never calls, so the run comes out correct (PERF.md section
-# 7, "for a `benchmark` PR"): the three token cells, by name.
+# 7, "for a `benchmark` PR"): the four token cells, by name.
 _PLANTS_NOTHING = {("smallthinker_21b_ep4.seq8k", "_half_batch"),
                    ("nemotron3_nano_ep16.seq4k", "_half_batch"),
-                   ("lfm2_24b_a2b_ep8.seq8k", "_half_batch")}
+                   ("lfm2_24b_a2b_ep8.seq8k", "_half_batch"),
+                   ("kanana2_30b_a3b_ep8.seq16k", "_half_batch")}
 
 
 @pytest.mark.parametrize("fault", [_correct._unchanged_state,
@@ -171,10 +173,60 @@ def _lfm2_cut(entry, cfg):
     assert ref.route_rows(cfg) == (16384, 8, 64)
 
 
+def _kanana_cut(entry, cfg):
+    a = cfg["model_args"]
+    # every width as published: hidden 2048, 32 heads whose queries and
+    # keys are 128 + 64 = 192 channels beside values of 128, a latent of
+    # 512, the dense MLP 6144, experts 768 with two shared, theta 1e6
+    published = {"hidden_size": 2048, "num_attention_heads": 32,
+                 "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+                 "v_head_dim": 128, "kv_lora_rank": 512,
+                 "intermediate_size": 6144, "moe_intermediate_size": 768,
+                 "n_shared_experts": 2, "routed_scaling_factor": 2.448,
+                 "rope_theta": 1000000, "rope_interleave": True,
+                 "rms_norm_eps": 1e-6}
+    for key, value in published.items():
+        assert cfg[key] == value and a[key] == value, key
+    assert cfg["qk_head_dim"] == 192 and cfg["num_key_value_heads"] == 32
+    assert cfg["q_lora_rank"] is None and cfg["rope_scaling"] is None
+    assert (cfg["n_group"], cfg["topk_group"]) == (1, 1)
+    assert (a["router_experts"], a["experts_per_token"]) == (128, 6)
+    assert cfg["num_experts_per_tok"] == 6 and cfg["norm_topk_prob"]
+    assert a["experts_held"] == cfg["n_routed_experts"] == 16
+    assert a["vocab_size"] == cfg["vocab_size"] == 128256 // 8
+    # layers 0-4 of the 48 published: the leading dense layer and four of
+    # the expert layers after it
+    assert a["num_layers"] == cfg["num_hidden_layers"] == 5
+    assert a["dense_layers"] == cfg["first_k_dense_replace"] == 1
+    assert set(entry["reduced"]) == set(cfg["reduced"]) == {
+        "num_hidden_layers", "n_routed_experts", "vocab_size",
+        "steps_per_pass"}
+    assert set(cfg["reduced_from"]) == set(cfg["reduced"])
+    assert "8 chips" in cfg["deployment"]
+    assert {"e_score_correction_bias", "untied head", "initialisation",
+            "sequences", "rope_interleave", "trainer.dense_lr",
+            "weights_seed"} <= set(cfg["assumed"])
+    from benchmark import sut
+    from benchmark.reference import deepseek_v3 as ref
+    # 543.1 M dense parameters (8.7 GB at 16 B); 11.05 T multiply-adds an
+    # example, 6.87 T of them attention's (66.3 TFLOP a step of one)
+    assert round(ref.tower_sizes(cfg)[0] / 1e6, 1) == 543.1
+    assert round(ref.macs_per_example(cfg) / 1e12, 2) == 11.05
+    assert round(ref.attention_macs(cfg) / 1e12, 2) == 6.87
+    assert round(ref.expert_gmm_macs(cfg) / 16384 / 1e6, 2) == 14.16
+    assert ref.route_rows(cfg) == (24576, 16, 128)
+    # every rung but the first is also a length of the model (the query
+    # projection's and the dense MLP's 6,144, twice that, a chunk's rows),
+    # so the cell is not listed under moe_route_ms_per_step
+    assert sut.route_rungs(*ref.route_rows(cfg)) == (3072, 6144, 12288,
+                                                     24576)
+
+
 @pytest.mark.parametrize("config,holds", [
     ("smallthinker_21b_ep4", _smallthinker_cut),
     ("nemotron3_nano_ep16", _nemotron_cut),
-    ("lfm2_24b_a2b_ep8", _lfm2_cut)])
+    ("lfm2_24b_a2b_ep8", _lfm2_cut),
+    ("kanana2_30b_a3b_ep8", _kanana_cut)])
 def test_the_cells_files_state_the_cut_and_the_published_widths(config,
                                                                 holds):
     import json

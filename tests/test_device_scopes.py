@@ -208,6 +208,7 @@ CELLS = {
     "smallthinker_21b_ep4.seq8k": TOWER,
     "nemotron3_nano_ep16.seq4k": TOWER + ("mixer", "dense_mlp"),
     "lfm2_24b_a2b_ep8.seq8k": TOWER + ("mixer", "dense_mlp"),
+    "kanana2_30b_a3b_ep8.seq16k": TOWER + ("latent", "dense_mlp"),
 }
 
 

@@ -164,7 +164,8 @@ def test_fewer_rows_than_the_metadata_was_made_for_take_it_unchanged():
 # experts, held, D, H) — benchmark/configs/*.json, trainer.expert_chunk
 CELLS = {"smallthinker_21b_ep4": (4096, 6, 64, 16, 2560, 768),
          "nemotron3_nano_ep16": (4096, 6, 128, 8, 2688, 1856),
-         "lfm2_24b_a2b_ep8": (4096, 4, 64, 8, 2048, 1536)}
+         "lfm2_24b_a2b_ep8": (4096, 4, 64, 8, 2048, 1536),
+         "kanana2_30b_a3b_ep8": (4096, 6, 128, 16, 2048, 768)}
 
 
 def _legal(tile, dim):

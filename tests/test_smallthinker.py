@@ -164,17 +164,20 @@ def test_model_loss_equals_reference_and_order_matters():
     assert preds is None and stats.shape == (len(model.stat_names),)
 
 
-@pytest.mark.parametrize("D", [16, 64])
+@pytest.mark.parametrize("D,Dv", [(16, 16), (64, 64), (24, 16)])
 @pytest.mark.parametrize("window", [None, 5, 12])
-def test_blocked_kernel_equals_unblocked_and_the_mask_says_where(window, D):
-    """Grouped heads (4 over 2), full and windowed, forward and backward;
-    a head of 64 channels is half a lane tile on the chip (its blocks take
-    the whole head size), one of 16 stands for the whole tiles."""
+def test_blocked_kernel_equals_unblocked_and_the_mask_says_where(window, D,
+                                                                 Dv):
+    """Grouped heads (4 over 2), full and windowed, forward and backward
+    (``dq``, ``dk``, ``dv``); a head of 64 channels is half a lane tile on
+    the chip (its blocks take the whole head size), one of 16 stands for
+    the whole tiles, and 24 against values of 16 for multi-head latent
+    attention's 192 against 128."""
     B, H, KV, T = 2, 4, 2, 32
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
     q = jax.random.normal(ks[0], (B, H, T, D))
     k = jax.random.normal(ks[1], (B, KV, T, D))
-    v = jax.random.normal(ks[2], (B, KV, T, D))
+    v = jax.random.normal(ks[2], (B, KV, T, Dv))
     with jax.default_matmul_precision("highest"):
         blocked = fa.attention(q, k, v, window=window, block=8)
         whole = fa.attention_reference(q, k, v, window=window)
@@ -183,8 +186,10 @@ def test_blocked_kernel_equals_unblocked_and_the_mask_says_where(window, D):
                                 argnums=(0, 1, 2))(q, k, v)
         g_blocked = f(lambda *a: fa.attention(*a, window=window, block=8))
         g_whole = f(lambda *a: fa.attention_reference(*a, window=window))
+    assert blocked.shape == (B, H, T, Dv)
     np.testing.assert_allclose(blocked, whole, atol=2e-6)
-    for x, y in zip(g_blocked, g_whole):
+    for x, y, like in zip(g_blocked, g_whole, (q, k, v)):
+        assert x.shape == like.shape
         np.testing.assert_allclose(x, y, atol=5e-6)
     # window and full attention agree on the positions whose window still
     # reaches the first token, and differ on every later one
