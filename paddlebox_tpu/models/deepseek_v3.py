@@ -30,6 +30,15 @@ next-token cross entropy in chunks of positions (``models/nn.py``). With
     out          = o W_o
 
 by ``ops/flash_attention.py``, whose values have a head size of their own.
+The tower hands the kernels q, k and v head-major in their dtype (bfloat16
+on the chip, float32 elsewhere) and makes each once: ``W_q`` and ``W_kv_b``
+as two products each, whose no-position part and values are cast in the
+write that makes them; the rotary parts turned in float32 and cast; the
+rotary key broadcast to every head only inside the bfloat16 write of
+``k``. The casts, broadcasts and concatenations commute, so the kernels
+receive the bits a float32 build cast at their door would give (for values
+of ordinary size: ``nn.rope``), and the backward pass sums the rotary
+key's cotangent over the heads in float32.
 
 dense feed-forward: ``(silu(m W_1) * (m W_3)) W_2``.
 
@@ -55,9 +64,10 @@ no gradient. Each layer is recomputed in the backward pass
 output and statistics it wrote; keys and values come again from the
 latent); the dense MLP and the experts take their tokens in chunks of
 ``expert_chunk_tokens``. Device scopes: the attention half under
-``attention``, its latent path — ``W_kv_a``, the latent's norm,
-``W_kv_b``, both rotations, the key's broadcast, q's split and concat —
-under ``latent`` inside it.
+``attention`` (with the query products and the cast of their
+no-position part), its latent path — ``W_kv_a``, the latent's norm,
+``W_kv_b`` and the casts of its products, both rotations, the key's
+broadcast, q's and k's bfloat16 writes — under ``latent`` inside it.
 """
 
 from __future__ import annotations
@@ -66,10 +76,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from paddlebox_tpu.models.nn import (causal_attention, chunked_swiglu,
-                                     next_token_loss, recomputed, rms_norm,
-                                     rope, vocabulary_ids)
+from paddlebox_tpu.models.nn import (chunked_swiglu, next_token_loss,
+                                     recomputed, rms_norm, rope,
+                                     vocabulary_ids)
 from paddlebox_tpu.monitor import device_scope
+from paddlebox_tpu.ops.flash_attention import attention
 from paddlebox_tpu.parallel.expert import (held_expert_ffn,
                                            route_sigmoid_top_k)
 
@@ -176,21 +187,34 @@ class DeepseekV3Model:
 
     @device_scope("attention")
     def _attention(self, p, u):
-        B, T, _ = u.shape
+        B, T, d = u.shape
         H, n, r, c = self.heads, self.nope, self.rope_dim, self.latent
-        q = (u @ p["wq"]).reshape(B, T, H, n + r)
+        cd = jnp.bfloat16 if jax.default_backend() == "tpu" else u.dtype
+        wq = p["wq"].reshape(d, H, n + r)
+        q_nope = jnp.einsum("btd,dhn->bhtn", u, wq[..., :n]).astype(cd)
+        # head-major in float32 too: made token-major, XLA copied it into
+        # a second layout for the turn
+        q_pe = jnp.einsum("btd,dhr->bhtr", u, wq[..., n:])
         with device_scope("latent"):
             lk = u @ p["wkv_a"]
-            kv = (rms_norm(lk[..., :c], p["kv_norm"], self.eps)
-                  @ p["wkv_b"]).reshape(B, T, H, n + self.v_dim)
-            k_pe = rope(lk[..., None, c:], self.theta, self.interleave)
-            q = jnp.concatenate(
-                [q[..., :n], rope(q[..., n:], self.theta, self.interleave)],
-                axis=-1)
-            k = jnp.concatenate(
-                [kv[..., :n], jnp.broadcast_to(k_pe, (B, T, H, r))], axis=-1)
-            v = kv[..., n:]
-        return causal_attention(q, k, v) @ p["wo"]
+            lat = rms_norm(lk[..., :c], p["kv_norm"], self.eps)
+            wkv = p["wkv_b"].reshape(c, H, n + self.v_dim)
+            k_nope = jnp.einsum("btc,chn->bhtn", lat, wkv[..., :n]).astype(cd)
+            v = jnp.einsum("btc,chv->bhtv", lat, wkv[..., n:]).astype(cd)
+            k_pe = jnp.swapaxes(
+                rope(lk[..., None, c:], self.theta, self.interleave), 1, 2)
+            q_pe = jnp.swapaxes(rope(jnp.swapaxes(q_pe, 1, 2), self.theta,
+                                     self.interleave), 1, 2).astype(cd)
+            q = jnp.concatenate([q_nope, q_pe], axis=-1)
+            # k_nope beside the one rotary key, as a sum with zeros where
+            # the other is: the compiler fuses the key's broadcast into
+            # that one write, where a concatenation writes it out first
+            k = jnp.pad(k_nope, ((0, 0),) * 3 + ((0, r),)) + jnp.broadcast_to(
+                jnp.pad(k_pe, ((0, 0),) * 3 + ((n, 0),)),
+                (B, H, T, n + r)).astype(cd)
+        o = attention(q, k, v)
+        return jnp.swapaxes(o, 1, 2).reshape(B, T, -1).astype(u.dtype) \
+            @ p["wo"]
 
     @device_scope("dense_mlp")
     def _dense(self, p, m):

@@ -71,13 +71,13 @@ def rope(x, theta: float, interleave: bool = False):
     inv = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
     ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
     if interleave:
-        # each angle twice, and each channel's partner by a roll along the
-        # channels: no axis of two, which the chip would pad to a lane tile
+        # each angle twice; each channel's partner by a full-precision
+        # product with a signed permutation, fused into the turn (a roll is
+        # written out): exact but for values near float32's limits
         cos, sin = (jnp.repeat(f(ang), 2, axis=-1)[None, :, None, :]
                     for f in (jnp.cos, jnp.sin))
-        even = jnp.arange(dim) % 2 == 0
-        partner = jnp.where(even, -jnp.roll(x, -1, axis=-1),
-                            jnp.roll(x, 1, axis=-1))
+        swap = np.kron(np.eye(dim // 2), [[0, 1], [-1, 0]]).astype(np.float32)
+        partner = jnp.einsum("bthr,rs->bths", x, swap, precision="highest")
         return x * cos + partner * sin
     cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)[None, :, None, :]
     sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)[None, :, None, :]

@@ -16,7 +16,9 @@ the cache but not read back.
 """
 
 import functools
+import re
 
+import numpy as np
 import pytest
 
 import jax
@@ -403,6 +405,114 @@ def test_latent_attention_compiles_at_the_published_widths(one_chip, on_tpu,
     assert f"bf16[{B},{H},{T},{Dv}]" in text
     if direction == "backward":
         assert f"bf16[{B},{H},{T},{D}]" in text
+
+
+_INSTRUCTION = re.compile(r"\s+(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) "
+                          r"([\w\-]+)\((.*)$")
+_COMPUTATION = re.compile(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_ARRAY = re.compile(r"(f32|bf16|s32|u32|pred)\[([\d,]*)\](\{[^}]*\})?")
+_BYTES = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4, "pred": 1}
+# what the MLA attention half's latent path wrote to HBM a layer, by
+# _hbm_writes, while it built q and k in float32, token-major, at 32 heads
+# x 192 channels and left their transposes and casts to nn.causal_attention
+# (the same compile of the program as it stood before its operands were
+# made in the kernels' dtype and layout)
+LATENT_WRITES_BEFORE = 3_129_673_792
+
+
+def _hbm_writes(text):
+    """{instruction: (scope, result, bytes it writes to HBM, whether it is a
+    product, whether it replicates rows over the lanes)} over the
+    instructions an ``XLA Ops`` event is made of (``device_scopes.
+    scopes_of_hlo``). A bitcast and the start of an asynchronous copy or
+    slice write nothing of their own; a result laid out in memory space 1
+    (``S(1)``: the core's own memory, where the compiler prefetches) is not
+    HBM. A product is a convolution, or a fusion around one; a broadcast
+    that maps no operand dimension to the result's last replicates rows
+    over the lanes."""
+    from paddlebox_tpu.monitor import device_scopes
+    rows = device_scopes.scopes_of_hlo(text)
+    products, comp = set(), None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+        elif comp and " convolution(" in line:
+            products.add(comp)
+    out = {}
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m or m.group(1) not in rows:
+            continue
+        name, result, opcode, rest = m.groups()
+        written = 0
+        if opcode not in ("bitcast", "copy-start", "slice-start"):
+            for dtype, dims, layout in _ARRAY.findall(result):
+                if "S(1)" not in layout:
+                    written += _BYTES[dtype] * int(np.prod(
+                        [int(d) for d in dims.split(",") if d]))
+        product = opcode == "convolution" or any(
+            c in products for c in re.findall(r"calls=%?([\w.\-]+)", rest))
+        mapped = re.search(r"dimensions=\{([\d,]*)\}", rest)
+        last = str(result.partition("]")[0].count(","))
+        replicas = opcode == "broadcast" and mapped is not None \
+            and last not in mapped.group(1).split(",")
+        out[name] = (rows[name]["scope"], result, written, product, replicas)
+    return out
+
+
+def latent_attention_half_text(one_chip):
+    """The optimized text of Kanana-2's attention half at the cell's widths
+    (32 heads of 192 / 128, a latent of 512, 16384 positions): forward,
+    recomputation and backward as a layer runs them
+    (``recomputed(keep=KEPT)``), for a described v5e."""
+    from paddlebox_tpu.models.deepseek_v3 import KEPT, DeepseekV3Model
+    from paddlebox_tpu.models.nn import recomputed
+    B, T, d = 1, 16384, 2048
+    model = DeepseekV3Model(
+        hidden_size=d, num_layers=1, dense_layers=1, num_attention_heads=32,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        kv_lora_rank=512, intermediate_size=8, moe_intermediate_size=8,
+        n_shared_experts=1, router_experts=8, experts_per_token=2,
+        experts_held=8, routed_scaling_factor=2.448, rope_theta=1e6,
+        rope_interleave=True, rms_norm_eps=1e-6, vocab_size=8, seq_len=T)
+    names = ("wq", "wkv_a", "wkv_b", "wo", "kv_norm")
+    shapes = {**model._shapes(True), "kv_norm": (512,)}
+
+    def layer_half(*args):
+        p, (u, g) = dict(zip(names, args[:5])), args[5:]
+        out, back = jax.vjp(recomputed(model._attention, keep=KEPT), p, u)
+        return out, back(g)
+
+    f32 = jnp.float32
+    return _compiled_text(layer_half, one_chip,
+                          *[(shapes[k], f32) for k in names],
+                          ((B, T, d), f32), ((B, T, d), f32))
+
+
+def test_latent_attention_operands_are_made_once_in_the_kernels_dtype(
+        one_chip, on_tpu):
+    """Kanana-2's attention half (``latent_attention_half_text``): q, k and
+    v reach the kernels in bfloat16, head-major, made once. Outside the
+    kernels' custom calls and the products no instruction writes a float32
+    array of the heads' channels at every position, in either layout (the
+    op's row statistics, one value a row replicated over the kernels' 128
+    lanes, are the attention op's own), and the latent path writes about
+    half the HBM it wrote building q and k in float32, token-major: 1.61
+    GB a layer against 3.13."""
+    writes = _hbm_writes(latent_attention_half_text(one_chip))
+    heads_f32 = [
+        (name, scope, result)
+        for name, (scope, result, _, product, replicas) in writes.items()
+        for dtype, dims, _ in _ARRAY.findall(result)
+        if dtype == "f32" and not (product or replicas
+                                   or name.startswith("pbtpu_attention"))
+        and len(dims.split(",")) >= 3 and int(dims.split(",")[-1]) >= 64
+        and {32, 16384} <= {int(x) for x in dims.split(",")}]
+    assert not heads_f32, heads_f32
+    latent = sum(w for scope, _, w, _, _ in writes.values()
+                 if scope == "latent")
+    assert latent <= 0.52 * LATENT_WRITES_BEFORE, latent
 
 
 @pytest.mark.parametrize("direction", ["forward", "backward"])

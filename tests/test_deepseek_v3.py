@@ -5,6 +5,8 @@ rotary slice of 8): the program's loss and gradients against the plain
 reference (``benchmark/reference/deepseek_v3.py``) on seeded random
 weights, outside a trainer so the attention kernels run in the Pallas
 interpreter; the interleaved rotation against a literal one, pair by pair;
+the attention half's kernel operands, made in bfloat16 and head-major,
+against the same half built in float32 and cast at the kernels' door;
 an expert layer cut over eight chips adding up to the uncut reference
 layer; through ``Trainer.train_pass`` for two passes with the reference
 followed step by step; and what the model declares."""
@@ -24,9 +26,12 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from paddlebox_tpu.models import MODEL_REGISTRY, base     # noqa: E402
-from paddlebox_tpu.models.deepseek_v3 import DeepseekV3Model  # noqa: E402
-from paddlebox_tpu.models.nn import rope                  # noqa: E402
+from paddlebox_tpu.models import deepseek_v3, nn          # noqa: E402
+from paddlebox_tpu.models.deepseek_v3 import (KEPT,       # noqa: E402
+                                              DeepseekV3Model)
+from paddlebox_tpu.models.nn import recomputed, rope      # noqa: E402
 from paddlebox_tpu.monitor import names                   # noqa: E402
+from paddlebox_tpu.ops import flash_attention             # noqa: E402
 
 from token_tower_common import follow_two_passes, tower   # noqa: E402
 
@@ -110,6 +115,103 @@ def test_rope_turns_each_pair_by_its_angle(dim, interleave):
         order = np.concatenate([np.arange(0, dim, 2), np.arange(1, dim, 2)])
         half = np.asarray(rope(jnp.asarray(x[..., order]), theta))
         np.testing.assert_allclose(half, got[..., order], atol=2e-5)
+
+
+def _rolled_rope(x, theta, interleave):
+    """The interleaved rotation as it was first written: each channel's
+    partner by a roll along the channels."""
+    if not interleave:
+        return rope(x, theta)
+    T, dim = x.shape[1], x.shape[-1]
+    inv = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos, sin = (jnp.repeat(f(ang), 2, axis=-1)[None, :, None, :]
+                for f in (jnp.cos, jnp.sin))
+    even = jnp.arange(dim) % 2 == 0
+    partner = jnp.where(even, -jnp.roll(x, -1, axis=-1),
+                        jnp.roll(x, 1, axis=-1))
+    return x * cos + partner * sin
+
+
+def _float32_operands(model, p, u):
+    """The attention half with its operands built in float32, token-major
+    — q concatenated at n + r channels, the rotary key broadcast to every
+    head — and handed to ``nn.causal_attention`` to transpose and cast."""
+    B, T, _ = u.shape
+    H, n, r, c = model.heads, model.nope, model.rope_dim, model.latent
+    rot = lambda x: _rolled_rope(x, model.theta, model.interleave)
+    q = (u @ p["wq"]).reshape(B, T, H, n + r)
+    lk = u @ p["wkv_a"]
+    kv = (nn.rms_norm(lk[..., :c], p["kv_norm"], model.eps)
+          @ p["wkv_b"]).reshape(B, T, H, n + model.v_dim)
+    q = jnp.concatenate([q[..., :n], rot(q[..., n:])], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :n], jnp.broadcast_to(rot(lk[..., None, c:]),
+                                       (B, T, H, r))], axis=-1)
+    return nn.causal_attention(q, k, kv[..., n:]) @ p["wo"]
+
+
+@pytest.mark.parametrize("interleave", [True, False])
+def test_the_kernels_operands_are_made_once_in_their_dtype(monkeypatch,
+                                                          interleave):
+    """The attention half as the chip runs it — the kernels' dtype
+    bfloat16 (``jax.default_backend`` says "tpu"), the kernels in the
+    Pallas interpreter — at the cell's head (queries and keys 128 + 64,
+    values 128) against the same half with float32 operands made
+    token-major and cast by ``nn.causal_attention``, forward,
+    recomputation and backward, operation by operation (no jit: what is
+    compared is the program's arithmetic, not how XLA:CPU fuses it). The
+    kernels receive the same bits, so the output and the gradients of
+    W_q, W_kv_b and W_o are the same bits. The gradients of the input, of
+    W_kv_a and of the latent's norm differ in the last float32 places:
+    each of W_q and W_kv_b is now two products, so the input's and the
+    latent's cotangents sum their 192 and 256 channels a head in two
+    parts (within 2e-6 of each leaf's largest value)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    seen = []
+
+    def recording(q, k, v, **kw):
+        seen.append((q, k, v))
+        return flash_attention.attention(q, k, v, interpret=True, **kw)
+
+    monkeypatch.setattr(deepseek_v3, "attention", recording)
+    monkeypatch.setattr(nn, "attention", recording)
+    model = DeepseekV3Model(
+        hidden_size=64, num_layers=1, dense_layers=1, num_attention_heads=2,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        kv_lora_rank=32, intermediate_size=8, moe_intermediate_size=8,
+        n_shared_experts=1, router_experts=4, experts_per_token=2,
+        experts_held=4, routed_scaling_factor=1.0, rope_theta=1e4,
+        rope_interleave=interleave, rms_norm_eps=1e-6, vocab_size=8,
+        seq_len=128)
+    p = {k: v for k, v in model.init(jax.random.PRNGKey(1))["layers"][0]
+         .items() if k in ("wq", "wkv_a", "wkv_b", "wo", "kv_norm")}
+    p["kv_norm"] = 1 + 0.1 * jax.random.normal(jax.random.PRNGKey(5), (32,))
+    u, g = (jax.random.normal(jax.random.PRNGKey(s), (1, 128, 64))
+            for s in (2, 3))
+
+    def run(half):
+        seen.clear()
+        half(p, u)
+        out, back = jax.vjp(recomputed(half, keep=KEPT), p, u)
+        return seen[0], out, back(g)
+
+    with jax.disable_jit():
+        ops_a, out_a, grads_a = run(model._attention)
+        ops_b, out_b, grads_b = run(
+            lambda p, u: _float32_operands(model, p, u))
+    for a, b in zip(ops_a, ops_b):
+        assert a.dtype == jnp.bfloat16 and a.shape[:3] == (1, 2, 128)
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    np.testing.assert_array_equal(out_a, out_b)
+    for name in ("wq", "wkv_b", "wo"):
+        np.testing.assert_array_equal(grads_a[0][name], grads_b[0][name])
+    for x, y in ((grads_a[1], grads_b[1]),
+                 (grads_a[0]["wkv_a"], grads_b[0]["wkv_a"]),
+                 (grads_a[0]["kv_norm"], grads_b[0]["kv_norm"])):
+        np.testing.assert_allclose(x, y, rtol=0,
+                                   atol=2e-6 * float(jnp.abs(y).max()))
 
 
 def test_eight_shares_and_the_shared_experts_once_add_up_to_the_uncut_layer():
