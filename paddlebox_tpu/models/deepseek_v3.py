@@ -24,7 +24,9 @@ next-token cross entropy in chunks of positions (``models/nn.py``). With
     [k_nope | v] = l W_kv_b                     (T, H, n + dv)
     q_pe, k_pe   = RoPE(q_pe), RoPE(k_pe)       (``nn.rope``;
                                                  adjacent pairs where
-                                                 ``rope_interleave``)
+                                                 ``rope_interleave``; left
+                                                 as they are where
+                                                 ``mla_use_nope``)
     k            = [k_nope | k_pe, the one rotary key of every head]
     o            = softmax(q k^T (n + r)^-0.5, causal) v      (T, H, dv)
     out          = o W_o
@@ -110,7 +112,7 @@ class DeepseekV3Model:
                  rope_interleave: bool, rms_norm_eps: float, vocab_size: int,
                  seq_len: int, first_expert: int = 0,
                  key_index_bits: int = 27, head_chunk: int = 2048,
-                 expert_chunk_tokens: int = 4096):
+                 expert_chunk_tokens: int = 4096, mla_use_nope: bool = False):
         self.emb_dim = self.d = int(hidden_size)
         self.layers, self.dense_layers = int(num_layers), int(dense_layers)
         if not 0 <= self.dense_layers <= self.layers:
@@ -126,6 +128,7 @@ class DeepseekV3Model:
         self.held = (int(first_expert), int(experts_held))
         self.scale = float(routed_scaling_factor)
         self.theta, self.interleave = float(rope_theta), bool(rope_interleave)
+        self.rotates = not mla_use_nope
         self.eps = float(rms_norm_eps)
         self.vocab, self.seq_len = int(vocab_size), int(seq_len)
         self.key_index_bits = int(key_index_bits)
@@ -185,6 +188,11 @@ class DeepseekV3Model:
 
     # -- the tower ---------------------------------------------------------
 
+    def _turn(self, x):
+        """The rotary part's rotation (``nn.rope``), or none where the
+        configuration says ``mla_use_nope``."""
+        return rope(x, self.theta, self.interleave) if self.rotates else x
+
     @device_scope("attention")
     def _attention(self, p, u):
         B, T, d = u.shape
@@ -201,10 +209,9 @@ class DeepseekV3Model:
             wkv = p["wkv_b"].reshape(c, H, n + self.v_dim)
             k_nope = jnp.einsum("btc,chn->bhtn", lat, wkv[..., :n]).astype(cd)
             v = jnp.einsum("btc,chv->bhtv", lat, wkv[..., n:]).astype(cd)
-            k_pe = jnp.swapaxes(
-                rope(lk[..., None, c:], self.theta, self.interleave), 1, 2)
-            q_pe = jnp.swapaxes(rope(jnp.swapaxes(q_pe, 1, 2), self.theta,
-                                     self.interleave), 1, 2).astype(cd)
+            k_pe = jnp.swapaxes(self._turn(lk[..., None, c:]), 1, 2)
+            q_pe = jnp.swapaxes(self._turn(jnp.swapaxes(q_pe, 1, 2)), 1,
+                                2).astype(cd)
             q = jnp.concatenate([q_nope, q_pe], axis=-1)
             # k_nope beside the one rotary key, as a sum with zeros where
             # the other is: the compiler fuses the key's broadcast into

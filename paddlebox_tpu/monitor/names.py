@@ -205,6 +205,12 @@ MODEL_STAT_NAMES: tuple[str, ...] = (
     "ssm.tokens",
     "ssm.chunks",
     "ssm.decay_log_min",
+    # ops/kda.py through a KDA mixer: the least cumulative log decay of any
+    # channel over one chunk (the op's chunk) in the step — how far the
+    # intra-chunk exponentials reach below float32's range (about -87;
+    # the kernels form them as differences that stay <= 0, so what it
+    # reads is how much of a chunk's start its end still sees)
+    "kda.chunk_decay_log_min",
 )
 
 # the host plan's counters (Trainer._host_plan, a batch at a time on the
@@ -255,6 +261,10 @@ KERNEL_NAMES: tuple[str, ...] = (
     # ops/ssm_scan.py: the Mamba-2 scan in chunks
     "pbtpu_ssm_fwd",
     "pbtpu_ssm_bwd",
+    # ops/kda.py: Kimi Delta Attention, the gated delta rule with a decay
+    # per channel, in chunks
+    "pbtpu_kda_fwd",
+    "pbtpu_kda_bwd",
     # ops/short_conv.py: the gated short convolution between an LFM2
     # mixer's projections
     "pbtpu_short_conv_fwd",

@@ -539,6 +539,34 @@ def test_short_conv_compiles_at_the_published_widths(one_chip, on_tpu,
 
 
 # ---------------------------------------------------------------------------
+# Kimi Delta Attention at the published widths and the cell's length: 32
+# heads of 128, one sequence of 16,384 positions in chunks of 128, q, k, v
+# in bfloat16 as the chip runs them, log a and beta in float32
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_kda_compiles_at_the_published_widths(one_chip, on_tpu, direction):
+    from paddlebox_tpu.ops import kda as kd
+    B, H, T, K = 1, 32, 16384, 128
+    assert kd.kda_geometry(kd.CHUNK, kd.SUB, K, K)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+
+    def op(*args):
+        return kd.kda(*args, interpret=False)
+
+    def grads(*args):
+        return jax.grad(lambda *a: jnp.sum(op(*a).astype(f32)),
+                        argnums=tuple(range(5)))(*args)
+
+    text = _compiled_text(
+        op if direction == "forward" else grads, one_chip,
+        ((B, H, T, K), bf16), ((B, H, T, K), bf16), ((B, H, T, K), bf16),
+        ((B, H, T, K), f32), ((B, H, T), f32))
+    assert "tpu_custom_call" in text and "pbtpu_kda_fwd" in text
+    assert ("pbtpu_kda_bwd" in text) == (direction == "backward")
+
+
+# ---------------------------------------------------------------------------
 # the held experts' grouped products (ISSUE 39): the pair at the three token
 # cells' operands, in the tiles the rule gives, at every rung of their
 # ladders — Mosaic takes the tiles and the VMEM they ask for
@@ -546,7 +574,8 @@ def test_short_conv_compiles_at_the_published_widths(one_chip, on_tpu,
 
 @pytest.mark.parametrize("cell", ["smallthinker_21b_ep4",
                                   "nemotron3_nano_ep16", "lfm2_24b_a2b_ep8",
-                                  "kanana2_30b_a3b_ep8"])
+                                  "kanana2_30b_a3b_ep8",
+                                  "kimi_linear_48b_a3b_ep32"])
 def test_grouped_products_compile_at_the_cells_operands(one_chip, cell):
     from paddlebox_tpu.ops import grouped_matmul as gm
     from paddlebox_tpu.parallel.expert import route_rungs

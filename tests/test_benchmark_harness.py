@@ -4,7 +4,7 @@ own loss over ordered tokens (``test_seam``), the traffic generator
 (``test_datagen``), the work counts (``test_work``), the per-layer readers
 held to the program's span, counter and device-scope names
 (``test_stage_metrics``, ``test_trace_reduce``, ``test_scope_metrics``,
-``test_grouped_product``, ``test_latent_kv_metric``), and what decides
+``test_grouped_product``, ``test_latent_kv_metric``, ``test_kda_metric``), and what decides
 ``correct`` (``test_correct``: the program against the f32 reference, the
 bfloat16 control and the planted faults, at CPU size for every cell of
 ``BENCHMARK.json`` and the waiting one) — plus a whole ``run.py --rehearse`` of each cell at its own rehearsal
@@ -24,6 +24,7 @@ from benchmark.tests import test_correct as _correct    # noqa: E402
 from benchmark.tests.test_correct import *      # noqa: E402,F401,F403
 from benchmark.tests.test_datagen import *      # noqa: E402,F401,F403
 from benchmark.tests.test_grouped_product import *  # noqa: E402,F401,F403
+from benchmark.tests.test_kda_metric import *   # noqa: E402,F401,F403
 from benchmark.tests.test_latent_kv_metric import *  # noqa: E402,F401,F403
 from benchmark.tests.test_seam import *         # noqa: E402,F401,F403
 from benchmark.tests.test_scope_metrics import *    # noqa: E402,F401,F403
@@ -34,11 +35,12 @@ from benchmark.tests.test_work import *         # noqa: E402,F401,F403
 # The cases of benchmark/tests in which nothing is planted: the fault
 # patches optax.sigmoid_binary_cross_entropy, which a tower that declares
 # its own loss never calls, so the run comes out correct (PERF.md section
-# 7, "for a `benchmark` PR"): the four token cells, by name.
+# 7, "for a `benchmark` PR"): the five token cells, by name.
 _PLANTS_NOTHING = {("smallthinker_21b_ep4.seq8k", "_half_batch"),
                    ("nemotron3_nano_ep16.seq4k", "_half_batch"),
                    ("lfm2_24b_a2b_ep8.seq8k", "_half_batch"),
-                   ("kanana2_30b_a3b_ep8.seq16k", "_half_batch")}
+                   ("kanana2_30b_a3b_ep8.seq16k", "_half_batch"),
+                   ("kimi_linear_48b_a3b_ep32.seq16k", "_half_batch")}
 
 
 @pytest.mark.parametrize("fault", [_correct._unchanged_state,
@@ -222,11 +224,69 @@ def _kanana_cut(entry, cfg):
                                                      24576)
 
 
+def _kimi_cut(entry, cfg):
+    a = cfg["model_args"]
+    # every width as published: hidden 2304; KDA 32 heads of 128 with
+    # convolutions of 4 taps; latent attention of 32 heads, 128 + 64 / 128,
+    # a latent of 512; the dense MLP 9216, experts 1024 with one shared
+    published = {"hidden_size": 2304, "num_attention_heads": 32,
+                 "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+                 "v_head_dim": 128, "kv_lora_rank": 512,
+                 "intermediate_size": 9216, "moe_intermediate_size": 1024,
+                 "routed_scaling_factor": 2.446, "rope_theta": 10000,
+                 "rms_norm_eps": 1e-5}
+    for key, value in published.items():
+        assert cfg[key] == value and a[key] == value, key
+    la = cfg["linear_attn_config"]
+    assert (a["kda_num_heads"], a["kda_head_dim"],
+            a["short_conv_kernel_size"]) == (
+        la["num_heads"], la["head_dim"], la["short_conv_kernel_size"]) \
+        == (32, 128, 4)
+    assert (a["kda_layers"], a["full_attn_layers"]) == (
+        la["kda_layers"], la["full_attn_layers"])
+    assert cfg["mla_use_nope"] and cfg["q_lora_rank"] is None
+    assert a["n_shared_experts"] == cfg["num_shared_experts"] == 1
+    assert (cfg["num_expert_group"], cfg["topk_group"]) == (1, 1)
+    assert (a["router_experts"], a["experts_per_token"]) == (256, 8)
+    assert cfg["num_experts_per_token"] == 8 and cfg["moe_renormalize"]
+    assert a["experts_held"] == cfg["num_experts"] == 8
+    assert a["vocab_size"] == cfg["vocab_size"] == 163840 // 8
+    # layers 1-5 of the 27 published: the leading dense KDA layer and one
+    # whole period after it (KDA, KDA, latent attention, KDA)
+    assert a["num_layers"] == cfg["num_hidden_layers"] == 5
+    assert a["dense_layers"] == cfg["first_k_dense_replace"] == 1
+    assert set(entry["reduced"]) == set(cfg["reduced"]) == {
+        "num_hidden_layers", "num_experts", "vocab_size", "steps_per_pass"}
+    assert set(cfg["reduced_from"]) == set(cfg["reduced"])
+    assert "32 chips" in cfg["deployment"]
+    assert {"e_score_correction_bias", "untied head", "initialisation",
+            "sequences", "mla_use_nope", "kda gates' low rank", "kda o_norm",
+            "A_log and dt_bias", "trainer.dense_lr",
+            "weights_seed"} <= set(cfg["assumed"])
+    from benchmark import sut
+    from benchmark.reference import kimi_linear as ref
+    # 555.2 M dense parameters (8.88 GB at 16 B); 7.07 T multiply-adds an
+    # example, 1.37 T of them the latent-attention layer's scores and
+    # values, 0.195 T the four delta rules' (42.4 TFLOP a step of one)
+    assert round(ref.tower_sizes(cfg)[0] / 1e6, 1) == 555.2
+    assert round(ref.macs_per_example(cfg) / 1e12, 2) == 7.07
+    assert round(ref.attention_macs(cfg) / 1e12, 2) == 1.37
+    assert round(ref.kda_macs(cfg) / 1e9, 1) == 195.1
+    assert round(ref.kda_bytes(cfg) / 1e9, 2) == 10.23
+    # 8 of 256 choices fall on 8 held experts: a quarter of a choice a
+    # token, three products of 2304 x 1024, four layers
+    assert ref.expert_gmm_macs(cfg) == 4 * 16384 * 0.25 * 3 * 2304 * 1024
+    assert ref.route_rows(cfg) == (32768, 8, 256)
+    assert sut.route_rungs(*ref.route_rows(cfg)) == (4096, 8192, 16384,
+                                                     32768)
+
+
 @pytest.mark.parametrize("config,holds", [
     ("smallthinker_21b_ep4", _smallthinker_cut),
     ("nemotron3_nano_ep16", _nemotron_cut),
     ("lfm2_24b_a2b_ep8", _lfm2_cut),
-    ("kanana2_30b_a3b_ep8", _kanana_cut)])
+    ("kanana2_30b_a3b_ep8", _kanana_cut),
+    ("kimi_linear_48b_a3b_ep32", _kimi_cut)])
 def test_the_cells_files_state_the_cut_and_the_published_widths(config,
                                                                 holds):
     import json

@@ -165,7 +165,8 @@ def test_fewer_rows_than_the_metadata_was_made_for_take_it_unchanged():
 CELLS = {"smallthinker_21b_ep4": (4096, 6, 64, 16, 2560, 768),
          "nemotron3_nano_ep16": (4096, 6, 128, 8, 2688, 1856),
          "lfm2_24b_a2b_ep8": (4096, 4, 64, 8, 2048, 1536),
-         "kanana2_30b_a3b_ep8": (4096, 6, 128, 16, 2048, 768)}
+         "kanana2_30b_a3b_ep8": (4096, 6, 128, 16, 2048, 768),
+         "kimi_linear_48b_a3b_ep32": (4096, 8, 256, 8, 2304, 1024)}
 
 
 def _legal(tile, dim):

@@ -21,7 +21,8 @@ def test_registry_complete():
     assert set(MODEL_REGISTRY) == {"dnn_ctr", "deepfm", "wide_deep",
                                    "dcn_v2", "dlrm", "mmoe", "pv_rank",
                                    "smallthinker", "nemotron_h",
-                                   "lfm2_moe", "deepseek_v3"}
+                                   "lfm2_moe", "deepseek_v3",
+                                   "kimi_linear"}
 
 
 @pytest.mark.parametrize("model_cls,kw", [
