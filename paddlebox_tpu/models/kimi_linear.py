@@ -77,7 +77,8 @@ def _l2norm(x, eps: float = 1e-6):
 
 class KimiLinearModel(DeepseekV3Model):
     name = "kimi_linear"
-    stat_names = DeepseekV3Model.stat_names + ("kda.chunk_decay_log_min",)
+    stat_names = DeepseekV3Model.stat_names + ("kda.chunk_decay_log_min",
+                                               "kda.pair_subchunks")
 
     def __init__(self, hidden_size: int, num_layers: int, dense_layers: int,
                  kda_layers: tuple, full_attn_layers: tuple,
@@ -187,7 +188,8 @@ class KimiLinearModel(DeepseekV3Model):
     @device_scope("mixer")
     def _kda_mixer(self, p, u):
         """(the mixer's output (B, T, d), the least cumulative log decay
-        of any channel over one chunk)."""
+        of any channel over one chunk, the sub-chunk visits the kernels'
+        forward call took pair by pair: ``ops/kda.py::pair_subchunks``)."""
         B, T, _ = u.shape
         H, K = self.kda_heads, self.kda_dim
         L = min(self.kda_chunk, T)
@@ -204,24 +206,26 @@ class KimiLinearModel(DeepseekV3Model):
         log_a = heads(jax.nn.softplus(f)) \
             * -jnp.exp(p["A_log"])[None, :, None, None]
         beta = jnp.swapaxes(jax.nn.sigmoid(u @ p["w_b"]), 1, 2)
-        o = kda(q.astype(cd), k.astype(cd), v.astype(cd), log_a, beta,
-                chunk=L)
+        o, pairs = kda(q.astype(cd), k.astype(cd), v.astype(cd), log_a,
+                       beta, chunk=L)
         o = jnp.swapaxes(o, 1, 2).astype(u.dtype)              # (B, T, H, K)
         gate = jax.nn.sigmoid((u @ p["w_ga"]) @ p["w_gb"])
         y = rms_norm(o, p["o_norm"], self.eps).reshape(B, T, H * K) * gate
         decay = jnp.sum(log_a.reshape(B, H, T // L, L, K), axis=3)
-        return y @ p["wo"], jnp.min(jax.lax.stop_gradient(decay))
+        return y @ p["wo"], (jnp.min(jax.lax.stop_gradient(decay)), pairs)
 
     def _mixed_layer(self, p, h, kind: str, dense: bool):
         """One layer over h (B, T, d): (h_next, its held experts' load and
         how its chunks were routed — None for a dense layer — and a KDA
-        mixer's decay gauge — 0 for latent attention)."""
+        mixer's decay gauge and pair-by-pair visits — 0 and 0 for latent
+        attention)."""
         B, T, d = h.shape
         u = rms_norm(h, p["attn_norm"], self.eps)
         if kind == "kda":
             out, decay = self._kda_mixer(p, u)
         else:
-            out, decay = self._attention(p, u), jnp.float32(0.0)
+            out, decay = self._attention(p, u), (jnp.float32(0.0),
+                                                 jnp.float32(0.0))
         h = h + out
         m = rms_norm(h, p["ffn_norm"], self.eps).reshape(B * T, d)
         y, route = (self._dense(p, m), None) if dense \
@@ -232,9 +236,9 @@ class KimiLinearModel(DeepseekV3Model):
         """(one loss an example (B,), the assignments each held expert
         received in each expert layer (layers, experts_held), each expert
         layer's sorted rows and whole-chunk routes (layers, 2), the least
-        chunk decay over the KDA layers)."""
+        chunk decay over the KDA layers, their pair-by-pair visits)."""
         h = pulled[..., 3:]
-        routed, decays = [], [jnp.float32(0.0)]
+        routed, decays = [], [(jnp.float32(0.0), jnp.float32(0.0))]
         for i, (p, kind) in enumerate(zip(params["layers"], self.kinds)):
             h, route, decay = recomputed(
                 self._mixed_layer, static_argnums=(2, 3), keep=KEPT)(
@@ -245,15 +249,16 @@ class KimiLinearModel(DeepseekV3Model):
         loads, took = (jnp.stack(v) for v in zip(*routed)) if routed else (
             jnp.zeros((0, self.held[1]), jnp.int32),
             jnp.zeros((0, 2), jnp.int32))
+        decays, pairs = zip(*decays)
         return (next_token_loss(params, h, local_ids, mask, self.eps,
                                 self.head_chunk), loads, took,
-                jnp.min(jnp.stack(decays)))
+                jnp.min(jnp.stack(decays)), sum(pairs))
 
     def loss(self, params, pulled, mask, dense, labels, local_ids):
         """The declared loss (models/base.py): the batch's mean, no
-        prediction, the step's routing statistics and its least chunk
-        decay."""
-        per_example, loads, took, decay = self.example_losses(
+        prediction, the step's routing statistics, its least chunk decay
+        and its pair-by-pair sub-chunk visits."""
+        per_example, loads, took, decay, pairs = self.example_losses(
             params, pulled, mask, local_ids)
         n_tok = pulled.shape[0] * pulled.shape[1]
         loads = jax.lax.stop_gradient(loads).astype(jnp.float32)
@@ -261,5 +266,5 @@ class KimiLinearModel(DeepseekV3Model):
             jnp.float32(n_tok * self.top_k
                         * (self.layers - self.dense_layers)),
             jnp.sum(loads), jnp.max(loads, initial=0.0),
-            *jnp.sum(took, axis=0).astype(jnp.float32), decay])
+            *jnp.sum(took, axis=0).astype(jnp.float32), decay, pairs])
         return jnp.mean(per_example), None, stats

@@ -211,6 +211,12 @@ MODEL_STAT_NAMES: tuple[str, ...] = (
     # the kernels form them as differences that stay <= 0, so what it
     # reads is how much of a chunk's start its end still sees)
     "kda.chunk_decay_log_min",
+    # and the (sequence, head block, chunk, sub-chunk) visits of the
+    # kernels' forward calls, one a layer and step, that took the pair-by-
+    # pair path because some channel of some head of the block fell by
+    # more than ``kda.LIMIT`` across half a sub-chunk (``kda.pair_subchunks``;
+    # the share's denominator: sequences x KDA layers x H / hb x T / sub)
+    "kda.pair_subchunks",
 )
 
 # the host plan's counters (Trainer._host_plan, a batch at a time on the

@@ -541,29 +541,40 @@ def test_short_conv_compiles_at_the_published_widths(one_chip, on_tpu,
 # ---------------------------------------------------------------------------
 # Kimi Delta Attention at the published widths and the cell's length: 32
 # heads of 128, one sequence of 16,384 positions in chunks of 128, q, k, v
-# in bfloat16 as the chip runs them, log a and beta in float32
+# in bfloat16 as the chip runs them, log a and beta in float32; at the
+# block of heads the rule picks (8), whose lowered module is no larger
+# than one head's (every product batched over the block: the tracing and
+# lowering a warm process repeats before it asks the compile cache)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("direction", ["forward", "backward"])
-def test_kda_compiles_at_the_published_widths(one_chip, on_tpu, direction):
+def test_kda_compiles_at_the_published_widths(one_chip, on_tpu, direction,
+                                              monkeypatch):
     from paddlebox_tpu.ops import kda as kd
     B, H, T, K = 1, 32, 16384, 128
     assert kd.kda_geometry(kd.CHUNK, kd.SUB, K, K)
+    assert kd.kda_head_block(H, kd.CHUNK, K, K) == 8
     bf16, f32 = jnp.bfloat16, jnp.float32
 
     def op(*args):
-        return kd.kda(*args, interpret=False)
+        return kd.kda(*args, interpret=False)[0]
 
     def grads(*args):
         return jax.grad(lambda *a: jnp.sum(op(*a).astype(f32)),
                         argnums=tuple(range(5)))(*args)
 
-    text = _compiled_text(
-        op if direction == "forward" else grads, one_chip,
-        ((B, H, T, K), bf16), ((B, H, T, K), bf16), ((B, H, T, K), bf16),
-        ((B, H, T, K), f32), ((B, H, T), f32))
+    fn = op if direction == "forward" else grads
+    shapes = (((B, H, T, K), bf16), ((B, H, T, K), bf16),
+              ((B, H, T, K), bf16), ((B, H, T, K), f32), ((B, H, T), f32))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    lowered = jax.jit(fn).lower(*args)
+    text = lowered.compile().as_text()
     assert "tpu_custom_call" in text and "pbtpu_kda_fwd" in text
     assert ("pbtpu_kda_bwd" in text) == (direction == "backward")
+    with monkeypatch.context() as m:
+        m.setattr(kd, "kda_head_block", lambda *shape: 1)
+        one_head = jax.jit(fn).lower(*args).as_text()
+    assert len(lowered.as_text()) <= 1.2 * len(one_head)
 
 
 # ---------------------------------------------------------------------------

@@ -6,7 +6,10 @@ whose states do not leak; a channel whose log decay sums to -200 inside
 one chunk (a single position's -150 among them: its sub-chunk is taken
 pair by pair), one that falls by 75 over half a sub-chunk (one reference
 still serves it), and keys that repeat, the cases a chunked form can
-lose; and the shapes the op refuses."""
+lose; and the shapes the op refuses. The kernels take a block of heads
+(``kda_head_block``: both heads here) and are held to the recurrence at
+that block and at one head a block; the count of sub-chunks the block
+took pair by pair (``pair_subchunks``) against one made in numpy."""
 
 import numpy as np
 import pytest
@@ -37,6 +40,14 @@ def _inputs(seed, case="plain"):
         # sub-chunk, one position alone -150
         log_a = log_a.at[:, 0, :CHUNK, 0].set(-50.0 / 31)
         log_a = log_a.at[:, 0, 12, 0].set(-150.0)
+    elif case == "both_heads":
+        # head 0 as above; head 1's channel 5 falls by 150 in the same
+        # sub-chunk of the first chunk and in one of the second: a block
+        # of both heads takes the first sub-chunk once
+        log_a = log_a.at[:, 0, :CHUNK, 0].set(-50.0 / 31)
+        log_a = log_a.at[:, 0, 12, 0].set(-150.0)
+        log_a = log_a.at[:, 1, 11, 5].set(-150.0)
+        log_a = log_a.at[:, 1, CHUNK + 10, 5].set(-150.0)
     elif case == "near_the_limit":
         # channel 3 of head 1 falls by 75 over half a sub-chunk: one
         # reference a sub-chunk still serves it, with factors near e^+-75
@@ -59,14 +70,43 @@ def _both(args, do):
         return o, back(do)
 
     kernels = jax.jit(lambda *a: run(
-        lambda *x: kd.kda(*x, chunk=CHUNK, sub=SUB, interpret=True)))
+        lambda *x: kd.kda(*x, chunk=CHUNK, sub=SUB, interpret=True)[0]))
     return kernels(*args, do), jax.jit(lambda *a: run(kd.kda_reference))(
         *args, do)
 
 
-@pytest.mark.parametrize("case", ["plain", "strong_decay", "near_the_limit",
-                                  "repeated_keys"])
+CASES = ["plain", "strong_decay", "near_the_limit", "repeated_keys",
+         "both_heads"]
+
+
+def _numpy_pairs(log_a, chunk, sub, hb):
+    """The (sequence, head block, sub-chunk) visits where some channel of
+    some head of the block falls by more than LIMIT across either half of
+    the sub-chunk, counted in numpy from log a."""
+    la = np.asarray(log_a, np.float32)
+    B, H, T, K = la.shape
+    g = np.cumsum(la.reshape(B, H, T // chunk, chunk, K), axis=3,
+                  dtype=np.float32).reshape(B, H, T // sub, sub, K)
+    mid = g[..., sub // 2, :]
+    fall = np.maximum(g[..., 0, :] - mid, mid - g[..., sub - 1, :])
+    fall = fall.reshape(B, H // hb, hb, T // sub, K).max(axis=(2, 4))
+    return int(np.sum(~(fall <= kd.LIMIT)))
+
+
+@pytest.mark.parametrize("case", CASES)
 def test_kernels_match_the_literal_recurrence(case):
+    assert kd.kda_head_block(H, CHUNK, K, V) == 2
+    _check(case)
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_kernels_at_one_head_a_block_match_the_literal_recurrence(
+        case, monkeypatch):
+    monkeypatch.setattr(kd, "kda_head_block", lambda *shape: 1)
+    _check(case)
+
+
+def _check(case):
     args, do = _inputs(3, case)
     if case == "strong_decay":
         cum = jnp.cumsum(args[3][0, 0, :CHUNK, 0])
@@ -124,3 +164,36 @@ def test_shapes_the_op_refuses(monkeypatch):
     assert kd.kda_geometry(128, 16, 128, 128)
     with pytest.raises(ValueError, match="128-lane"):
         kd.kda(q, k, v, log_a, beta, chunk=32, sub=8)
+
+
+def test_the_head_block_follows_the_shapes():
+    """The largest divisor of H whose estimate the budget holds: both of
+    the tests' heads; at the published widths a block of 8 of 32 (16
+    would pass the budget); one head where none fits."""
+    assert kd.kda_head_block(H, CHUNK, K, V) == 2
+    assert kd.kda_head_block(32, 128, 128, 128) == 8
+    assert kd._vmem_bytes(8, 128, 128, 128) <= kd.VMEM_BUDGET \
+        < kd._vmem_bytes(16, 128, 128, 128)
+    assert kd.kda_head_block(24, 128, 128, 128) == 8
+    assert kd.kda_head_block(7, 128, 128, 128) == 7
+    assert kd.kda_head_block(4, 128, 4096, 4096) == 1
+
+
+@pytest.mark.parametrize("block", ["one", "rule"])
+@pytest.mark.parametrize("case", ["plain", "strong_decay", "both_heads"])
+def test_pair_subchunks_counts_what_numpy_counts(case, block, monkeypatch):
+    """The op's count at the kernels' block against numpy's from log a:
+    none where every fall is small; head 0's one sub-chunk a sequence;
+    with head 1's two more, three a sequence at one head a block and two
+    at a block of both (one sub-chunk is both heads')."""
+    hb = 1 if block == "one" else 2
+    if block == "one":
+        monkeypatch.setattr(kd, "kda_head_block", lambda *shape: 1)
+    (q, k, v, log_a, beta), _ = _inputs(3, case)
+    _, pairs = jax.jit(lambda *a: kd.kda(*a, chunk=CHUNK, sub=SUB,
+                                         interpret=True))(
+        q, k, v, log_a, beta)
+    want = _numpy_pairs(log_a, CHUNK, SUB, hb)
+    assert float(pairs) == want
+    assert want == B * {"plain": 0, "strong_decay": 1,
+                        "both_heads": 3 if hb == 1 else 2}[case]

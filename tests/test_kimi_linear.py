@@ -91,7 +91,7 @@ def test_the_chunk_decay_gauge_and_the_declaration():
     assert preds is None and stats.shape == (len(model.stat_names),)
     assert not base.predicts(model)
     assert model.stat_names == names.MODEL_STAT_NAMES[:5] + (
-        "kda.chunk_decay_log_min",)
+        "kda.chunk_decay_log_min", "kda.pair_subchunks")
     assert set(model.stat_names) <= set(names.MODEL_STAT_NAMES)
     got = dict(zip(model.stat_names, np.asarray(stats)))
     with jax.default_matmul_precision("highest"):
@@ -102,6 +102,8 @@ def test_the_chunk_decay_gauge_and_the_declaration():
     assert want < -10.0
     np.testing.assert_allclose(got["kda.chunk_decay_log_min"], want,
                                rtol=1e-5)
+    # no fall near LIMIT at the initial weights: no pair-by-pair visit
+    assert got["kda.pair_subchunks"] == 0
     # four expert layers after the dense one
     assert got["moe.assignments"] == ids.size * a["experts_per_token"] * 4
     assert 0 < got["moe.held_assignments"] <= got["moe.route_rows"] \
@@ -115,6 +117,45 @@ def test_the_chunk_decay_gauge_and_the_declaration():
         MODEL_REGISTRY["kimi_linear"](**{**a, "kda_layers": (1, 2, 3, 4)})
     with pytest.raises(ValueError, match="exactly one"):
         MODEL_REGISTRY["kimi_linear"](**{**a, "full_attn_layers": ()})
+
+
+def test_the_pair_subchunk_counter_counts_what_numpy_counts():
+    """Every KDA layer's decay raised 400-fold (``A_log`` + log 400), so
+    channels fall by more than ``LIMIT`` across half a sub-chunk: the
+    step's ``kda.pair_subchunks`` is the sum over sequences and KDA layers
+    of what numpy counts from the reference's own gates, at the head block
+    the kernels' rule picks."""
+    from test_kda import _numpy_pairs
+    from paddlebox_tpu.ops import kda as kd
+    cfg, ref, model, params, pulled, ids = tower(CELL, 1)
+    a = cfg["model_args"]
+    params = {**params, "layers": [
+        {**p, "A_log": p["A_log"] + np.log(400.0)} if "A_log" in p else p
+        for p in params["layers"]]}
+    mask = jnp.ones(ids.shape, bool)
+    labels = jnp.zeros((ids.shape[0],))
+    stats = jax.jit(lambda p, x, ids: model.loss(p, x, mask, None, labels,
+                                                 ids)[2])(params, pulled, ids)
+    got = dict(zip(model.stat_names, np.asarray(stats)))
+    H, K = a["kda_num_heads"], a["kda_head_dim"]
+    C = min(a["kda_chunk"], a["seq_len"])
+    c = min(kd.SUB, C)
+    hb = kd.kda_head_block(H, C, K, K)
+    want = 0
+    with jax.default_matmul_precision("highest"):
+        for e in pulled[..., 3:]:
+            h = e
+            for p, kind, dense in zip(params["layers"], model.kinds,
+                                      [i < model.dense_layers for i in
+                                       range(len(model.kinds))]):
+                if kind == "kda":
+                    log_a, _ = ref._kda_gates(
+                        p, rms_norm(h, p["attn_norm"], model.eps), a)
+                    want += _numpy_pairs(
+                        jnp.transpose(log_a, (1, 0, 2))[None], C, c, hb)
+                h = ref._layer(p, h, kind, dense, a)
+    assert want > 0
+    assert got["kda.pair_subchunks"] == want
 
 
 def test_eight_shares_and_the_shared_expert_add_up_to_the_uncut_layer():
@@ -211,3 +252,5 @@ def test_two_passes_train_and_their_statistics_reach_the_flight_record(
     assert 0 < st["moe.held_assignments"] < st["moe.assignments"]
     # the gauge is a pass's least step: below zero, and no counter
     assert followed["snapshot"]["kda.chunk_decay_log_min"] < 0
+    # a counter: no sub-chunk of the initial weights' steps fell far
+    assert st["kda.pair_subchunks"] == 0
